@@ -191,14 +191,10 @@ def build_forward(
         ) from None
     # Persistent XLA compile cache (the prebuilt-binaries analogue), wired
     # at build time so EVERY builder caller — tuner candidates included —
-    # gets it, not just the run/bench entry mains. Never fatal: a read-only
-    # FS degrades to uncached compiles.
-    try:
-        from .utils.compile_cache import enable_persistent_cache
+    # gets it, not just the run/bench entry mains.
+    from .utils.compile_cache import enable_persistent_cache
 
-        enable_persistent_cache()
-    except Exception:
-        pass
+    enable_persistent_cache()
     if pol.quantized:
         if exec_cfg.model != "blocks12" or exec_cfg.strategy not in (
             "single", "halo", "staged_halo", "replicated"
@@ -217,7 +213,7 @@ def build_forward(
             # Sharded int8w rungs: int8 values + per-channel scales ride the
             # replicated param tree; each rung is expected to re-screen via
             # precision.gate.ToleranceGate.screen_sharded before its rows
-            # publish (scripts/on_heal.sh wires this on-chip).
+            # publish.
             need = n_shards
             if mesh is None and jax.device_count() < need:
                 raise ValueError(

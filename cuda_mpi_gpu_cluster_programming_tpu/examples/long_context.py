@@ -136,6 +136,8 @@ def main(argv=None) -> int:
     if args.verify:
         want = np.asarray(attention(q, k, v, causal=args.causal), np.float32)
         delta = float(np.max(np.abs(want - np.asarray(out, np.float32))))
+        # fp32 inputs multiply at true fp32 in both engines and in the
+        # reference (ops.reference.mxu_precision), on the MXU as on the CPU.
         tol = 1e-4 if args.dtype == "fp32" else 3e-2
         ok = delta <= tol
         print(f"Verification: max|delta| = {delta:.2e} (tol {tol:.0e}) -> "

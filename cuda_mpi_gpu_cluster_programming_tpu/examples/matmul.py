@@ -33,9 +33,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..parallel.compat import shard_map
 from ..parallel.mesh import make_mesh
 
 MAXDIM = 1 << 12  # 4096 (template.c:20)
@@ -119,12 +119,9 @@ def _build_ring(mesh: Mesh, axis: str):
 
         # The carry must be marked device-varying over the mesh axis up front
         # (the ppermute output is), or the fori_loop carry types mismatch.
-        # parallel.compat.to_varying: lax.pcast where available, identity on
-        # releases whose rep system has no varying annotation.
-        from ..parallel.compat import to_varying
-
-        acc = to_varying((axis,))(
-            jnp.zeros((a_blk.shape[0], b_blk.shape[1]), a_blk.dtype)
+        acc = jax.lax.pcast(
+            jnp.zeros((a_blk.shape[0], b_blk.shape[1]), a_blk.dtype),
+            (axis,), to="varying",
         )
         acc, _ = jax.lax.fori_loop(0, n_shards, step, (acc, b_blk))
         return acc

@@ -72,7 +72,7 @@ def make_parser() -> argparse.ArgumentParser:
         "--fail-on-regression",
         action="store_true",
         help="exit 3 when any >threshold regression survives echo "
-        "exclusion — the CI gate mode (tier-1 + on_heal.sh wiring)",
+        "exclusion — the CI gate mode",
     )
     rp.add_argument(
         "--json",
@@ -152,8 +152,8 @@ def make_parser() -> argparse.ArgumentParser:
         "--live",
         action="store_true",
         help="measure a per-stage breakdown NOW (observability.stages on "
-        "the current backend) and attribute it — CPU runs are judged "
-        "against an assumed spec, and say so",
+        "the current backend) and attribute it — TPU only: a device "
+        "outside the spec table has no roof to judge against",
     )
     rf.add_argument("--batch", type=int, default=4, help="live batch size")
     rf.add_argument(
@@ -194,7 +194,7 @@ def make_parser() -> argparse.ArgumentParser:
         "--fail-on-budget-burn",
         action="store_true",
         help="exit 3 when any SLO class has burned through its error "
-        "budget (burn > 1.0) — the on_heal.sh chip-time gate mode",
+        "budget (burn > 1.0) — the gate mode",
     )
     return p
 
@@ -390,8 +390,16 @@ def _roofline_main(args) -> int:
         from .roofline import attribute_roofline
         from .stages import attribute_stages
 
+        from .specs import UnknownDeviceError, spec_for
+
         import dataclasses as _dc
 
+        device = jax.devices()[0]
+        try:
+            spec_for(device.device_kind)  # before spending the measurement
+        except UnknownDeviceError as e:
+            print(f"roofline --live: {e}", file=sys.stderr)
+            return 2
         cfg = _dc.replace(
             BLOCKS12, in_height=args.height, in_width=args.width
         )
@@ -403,7 +411,6 @@ def _roofline_main(args) -> int:
             repeats=args.repeats,
             warmup=1,
         )
-        device = jax.devices()[0]
         rep = attribute_roofline(
             dict(att.stages),
             dtype=args.dtype,

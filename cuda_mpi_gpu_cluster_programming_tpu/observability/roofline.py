@@ -293,7 +293,6 @@ class RooflineReport:
     batch: int
     device: str  # spec name the verdicts are judged against
     device_kind: str  # what jax reported (or the row carried)
-    spec_assumed: bool  # True = no spec matched; v5e default stands in
     peak_tflops: float
     hbm_gbps: float
     ridge_intensity: float  # FLOP/byte where the roofs cross
@@ -314,7 +313,6 @@ class RooflineReport:
             "batch": self.batch,
             "device": self.device,
             "device_kind": self.device_kind,
-            "spec_assumed": self.spec_assumed,
             "peak_tflops": self.peak_tflops,
             "hbm_gbps": self.hbm_gbps,
             "ridge_intensity": round(self.ridge_intensity, 2),
@@ -338,7 +336,6 @@ class RooflineReport:
         """The ranked stage table (the CLI's text face)."""
         hdr = (
             f"roofline [{self.dtype} b={self.batch} {self.device}"
-            f"{' (assumed spec)' if self.spec_assumed else ''}"
             f" peak={self.peak_tflops:g}TF/s hbm={self.hbm_gbps:g}GB/s"
             f" ridge_ai={self.ridge_intensity:.0f}]"
         )
@@ -423,11 +420,13 @@ def attribute_roofline(
 
     ``peak_override`` lets a bench row's own ``assumed_peak_tflops``
     govern (the row must reproduce its committed MFU from its own
-    fields); otherwise the spec table (+ env overrides) decides.
+    fields); otherwise the spec table decides. A ``device_kind`` the
+    table does not know raises ``specs.UnknownDeviceError`` — a CPU run
+    is not judged against an assumed chip.
     ``pass_img_s`` computes the whole-pass MFU the conventional way
     (img/s x matmul FLOPs per image / peak) — exactly bench's formula.
     """
-    spec, assumed = spec_for(device_kind)
+    spec = spec_for(device_kind)
     peak = (
         float(peak_override)
         if peak_override
@@ -524,7 +523,6 @@ def attribute_roofline(
         batch=batch,
         device=spec.name,
         device_kind=device_kind or "",
-        spec_assumed=assumed,
         peak_tflops=peak,
         hbm_gbps=bw,
         ridge_intensity=ridge,

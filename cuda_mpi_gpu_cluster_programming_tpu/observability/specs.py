@@ -11,11 +11,12 @@ compute-vs-HBM-bound classification, so a bench row's ``assumed_peak``
 and a roofline verdict can never disagree about what the chip can do.
 
 Numbers come from the public TPU spec sheets, matched against jax's
-``device_kind`` string exactly the way ``bench.py`` always has ("v5"
-matches the "TPU v5 lite" spelling v5e reports). Per-dtype peaks:
+``device_kind`` string ("v5" matches the "TPU v5 lite" spelling v5e
+reports). A device that is not in the table is an error
+(:class:`UnknownDeviceError`), never a default: a CPU run gets no MFU at
+all rather than one judged against an assumed chip. Per-dtype peaks:
 
-- ``bf16`` — the MXU peak from the table (``BENCH_PEAK_TFLOPS``
-  overrides, same contract as the bench headline).
+- ``bf16`` — the MXU peak from the table.
 - ``fp32`` — ``bf16 / 6``: ``lax.Precision.HIGHEST`` synthesizes true
   fp32 MACs out of 6 bf16 MXU passes (the ``fp32_ceiling_fraction``
   convention bench rows already carry).
@@ -26,15 +27,14 @@ matches the "TPU v5 lite" spelling v5e reports). Per-dtype peaks:
   is still recorded on the spec for reference.
 
 Stdlib-only at module scope (bench imports this before jax exists);
-:func:`device_memory_stats` imports jax lazily and degrades to a
-process-RSS reading so the ``mem_snapshot`` telemetry record always has
-something truthful to say (``source`` names which reading it is).
+:func:`device_memory_stats` imports jax lazily. On the CPU backend, which
+exposes no ``memory_stats()``, it reports the process RSS and says so
+(``source`` names which reading it is).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import os
 from typing import List, Optional, Tuple
 
 # lax.Precision.HIGHEST fp32 synthesis: 6 bf16 MXU passes per fp32 MAC.
@@ -78,43 +78,32 @@ SPEC_TABLE: Tuple[DeviceSpec, ...] = (
     DeviceSpec("v2", "TPU v2", 45.0, 700.0, None),
 )
 
-# Unknown kind (CPU containers, exotic relays): assume the chip we
-# actually develop on — callers surface the ``assumed`` bit visibly.
-DEFAULT_SPEC = SPEC_TABLE[2]
+class UnknownDeviceError(ValueError):
+    """``device_kind`` matches no row of :data:`SPEC_TABLE`."""
 
 
-def spec_for(device_kind: str) -> Tuple[DeviceSpec, bool]:
-    """``(spec, assumed)`` for a jax ``device_kind`` string. ``assumed``
-    is True when the kind matched nothing and the v5e default stands in
-    (a CPU mesh judged against an assumed chip must SAY so)."""
+def spec_for(device_kind: str) -> DeviceSpec:
+    """The spec row for a jax ``device_kind`` string; raises
+    :class:`UnknownDeviceError` when the kind matches nothing."""
     kind = (device_kind or "").lower()
     for spec in SPEC_TABLE:
         if spec.marker in kind:
-            return spec, False
-    return DEFAULT_SPEC, True
+            return spec
+    raise UnknownDeviceError(
+        f"device kind {device_kind!r} is not in the spec table "
+        f"({', '.join(s.name for s in SPEC_TABLE)}): no peak to judge against"
+    )
 
 
 def peak_tflops(device_kind: str, dtype: str = "bf16") -> float:
     """Peak TFLOP/s for ``device_kind`` under this repo's ``dtype``
-    policies. ``BENCH_PEAK_TFLOPS`` overrides the bf16 MXU peak (the
-    historical bench contract); the fp32 ceiling scales with it."""
-    env = os.environ.get("BENCH_PEAK_TFLOPS")
-    if env:
-        bf16 = float(env)
-    else:
-        spec, _assumed = spec_for(device_kind)
-        bf16 = spec.bf16_tflops
-    return bf16 / FP32_SYNTH_FACTOR if dtype == "fp32" else bf16
+    policies."""
+    return spec_for(device_kind).peak_tflops(dtype)
 
 
 def hbm_gbps(device_kind: str) -> float:
-    """HBM bandwidth (GB/s) for ``device_kind``; ``BENCH_PEAK_HBM_GBPS``
-    overrides (the bandwidth twin of ``BENCH_PEAK_TFLOPS``)."""
-    env = os.environ.get("BENCH_PEAK_HBM_GBPS")
-    if env:
-        return float(env)
-    spec, _assumed = spec_for(device_kind)
-    return spec.hbm_gbps
+    """HBM bandwidth (GB/s) for ``device_kind``."""
+    return spec_for(device_kind).hbm_gbps
 
 
 def bf16_peak_table() -> List[Tuple[str, float]]:
@@ -129,58 +118,48 @@ def bf16_peak_table() -> List[Tuple[str, float]]:
 def device_memory_stats() -> dict:
     """One resource snapshot for the ``mem_snapshot`` journal record.
 
-    Prefers jax's per-device ``memory_stats()`` (``source="device"``:
+    jax's per-device ``memory_stats()`` (``source="device"``:
     bytes_in_use / peak_bytes_in_use / bytes_limit summed over local
-    devices, with the per-device list alongside); on backends that
-    expose none (the CPU container) it degrades to the process max-RSS
-    (``source="rss"``) so the telemetry lane never goes silent — the
-    record always says which reading it carries.
+    devices, with the per-device list alongside). The CPU backend exposes
+    none, so there the record carries the process max-RSS
+    (``source="rss"``); a TPU that reports no stats is an error.
     """
-    try:
-        import jax
+    import jax
 
-        devices = []
-        for d in jax.local_devices():
-            getter = getattr(d, "memory_stats", None)
-            stats = getter() if callable(getter) else None
-            if isinstance(stats, dict) and stats.get("bytes_in_use") is not None:
-                devices.append(
-                    {
-                        "device": getattr(d, "id", len(devices)),
-                        "bytes_in_use": int(stats["bytes_in_use"]),
-                        "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
-                        "bytes_limit": stats.get("bytes_limit"),
-                    }
-                )
-        if devices:
-            def _total(field: str) -> Optional[int]:
-                vals = [d.get(field) for d in devices]
-                nums = [v for v in vals if isinstance(v, (int, float))]
-                return int(sum(nums)) if nums else None
+    devices = []
+    for d in jax.local_devices():
+        stats = d.memory_stats()
+        if stats and stats.get("bytes_in_use") is not None:
+            devices.append(
+                {
+                    "device": d.id,
+                    "bytes_in_use": int(stats["bytes_in_use"]),
+                    "peak_bytes_in_use": stats.get("peak_bytes_in_use"),
+                    "bytes_limit": stats.get("bytes_limit"),
+                }
+            )
+    if devices:
+        def _total(field: str) -> Optional[int]:
+            nums = [
+                d[field] for d in devices if isinstance(d[field], (int, float))
+            ]
+            return int(sum(nums)) if nums else None
 
-            return {
-                "source": "device",
-                "bytes_in_use": _total("bytes_in_use"),
-                "peak_bytes_in_use": _total("peak_bytes_in_use"),
-                "bytes_limit": _total("bytes_limit"),
-                "devices": devices,
-            }
-    except Exception:  # backend quirks must never break the dispatch loop
-        pass
-    try:
-        import resource
-
-        rss_kb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
         return {
-            "source": "rss",
-            "bytes_in_use": int(rss_kb) * 1024,  # linux reports KB
-            "peak_bytes_in_use": None,
-            "bytes_limit": None,
+            "source": "device",
+            "bytes_in_use": _total("bytes_in_use"),
+            "peak_bytes_in_use": _total("peak_bytes_in_use"),
+            "bytes_limit": _total("bytes_limit"),
+            "devices": devices,
         }
-    except Exception:
-        return {
-            "source": "none",
-            "bytes_in_use": None,
-            "peak_bytes_in_use": None,
-            "bytes_limit": None,
-        }
+    if jax.default_backend() == "tpu":
+        raise RuntimeError("TPU devices reported no memory_stats()")
+    import resource
+
+    rss_kb = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss
+    return {
+        "source": "rss",
+        "bytes_in_use": int(rss_kb) * 1024,  # linux reports KB
+        "peak_bytes_in_use": None,
+        "bytes_limit": None,
+    }

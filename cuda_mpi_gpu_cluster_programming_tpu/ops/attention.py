@@ -16,6 +16,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .reference import mxu_precision
+
 NEG_INF = -1e30  # finite mask value: keeps running-max math NaN-free
 
 
@@ -30,11 +32,14 @@ def attention(
     b, lq, h, d = q.shape
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
     # (B, H, Lq, Lk) scores in fp32.
-    s = jnp.einsum("blhd,bmhd->bhlm", q.astype(jnp.float32), k.astype(jnp.float32)) * scale
+    prec = mxu_precision(q.dtype)
+    s = jnp.einsum(
+        "blhd,bmhd->bhlm", q.astype(jnp.float32), k.astype(jnp.float32), precision=prec
+    ) * scale
     if causal:
         lk = k.shape[1]
         mask = jnp.arange(lq)[:, None] >= jnp.arange(lk)[None, :]
         s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bhlm,bmhd->blhd", p, v.astype(jnp.float32))
+    out = jnp.einsum("bhlm,bmhd->blhd", p, v.astype(jnp.float32), precision=prec)
     return out.astype(q.dtype)

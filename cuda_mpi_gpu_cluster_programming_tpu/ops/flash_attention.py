@@ -35,11 +35,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .attention import NEG_INF
-from .vma import vma_struct as _vma_struct
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from .reference import mxu_precision
+from .vma import interpret_mode as _interpret, vma_struct as _vma_struct
 
 
 def _spec(block_shape, index_map):
@@ -85,8 +82,10 @@ def _fwd_kernel(
         q = q_ref[0, 0].astype(jnp.float32) * scale  # (bq, D)
         k_blk = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
         v_blk = v_ref[0, 0].astype(jnp.float32)
+        prec = mxu_precision(q_ref.dtype)
         s = lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q, k_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
         )  # (bq, bk)
         if causal:
             s = _causal_mask(s, qi, ki, bq, bk)
@@ -97,7 +96,8 @@ def _fwd_kernel(
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, None])  # (bq, bk)
         acc_sc[...] = acc_sc[...] * corr[:, None] + lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p, v_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
         )
         den_new = den_prev * corr + jnp.sum(p, axis=-1)
         m_sc[...] = jnp.broadcast_to(m_new[:, None], m_sc.shape)
@@ -174,7 +174,8 @@ def _recompute_p(q_ref, k_ref, lse_ref, qi, ki, bq, bk, causal, scale):
     q = q_ref[0, 0].astype(jnp.float32) * scale
     k_blk = k_ref[0, 0].astype(jnp.float32)
     s = lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, k_blk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=mxu_precision(q_ref.dtype),
     )
     if causal:
         s = _causal_mask(s, qi, ki, bq, bk)
@@ -200,13 +201,15 @@ def _dq_kernel(
         p = _recompute_p(q_ref, k_ref, lse_ref, qi, ki, bq, bk, causal, scale)
         do = do_ref[0, 0].astype(jnp.float32)  # (bq, D)
         v_blk = v_ref[0, 0].astype(jnp.float32)  # (bk, D)
+        prec = mxu_precision(q_ref.dtype)
         dp = lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            do, v_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
         )  # (bq, bk)
         ds = p * (dp - delta_ref[0, 0, 0][:, None])  # (bq, bk)
         dq_sc[...] += scale * lax.dot_general(
             ds, k_ref[0, 0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=prec,
         )
 
     @pl.when(ki == nk - 1)
@@ -235,16 +238,19 @@ def _dkv_kernel(
         p = _recompute_p(q_ref, k_ref, lse_ref, qi, ki, bq, bk, causal, scale)
         do = do_ref[0, 0].astype(jnp.float32)  # (bq, D)
         v_blk = v_ref[0, 0].astype(jnp.float32)
+        prec = mxu_precision(q_ref.dtype)
         dv_sc[...] += lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
         )  # (bk, D)
         dp = lax.dot_general(
-            do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            do, v_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=prec,
         )  # (bq, bk)
         ds = p * (dp - delta_ref[0, 0, 0][:, None])
         dk_sc[...] += scale * lax.dot_general(
             ds, q_ref[0, 0].astype(jnp.float32), (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=prec,
         )  # (bk, D)
 
     @pl.when(qi == nq - 1)

@@ -32,11 +32,12 @@ In-kernel structure per program (one image; grid over batch only):
 
 Off-TPU the kernel runs in Pallas interpreter mode like every kernel in
 ``pallas_kernels`` — CPU tests hold fp32/bf16 outputs bitwise equal to the
-staged Pallas chain (tests/test_megakernel.py). On-chip lowering of the
-in-register W-axis swap is the open Mosaic risk; per repo precedent
-(g8, hpool) the first on-chip proof + A/B rides ``scripts/on_heal.sh``'s
-gated megakernel step, and the autotuner only selects the fused candidate
-where it measures faster under a ToleranceGate pass.
+staged Pallas chain (tests/test_megakernel.py). On the chip both blocks
+lower through Mosaic — the in-register W-axis swap included — and agree
+with the fp32 XLA forward inside the precision gate at b=128, 227x227,
+fp32 and bf16 (v5e, 2026-09-26, PR 21; compiled and compared, not timed).
+The autotuner only selects the fused candidate where it measures faster
+under a ToleranceGate pass.
 """
 
 from __future__ import annotations

@@ -44,38 +44,25 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .vma import vma_struct
-
-try:  # pltpu is importable on CPU; only used for memory-space hints
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from .reference import mxu_precision as _mxu_precision
+from .vma import interpret_mode as _interpret, vma_struct
 
 
 def _tc_params(*semantics: str):
     """Grid dimension semantics for the Mosaic scheduler ('parallel' grid
     dims let it pipeline DMA against compute across programs). None in
     interpreter mode, where CompilerParams is ignored anyway."""
-    if pltpu is None or _interpret():
+    if _interpret():
         return None
     return pltpu.CompilerParams(dimension_semantics=tuple(semantics))
 
 
 def _vmem_spec(block_shape=None, index_map=None):
-    kw = {}
-    if _VMEM is not None:
-        kw["memory_space"] = _VMEM
     if block_shape is None:
-        return pl.BlockSpec(**kw)
-    return pl.BlockSpec(block_shape, index_map, **kw)
+        return pl.BlockSpec(memory_space=pltpu.VMEM)
+    return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
 def env_variant(env_name: str, default: str, allowed: tuple) -> str:
@@ -122,9 +109,11 @@ def env_variant(env_name: str, default: str, allowed: tuple) -> str:
 #             to vcol): space-to-depth at g=2s puts g*g*C channels on the
 #             lanes (conv1: 192 vs 48) and computes the 2x2 output phases
 #             on separate grid programs (see _conv_g8_kernel). Round-5
-#             named lever targeting conv1's measured data-movement bound;
-#             coded + CPU-verified against a wedged chip, on-chip
-#             lowering proof and A/B queued in scripts/on_heal.sh.
+#             named lever targeting conv1's measured data-movement bound.
+#             Lowers through Mosaic and agrees with the fp32 XLA forward
+#             inside the precision gate at b=128, fp32 and bf16 (v5e,
+#             2026-09-26, PR 21 — after its output store stopped going
+#             through a memref slice Mosaic refused); never timed.
 def _conv_variant() -> str:
     return env_variant("TPU_FRAMEWORK_CONV", "vcol", ("taps", "pairs", "fused", "vcol", "g8"))
 
@@ -282,17 +271,12 @@ class LayerVariants(NamedTuple):
         return self.default
 
 
-def _mxu_precision(dtype):
-    """fp32 inputs: HIGHEST = true fp32 MACs on the MXU (the default would
-    round the operands to bf16 and miss the reference numerics by ~1e-3
-    rel). bf16 inputs: native bf16 MACs, fp32 accumulation."""
-    return lax.Precision.HIGHEST if dtype == jnp.float32 else lax.Precision.DEFAULT
-
-
 def _conv_epilogue(acc, b_ref, o_ref, *, bh: int, wo_p: int, k: int, relu: bool,
-                   hpool=None):
+                   hpool=None, out_index=(0,)):
     """Shared bias + optional-ReLU + cast tail of both conv variants —
     one place, so the variants cannot diverge numerically in the epilogue.
+    ``out_index``: the leading unit dims of ``o_ref`` the (bh, wo_p, k)
+    result is stored under.
 
     ``hpool=(window, stride, hp_o)`` (round-5 fusion lever): additionally
     max-pool the H axis in-kernel before the write, so the full-height
@@ -322,7 +306,7 @@ def _conv_epilogue(acc, b_ref, o_ref, *, bh: int, wo_p: int, k: int, relu: bool,
             win = u[q : q + hp_o, p]
             res = win if res is None else jnp.maximum(res, win)
         out = res
-    o_ref[0] = out
+    o_ref[out_index] = out
 
 
 def _conv_fused_kernel(x_ref, w_ref, b_ref, o_ref, *, bh: int, wo_p: int, relu: bool):
@@ -503,10 +487,14 @@ def _conv_g8_kernel(x_ref, w_ref, b_ref, o_ref, *, fq8: int, bh: int, wo_p: int,
             preferred_element_type=jnp.float32,
             precision=prec,
         )
-    # Shared epilogue via a phase sub-ref (o_ref.at[0, 0] drops the two
-    # leading unit dims so _conv_epilogue's o_ref[0] write lands on
-    # [0, 0, 0]) — the one-place invariant holds across all variants.
-    _conv_epilogue(acc, b_ref, o_ref.at[0, 0], bh=bh, wo_p=wo_p, k=k, relu=relu)
+    # Shared epilogue — the one-place invariant holds across all variants.
+    # The store indexes the three leading unit dims directly: a phase
+    # sub-ref (o_ref.at[0, 0]) is a memref slice, which Mosaic refuses for
+    # K=96 ("Slice shape along dimension 5 must be aligned to tiling (128),
+    # but is 96" — v5e, 2026-09-26).
+    _conv_epilogue(
+        acc, b_ref, o_ref, bh=bh, wo_p=wo_p, k=k, relu=relu, out_index=(0, 0, 0)
+    )
 
 
 def _weights_to_phase_depth(w: jax.Array, s: int, g: int, fq8: int) -> jax.Array:
@@ -1021,29 +1009,39 @@ def maxpool_pallas_w(x: jax.Array, *, window: int, stride: int, vma=None) -> jax
     return jnp.swapaxes(z, 1, 2)
 
 
+# Pixels per LRN program. The op is pointwise over (H, W) and its band
+# matmul is the only place a block's row count could reach the numerics
+# (XLA:CPU picks its dot strategy, and so its summation order, by M), so
+# every program multiplies one (LRN_ROWS, C) tile whatever the image or
+# shard height: a shard of 2 rows and the whole image run the same dot.
+LRN_ROWS = 128
+
+
 def _lrn_kernel(x_ref, o_ref, *, size: int, alpha: float, beta: float, k: float, alpha_over_size: bool):
-    """Cross-channel LRN; the channel-window sum of squares is a banded
-    0/1-matrix matmul on the MXU — no lane-dimension slicing, and the band
-    edges implement the reference's window truncation exactly."""
+    """Cross-channel LRN over one (LRN_ROWS, C) tile of pixels; the
+    channel-window sum of squares is a banded 0/1-matrix matmul on the MXU
+    — no lane-dimension slicing, and the band edges implement the
+    reference's window truncation exactly."""
     # All math in fp32 regardless of the activation dtype: the band matmul
     # must be dtype-homogeneous (Mosaic rejects a bf16 lhs against the f32
     # band — "Bad lhs type"), and the scale/power path is precision-critical.
-    x = x_ref[0].astype(jnp.float32)  # (H, W, C)
-    h, w, c = x.shape
+    x = x_ref[0].astype(jnp.float32)  # (LRN_ROWS, C)
+    c = x.shape[-1]
     half = size // 2
     ci = lax.broadcasted_iota(jnp.int32, (c, c), 0)
     cj = lax.broadcasted_iota(jnp.int32, (c, c), 1)
     band = (jnp.abs(ci - cj) <= half).astype(jnp.float32)
-    sq = (x * x).reshape(h * w, c)
     ssum = jnp.dot(
-        sq, band, preferred_element_type=jnp.float32, precision=lax.Precision.HIGHEST
-    ).reshape(h, w, c)
+        x * x, band, preferred_element_type=jnp.float32, precision=lax.Precision.HIGHEST
+    )
     a = alpha / size if alpha_over_size else alpha
     scale = k + a * ssum
     o_ref[0] = (x / scale**beta).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("size", "alpha", "beta", "k", "alpha_over_size"))
+@functools.partial(
+    jax.jit, static_argnames=("size", "alpha", "beta", "k", "alpha_over_size", "vma")
+)
 def lrn_pallas(
     x: jax.Array,
     *,
@@ -1052,20 +1050,30 @@ def lrn_pallas(
     beta: float,
     k: float,
     alpha_over_size: bool = False,
+    vma=None,
 ) -> jax.Array:
+    """Cross-channel LRN. ``vma``: see ops.vma (a tuple, for the jit).
+
+    The pixels are flattened to (N, H*W, C) and zero-padded to whole
+    LRN_ROWS tiles (a zero pixel normalises to zero and is sliced off), so
+    the result for a pixel does not depend on how many rows came with it."""
     n, h, wdt, c = x.shape
+    m = h * wdt
+    tiles = pl.cdiv(m, LRN_ROWS)
+    xp = jnp.pad(x.reshape(n, m, c), ((0, 0), (0, tiles * LRN_ROWS - m), (0, 0)))
     kernel = functools.partial(
         _lrn_kernel, size=size, alpha=alpha, beta=beta, k=k, alpha_over_size=alpha_over_size
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=(n,),
-        in_specs=[_vmem_spec((1, h, wdt, c), lambda i: (i, 0, 0, 0))],
-        out_specs=_vmem_spec((1, h, wdt, c), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        compiler_params=_tc_params("parallel"),
+        grid=(n, tiles),
+        in_specs=[_vmem_spec((1, LRN_ROWS, c), lambda i, j: (i, j, 0))],
+        out_specs=_vmem_spec((1, LRN_ROWS, c), lambda i, j: (i, j, 0)),
+        out_shape=vma_struct(xp.shape, x.dtype, vma),
+        compiler_params=_tc_params("parallel", "parallel"),
         interpret=_interpret(),
-    )(x)
+    )(xp)
+    return out[:, :m].reshape(n, h, wdt, c)
 
 
 def relu_pallas(x: jax.Array) -> jax.Array:

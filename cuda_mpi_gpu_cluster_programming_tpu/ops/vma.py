@@ -1,4 +1,5 @@
-"""Varying-mesh-axes tagging for pallas_call out_shapes.
+"""Pallas interpret-mode switch and varying-mesh-axes tagging for
+pallas_call out_shapes.
 
 ``pallas_call`` outputs carry no vma metadata, so a ``shard_map`` caller
 with ``check_vma=True`` rejects any body containing a kernel — which
@@ -11,13 +12,14 @@ checker on end to end.
 
 from __future__ import annotations
 
-import os
-
 import jax
 
 
 def interpret_mode() -> bool:
-    """Pallas interpret mode — same rule as every kernel's ``_interpret``."""
+    """Whether Pallas kernels run interpreted: everywhere but on a TPU,
+    where they lower through Mosaic. The ONE place this is decided — every
+    kernel wrapper, the vma tagging below and the tuner's candidate space
+    read it."""
     return jax.default_backend() != "tpu"
 
 
@@ -39,19 +41,8 @@ def vma_struct(shape, dtype, vma=None) -> jax.ShapeDtypeStruct:
 
 def kernel_check_vma() -> bool:
     """``check_vma`` value for shard_map bodies containing Pallas kernels:
-    True on real TPU (kernels tag their out_shapes via :func:`vma_struct`,
-    so the checker guards the body's collectives end to end — the scoped
-    fix for the round-3 advisor finding), False in interpret mode (see
-    :func:`vma_struct`; revisit when jax's interpreter propagates vma).
-
-    ``TPU_FRAMEWORK_CHECK_VMA=0|1`` overrides — the operational
-    kill-switch: the on-TPU tagged path cannot run in CI (interpret mode
-    always drops the tags), so its first execution happens inside a
-    scarce heal window; scripts/on_heal.sh probes it with a tiny tagged
-    shard_map first and exports =0 for the rest of the queue if the
-    chip-side checker rejects anything, instead of burning the capture.
-    """
-    env = os.environ.get("TPU_FRAMEWORK_CHECK_VMA", "").strip()
-    if env in ("0", "1"):
-        return env == "1"
+    True on TPU (kernels tag their out_shapes via :func:`vma_struct`, so
+    the checker guards the body's collectives end to end), False in
+    interpret mode (see :func:`vma_struct`; revisit when jax's interpreter
+    propagates vma)."""
     return not interpret_mode()

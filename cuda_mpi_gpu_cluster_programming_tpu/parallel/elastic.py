@@ -64,8 +64,8 @@ class ElasticPool:
     ``alive()`` filters the CURRENT ``jax.devices()`` against the lost-id
     set rather than caching a device list — the pool owns the *exclusions*,
     the runtime owns the *roster*, so a rebuild after any runtime-side
-    change (a healed tunnel re-enumerating, a restarted backend) sees the
-    truth of that moment.
+    change (a device re-enumerating, a restarted backend) sees the truth
+    of that moment.
     """
 
     def __init__(
@@ -137,7 +137,7 @@ class ElasticPool:
     def recently_lost(self, k: int) -> List[int]:
         """The k most recently lost ids, most recent first — what a
         ``device_rejoin`` drill heals (the device that just blipped is the
-        one whose tunnel recycles)."""
+        one that comes back)."""
         return list(reversed(self._lost_order))[: max(0, int(k))]
 
     def summary(self) -> str:
@@ -218,7 +218,7 @@ class ElasticPool:
 
     def rejoin_check(self, cause: str = "rejoin_check") -> dict:
         """Re-run the fresh-roster check over every heal still pending —
-        the consumers' between-batches hook (a recycled tunnel may take a
+        the consumers' between-batches hook (a returning device may take a
         while to re-enumerate)."""
         if not self._heal_pending:
             return {"probation": [], "absent": [], "quarantined": []}

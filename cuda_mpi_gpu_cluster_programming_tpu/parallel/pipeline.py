@@ -27,10 +27,9 @@ from typing import Any, Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .compat import shard_map
 from .mesh import make_mesh
 
 Params = Any

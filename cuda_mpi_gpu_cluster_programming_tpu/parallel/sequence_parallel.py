@@ -27,19 +27,20 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import NEG_INF
+from ..ops.reference import mxu_precision
 from ..ops.vma import kernel_check_vma
-from .compat import shard_map, to_varying
 from .mesh import make_mesh
 
 
 def _block_scores(q, k, scale):
     """(B, Lq, H, D) x (B, Lk, H, D) -> fp32 scores (B, H, Lq, Lk)."""
     return jnp.einsum(
-        "blhd,bmhd->bhlm", q.astype(jnp.float32), k.astype(jnp.float32)
+        "blhd,bmhd->bhlm", q.astype(jnp.float32), k.astype(jnp.float32),
+        precision=mxu_precision(q.dtype),
     ) * scale
 
 
@@ -68,7 +69,8 @@ def _ring_attention_local(q, k, v, *, axis_name: str, n_shards: int, causal: boo
         corr = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new[..., None])  # (B, H, Lb, Lb)
         num = num * corr[..., None] + jnp.einsum(
-            "bhlm,bmhd->bhld", p, v_blk.astype(jnp.float32)
+            "bhlm,bmhd->bhld", p, v_blk.astype(jnp.float32),
+            precision=mxu_precision(q.dtype),
         )
         den = den * corr + jnp.sum(p, axis=-1)
         # Circulate K/V one hop: shard i -> shard (i+1) mod n.
@@ -94,13 +96,11 @@ def _ring_attention_local(q, k, v, *, axis_name: str, n_shards: int, causal: boo
 
 
 def _to_varying_fn(axes):
-    # lax.pcast(..., to='varying') is the current spelling; pvary the
-    # deprecated alias; identity on releases without either (their rep
-    # system does not type fori_loop carries as varying). ``axes``: every
-    # mesh axis the loop carry varies over — with a head_axis (sp x tp
-    # composition) the K/V inputs vary over BOTH, and fori_loop demands
-    # carry-in/carry-out type equality. One implementation: parallel.compat.
-    return to_varying(axes)
+    # ``axes``: every mesh axis the loop carry varies over — with a
+    # head_axis (sp x tp composition) the K/V inputs vary over BOTH, and
+    # fori_loop demands carry-in/carry-out type equality.
+    axes = tuple(axes)
+    return lambda a: lax.pcast(a, axes, to="varying")
 
 
 def _ring_attention_local_flash(q, k, v, *, axis_name: str, n_shards: int, causal: bool, vary_axes=None):

@@ -23,17 +23,17 @@ slice; Barrier/Wtime -> block_until_ready + host timing.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.alexnet import BLOCKS12, Blocks12Config
 from ..ops import reference as ops
 from ..ops.vma import kernel_check_vma
-from .compat import shard_map
 from .halo import exchange
 from .mesh import make_mesh
 from .plan import LayerPlan, make_shard_plan
@@ -158,11 +158,10 @@ def build_sharded_forward(
         from ..resilience.sentinel import tree_digest
 
     if tier == "pallas":
-        import functools
-
         from ..ops.pallas_kernels import (
             KernelVariants,
             conv2d_pallas_hvalid,
+            lrn_pallas,
             maxpool_pallas,
         )
 
@@ -195,8 +194,17 @@ def build_sharded_forward(
             if lp.kind == "conv":
                 governing = lv.for_layer(lp.name) if lv is not None else kv
             layer_fns[lp.name] = _fns(governing)
+        # The Pallas tier runs the Pallas LRN per shard too, as the
+        # single-device Pallas forward does: on a TPU the XLA LRN is
+        # last-ulps off it (different window sum and pow), which broke the
+        # within-tier bitwise contract on chips (v5e, PR 21). The int8w
+        # contract keeps the XLA op on fp32 (forward_blocks12_int8w).
+        lrn_fn = (
+            ops.lrn if quantized else functools.partial(lrn_pallas, vma=(AXIS,))
+        )
     else:
         layer_fns = None
+        lrn_fn = ops.lrn
 
     specs = dict(model_cfg.layer_chain())
 
@@ -209,7 +217,7 @@ def build_sharded_forward(
             if lp.kind == "pointwise":
                 # int8w contract: LRN computes in fp32 (squares + pow need
                 # the headroom) — same as forward_blocks12_int8w.
-                cur = ops.lrn(
+                cur = lrn_fn(
                     cur.astype(jnp.float32) if quantized else cur,
                     size=spec.size,
                     alpha=spec.alpha,

@@ -31,12 +31,11 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.alexnet import BLOCKS12, Blocks12Config
 from ..ops.reference import conv2d, lrn, maxpool, relu
-from .compat import shard_map
 from .mesh import make_mesh
 
 

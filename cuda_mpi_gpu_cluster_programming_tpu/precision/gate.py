@@ -7,9 +7,7 @@ precision subsystem. A gate screening runs the candidate policy and the
 fp32 reference THROUGH THE SAME staged forward (per-layer taps at every
 conv/pool/LRN boundary), compares each stage against its budget, and
 journals a ``gate_pass``/``gate_fail`` record — the autotuner refuses to
-let a non-fp32 dtype win (or even be swept) without a pass, and
-``scripts/on_heal.sh`` refuses to publish a tuned non-fp32 headline row
-whose gate fails on-chip.
+let a non-fp32 dtype win (or even be swept) without a pass.
 
 Trust chain: before trusting the on-device fp32 forward as the oracle, the
 gate preflights ``resilience.sentinel.oracle_spot_check`` — the numpy
@@ -276,7 +274,7 @@ class ToleranceGate:
     (missing entries fall back to :data:`DEFAULT_BUDGETS`). ``journal``: a
     ``resilience.journal.Journal`` receiving one fsync'd ``gate_pass`` /
     ``gate_fail`` record per screening — the durable evidence the
-    autotuner's persistence and ``on_heal.sh``'s publish step key on."""
+    autotuner's persistence keys on."""
 
     def __init__(self, budgets=None, journal=None, preflight: bool = True):
         self.budgets = dict(DEFAULT_BUDGETS)

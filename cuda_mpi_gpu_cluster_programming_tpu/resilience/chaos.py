@@ -2,9 +2,9 @@
 
 Enabled via the ``CHAOS_SPEC`` environment variable so every recovery path
 in the resilience subsystem is exercisable on CPU, in-process, in tier-1
-tests — no wedged tunnel required. The spec is a comma-separated list:
+tests — no real fault required. The spec is a comma-separated list:
 
-    CHAOS_SPEC="seed=7,ssh=2,subprocess_wedge=1,collective=p0.5"
+    CHAOS_SPEC="seed=7,ssh=2,kernel_compile=1,collective=p0.5"
 
 - ``seed=N``     — RNG seed for probabilistic sites (default 0).
 - ``<site>=N``   — fail the first N draws at that site, then heal
@@ -67,8 +67,6 @@ fires would report "recovery path exercised" without exercising anything):
                       pre-shedding, with accounting closed both ways.
     kernel_compile    run CLI build step (pallas tier) — Mosaic lowering
                       failure; degrades Pallas -> XLA reference tier.
-    subprocess_wedge  harness.run_case — the classic wedged-tunnel capture
-                      (run "succeeds" with value=0.0 output).
     ssh               parallel.deploy transports — transient ssh exit.
     rsync             parallel.deploy transports — transient rsync exit.
     sdc               train loop — seeded single-bit param corruption
@@ -97,7 +95,6 @@ KNOWN_SITES = (
     "collective",
     "device_loss",
     "kernel_compile",
-    "subprocess_wedge",
     "ssh",
     "rsync",
     "sdc",

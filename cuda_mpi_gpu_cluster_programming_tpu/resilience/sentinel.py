@@ -314,7 +314,7 @@ def cross_replica_digests(x, mesh, axis_name: str) -> np.ndarray:
     digest per shard. Rows that SHOULD be replicas (same logical content per
     shard) must digest identically; ``max - min`` of the result is the
     divergence checksum for the dp/sp/tp paths."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     f = shard_map(

@@ -7,9 +7,18 @@ entry ``python -m cuda_mpi_gpu_cluster_programming_tpu.serving.fleet
 :class:`~.frontend.ServingFrontend` on an ephemeral port and announcing
 readiness with one machine-parsed line::
 
-    FLEET_READY name=b0 port=41231
+    FLEET_READY name=b0 port=41231 platform=tpu ndev=1
 
-so host loss is a process fault, not a thread fault — the drills that
+**One process for each chip.** A TPU chip belongs to one process at a
+time, so on a TPU host the launcher (a) refuses to start from a process
+that has already initialised a JAX backend — that process holds every
+chip and each child would hang opening one — (b) pins child ``i`` to
+local chip ``i`` through libtpu's per-process environment
+(:func:`chip_pin_env`), and (c) refuses ``n`` above the local chip count
+up front. Off TPU (no chip device nodes, the CPU test mesh) children
+inherit the parent's environment unchanged.
+
+So host loss is a process fault, not a thread fault — the drills that
 matter (``host_loss`` chaos SIGKILLs a backend mid-load) exercise a
 kill(2) across a process boundary, the thing every earlier drill
 (device loss, SDC, flap) could not: those all die *inside* one process.
@@ -33,6 +42,7 @@ every other chaos site.
 
 from __future__ import annotations
 
+import glob
 import os
 import subprocess
 import sys
@@ -51,16 +61,72 @@ class FleetError(RuntimeError):
     pass
 
 
+_GOOGLE_PCI_VENDOR = "0x1ae0"
+
+
+def local_tpu_chips(
+    dev: str = "/dev", iommu_groups: str = "/sys/kernel/iommu_groups"
+) -> int:
+    """TPU chips this host hands to its processes, counted without opening
+    one (the launcher must stay off JAX): the ``/dev/accelN`` nodes, or the
+    ``/dev/vfio/N`` nodes whose IOMMU group N holds a Google PCI function.
+    A VFIO group bound to any other device is not a chip, and a chip in
+    sysfs whose group node is not exposed is not this host's to open (the
+    one-chip v5e machine lists four and exposes one)."""
+    accel = glob.glob(f"{dev}/accel[0-9]*")
+    if accel:
+        return len(accel)
+    return sum(
+        any(
+            Path(vendor).read_text().strip() == _GOOGLE_PCI_VENDOR
+            for vendor in glob.glob(
+                f"{iommu_groups}/{Path(node).name}/devices/*/vendor"
+            )
+        )
+        for node in glob.glob(f"{dev}/vfio/[0-9]*")
+    )
+
+
+def jax_backend_initialised() -> bool:
+    """Whether this process has opened a JAX backend (and so holds the
+    host's chips), asked without opening one. jax 0.9.0 has no public
+    spelling; this is the one place the private one is named
+    (tests/test_router.py checks it is still there)."""
+    from jax._src import xla_bridge
+
+    return xla_bridge.backends_are_initialized()
+
+
+def chip_pin_env(index: int) -> Dict[str, str]:
+    """libtpu environment that confines one process to local chip
+    ``index`` as a standalone one-chip slice (own mesh-controller and
+    metrics ports, so sibling processes on the host do not collide)."""
+    return {
+        "TPU_VISIBLE_CHIPS": str(index),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{8476 + index}",
+        "TPU_MESH_CONTROLLER_PORT": str(8476 + index),
+        "TPU_RUNTIME_METRICS_PORTS": str(8431 + index),
+    }
+
+
 class BackendProc:
     """One spawned backend: process handle + announced endpoint."""
 
     def __init__(
-        self, index: int, proc: subprocess.Popen, port: int, journal_path: str
+        self,
+        index: int,
+        proc: subprocess.Popen,
+        ready: Dict[str, str],
+        journal_path: str,
     ):
         self.index = index
         self.name = f"b{index}"
         self.proc = proc
-        self.port = port
+        self.port = int(ready["port"])
+        self.platform = ready.get("platform", "")
+        self.n_devices = int(ready.get("ndev", 0))
         self.journal_path = journal_path
 
     @property
@@ -72,12 +138,13 @@ class BackendProc:
         return self.proc.poll() is None
 
 
-def _read_ready(proc: subprocess.Popen, timeout_s: float) -> int:
-    """Scan child stdout for the READY line (bounded — a backend that
-    never comes up is a spawn failure, not a hang). The scan runs in a
-    helper thread so a wedged child can't block the launcher past its
-    deadline."""
-    found: List[int] = []
+def _read_ready(proc: subprocess.Popen, timeout_s: float) -> Dict[str, str]:
+    """Scan child stdout for the READY line and return its ``key=value``
+    fields (bounded — a backend that never comes up is a spawn failure,
+    not a hang; a child that exits closes its stdout and fails at once).
+    The scan runs in a helper thread so a stuck child can't block the
+    launcher past its deadline."""
+    found: List[Dict[str, str]] = []
     err: List[str] = []
 
     def _scan() -> None:
@@ -85,10 +152,12 @@ def _read_ready(proc: subprocess.Popen, timeout_s: float) -> int:
         for line in proc.stdout:  # type: ignore[union-attr]
             tail.append(line.rstrip()[-200:])
             if line.startswith(READY_PREFIX):
-                for tok in line.split():
-                    if tok.startswith("port="):
-                        found.append(int(tok[5:]))
-                        return
+                fields = dict(
+                    tok.split("=", 1) for tok in line.split()[1:] if "=" in tok
+                )
+                if "port" in fields:
+                    found.append(fields)
+                    return
         err.append("; ".join(tail[-5:]))
 
     t = threading.Thread(target=_scan, daemon=True)
@@ -110,8 +179,9 @@ class BackendFleet:
     ``journal_dir`` receives one ``backend_<i>.jsonl`` per backend (and
     is where callers point the router's own journal, so one directory
     exports as one stitched timeline). Children inherit the environment
-    plus ``JAX_PLATFORMS`` and a PYTHONPATH entry for the repo root, so
-    the fleet spawns correctly from any cwd.
+    plus a PYTHONPATH entry for the repo root, so the fleet spawns
+    correctly from any cwd; on a TPU host each is also pinned to its own
+    chip (module docstring).
     """
 
     def __init__(
@@ -147,6 +217,13 @@ class BackendFleet:
         # real controllers to arbitrate across.
         self.controller = controller
         self.backends: List[Optional[BackendProc]] = [None] * n
+        # Pin one chip per child unless the children are held to the CPU.
+        child_platforms = {**os.environ, **self._extra_env}.get(
+            "JAX_PLATFORMS", ""
+        )
+        self._chips = (
+            0 if child_platforms.split(",")[0] == "cpu" else local_tpu_chips()
+        )
 
     def _spawn(self, index: int) -> BackendProc:
         jpath = str(self.journal_dir / f"backend_{index}.jsonl")
@@ -179,6 +256,8 @@ class BackendFleet:
         # Chaos must not recurse into children: the parent owns the
         # host_loss budget; a child re-drawing it would double-fire.
         env.pop(chaos.CHAOS_ENV, None)
+        if self._chips:
+            env.update(chip_pin_env(index))
         proc = subprocess.Popen(
             cmd,
             stdout=subprocess.PIPE,
@@ -186,13 +265,35 @@ class BackendFleet:
             text=True,
             env=env,
         )
-        port = _read_ready(proc, self.spawn_timeout_s)
-        return BackendProc(index, proc, port, jpath)
+        backend = BackendProc(
+            index, proc, _read_ready(proc, self.spawn_timeout_s), jpath
+        )
+        if self._chips and (
+            backend.platform != "tpu" or backend.n_devices != 1
+        ):
+            proc.kill()
+            raise FleetError(
+                f"backend b{index} was pinned to chip {index} but came up "
+                f"on platform={backend.platform!r} with "
+                f"{backend.n_devices} device(s)"
+            )
+        return backend
 
     def start(self) -> "BackendFleet":
+        if self._chips:
+            if self.n > self._chips:
+                raise FleetError(
+                    f"a fleet of {self.n} backends needs {self.n} local TPU "
+                    f"chips (one process per chip), this host has "
+                    f"{self._chips}"
+                )
+            if jax_backend_initialised():
+                raise FleetError(
+                    "this process has already initialised a JAX backend and "
+                    "holds the host's TPU chips; a backend fleet must be "
+                    "launched from a process that has not touched JAX"
+                )
         self.journal_dir.mkdir(parents=True, exist_ok=True)
-        # Spawn all children first (they warm up concurrently), then
-        # collect READY lines — fleet bring-up costs one warmup, not N.
         for i in range(self.n):
             self.backends[i] = self._spawn(i)
         return self
@@ -314,7 +415,13 @@ def _child_main(argv: List[str]) -> int:
     )
     srv.start()
     fe = ServingFrontend(srv, port=args.port).start()
-    print(f"{READY_PREFIX} name={args.name} port={fe.port}", flush=True)
+    import jax
+
+    print(
+        f"{READY_PREFIX} name={args.name} port={fe.port} "
+        f"platform={jax.default_backend()} ndev={jax.local_device_count()}",
+        flush=True,
+    )
     try:
         while True:  # host loss is SIGKILL; orderly stop is SIGTERM
             time.sleep(3600)

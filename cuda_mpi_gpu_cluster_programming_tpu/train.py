@@ -311,6 +311,9 @@ def main(argv=None) -> int:
         from .utils.env_info import force_virtual_cpu
 
         force_virtual_cpu(args.fake_devices)
+    from .utils.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache()
     import dataclasses
 
     import jax
@@ -522,9 +525,8 @@ def main(argv=None) -> int:
         # here silently exported the INITIAL params).
         first, last, steps_run, student, opt_state = rc
         if sup is not None:
-            # Machine-parseable elastic summary (scripts/on_heal.sh gates
-            # on 'Elastic: .*replays='): rung, trip kinds, replay count,
-            # surviving pool.
+            # Machine-parseable elastic summary: rung, trip kinds, replay
+            # count, surviving pool.
             print(f"Elastic: {sup.summary()}")
             if jr is not None:
                 # One-line fleet-health fold of the work-dir journal

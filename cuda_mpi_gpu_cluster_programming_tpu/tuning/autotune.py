@@ -24,6 +24,7 @@ from typing import Callable, Dict, Optional, Tuple
 
 from ..observability.trace import span as obs_span
 from ..ops.pallas_kernels import KernelVariants
+from ..ops.vma import interpret_mode
 from ..resilience import chaos
 from ..resilience.policy import Deadline
 from .plan import (
@@ -126,12 +127,6 @@ def _default_timer(
     return st.per_call_ms, st.ci95_ms, st.n_samples
 
 
-def _interpret_mode() -> bool:
-    import jax
-
-    return jax.default_backend() != "tpu"
-
-
 def tune_layer(
     g: ConvGeometry,
     *,
@@ -153,7 +148,7 @@ def tune_layer(
     its fp32-oracle screen never spends timing budget and its fate is
     attributable in the plan record (``pruned_reasons``), exactly like a
     geometry prune."""
-    interpret = _interpret_mode() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     default = KernelVariants().bind(g.out_channels)
     pruned: list = []
     cands = candidate_space(

@@ -6,44 +6,37 @@ prebuilt_executables_local/). On TPU the "build" is XLA jit compilation;
 the analogue is JAX's persistent compilation cache: the first run of a
 (program, shape, backend) point pays the full compile, every later process
 — including each harness case subprocess — deserializes the cached
-executable instead (observed: Compile_ms drops from seconds to tens of ms).
+executable instead.
 
-Enabled by default in every entry point (run.py, bench.py, train.py,
-examples). Controls:
-
-- ``TPU_FRAMEWORK_COMPILE_CACHE=<dir>`` — cache location (default
-  ``<repo-root>/.xla_cache``; created on demand, git-ignored).
-- ``TPU_FRAMEWORK_COMPILE_CACHE=0`` (or ``off``/``none``) — disable.
+Enabled by every entry point (run.py, bench.py, train.py, chip_smoke.py,
+and ``configs.build_forward`` for library callers). One way to place it:
+``JAX_COMPILATION_CACHE_DIR``, which JAX reads itself — when it is set this
+module sets no directory. Unset, the cache lives at the fixed
+``<repo-root>/.xla_cache`` (git-ignored; fixed because the directory is part
+of the cache key, so a path that moves never hits).
 """
 
 from __future__ import annotations
 
 import os
 from pathlib import Path
-from typing import Optional
 
-_DISABLE = {"0", "off", "none", "disabled"}
 DEFAULT_DIR = Path(__file__).resolve().parent.parent.parent / ".xla_cache"
 
 
-def enable_persistent_cache(cache_dir: Optional[os.PathLike] = None) -> Optional[Path]:
-    """Point JAX at a persistent on-disk compilation cache.
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
 
     Must be called before the first jit compilation to take effect for it
-    (later calls still apply to subsequent compilations). Returns the cache
-    directory, or None when disabled via the env switch.
+    (later calls still apply to subsequent compilations).
     """
-    env = os.environ.get("TPU_FRAMEWORK_COMPILE_CACHE", "")
-    if env.strip().lower() in _DISABLE:
-        return None
-    path = Path(cache_dir or env or DEFAULT_DIR)
-    path.mkdir(parents=True, exist_ok=True)
-
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", str(path))
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        DEFAULT_DIR.mkdir(parents=True, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", str(DEFAULT_DIR))
     # The workload's jits are small (the whole model compiles in seconds);
     # without floor overrides JAX would skip caching them entirely.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    return path
+    return jax.config.jax_compilation_cache_dir

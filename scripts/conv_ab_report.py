@@ -1,13 +1,13 @@
-"""Summarize the on-heal conv-variant A/B into the PALLAS_PERF lever table.
+"""Summarize a conv-variant A/B log into the PALLAS_PERF lever table.
 
-The heal queue (scripts/on_heal.sh) runs `run.py --config v3_pallas` across
-the lever grid (conv=taps|pairs x rowblock 8|16|32 x kblock 0|128 x
-fp32|bf16) and prefixes each harness-contract stdout line with the combo:
+A chip A/B runs `run.py --config v3_pallas` across the lever grid
+(conv=taps|pairs x rowblock 8|16|32 x kblock 0|128 x fp32|bf16) and
+prefixes each harness-contract stdout line with the combo:
 
     conv=taps rb=8 kb=0 bf16 AlexNet TPU Forward Pass completed in 2.134 ms
     (amortized over 100 fenced passes; 59981.2 img/s)
 
-This script parses those lines out of an on_heal log, ranks combos by
+This script parses those lines out of such a log, ranks combos by
 throughput per compute tier, and emits the markdown table for
 docs/PALLAS_PERF.md plus the adoption verdict against the round-3 bar
 (v3_pallas bf16 >= 0.5x v1_jit at b=128 — VERDICT r3/r4 item 3). The
@@ -15,7 +15,7 @@ v1_jit reference rows come from perf/bench_latest.json (fresh same-session
 numbers; the bar is only meaningful same-chip, same-day).
 
 Usage:
-    python scripts/conv_ab_report.py logs/on_heal_YYYYmmdd_HHMM.log
+    python scripts/conv_ab_report.py <ab_log>
 """
 
 from __future__ import annotations
@@ -27,7 +27,7 @@ from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent.parent
 
-# combo prefix added by on_heal.sh's sed, then the run.py stdout contract.
+# combo prefix added by the A/B driver, then the run.py stdout contract.
 # The optional fuse= prefix carries the round-5 hpool epilogue-fusion A/B
 # rows (fuse=none|hpool conv=vcol rb=64 kb=0 ...).
 _LINE = re.compile(

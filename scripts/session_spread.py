@@ -7,13 +7,13 @@ timing; the round-4 protocol (grow chain to >=100 ms of work, resample to
 ci95 < 5%, MAD CI) claims <10%. This prints per-cell spread
 |t_a - t_b| / mean(t_a, t_b) over cells present in BOTH sessions, flagging
 the sub-3 ms rows the claim is about, and exits 1 if any sub-3 ms cell
-exceeds SPREAD_BAR (default 0.10) so on_heal.sh logs a visible failure.
+exceeds SPREAD_BAR (default 0.10).
 
 Usage: python scripts/session_spread.py [--bar 0.10] [--logs logs]
 Session selection: the two newest ``logs/bench_*`` whose run logs carry a
 ``Devices: ... (tpu)``-style non-cpu backend banner (run.py prints it in
 every case log) — a --fake-devices CPU smoke session landing in logs/
-between heal windows must not be compared against a TPU session. Pass
+must not be compared against a TPU session. Pass
 --sessions A B to pin explicitly (no backend filter then).
 """
 

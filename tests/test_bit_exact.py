@@ -8,7 +8,13 @@ versions were never numerically comparable at all, SURVEY §4.3):
    - XLA-op tier: v2.1_replicated / v2.2_sharded / v7_tp == single-device
      jit(forward_blocks12), np.testing.assert_array_equal.
    - Pallas tier: v4_hybrid / v5_collective == single-device
-     jit(forward_blocks12_pallas), likewise bitwise.
+     jit(forward_blocks12_pallas), likewise bitwise — every layer a Pallas
+     kernel per shard, the LRN included (PR 21: with the XLA LRN in the
+     shard body the two were last-ulps apart on the TPU; chip_smoke.py
+     holds the 4-shard case on the chips). lrn_pallas multiplies fixed
+     (LRN_ROWS, C) pixel tiles, so its band matmul has one shape whatever
+     the shard's row count (interpreted on XLA:CPU, a dot whose M followed
+     the block — 2 or 3 rows at 8 or 5 shards — reassociated by 1 ulp).
 2. ACROSS tiers (Pallas vs XLA-op) outputs are NOT bit-identical — the two
    lower conv with different fp32 accumulation orders (tap-matmul
    decomposition vs XLA's conv expansion), and fp32 addition is not

@@ -17,8 +17,7 @@ never tightened, accounting closed) and the ``replay --controller
 on|off`` A/B over one recorded saturating trace (books closed both
 ways, actions journaled with evidence on the on side, protected-class
 burn strictly lower with the controller on, calm trace => zero
-actions) — the tier-1 gate ``BENCH_MODE=control`` re-runs from
-``scripts/on_heal.sh``.
+actions) — the tier-1 gate ``BENCH_MODE=control`` re-runs.
 """
 
 import dataclasses

@@ -778,9 +778,7 @@ def test_bench_fleetcontrol_ab_acceptance_drill(tmp_path):
     per-class accounting closes at the router both ways.
 
     Real timing path over live subprocesses (~1 min), so marked slow —
-    ``on_heal.sh`` runs it as the fleet-control smoke gate before chip
-    time, and tier-1 covers the controller logic with the injected
-    clock above."""
+    tier-1 covers the controller logic with the injected clock above."""
     proc = subprocess.run(
         [sys.executable, str(ROOT / "bench.py")],
         cwd=ROOT, capture_output=True, text=True, timeout=560,

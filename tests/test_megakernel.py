@@ -7,8 +7,7 @@ byte identity), the sharded-int8w rung drills, and the regression gate's
 staged-vs-fused variant separation.
 
 All on CPU via the Pallas interpreter (the same numerics as the Mosaic
-lowering for the vcol/sep2 regime; on-chip proof rides scripts/
-on_heal.sh behind its probe gate)."""
+lowering for the vcol/sep2 regime)."""
 
 import jax
 import jax.numpy as jnp
@@ -255,7 +254,9 @@ def test_roofline_joins_block_names_against_fused_model():
     assert rep2.granularity == "stage"
     assert all(s.mfu_ceiling is None for s in rep2.stages)
     with pytest.raises(ValueError, match="no ledger stage or fused block"):
-        attribute_roofline({"bogus": 1.0}, dtype="fp32", batch=1)
+        attribute_roofline(
+            {"bogus": 1.0}, dtype="fp32", batch=1, device_kind="TPU v5e"
+        )
 
 
 def test_bench_breakdown_routes_fused_rows_to_blocks(seeded, monkeypatch):

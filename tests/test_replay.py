@@ -434,17 +434,15 @@ def test_run_cli_serve_replay(recorded_journal, tmp_path):
 # the regression gate wired into tier-1
 
 
-def test_gate_passes_committed_bench_trail_via_echo_exclusion():
-    """THE tier-1 gate: the committed BENCH_r* trajectory passes, and it
-    passes because the r04 echo is detected and excluded attributably —
-    not because the stale trail happens to be flat."""
+def test_gate_passes_an_echo_trail_via_echo_exclusion(echo_trail):
+    """THE tier-1 gate: a trail of failed rounds echoing earlier numbers
+    passes, and it passes because the r04 echo is detected and excluded
+    attributably — not because the stale trail happens to be flat."""
     from cuda_mpi_gpu_cluster_programming_tpu.observability.gate import (
         evaluate,
     )
 
-    paths = sorted(ROOT.glob("BENCH_r0*.json"))
-    assert len(paths) >= 5  # the committed wedge trail
-    verdict = evaluate(paths)
+    verdict = evaluate(echo_trail)
     assert verdict.ok, [r.to_obj() for r in verdict.regressions]
     by_name = {r.name: r for r in verdict.rows}
     assert by_name["BENCH_r04.json"].provenance == (
@@ -540,13 +538,17 @@ def test_gate_echo_cannot_mask_or_manufacture_regressions(tmp_path):
     assert verdict.ok and not verdict.echoes
 
 
-def test_bench_mode_gate_subprocess():
-    """BENCH_MODE=gate over the committed repo trail: one parseable
-    verdict row, exit 0 — the wiring on_heal.sh and CI consume."""
+def test_bench_mode_gate_subprocess(echo_trail):
+    """BENCH_MODE=gate over a bench trail: one parseable verdict row,
+    exit 0 — the wiring CI consumes."""
     proc = subprocess.run(
         [sys.executable, "bench.py"],
         capture_output=True, text=True, cwd=ROOT, timeout=120,
-        env={**os.environ, "BENCH_MODE": "gate"},
+        env={
+            **os.environ,
+            "BENCH_MODE": "gate",
+            "BENCH_GATE_PATHS": str(echo_trail[0].parent / "BENCH_r0*.json"),
+        },
     )
     assert proc.returncode == 0, proc.stderr[-2000:]
     row = json.loads(proc.stdout.strip().splitlines()[-1])

@@ -2,8 +2,8 @@
 
 Covers the policy core (backoff/jitter/deadline math, retry_call, FaultLog),
 every chaos injector (seeded CHAOS_SPEC), the Degrader fallback chains
-(v5 -> v4 -> v2.2 -> v1 and Pallas -> XLA), the harness wedge-aware
-re-capture (no value=0.0 row is ever committed), the run CLI's
+(v5 -> v4 -> v2.2 -> v1 and Pallas -> XLA), the harness's bounded
+timeout re-capture (exactly one committed row), the run CLI's
 --fallback-chain degradation, and the deploy layer's retrying transports +
 quorum degradation.
 """
@@ -297,7 +297,7 @@ def test_tier_fallback_chains():
     assert tier_fallback_chain("v1_jit") == ["v1_jit"]
 
 
-# ------------------------------------------------- harness wedge re-capture ---
+# ------------------------------------------------------- harness re-capture ---
 
 _HEALTHY_STDOUT = (
     "Compile time: 812.0 ms\n"
@@ -311,87 +311,58 @@ def _fake_proc(rc=0, stdout=_HEALTHY_STDOUT, stderr=""):
     return subprocess.CompletedProcess(["fake"], rc, stdout=stdout, stderr=stderr)
 
 
-def test_harness_wedge_recapture_commits_one_healthy_row(tmp_path, monkeypatch):
-    """CHAOS_SPEC wedges the first capture; the retry re-runs and the ONE
-    committed row is the healthy one, tagged with attempt metadata."""
-    monkeypatch.setenv(chaos.CHAOS_ENV, "subprocess_wedge=1")
-    chaos.reset()
-    monkeypatch.setattr(harness.subprocess, "run", lambda *a, **k: _fake_proc())
-    session = harness.Session(log_root=tmp_path)
+def test_harness_timeout_retry_commits_one_healthy_row(tmp_path, monkeypatch):
+    """The first attempt times out; the retry re-runs and the ONE committed
+    row is the healthy one, tagged with attempt metadata."""
+    outcomes = [subprocess.TimeoutExpired(["fake"], 1.0), _fake_proc()]
+
+    def run(*a, **k):
+        out = outcomes.pop(0)
+        if isinstance(out, Exception):
+            raise out
+        return out
+
+    session = harness.Session(log_root=tmp_path)  # before the run() stub: git_commit
+    monkeypatch.setattr(harness.subprocess, "run", run)
+    slept = []
     r = harness.run_case(
         session, "v1_jit", "V1 Serial", 1, 1, fake_devices=2,
-        retry_policy=RetryPolicy(max_retries=2, base_delay_s=0.01, jitter=0.0),
-        sleep=lambda s: None,
+        retry_policy=RetryPolicy(max_retries=2, base_delay_s=0.5, jitter=0.0),
+        sleep=slept.append,
     )
     assert r.status == harness.OK
-    assert r.attempts == 2
+    assert r.attempts == 2 and slept == [0.5]
     assert r.time_ms == 1.234
-    assert "wedged capture (value=0.0)" in r.resilience_msg
+    assert "TIMEOUT" in r.resilience_msg
     with open(session.csv_path) as f:
         rows = list(csv.reader(f))
     assert len(rows) == 2  # header + exactly ONE committed row
-    assert rows[1][15] == "1.234"  # ExecutionTime_ms: never the wedged 0.000
+    assert rows[1][15] == "1.234"  # ExecutionTime_ms
     assert rows[1][20] == "2"  # Attempts
     # both attempts' logs survive on disk
     assert (session.dir / "run_v1_jit_np1_b1.log").exists()
     assert (session.dir / "run_v1_jit_np1_b1_try1.log").exists()
 
 
-def test_harness_terminal_wedge_suppressed_not_persisted(tmp_path, monkeypatch):
-    """A wedge that outlives the retry budget is committed as ENV_WARN with
-    its numbers CLEARED — zero value=0.0 rows in the CSV."""
-    monkeypatch.setenv(chaos.CHAOS_ENV, "subprocess_wedge=9")
-    chaos.reset()
-    monkeypatch.setattr(harness.subprocess, "run", lambda *a, **k: _fake_proc())
-    session = harness.Session(log_root=tmp_path)
-    r = harness.run_case(
-        session, "v1_jit", "V1 Serial", 1, 1, fake_devices=2,
-        retry_policy=RetryPolicy(max_retries=1, base_delay_s=0.01, jitter=0.0),
-        sleep=lambda s: None,
-    )
-    assert r.status == harness.ENV_WARN
-    assert r.attempts == 2
-    assert "wedged capture suppressed" in r.run_msg
-    assert r.time_ms is None and r.first5 == ""
-    csv_text = session.csv_path.read_text()
-    assert "0.000" not in csv_text  # the garbage measurement never lands
+def test_harness_backend_init_failure_is_a_fail_not_retried(tmp_path, monkeypatch):
+    """A run asked for the TPU that cannot initialise it FAILs — no warning
+    class excuses a missing device, and nothing retries it."""
+    calls = {"n": 0}
 
+    def run(*a, **k):
+        calls["n"] += 1
+        return _fake_proc(
+            rc=1, stdout="", stderr="RuntimeError: Unable to initialize backend 'tpu'"
+        )
 
-def test_harness_wedge_probe_annotates_fault_log(tmp_path, monkeypatch):
-    """On the real backend (fake_devices=0) a wedge consults the bounded
-    probe and the verdict lands in the fault trail."""
-    monkeypatch.setenv(chaos.CHAOS_ENV, "subprocess_wedge=1")
-    chaos.reset()
-    monkeypatch.setattr(harness.subprocess, "run", lambda *a, **k: _fake_proc())
-    monkeypatch.setattr(harness, "_probe_verdict", [time.monotonic(), True])
-    session = harness.Session(log_root=tmp_path)
-    r = harness.run_case(
-        session, "v1_jit", "V1 Serial", 1, 1, fake_devices=0,
-        retry_policy=RetryPolicy(max_retries=1, base_delay_s=0.01, jitter=0.0),
-        sleep=lambda s: None,
-    )
-    assert r.status == harness.OK and r.attempts == 2
-    assert "probe: device responsive" in r.resilience_msg
-
-
-def test_harness_retries_env_warn_then_recovers(tmp_path, monkeypatch):
-    """ENV_WARN (transient backend-init failure) retries with backoff and
-    the committed row is the recovered one."""
-    outcomes = [
-        _fake_proc(rc=1, stdout="", stderr="RuntimeError: Unable to initialize backend 'tpu'"),
-        _fake_proc(),
-    ]
     session = harness.Session(log_root=tmp_path)  # before the run() stub: git_commit
-    monkeypatch.setattr(harness.subprocess, "run", lambda *a, **k: outcomes.pop(0))
-    slept = []
+    monkeypatch.setattr(harness.subprocess, "run", run)
     r = harness.run_case(
-        session, "v1_jit", "V1 Serial", 1, 1, fake_devices=2,
-        retry_policy=RetryPolicy(max_retries=1, base_delay_s=0.5, jitter=0.0),
-        sleep=slept.append,
+        session, "v1_jit", "V1 Serial", 1, 1,
+        retry_policy=RetryPolicy(max_retries=2, base_delay_s=0.01, jitter=0.0),
+        sleep=lambda s: None,
     )
-    assert r.status == harness.OK and r.attempts == 2
-    assert slept == [0.5]
-    assert "ENV_WARN" in r.resilience_msg
+    assert r.status == harness.FAIL and r.attempts == 1 and calls["n"] == 1
 
 
 def test_harness_no_retry_on_genuine_fail(tmp_path, monkeypatch):
@@ -428,18 +399,6 @@ def test_harness_degraded_triage_from_run_log(tmp_path, monkeypatch):
     assert rows[1][14] == harness.DEGRADED
     # DEGRADED is a warning: the sweep exit code treats it like OK
     assert harness.STATUS_SYMBOL[harness.DEGRADED] == "↓"
-
-
-def test_is_wedged_detection():
-    r = harness.CaseResult("V1", "v1_jit", 1, 1)
-    r.run_status = harness.OK
-    r.time_ms = 0.0
-    assert harness.is_wedged(r, "")
-    r.time_ms = 1.5
-    assert not harness.is_wedged(r, "healthy log")
-    assert harness.is_wedged(r, "probe: wedged tunnel diagnosis")
-    r.run_status = harness.FAIL  # non-OK rows are triaged elsewhere
-    assert not harness.is_wedged(r, "wedged tunnel")
 
 
 # ------------------------------------------------------ run CLI degradation ---
@@ -705,14 +664,13 @@ def _fake_run_once_factory(calls, die_on=None):
     """A _run_once stand-in: records (config, np, batch) per launch, writes a
     healthy log, and optionally simulates a kill at the Nth launch."""
 
-    def fake(r, cmd, env, log_path, timeout_s, fake_devices):
+    def fake(r, cmd, env, log_path, timeout_s):
         calls.append((r.config_key, r.np, r.batch))
         if die_on is not None and len(calls) == die_on:
             raise KeyboardInterrupt  # the sweep process dies mid-case
         log_path.write_text(_RESUME_STDOUT)
         r.run_status = harness.OK
         harness.parse_run_log(_RESUME_STDOUT, r)
-        return _RESUME_STDOUT
 
     return fake
 

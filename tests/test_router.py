@@ -583,6 +583,73 @@ def test_export_renders_router_lane(tmp_path):
     json.dumps(obj)  # serializable end to end
 
 
+# ------------------------------------------ one process for each chip ---
+
+
+def test_fleet_on_a_tpu_host_refuses_up_front(tmp_path, monkeypatch):
+    """On a TPU host the launcher pins one chip per backend: more backends
+    than local chips, or a parent that already holds the chips (it has
+    initialised a JAX backend, as this test process has), is refused at
+    once with the reason — never a wait for the spawn timeout."""
+    from cuda_mpi_gpu_cluster_programming_tpu.serving import fleet as fleet_mod
+
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    monkeypatch.setattr(fleet_mod, "local_tpu_chips", lambda: 2)
+    t0 = time.monotonic()
+    with pytest.raises(fleet_mod.FleetError, match="needs 3 local TPU chips"):
+        BackendFleet(3, tmp_path).start()
+    with pytest.raises(fleet_mod.FleetError, match="already initialised a JAX backend"):
+        BackendFleet(2, tmp_path).start()
+    assert time.monotonic() - t0 < 5.0
+    # children held to the CPU are not pinned, whatever the host has
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert BackendFleet(3, tmp_path)._chips == 0
+
+
+def test_local_tpu_chips_counts_only_vfio_groups_that_hold_a_tpu(tmp_path):
+    """A /dev/vfio/N node is a chip only if IOMMU group N holds a Google PCI
+    function; a chip in sysfs without its group node is not counted."""
+    from cuda_mpi_gpu_cluster_programming_tpu.serving.fleet import local_tpu_chips
+
+    dev, groups = tmp_path / "dev", tmp_path / "iommu_groups"
+    (dev / "vfio").mkdir(parents=True)
+    for node in ("vfio", "1", "7"):
+        (dev / "vfio" / node).touch()
+    for group, vendor in (("0", "0x1ae0"), ("1", "0x1ae0"), ("7", "0x8086")):
+        fn = groups / group / "devices" / f"0000:00:0{group}.0"
+        fn.mkdir(parents=True)
+        (fn / "vendor").write_text(vendor + "\n")
+    assert local_tpu_chips(str(dev), str(groups)) == 1  # group 1 only
+    (dev / "accel0").touch()
+    (dev / "accel1").touch()
+    assert local_tpu_chips(str(dev), str(groups)) == 2
+    assert local_tpu_chips(str(tmp_path / "none"), str(groups)) == 0
+
+
+def test_jax_backend_initialised_private_api_exists():
+    """API-drift guard: the launcher's parent check names a private jax
+    function; a jax upgrade that moves it must surface here, not as a
+    launcher that silently stops refusing (this process has a backend)."""
+    import jax
+
+    from cuda_mpi_gpu_cluster_programming_tpu.serving.fleet import (
+        jax_backend_initialised,
+    )
+
+    jax.devices()
+    assert jax_backend_initialised() is True
+
+
+def test_chip_pin_env_gives_each_backend_its_own_chip_and_ports():
+    from cuda_mpi_gpu_cluster_programming_tpu.serving.fleet import chip_pin_env
+
+    envs = [chip_pin_env(i) for i in range(4)]
+    assert [e["TPU_VISIBLE_CHIPS"] for e in envs] == ["0", "1", "2", "3"]
+    assert all(e["TPU_PROCESS_BOUNDS"] == "1,1,1" for e in envs)
+    for key in ("TPU_MESH_CONTROLLER_PORT", "TPU_RUNTIME_METRICS_PORTS"):
+        assert len({e[key] for e in envs}) == 4  # no two backends collide
+
+
 # ------------------------------------------------- acceptance drill ---
 
 

@@ -140,7 +140,7 @@ def test_cross_replica_digests_clean_vs_corrupt():
 
 
 def test_replica_spread_inside_shard_map():
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = Mesh(np.array(jax.devices()).reshape(8), ("dp",))
     f = shard_map(

@@ -591,13 +591,9 @@ def test_bench_serve_mode_cpu_smoke(tmp_path):
     bd = row["breakdown"]
     assert set(bd["stages"]) == {"conv1", "pool1", "conv2", "pool2", "lrn2"}
     assert bd["stage_sum_ms"] > 0
-    # ISSUE 13: serve rows carry the roofline join beside the breakdown,
-    # at the geometry the service actually dispatches.
-    rf = row["roofline"]
-    assert rf["source"] == "breakdown"
-    assert {s["name"] for s in rf["stages"]} == set(bd["stages"])
-    assert all(s["bound"] in ("compute", "memory") for s in rf["stages"])
-    assert set(rf["blocks"]) == {"block1", "block2"}
+    # The roofline join rides beside the breakdown on a chip in the spec
+    # table; this CPU smoke has no roof to be judged against and says so.
+    assert "not in the spec table" in row["roofline"]["skipped"]
     metrics = row["metrics"]
     assert metrics["serve.ok"] == row["n_ok"]
     assert metrics["serve.batch_ms"]["count"] >= 1

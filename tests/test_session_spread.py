@@ -1,8 +1,8 @@
 """scripts/session_spread.py: the work-floor protocol's acceptance check.
 
-Validates the comparison logic off-chip (the real input is two heal-window
-TPU sessions): common-cell matching, the sub-3 ms bar, the exit code
-contract on_heal.sh logs, and the real-backend session filter that keeps
+Validates the comparison logic off-chip (the real input is two TPU
+sessions): common-cell matching, the sub-3 ms bar, the exit code
+contract, and the real-backend session filter that keeps
 --fake-devices smoke sessions out of the auto-selection.
 """
 

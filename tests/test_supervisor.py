@@ -9,11 +9,6 @@ CSV surfacing, and the digest taps of the sequence-parallel forwards.
 """
 
 import dataclasses
-import importlib.util
-import json
-import subprocess
-import sys
-from pathlib import Path
 
 import jax
 import numpy as np
@@ -43,7 +38,6 @@ from cuda_mpi_gpu_cluster_programming_tpu.resilience.supervisor import (
     default_ladder,
 )
 
-ROOT = Path(__file__).resolve().parent.parent
 
 CFG = dataclasses.replace(BLOCKS12, in_height=63, in_width=63)
 
@@ -380,94 +374,3 @@ def test_harness_supervisor_msg_column_roundtrip(tmp_path):
     assert rows[0]["Status"] == harness.DEGRADED  # lower rung != requested tier
     rebuilt = harness.case_result_from_row(rows[0])
     assert rebuilt.supervisor_msg == r.supervisor_msg
-
-
-# ----------------------------------------------- capture_evidence resume ---
-
-
-def _load_capture_evidence():
-    spec = importlib.util.spec_from_file_location(
-        "capture_evidence_under_test", ROOT / "scripts" / "capture_evidence.py"
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_capture_evidence_journal_resume(tmp_path, monkeypatch, capsys):
-    """A killed capture re-run with the same out-dir skips journaled-OK
-    steps (the third ROADMAP open item). Subprocesses are stubbed; the
-    probe always re-runs."""
-    ce = _load_capture_evidence()
-    calls = []
-
-    def fake_subprocess_run(cmd, **kw):
-        calls.append(cmd)
-        return subprocess.CompletedProcess(
-            cmd, 0, stdout='{"value": 1.0, "attempts": 1}\n', stderr=""
-        )
-
-    monkeypatch.setattr(ce.subprocess, "run", fake_subprocess_run)
-    # Redirect the script's repo root: bench_latest.json and any other
-    # artifact lands in the sandbox, never in the real perf/.
-    monkeypatch.setattr(ce, "ROOT", tmp_path)
-    probes = []
-    monkeypatch.setattr(
-        ce, "probe", lambda t: probes.append(1) or (True, "cpu-stub")
-    )
-    argv = [
-        "capture_evidence.py", "--quick", "--skip-perf-sweep",
-        "--out-dir", str(tmp_path),
-    ]
-    monkeypatch.setattr(sys, "argv", argv)
-    assert ce.main() == 0
-    first_calls = len(calls)
-    assert first_calls > 0 and probes == [1]
-    records = Journal.load(tmp_path / ce.JOURNAL_NAME)
-    ok_steps = {r["key"] for r in records if str(r["status"]).startswith("OK")}
-    assert {"probe", "harness", "bench", "report", "plots"} <= ok_steps
-
-    # Re-run with the same out-dir: every journaled-OK step skips; only the
-    # probe re-runs (and is re-journaled).
-    calls.clear()
-    assert ce.main() == 0
-    assert calls == []  # zero subprocesses: everything journaled-complete
-    assert probes == [1, 1]  # but the device was re-probed
-    out = capsys.readouterr().out
-    assert "journaled-complete" in out
-
-    # --fresh discards the journal: steps run again.
-    monkeypatch.setattr(sys, "argv", argv + ["--fresh"])
-    calls.clear()
-    assert ce.main() == 0
-    assert len(calls) == first_calls
-
-
-def test_capture_evidence_failed_step_reruns_on_resume(tmp_path, monkeypatch):
-    """Only OK steps skip: a step journaled as failed re-runs."""
-    ce = _load_capture_evidence()
-    (tmp_path / ce.JOURNAL_NAME).write_text(
-        json.dumps({"kind": "step", "key": "harness", "status": "rc=1"}) + "\n"
-        + json.dumps({"kind": "step", "key": "bench", "status": "OK", "rc": 0})
-        + "\n"
-    )
-    calls = []
-
-    def fake_subprocess_run(cmd, **kw):
-        calls.append(cmd)
-        return subprocess.CompletedProcess(
-            cmd, 0, stdout='{"value": 1.0}\n', stderr=""
-        )
-
-    monkeypatch.setattr(ce.subprocess, "run", fake_subprocess_run)
-    monkeypatch.setattr(ce, "ROOT", tmp_path)
-    monkeypatch.setattr(ce, "probe", lambda t: (True, "cpu-stub"))
-    monkeypatch.setattr(
-        sys, "argv",
-        ["capture_evidence.py", "--quick", "--skip-perf-sweep",
-         "--out-dir", str(tmp_path)],
-    )
-    ce.main()
-    ran = {c[2] if c[1] == "-m" else Path(str(c[1])).name for c in calls}
-    assert any("harness" in str(r) for r in ran)  # failed step re-ran
-    assert not any(str(r).endswith("bench.py") for r in ran)  # OK step skipped
