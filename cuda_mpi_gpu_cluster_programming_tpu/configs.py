@@ -261,9 +261,13 @@ def build_forward(
         return _observed(fwd, exec_cfg, pol.name, n_shards)
     import jax.numpy as jnp
 
+    from .ops import scopes
+
     def fwd_bf16(p, x):
-        pb = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
-        return fwd(pb, x.astype(jnp.bfloat16)).astype(jnp.float32)
+        with scopes.cast_in():
+            pb = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
+            xb = x.astype(jnp.bfloat16)
+        return fwd(pb, xb).astype(jnp.float32)
 
     return _observed(_jit(fwd_bf16, donate), exec_cfg, pol.name, n_shards)
 

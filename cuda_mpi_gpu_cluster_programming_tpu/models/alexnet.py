@@ -19,6 +19,7 @@ from typing import Any, Dict, Tuple
 import jax
 
 from ..ops import reference as ops
+from ..ops import scopes
 from ..ops.shapes import conv_out_dim, pool_out_dim
 
 Params = Dict[str, Dict[str, Any]]
@@ -63,14 +64,10 @@ class Blocks12Config:
     lrn2: LrnSpec = LrnSpec(5, 1e-4, 0.75, 2.0)
 
     def layer_chain(self) -> Tuple[Tuple[str, Any], ...]:
-        """The spatial layer sequence (used by the shard planner)."""
-        return (
-            ("conv1", self.conv1),
-            ("pool1", self.pool1),
-            ("conv2", self.conv2),
-            ("pool2", self.pool2),
-            ("lrn2", self.lrn2),
-        )
+        """The spatial layer sequence (used by the shard planner), under
+        the names every forward scopes its layers with."""
+        specs = (self.conv1, self.pool1, self.conv2, self.pool2, self.lrn2)
+        return tuple(zip(scopes.BLOCKS12_LAYERS, specs))
 
 
 BLOCKS12 = Blocks12Config()
@@ -152,6 +149,28 @@ def matmul_flops_per_image(cfg: Blocks12Config = BLOCKS12) -> int:
     return sum(mm for _name, _f, mm in stage_flops(cfg))
 
 
+def forward_chain(params: Params, x: jax.Array, chain) -> jax.Array:
+    """Run a ``layer_chain()`` on the XLA op tier, each layer under its own
+    ``jax.named_scope`` (``ops.scopes``; ReLU inside its convolution's), so
+    that a device trace of any program built on this forward splits by layer.
+    The one chain walk behind ``forward_blocks12`` and
+    ``alexnet_full.forward_spatial``."""
+    for name, spec in chain:
+        with scopes.layer(name):
+            if isinstance(spec, ConvSpec):
+                p = params[name]
+                x = ops.conv2d(x, p["w"], p["b"], stride=spec.stride, padding=spec.padding)
+                x = ops.relu(x)
+            elif isinstance(spec, PoolSpec):
+                x = ops.maxpool(x, window=spec.window, stride=spec.stride)
+            elif isinstance(spec, LrnSpec):
+                x = ops.lrn(
+                    x, size=spec.size, alpha=spec.alpha, beta=spec.beta, k=spec.k,
+                    alpha_over_size=spec.alpha_over_size,
+                )
+    return x
+
+
 def forward_blocks12(params: Params, x: jax.Array, cfg: Blocks12Config = BLOCKS12) -> jax.Array:
     """Forward pass Conv1→ReLU→Pool1→Conv2→ReLU→Pool2→LRN2.
 
@@ -159,14 +178,4 @@ def forward_blocks12(params: Params, x: jax.Array, cfg: Blocks12Config = BLOCKS1
     orchestrator (v1_serial/src/alexnet_serial.cpp:67-186). ``x`` is NHWC;
     params is ``{"conv1": {"w","b"}, "conv2": {"w","b"}}`` with HWIO weights.
     """
-    c1, p1, c2, p2, n2 = cfg.conv1, cfg.pool1, cfg.conv2, cfg.pool2, cfg.lrn2
-    x = ops.conv2d(x, params["conv1"]["w"], params["conv1"]["b"], stride=c1.stride, padding=c1.padding)
-    x = ops.relu(x)
-    x = ops.maxpool(x, window=p1.window, stride=p1.stride)
-    x = ops.conv2d(x, params["conv2"]["w"], params["conv2"]["b"], stride=c2.stride, padding=c2.padding)
-    x = ops.relu(x)
-    x = ops.maxpool(x, window=p2.window, stride=p2.stride)
-    x = ops.lrn(
-        x, size=n2.size, alpha=n2.alpha, beta=n2.beta, k=n2.k, alpha_over_size=n2.alpha_over_size
-    )
-    return x
+    return forward_chain(params, x, cfg.layer_chain())

@@ -29,7 +29,8 @@ import jax
 import jax.numpy as jnp
 
 from ..ops import reference as ops
-from .alexnet import BLOCKS12, Blocks12Config, ConvSpec, LrnSpec, PoolSpec
+from ..ops import scopes
+from .alexnet import BLOCKS12, Blocks12Config, ConvSpec, PoolSpec, forward_chain
 
 Params = Dict[str, Dict[str, Any]]
 
@@ -50,11 +51,9 @@ class AlexNetConfig:
 
     def layer_chain(self) -> Tuple[Tuple[str, Any], ...]:
         """Spatial chain (shard-planner compatible: conv/pool/lrn specs)."""
-        return self.blocks12.layer_chain() + (
-            ("conv3", self.conv3),
-            ("conv4", self.conv4),
-            ("conv5", self.conv5),
-            ("pool5", self.pool5),
+        specs = (self.conv3, self.conv4, self.conv5, self.pool5)
+        return self.blocks12.layer_chain() + tuple(
+            zip(scopes.ALEXNET_TAIL_LAYERS, specs)
         )
 
     # Duck-type the fields the shard planner / sharded pipeline read.
@@ -86,28 +85,7 @@ def spatial_output_shape(cfg: AlexNetConfig = ALEXNET) -> Tuple[int, int, int]:
 
 def forward_spatial(params: Params, x: jax.Array, cfg: AlexNetConfig = ALEXNET) -> jax.Array:
     """Conv1..Pool5 feature extractor; ReLU after every conv."""
-    for name, spec in cfg.layer_chain():
-        if isinstance(spec, ConvSpec):
-            x = ops.conv2d(
-                x,
-                params[name]["w"],
-                params[name]["b"],
-                stride=spec.stride,
-                padding=spec.padding,
-            )
-            x = ops.relu(x)
-        elif isinstance(spec, PoolSpec):
-            x = ops.maxpool(x, window=spec.window, stride=spec.stride)
-        elif isinstance(spec, LrnSpec):
-            x = ops.lrn(
-                x,
-                size=spec.size,
-                alpha=spec.alpha,
-                beta=spec.beta,
-                k=spec.k,
-                alpha_over_size=spec.alpha_over_size,
-            )
-    return x
+    return forward_chain(params, x, cfg.layer_chain())
 
 
 def fc_head(
@@ -123,17 +101,21 @@ def fc_head(
     matmuls — already the MXU's native shape; a hand kernel would add nothing
     over XLA here.
     """
-    x = feats.reshape(feats.shape[0], -1)
+    fc6, fc7, fc8 = scopes.FC_LAYERS
     keys = (
         jax.random.split(dropout_key, 2) if dropout_key is not None else (None, None)
     )
-    for name, key in (("fc6", keys[0]), ("fc7", keys[1])):
-        x = ops.relu(x @ params[name]["w"] + params[name]["b"])
-        if key is not None and cfg.dropout_rate > 0:
-            keep = 1.0 - cfg.dropout_rate
-            mask = jax.random.bernoulli(key, keep, x.shape)
-            x = jnp.where(mask, x / keep, 0.0)
-    return x @ params["fc8"]["w"] + params["fc8"]["b"]
+    with scopes.layer(fc6):
+        x = feats.reshape(feats.shape[0], -1)
+    for name, key in ((fc6, keys[0]), (fc7, keys[1])):
+        with scopes.layer(name):
+            x = ops.relu(x @ params[name]["w"] + params[name]["b"])
+            if key is not None and cfg.dropout_rate > 0:
+                keep = 1.0 - cfg.dropout_rate
+                mask = jax.random.bernoulli(key, keep, x.shape)
+                x = jnp.where(mask, x / keep, 0.0)
+    with scopes.layer(fc8):
+        return x @ params[fc8]["w"] + params[fc8]["b"]
 
 
 def forward_alexnet(

@@ -33,12 +33,14 @@ import dataclasses
 import functools
 from typing import Callable, List, Tuple
 
+from ..ops.scopes import BLOCKS12_LAYERS
 from .trace import off_timed_path, span
 
 # The sentinel tap boundaries (parallel.sharded / tensor_parallel
 # with_digests=True) — conv stages include their ReLU, exactly as the
-# in-graph digest taps bound them.
-SENTINEL_STAGES = ("conv1", "pool1", "conv2", "pool2", "lrn2")
+# in-graph digest taps bound them and as the forwards scope them: the
+# names are the scopes' own.
+SENTINEL_STAGES = BLOCKS12_LAYERS
 
 
 def sentinel_stage_fns(cfg=None, tier: str = "reference") -> List[Tuple[str, Callable]]:

@@ -314,6 +314,11 @@ def _conv_block(
         out_shape=vma_struct((n, hp_o, wp_o, kk), out_dtype, vma),
         compiler_params=pk._tc_params("parallel"),
         interpret=pk._interpret(),
+        name=(
+            f"conv_block_{variant}_pool"
+            + ("_lrn" if lrn is not None else "")
+            + ("_int8w" if scale is not None else "")
+        ),
     )(*operands)
 
 

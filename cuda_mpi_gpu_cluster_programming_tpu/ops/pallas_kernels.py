@@ -672,6 +672,7 @@ def _conv2d_pallas(
             out_shape=vma_struct((n, 2, 2, bh8, wo2_p, kk), x.dtype, vma),
             compiler_params=_tc_params("parallel", "parallel", "parallel"),
             interpret=_interpret(),
+            name="conv2d_g8",
         )(xs8, w8, b)
         # out[j, l] = out8[j%2, l%2, j//2, l//2]: interleave rows/cols by
         # phase, then crop the alignment padding (it lands past ho/wo).
@@ -783,6 +784,7 @@ def _conv2d_pallas(
                 out_shape=vma_struct((n, hp_o, wo_p, kk), x.dtype, vma),
                 compiler_params=_tc_params("parallel", "parallel"),
                 interpret=_interpret(),
+                name=f"conv2d_{variant}_hpool",
             )(*operands)
             return out[:, :, :wo, :]
         # Mosaic constraint (measured on the real v5e, 2026-07-31): every
@@ -838,6 +840,7 @@ def _conv2d_pallas(
                 out_shape=vma_struct((n, ho_p, wo_p, kk), x.dtype, vma),
                 compiler_params=_tc_params("parallel", "parallel", "parallel"),
                 interpret=_interpret(),
+                name=f"conv2d_{variant}_kblock",
             )(*operands)
             if ho_p != ho or wo_p != wo:
                 out = out[:, :ho, :wo, :]
@@ -855,6 +858,7 @@ def _conv2d_pallas(
         out_shape=vma_struct((n, ho_p, wo_p, w.shape[-1]), x.dtype, vma),
         compiler_params=_tc_params("parallel", "parallel"),
         interpret=_interpret(),
+        name=f"conv2d_{variant}",
     )(*operands)
     if ho_p != ho or wo_p != wo:
         out = out[:, :ho, :wo, :]
@@ -945,6 +949,7 @@ def _maxpool_phases(x: jax.Array, *, window: int, stride: int, vma=None) -> jax.
         out_shape=vma_struct((n, ho, wo, c), x.dtype, vma),
         compiler_params=_tc_params("parallel"),
         interpret=_interpret(),
+        name="maxpool_phases",
     )(xph)
 
 
@@ -986,6 +991,7 @@ def _pool_rows(x: jax.Array, *, window: int, stride: int, vma=None) -> jax.Array
         out_shape=vma_struct((n, ho, w, c), x.dtype, vma),
         compiler_params=_tc_params("parallel"),
         interpret=_interpret(),
+        name="maxpool_rows",
     )(xv)
 
 
@@ -1072,6 +1078,7 @@ def lrn_pallas(
         out_shape=vma_struct(xp.shape, x.dtype, vma),
         compiler_params=_tc_params("parallel", "parallel"),
         interpret=_interpret(),
+        name="lrn_band",
     )(xp)
     return out[:, :m].reshape(n, h, wdt, c)
 
@@ -1105,6 +1112,7 @@ def relu_pallas(x: jax.Array) -> jax.Array:
             out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
             compiler_params=_tc_params("parallel"),
             interpret=_interpret(),
+            name="relu",
         )(x)
     return pl.pallas_call(
         kernel,
@@ -1112,4 +1120,5 @@ def relu_pallas(x: jax.Array) -> jax.Array:
         out_specs=_vmem_spec(),
         out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
         interpret=_interpret(),
+        name="relu",
     )(x)

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from ..models.alexnet import BLOCKS12, Blocks12Config
 from . import pallas_kernels as pk
+from . import scopes
 
 
 def _chain_variant() -> str:
@@ -53,7 +54,7 @@ def _layer_variants(v, name: str) -> "pk.KernelVariants":
     return v.for_layer(name) if isinstance(v, pk.LayerVariants) else v
 
 
-def _conv_then_pool(x, w, b, cspec, pspec, v: "pk.KernelVariants", lrn=None):
+def _conv_then_pool(x, w, b, cspec, pspec, v: "pk.KernelVariants", names, lrn=None):
     """conv(+relu) then max-pool, the ONE place that decides whether the
     pool rides the conv pass — both forward builders route conv->pool
     adjacencies through here, so the geometry gates cannot drift between
@@ -67,19 +68,25 @@ def _conv_then_pool(x, w, b, cspec, pspec, v: "pk.KernelVariants", lrn=None):
     accumulation order, same cast points — tests/test_megakernel.py).
     When ``lrn`` is given but the fused path is not taken, the trailing
     LRN still runs here (staged), so callers hand off the whole block
-    either way."""
+    either way.
+
+    ``names``: the layers' scope names, ``(conv, pool)`` or ``(conv, pool,
+    lrn)``. A kernel that covers several layers runs under all of their
+    names (``conv1+pool1``), a staged layer under its own."""
     from . import megakernel as mk
 
+    conv_name, pool_name = names[:2]
     ho = (x.shape[1] + 2 * cspec.padding - cspec.filter_size) // cspec.stride + 1
     if v.fuse == "block" and not mk.block_fusible_reason(
         variant=v.conv, row_block=v.row_block, k_block=v.k_block,
         pool=v.pool, out_h=ho, pool_window=pspec.window,
     ):
-        return mk.conv_block_pallas(
-            x, w, b, stride=cspec.stride, padding=cspec.padding,
-            pool_window=pspec.window, pool_stride=pspec.stride,
-            lrn=lrn, variant=v.conv, row_block=v.row_block,
-        )
+        with scopes.layer(*names):
+            return mk.conv_block_pallas(
+                x, w, b, stride=cspec.stride, padding=cspec.padding,
+                pool_window=pspec.window, pool_stride=pspec.stride,
+                lrn=lrn, variant=v.conv, row_block=v.row_block,
+            )
     if (
         v.fuse == "hpool"
         and v.conv in ("taps", "vcol")
@@ -87,25 +94,30 @@ def _conv_then_pool(x, w, b, cspec, pspec, v: "pk.KernelVariants", lrn=None):
         and v.row_block >= ho
         and v.k_block == 0
     ):
-        y = pk.conv2d_pallas(
-            x, w, b, stride=cspec.stride, padding=cspec.padding, relu=True,
-            variant=v.conv, row_block=v.row_block, k_block=0,
-            hpool=(pspec.window, pspec.stride),
-        )
-        out = pk.maxpool_pallas_w(y, window=pspec.window, stride=pspec.stride)
+        with scopes.layer(conv_name, pool_name):  # the pool's H stage rides the conv
+            y = pk.conv2d_pallas(
+                x, w, b, stride=cspec.stride, padding=cspec.padding, relu=True,
+                variant=v.conv, row_block=v.row_block, k_block=0,
+                hpool=(pspec.window, pspec.stride),
+            )
+        with scopes.layer(pool_name):
+            out = pk.maxpool_pallas_w(y, window=pspec.window, stride=pspec.stride)
     else:
-        y = pk.conv2d_pallas(
-            x, w, b, stride=cspec.stride, padding=cspec.padding, relu=True,
-            variant=v.conv, row_block=v.row_block, k_block=v.k_block,
-        )
-        out = pk.maxpool_pallas(
-            y, window=pspec.window, stride=pspec.stride, variant=v.pool
-        )
+        with scopes.layer(conv_name):
+            y = pk.conv2d_pallas(
+                x, w, b, stride=cspec.stride, padding=cspec.padding, relu=True,
+                variant=v.conv, row_block=v.row_block, k_block=v.k_block,
+            )
+        with scopes.layer(pool_name):
+            out = pk.maxpool_pallas(
+                y, window=pspec.window, stride=pspec.stride, variant=v.pool
+            )
     if lrn is not None:
-        out = pk.lrn_pallas(
-            out, size=lrn.size, alpha=lrn.alpha, beta=lrn.beta, k=lrn.k,
-            alpha_over_size=lrn.alpha_over_size,
-        )
+        with scopes.layer(names[2]):
+            out = pk.lrn_pallas(
+                out, size=lrn.size, alpha=lrn.alpha, beta=lrn.beta, k=lrn.k,
+                alpha_over_size=lrn.alpha_over_size,
+            )
     return out
 
 
@@ -128,14 +140,21 @@ def forward_blocks12_pallas(
     pad128 = (chain if chain is not None else _chain_variant()) == "pad128"
     w1, b1 = params["conv1"]["w"], params["conv1"]["b"]
     w2, b2 = params["conv2"]["w"], params["conv2"]["b"]
+    conv1, pool1, conv2, pool2, lrn2 = scopes.BLOCKS12_LAYERS
     if pad128:
         kp = -(-w1.shape[-1] // 128) * 128  # conv1 output channels -> 128
-        w1, b1 = _pad_axis(w1, 3, kp), _pad_axis(b1, 0, kp)
-        w2 = _pad_axis(w2, 2, kp)  # conv2 contraction axis: zero rows
-    x = _conv_then_pool(x, w1, b1, c1, p1, _layer_variants(v, "conv1"))
+        with scopes.layer(conv1):
+            w1, b1 = _pad_axis(w1, 3, kp), _pad_axis(b1, 0, kp)
+        with scopes.layer(conv2):
+            w2 = _pad_axis(w2, 2, kp)  # conv2 contraction axis: zero rows
+    x = _conv_then_pool(
+        x, w1, b1, c1, p1, _layer_variants(v, conv1), (conv1, pool1)
+    )
     # Block 2's trailing LRN rides the conv->pool handoff so fuse="block"
     # can fold it into the same pass; staged paths run it after the pool.
-    x = _conv_then_pool(x, w2, b2, c2, p2, _layer_variants(v, "conv2"), lrn=n2)
+    x = _conv_then_pool(
+        x, w2, b2, c2, p2, _layer_variants(v, conv2), (conv2, pool2, lrn2), lrn=n2
+    )
     return x
 
 
@@ -168,36 +187,40 @@ def forward_alexnet_pallas(
                 # pool it feeds. A trailing LRN is part of the block.
                 nxt2 = chain[idx + 2][1] if idx + 2 < len(chain) else None
                 lrn = nxt2 if isinstance(nxt2, LrnSpec) else None
+                names = tuple(n for n, _s in chain[idx : idx + (3 if lrn else 2)])
                 x = _conv_then_pool(
                     x, params[name]["w"], params[name]["b"], spec, nxt, lv,
-                    lrn=lrn,
+                    names, lrn=lrn,
                 )
                 skip_idx.add(idx + 1)
                 if lrn is not None:
                     skip_idx.add(idx + 2)
                 continue
-            x = pk.conv2d_pallas(
-                x,
-                params[name]["w"],
-                params[name]["b"],
-                stride=spec.stride,
-                padding=spec.padding,
-                relu=True,
-                variant=lv.conv,
-                row_block=lv.row_block,
-                k_block=lv.k_block,
-            )
+            with scopes.layer(name):
+                x = pk.conv2d_pallas(
+                    x,
+                    params[name]["w"],
+                    params[name]["b"],
+                    stride=spec.stride,
+                    padding=spec.padding,
+                    relu=True,
+                    variant=lv.conv,
+                    row_block=lv.row_block,
+                    k_block=lv.k_block,
+                )
         elif isinstance(spec, PoolSpec):
-            x = pk.maxpool_pallas(
-                x, window=spec.window, stride=spec.stride, variant=lv.pool
-            )
+            with scopes.layer(name):
+                x = pk.maxpool_pallas(
+                    x, window=spec.window, stride=spec.stride, variant=lv.pool
+                )
         elif isinstance(spec, LrnSpec):
-            x = pk.lrn_pallas(
-                x,
-                size=spec.size,
-                alpha=spec.alpha,
-                beta=spec.beta,
-                k=spec.k,
-                alpha_over_size=spec.alpha_over_size,
-            )
+            with scopes.layer(name):
+                x = pk.lrn_pallas(
+                    x,
+                    size=spec.size,
+                    alpha=spec.alpha,
+                    beta=spec.beta,
+                    k=spec.k,
+                    alpha_over_size=spec.alpha_over_size,
+                )
     return fc_head(params, x, cfg)

@@ -1,0 +1,65 @@
+"""The names the program gives its layers where they run.
+
+Every production forward wraps each layer in ``jax.named_scope`` under the
+names below, so the compiled program's instructions carry them in
+``metadata={op_name=...}`` and a device trace can be split by layer after any
+refactor of the fusions (the instruction names ``fusion.<n>`` are the
+compiler's and change with it). Scopes are metadata only: they change nothing
+the compiler builds.
+
+One vocabulary, defined here and nowhere else: ``Blocks12Config.layer_chain``
+and ``AlexNetConfig.layer_chain`` take their names from it, and so do the
+sentinel taps (``observability.stages.SENTINEL_STAGES``). ReLU belongs to its
+convolution's scope, as the taps bound the stages.
+"""
+
+from __future__ import annotations
+
+import jax
+
+BLOCKS12_LAYERS = ("conv1", "pool1", "conv2", "pool2", "lrn2")
+ALEXNET_TAIL_LAYERS = ("conv3", "conv4", "conv5", "pool5")
+FC_LAYERS = ("fc6", "fc7", "fc8")
+LAYERS = BLOCKS12_LAYERS + ALEXNET_TAIL_LAYERS + FC_LAYERS
+
+# Parameters and input to the compute type: the bf16 wrapper's casts and the
+# int8w quantisation.
+CAST_IN = "cast_in"
+# The sharded paths: the pad (or replication) before the shard_map, the slice
+# (or constraint) after it, and a layer's neighbour exchange, nested in that
+# layer's scope as ``<layer>/halo.<layer>``.
+SCATTER = "scatter"
+GATHER = "gather"
+HALO_PREFIX = "halo."
+
+
+def _check(name: str) -> None:
+    if name not in LAYERS:
+        raise ValueError(f"{name!r} is not a layer name ({', '.join(LAYERS)})")
+
+
+def layer(*names: str):
+    """The scope of one layer, or of one kernel that covers several
+    (``layer("conv1", "pool1")`` is ``conv1+pool1``: a fused block is never
+    silently its convolution)."""
+    for name in names:
+        _check(name)
+    return jax.named_scope("+".join(names))
+
+
+def halo(layer_name: str):
+    """The exchange that fetches ``layer_name``'s neighbour rows or channels."""
+    _check(layer_name)
+    return jax.named_scope(HALO_PREFIX + layer_name)
+
+
+def cast_in():
+    return jax.named_scope(CAST_IN)
+
+
+def scatter():
+    return jax.named_scope(SCATTER)
+
+
+def gather():
+    return jax.named_scope(GATHER)

@@ -17,6 +17,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.alexnet import BLOCKS12, Blocks12Config, forward_blocks12
+from ..ops import scopes
 from .mesh import make_mesh
 
 
@@ -44,9 +45,11 @@ def build_replicated_forward(
 
     @jax.jit
     def fwd(params, x):
-        params = jax.lax.with_sharding_constraint(params, repl)
-        x = jax.lax.with_sharding_constraint(x, repl)
+        with scopes.scatter():  # the Bcast: everything to every device
+            params = jax.lax.with_sharding_constraint(params, repl)
+            x = jax.lax.with_sharding_constraint(x, repl)
         out = model_fwd(params, x)
-        return jax.lax.with_sharding_constraint(out, repl)
+        with scopes.gather():
+            return jax.lax.with_sharding_constraint(out, repl)
 
     return fwd

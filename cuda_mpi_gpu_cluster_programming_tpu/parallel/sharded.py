@@ -33,6 +33,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.alexnet import BLOCKS12, Blocks12Config
 from ..ops import reference as ops
+from ..ops import scopes
 from ..ops.vma import kernel_check_vma
 from .halo import exchange
 from .mesh import make_mesh
@@ -61,7 +62,8 @@ def _apply_spatial(
 ) -> jax.Array:
     """One conv/pool layer on a per-shard block (N, b_in, W, C)."""
     ex = exchange(staged)
-    padded = ex(x, lp.h_top, lp.h_bot, axis_name, n)
+    with scopes.halo(lp.name):
+        padded = ex(x, lp.h_top, lp.h_bot, axis_name, n)
     if lp.pad_bot:
         padded = jnp.pad(padded, ((0, 0), (0, lp.pad_bot), (0, 0), (0, 0)))
     i = lax.axis_index(axis_name)
@@ -208,42 +210,47 @@ def build_sharded_forward(
 
     specs = dict(model_cfg.layer_chain())
 
+    def layer_body(lp, spec, params, cur):
+        if lp.kind == "pointwise":
+            # int8w contract: LRN computes in fp32 (squares + pow need
+            # the headroom) — same as forward_blocks12_int8w.
+            return lrn_fn(
+                cur.astype(jnp.float32) if quantized else cur,
+                size=spec.size,
+                alpha=spec.alpha,
+                beta=spec.beta,
+                k=spec.k,
+                alpha_over_size=spec.alpha_over_size,
+            )
+        conv_fn, pool_fn = (
+            layer_fns[lp.name]
+            if layer_fns is not None
+            else (_conv_hvalid, _pool_hvalid)
+        )
+        cur = _apply_spatial(
+            lp, cur, params, spec, AXIS, n, conv_fn, pool_fn, staged
+        )
+        if lp.kind == "conv":
+            cur = ops.relu(cur)
+            if quantized:
+                # activations ride bf16 between quantized stages
+                cur = cur.astype(jnp.bfloat16)
+        return cur
+
     def shard_body(params, xb):
         # xb: (N, b0, W, C) — this shard's rows (zero-padded past H)
         cur = xb
         digs = {}
         for lp in splan.layers:
             spec = specs[lp.name]
-            if lp.kind == "pointwise":
-                # int8w contract: LRN computes in fp32 (squares + pow need
-                # the headroom) — same as forward_blocks12_int8w.
-                cur = lrn_fn(
-                    cur.astype(jnp.float32) if quantized else cur,
-                    size=spec.size,
-                    alpha=spec.alpha,
-                    beta=spec.beta,
-                    k=spec.k,
-                    alpha_over_size=spec.alpha_over_size,
-                )
-            else:
-                conv_fn, pool_fn = (
-                    layer_fns[lp.name]
-                    if layer_fns is not None
-                    else (_conv_hvalid, _pool_hvalid)
-                )
-                cur = _apply_spatial(
-                    lp, cur, params, spec, AXIS, n, conv_fn, pool_fn, staged
-                )
-                if lp.kind == "conv":
-                    cur = ops.relu(cur)
-                    if quantized:
-                        # activations ride bf16 between quantized stages
-                        cur = cur.astype(jnp.bfloat16)
-            if with_digests:
-                # In-graph sentinel tap: one float32 digest of this shard's
-                # block at the layer boundary. Shard-varying (each shard
-                # digests its own rows) — concatenated to (n,) by out_specs.
-                digs[lp.name] = tree_digest(cur)[None]
+            with scopes.layer(lp.name):
+                cur = layer_body(lp, spec, params, cur)
+                if with_digests:
+                    # In-graph sentinel tap: one float32 digest of this
+                    # shard's block at the layer boundary. Shard-varying
+                    # (each shard digests its own rows) — concatenated to
+                    # (n,) by out_specs.
+                    digs[lp.name] = tree_digest(cur)[None]
         return (cur, digs) if with_digests else cur
 
     out_spec = P(None, AXIS, None, None)
@@ -275,18 +282,21 @@ def build_sharded_forward(
             # every builder expects; "w" carries the int8 values so the
             # shard body's param access pattern is unchanged, "scale"
             # marks the entry quantized.
-            params = {
-                name: {"w": e["q"], "scale": e["scale"], "b": e["b"]}
-                for name, e in quantize_conv_params(params).items()
-            }
-            x = x.astype(jnp.bfloat16)
+            with scopes.cast_in():
+                params = {
+                    name: {"w": e["q"], "scale": e["scale"], "b": e["b"]}
+                    for name, e in quantize_conv_params(params).items()
+                }
+                x = x.astype(jnp.bfloat16)
         pad = h_pad - x.shape[1]
         if pad:
-            x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+            with scopes.scatter():
+                x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        out = sharded(params, x)  # (N, n*b_final, W', C') [, digests]
         if with_digests:
-            out, digs = sharded(params, x)
-            return out[:, :l_final], digs
-        out = sharded(params, x)  # (N, n*b_final, W', C')
-        return out[:, :l_final]
+            out, digs = out
+        with scopes.gather():
+            out = out[:, :l_final]
+        return (out, digs) if with_digests else out
 
     return fwd

@@ -35,6 +35,7 @@ from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.alexnet import BLOCKS12, Blocks12Config
+from ..ops import scopes
 from ..ops.reference import conv2d, lrn, maxpool, relu
 from .mesh import make_mesh
 
@@ -105,30 +106,38 @@ def build_tp_forward(
                 digs[name] = tree_digest(v)[None]
             return v
 
+        conv1, pool1, conv2, pool2, lrn2 = scopes.BLOCKS12_LAYERS
         # Block 1 on this shard's filter slice: (B, h, w, K1/n).
-        y = tap("conv1", relu(conv2d(x, p1["w"], p1["b"], stride=cfg.conv1.stride, padding=cfg.conv1.padding)))
-        y = tap("pool1", maxpool(y, window=cfg.pool1.window, stride=cfg.pool1.stride))
-        # conv2 needs every conv1 channel: gather the channel axis (the TP
-        # boundary collective — activations are small here, 27x27x96).
-        y = lax.all_gather(y, axis_name, axis=3, tiled=True)
-        z = tap("conv2", relu(conv2d(y, p2["w"], p2["b"], stride=cfg.conv2.stride, padding=cfg.conv2.padding)))
-        z = tap("pool2", maxpool(z, window=cfg.pool2.window, stride=cfg.pool2.stride))
-        # LRN crosses channels: exchange `half` neighbor channels, normalize,
-        # keep the owned slice.
-        if n_shards > 1:
-            zp = _channel_halo(z, half, axis_name, n_shards)
-        else:
-            zp = z
-        zl = lrn(
-            zp,
-            size=cfg.lrn2.size,
-            alpha=cfg.lrn2.alpha,
-            beta=cfg.lrn2.beta,
-            k=cfg.lrn2.k,
-            alpha_over_size=cfg.lrn2.alpha_over_size,
-        )
-        out = zl[..., half:-half] if n_shards > 1 else zl
-        tap("lrn2", out)
+        with scopes.layer(conv1):
+            y = tap(conv1, relu(conv2d(x, p1["w"], p1["b"], stride=cfg.conv1.stride, padding=cfg.conv1.padding)))
+        with scopes.layer(pool1):
+            y = tap(pool1, maxpool(y, window=cfg.pool1.window, stride=cfg.pool1.stride))
+        with scopes.layer(conv2):
+            # conv2 needs every conv1 channel: gather the channel axis (the
+            # TP boundary collective — activations are small here, 27x27x96).
+            with scopes.halo(conv2):
+                y = lax.all_gather(y, axis_name, axis=3, tiled=True)
+            z = tap(conv2, relu(conv2d(y, p2["w"], p2["b"], stride=cfg.conv2.stride, padding=cfg.conv2.padding)))
+        with scopes.layer(pool2):
+            z = tap(pool2, maxpool(z, window=cfg.pool2.window, stride=cfg.pool2.stride))
+        with scopes.layer(lrn2):
+            # LRN crosses channels: exchange `half` neighbor channels,
+            # normalize, keep the owned slice.
+            if n_shards > 1:
+                with scopes.halo(lrn2):
+                    zp = _channel_halo(z, half, axis_name, n_shards)
+            else:
+                zp = z
+            zl = lrn(
+                zp,
+                size=cfg.lrn2.size,
+                alpha=cfg.lrn2.alpha,
+                beta=cfg.lrn2.beta,
+                k=cfg.lrn2.k,
+                alpha_over_size=cfg.lrn2.alpha_over_size,
+            )
+            out = zl[..., half:-half] if n_shards > 1 else zl
+        tap(lrn2, out)
         return (out, digs) if with_digests else out
 
     wspec = P(None, None, None, axis_name)  # HWIO: shard the O axis
@@ -137,7 +146,7 @@ def build_tp_forward(
         "conv2": {"w": wspec, "b": P(axis_name)},
     }
     out_spec = P(None, None, None, axis_name)
-    stages = ("conv1", "pool1", "conv2", "pool2", "lrn2")
+    stages = scopes.BLOCKS12_LAYERS
     fn = shard_map(
         local,
         mesh=mesh,

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..models.alexnet import BLOCKS12, Blocks12Config
+from ..ops import scopes
 
 QMAX = 127  # symmetric int8: [-127, 127]; -128 is unused (no zero-point)
 
@@ -119,7 +120,7 @@ def int8w_conv(
     return y.astype(jnp.bfloat16)
 
 
-def int8w_conv_then_pool(x, q, scale, b, cspec, pspec, v=None, *, tier="pallas", lrn=None):
+def int8w_conv_then_pool(x, q, scale, b, cspec, pspec, names, v=None, *, tier="pallas", lrn=None):
     """The int8w lowering unit the dtype sweep times — the quantized
     counterpart of ``ops.pallas_model._conv_then_pool`` (conv + rescale +
     bias + ReLU, then the trailing max pool under the same per-layer
@@ -130,7 +131,9 @@ def int8w_conv_then_pool(x, q, scale, b, cspec, pspec, v=None, *, tier="pallas",
     conv kernel writes bf16 before the host rescale), so megakernel int8w
     parity is tolerance-gated, not bitwise. ``lrn`` (a LrnSpec) folds the
     block's trailing LRN in either way — fused in-kernel, staged via the
-    fp32 reference LRN (the same op ``forward_blocks12_int8w`` uses)."""
+    fp32 reference LRN (the same op ``forward_blocks12_int8w`` uses).
+    ``names``: the layers' scope names, as ``_conv_then_pool`` takes them."""
+    conv_name, pool_name = names[:2]
     ho = (x.shape[1] + 2 * cspec.padding - cspec.filter_size) // cspec.stride + 1
     if tier == "pallas" and v is not None and v.fuse == "block":
         from ..ops import megakernel as mk
@@ -139,34 +142,38 @@ def int8w_conv_then_pool(x, q, scale, b, cspec, pspec, v=None, *, tier="pallas",
             variant=v.conv, row_block=v.row_block, k_block=v.k_block,
             pool=v.pool, out_h=ho, pool_window=pspec.window,
         ):
-            return mk.int8w_conv_block_pallas(
-                x, q, scale, b, stride=cspec.stride, padding=cspec.padding,
-                pool_window=pspec.window, pool_stride=pspec.stride,
-                lrn=lrn, variant=v.conv, row_block=v.row_block,
-            )
-    y = int8w_conv(
-        x, q, scale, b, stride=cspec.stride, padding=cspec.padding,
-        relu=True, tier=tier, variants=v,
-    )
-    if tier == "pallas":
-        from ..ops import pallas_kernels as pk
-
-        pool_variant = v.pool if v is not None else None
-        out = pk.maxpool_pallas(
-            y, window=pspec.window, stride=pspec.stride, variant=pool_variant
+            with scopes.layer(*names):
+                return mk.int8w_conv_block_pallas(
+                    x, q, scale, b, stride=cspec.stride, padding=cspec.padding,
+                    pool_window=pspec.window, pool_stride=pspec.stride,
+                    lrn=lrn, variant=v.conv, row_block=v.row_block,
+                )
+    with scopes.layer(conv_name):
+        y = int8w_conv(
+            x, q, scale, b, stride=cspec.stride, padding=cspec.padding,
+            relu=True, tier=tier, variants=v,
         )
-    else:
-        from ..ops import reference as ops
+    with scopes.layer(pool_name):
+        if tier == "pallas":
+            from ..ops import pallas_kernels as pk
 
-        out = ops.maxpool(y, window=pspec.window, stride=pspec.stride)
+            pool_variant = v.pool if v is not None else None
+            out = pk.maxpool_pallas(
+                y, window=pspec.window, stride=pspec.stride, variant=pool_variant
+            )
+        else:
+            from ..ops import reference as ops
+
+            out = ops.maxpool(y, window=pspec.window, stride=pspec.stride)
     if lrn is not None:
         from ..ops import reference as ops
 
-        out = ops.lrn(
-            out.astype(jnp.float32),
-            size=lrn.size, alpha=lrn.alpha, beta=lrn.beta, k=lrn.k,
-            alpha_over_size=lrn.alpha_over_size,
-        )
+        with scopes.layer(names[2]):
+            out = ops.lrn(
+                out.astype(jnp.float32),
+                size=lrn.size, alpha=lrn.alpha, beta=lrn.beta, k=lrn.k,
+                alpha_over_size=lrn.alpha_over_size,
+            )
     return out
 
 
@@ -193,8 +200,11 @@ def forward_blocks12_int8w(
     from ..ops.pallas_model import _layer_variants
     from ..ops import pallas_kernels as pk
 
-    qp = quantize_conv_params(params)
+    with scopes.cast_in():  # parameters to int8, input to bf16
+        qp = quantize_conv_params(params)
+        cur = x.astype(jnp.bfloat16)
     c1, p1, c2, p2, n2 = cfg.conv1, cfg.pool1, cfg.conv2, cfg.pool2, cfg.lrn2
+    conv1, pool1, conv2, pool2, lrn2 = scopes.BLOCKS12_LAYERS
     v = variants if variants is not None else pk.KernelVariants()
     stages = {}
 
@@ -211,47 +221,46 @@ def forward_blocks12_int8w(
         # block has no interior boundaries to tap; the gate screens fused
         # outputs at BLOCK granularity instead (precision.gate
         # ``screen_blocks``).
-        cur = x.astype(jnp.bfloat16)
-        e1, e2 = qp["conv1"], qp["conv2"]
+        e1, e2 = qp[conv1], qp[conv2]
         cur = int8w_conv_then_pool(
-            cur, e1["q"], e1["scale"], e1["b"], c1, p1,
-            _layer_variants(v, "conv1"), tier=tier,
+            cur, e1["q"], e1["scale"], e1["b"], c1, p1, (conv1, pool1),
+            _layer_variants(v, conv1), tier=tier,
         )
         return int8w_conv_then_pool(
-            cur, e2["q"], e2["scale"], e2["b"], c2, p2,
-            _layer_variants(v, "conv2"), tier=tier, lrn=n2,
+            cur, e2["q"], e2["scale"], e2["b"], c2, p2, (conv2, pool2, lrn2),
+            _layer_variants(v, conv2), tier=tier, lrn=n2,
         )
 
-    cur = x.astype(jnp.bfloat16)
+    from ..ops import reference as ops
+
     for cname, cspec, pname, pspec in (
-        ("conv1", c1, "pool1", p1),
-        ("conv2", c2, "pool2", p2),
+        (conv1, c1, pool1, p1),
+        (conv2, c2, pool2, p2),
     ):
         lv = _layer_variants(v, cname)
         e = qp[cname]
-        cur = int8w_conv(
-            cur, e["q"], e["scale"], e["b"],
-            stride=cspec.stride, padding=cspec.padding, relu=True,
-            tier=tier, variants=lv,
-        )
-        tap(cname, cur)
-        if tier == "pallas":
-            cur = pk.maxpool_pallas(
-                cur, window=pspec.window, stride=pspec.stride, variant=lv.pool
+        with scopes.layer(cname):
+            cur = int8w_conv(
+                cur, e["q"], e["scale"], e["b"],
+                stride=cspec.stride, padding=cspec.padding, relu=True,
+                tier=tier, variants=lv,
             )
-        else:
-            from ..ops import reference as ops
-
-            cur = ops.maxpool(cur, window=pspec.window, stride=pspec.stride)
+        tap(cname, cur)
+        with scopes.layer(pname):
+            if tier == "pallas":
+                cur = pk.maxpool_pallas(
+                    cur, window=pspec.window, stride=pspec.stride, variant=lv.pool
+                )
+            else:
+                cur = ops.maxpool(cur, window=pspec.window, stride=pspec.stride)
         tap(pname, cur)
-    from ..ops import reference as ops
-
-    out = ops.lrn(
-        cur.astype(jnp.float32),
-        size=n2.size, alpha=n2.alpha, beta=n2.beta, k=n2.k,
-        alpha_over_size=n2.alpha_over_size,
-    )
-    tap("lrn2", out)
+    with scopes.layer(lrn2):
+        out = ops.lrn(
+            cur.astype(jnp.float32),
+            size=n2.size, alpha=n2.alpha, beta=n2.beta, k=n2.k,
+            alpha_over_size=n2.alpha_over_size,
+        )
+    tap(lrn2, out)
     return (out, stages) if taps else out
 
 

@@ -69,6 +69,9 @@ def _default_timer(
     # candidate — fused candidates fold it in-kernel, staged candidates run
     # it as the trailing launch — so fused-vs-staged compare equal work.
     lrn = LrnSpec(*g.lrn) if g.lrn else None
+    # The scope names of this conv's block: its pool and LRN share its index.
+    idx = g.name[len("conv"):]
+    names = (g.name, f"pool{idx}", f"lrn{idx}")[: 3 if lrn else 2]
     n_small = max(1, warmup)
     if dtype == "int8w":
         # The quantized lowering unit: bf16 activations, int8-valued bf16
@@ -86,7 +89,7 @@ def _default_timer(
         if pspec is not None:
             fn = jax.jit(
                 lambda x, q, s, b: int8w_conv_then_pool(
-                    x, q, s, b, cspec, pspec, v, tier="pallas", lrn=lrn
+                    x, q, s, b, cspec, pspec, names, v, tier="pallas", lrn=lrn
                 )
             )
         else:
@@ -110,7 +113,7 @@ def _default_timer(
     b = jnp.zeros((g.out_channels,), jdt)
     if g.has_pool:
         fn = jax.jit(
-            lambda x, w, b: _conv_then_pool(x, w, b, cspec, pspec, v, lrn=lrn)
+            lambda x, w, b: _conv_then_pool(x, w, b, cspec, pspec, v, names, lrn=lrn)
         )
     else:
         fn = jax.jit(

@@ -39,4 +39,9 @@ def enable_persistent_cache() -> str:
     # without floor overrides JAX would skip caching them entirely.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # JAX keys the cache on the program with its metadata stripped, so a
+    # cached executable keeps the op names of whichever build wrote it. A
+    # profile must carry THIS build's layer names (ops/scopes.py): key on
+    # the metadata too.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return jax.config.jax_compilation_cache_dir

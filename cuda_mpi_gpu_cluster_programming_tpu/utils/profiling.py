@@ -1,4 +1,4 @@
-"""Profiling: per-layer breakdown, named scopes, and trace capture.
+"""Profiling: per-layer breakdown and trace capture.
 
 The reference's only profiling is one wall-clock print per pass — the
 "completed in X ms" line its harness regexes (SURVEY §5.1: "timing
@@ -6,13 +6,13 @@ print-format IS the profiling API") — while per-phase breakdowns and real
 profilers are documented as future work (reference README.md:233,720-735).
 This module ships them:
 
-- :func:`forward_annotated` — the Blocks 1-2 pass with ``jax.named_scope``
-  around every layer, so XLA profiler traces attribute time per layer.
 - :func:`layer_breakdown` — fenced per-layer wall timing (each prefix of the
   layer chain jitted separately; per-layer cost by differencing is wrong on
   an async device, so each stage is timed end-to-end on its own).
 - :func:`trace` — ``jax.profiler.trace`` wrapper writing a TensorBoard-able
-  trace directory.
+  trace directory. The production forwards name every layer themselves
+  (``jax.named_scope``, ``ops/scopes.py``), so a trace of ``run.py --profile
+  DIR`` attributes device time per layer with no second copy of the forward.
 """
 
 from __future__ import annotations
@@ -131,14 +131,6 @@ def _tier_ops(tier: str):
 
         return conv, pool, lrn, True
     raise ValueError(f"tier must be reference|pallas, got {tier!r}")
-
-
-def forward_annotated(params: Params, x: jax.Array, cfg=BLOCKS12) -> jax.Array:
-    """The model's forward pass with a named scope per layer (for traces)."""
-    for name, fn in stage_fns(cfg):
-        with jax.named_scope(name):
-            x = fn(params, x)
-    return x
 
 
 def layer_breakdown(

@@ -1,0 +1,132 @@
+"""Every production forward names its layers where they run: the compiled
+program of each execution config carries the scopes of ``ops/scopes.py`` in
+``metadata={op_name=...}`` (the CPU compiler keeps them as the TPU's does), so
+a device trace of any of them splits by layer. Tiny sizes; sharded entries on
+the virtual CPU devices."""
+
+from __future__ import annotations
+
+import re
+
+import jax
+import pytest
+
+from cuda_mpi_gpu_cluster_programming_tpu.configs import REGISTRY, build_forward
+from cuda_mpi_gpu_cluster_programming_tpu.models.alexnet import Blocks12Config
+from cuda_mpi_gpu_cluster_programming_tpu.models.alexnet_full import (
+    AlexNetConfig,
+    init_full_deterministic,
+)
+from cuda_mpi_gpu_cluster_programming_tpu.models.init import init_params_deterministic
+from cuda_mpi_gpu_cluster_programming_tpu.ops import scopes
+
+SMALL = Blocks12Config(in_height=63, in_width=63)
+SMALL_FULL = AlexNetConfig(  # 99x99 leaves pool5 a 2x2 map to flatten
+    blocks12=Blocks12Config(in_height=99, in_width=99), fc6=32, fc7=32, num_classes=10
+)
+HALO_LAYERS = ("conv1", "pool1", "conv2", "pool2")  # the layers whose windows cross rows
+
+
+def _paths(fwd, params, x):
+    """Every ``op_name`` of the compiled program, in the text's order."""
+    text = fwd.lower(params, x).compile().as_text()
+    return re.findall(r'op_name="((?:[^"\\]|\\.)*)"', text)
+
+
+def _scoped(paths, scope):
+    return [p for p in paths if scope in p.split("/")[:-1]]
+
+
+def _build(key, compute="fp32"):
+    exec_cfg = REGISTRY[key]
+    full = exec_cfg.model == "alexnet_full"
+    model_cfg = SMALL_FULL if full else SMALL
+    # four shards: the 2 output rows then leave padding for the gather to slice off
+    n_shards = 1 if exec_cfg.strategy == "single" else 4
+    fwd = build_forward(exec_cfg, model_cfg, n_shards=n_shards, compute=compute)
+    params = init_full_deterministic(model_cfg) if full else init_params_deterministic(model_cfg)
+    x = jax.ShapeDtypeStruct(
+        (2, model_cfg.in_height, model_cfg.in_width, model_cfg.in_channels), "float32"
+    )
+    return exec_cfg, fwd, params, x
+
+
+@pytest.mark.parametrize("key", sorted(REGISTRY))
+def test_every_layer_of_the_chain_is_named_in_the_compiled_program(key):
+    exec_cfg, fwd, params, x = _build(key)
+    paths = _paths(fwd, params, x)
+    chain = scopes.BLOCKS12_LAYERS
+    if exec_cfg.model == "alexnet_full":
+        chain += scopes.ALEXNET_TAIL_LAYERS + scopes.FC_LAYERS
+    for layer in chain:
+        assert _scoped(paths, layer), f"{key}: no operation under the scope {layer!r}"
+    # in order: the jaxpr is the program as written, before any scheduling
+    stacks = list(_name_stacks(jax.make_jaxpr(fwd)(params, x).jaxpr))
+    seen = []
+    for stack in stacks:
+        for part in stack.split("/"):
+            if part in chain and part not in seen:
+                seen.append(part)
+    assert tuple(seen) == chain, f"{key}: layers run as {seen}"
+    if exec_cfg.strategy in ("halo", "staged_halo"):
+        for layer in HALO_LAYERS:
+            inside = [p for p in paths if f"/{layer}/{scopes.HALO_PREFIX}{layer}/" in p]
+            assert inside, f"{key}: no halo.{layer} nested in {layer}"
+        # the pad before the shard_map keeps its name through the compiler;
+        # the slice after it is rewritten with the all-gather the partitioner
+        # puts in and comes out with none (the benchmark counts a collective
+        # that has no scope of its own under gather, by its opcode)
+        assert _scoped(paths, scopes.SCATTER), f"{key}: nothing under scatter"
+        assert any(scopes.GATHER in s.split("/") for s in stacks), f"{key}: nothing under gather"
+    if exec_cfg.strategy == "tp":
+        assert [p for p in paths if "/conv2/halo.conv2/" in p]  # the channel all-gather
+        assert [p for p in paths if "/lrn2/halo.lrn2/" in p]  # the LRN's channel halo
+
+
+def _name_stacks(jaxpr):
+    """The name stack of every equation, through nested jaxprs, in order."""
+    for eqn in jaxpr.eqns:
+        yield str(eqn.source_info.name_stack)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _name_stacks(sub)
+
+
+@pytest.mark.parametrize("key,compute", [("v1_jit", "bf16"), ("v1_jit", "int8w"), ("v2.2_sharded", "int8w")])
+def test_the_casts_to_the_compute_type_are_named(key, compute):
+    _cfg, fwd, params, x = _build(key, compute)
+    paths = _paths(fwd, params, x)
+    assert [p for p in _scoped(paths, scopes.CAST_IN) if "convert_element_type" in p]
+    for layer in scopes.BLOCKS12_LAYERS:
+        assert _scoped(paths, layer), layer
+
+
+def test_a_kernel_that_covers_conv_and_pool_says_so():
+    """``fuse="block"`` runs a block as one kernel: its scope names every
+    layer it covers, and no operation claims to be the convolution alone."""
+    from cuda_mpi_gpu_cluster_programming_tpu.ops import pallas_kernels as pk
+    from cuda_mpi_gpu_cluster_programming_tpu.ops.pallas_model import forward_blocks12_pallas
+
+    fwd = jax.jit(
+        lambda p, x: forward_blocks12_pallas(p, x, SMALL, variants=pk.KernelVariants(fuse="block"))
+    )
+    x = jax.ShapeDtypeStruct((1, 63, 63, 3), "float32")
+    paths = _paths(fwd, init_params_deterministic(SMALL), x)
+    assert _scoped(paths, "conv1+pool1") and _scoped(paths, "conv2+pool2+lrn2")
+    assert not _scoped(paths, "conv1") and not _scoped(paths, "lrn2")
+
+
+def test_a_name_outside_the_vocabulary_is_refused():
+    with pytest.raises(ValueError, match="not a layer name"):
+        scopes.layer("conv9")
+    with pytest.raises(ValueError, match="not a layer name"):
+        scopes.halo("cast_in")
+
+
+def test_the_chains_and_the_sentinel_stages_take_their_names_from_the_scopes():
+    from cuda_mpi_gpu_cluster_programming_tpu.observability.stages import SENTINEL_STAGES
+
+    assert SENTINEL_STAGES is scopes.BLOCKS12_LAYERS
+    assert tuple(n for n, _s in SMALL.layer_chain()) == scopes.BLOCKS12_LAYERS
+    assert tuple(n for n, _s in SMALL_FULL.layer_chain()) == (
+        scopes.BLOCKS12_LAYERS + scopes.ALEXNET_TAIL_LAYERS
+    )

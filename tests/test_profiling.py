@@ -1,4 +1,5 @@
-"""Profiling subsystem: breakdown correctness, annotated-pass equivalence."""
+"""Profiling subsystem: breakdown correctness, and the production forward as
+the annotated one (it names its layers itself; there is no second copy)."""
 
 import glob
 import os
@@ -15,11 +16,29 @@ from cuda_mpi_gpu_cluster_programming_tpu.utils import profiling
 
 
 def test_annotated_forward_matches_plain():
+    """The production forward carries a scope per layer, in order, and the
+    scopes change nothing it computes: it equals the bare ops in sequence."""
+    from cuda_mpi_gpu_cluster_programming_tpu.ops import reference as ops
+    from cuda_mpi_gpu_cluster_programming_tpu.ops import scopes
+
+    def plain(p, x, c=BLOCKS12):
+        x = ops.relu(ops.conv2d(x, p["conv1"]["w"], p["conv1"]["b"], stride=c.conv1.stride, padding=c.conv1.padding))
+        x = ops.maxpool(x, window=c.pool1.window, stride=c.pool1.stride)
+        x = ops.relu(ops.conv2d(x, p["conv2"]["w"], p["conv2"]["b"], stride=c.conv2.stride, padding=c.conv2.padding))
+        x = ops.maxpool(x, window=c.pool2.window, stride=c.pool2.stride)
+        n = c.lrn2
+        return ops.lrn(x, size=n.size, alpha=n.alpha, beta=n.beta, k=n.k, alpha_over_size=n.alpha_over_size)
+
     params = init_params_deterministic()
     x = deterministic_input(batch=1)
-    a = jax.jit(profiling.forward_annotated)(params, x)
-    b = jax.jit(forward_blocks12)(params, x)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    fwd = jax.jit(forward_blocks12)
+    np.testing.assert_array_equal(
+        np.asarray(fwd(params, x)), np.asarray(jax.jit(plain)(params, x))
+    )
+    text = fwd.lower(params, x).compile().as_text()
+    at = [text.find(f"/{name}/") for name in scopes.BLOCKS12_LAYERS]
+    assert all(i >= 0 for i in at), dict(zip(scopes.BLOCKS12_LAYERS, at))
+    assert "/conv1/" not in jax.jit(plain).lower(params, x).compile().as_text()
 
 
 def test_stage_fns_compose_to_forward():
@@ -49,7 +68,7 @@ def test_trace_writes_files(tmp_path):
     x = deterministic_input(batch=1)
     d = str(tmp_path / "trace")
     with profiling.trace(d):
-        jax.block_until_ready(jax.jit(profiling.forward_annotated)(params, x))
+        jax.block_until_ready(jax.jit(forward_blocks12)(params, x))
     assert glob.glob(os.path.join(d, "**", "*"), recursive=True)
 
 
