@@ -256,10 +256,22 @@ def build_forward(
             pol.name,
             1,
         )
-    fwd = _build_forward_fp32(exec_cfg, model_cfg, n_shards, mesh, plan, donate)
-    if pol.name == "fp32":
-        return _observed(fwd, exec_cfg, pol.name, n_shards)
     import jax.numpy as jnp
+
+    bf16 = pol.name != "fp32"
+    # The row-sharded Blocks 1-2 forward casts for itself: the input where
+    # it is held, before it is scattered in the compute type, the parameters
+    # inside its step program (parallel.sharded). A jit round it would hand
+    # it a tracer, and with that the whole input to every device.
+    casts_itself = exec_cfg.model == "blocks12" and exec_cfg.strategy in (
+        "halo", "staged_halo"
+    )
+    fwd = _build_forward_fp32(
+        exec_cfg, model_cfg, n_shards, mesh, plan, donate,
+        compute_dtype=jnp.bfloat16 if bf16 and casts_itself else None,
+    )
+    if casts_itself or not bf16:
+        return _observed(fwd, exec_cfg, pol.name, n_shards)
 
     from .ops import scopes
 
@@ -306,6 +318,7 @@ def _build_forward_fp32(
     mesh: Optional[jax.sharding.Mesh] = None,
     plan=None,
     donate: bool = False,
+    compute_dtype=None,
 ) -> Callable:
     need = n_shards if exec_cfg.strategy != "single" else 1
     if mesh is None and jax.device_count() < need:
@@ -380,6 +393,7 @@ def _build_forward_fp32(
             tier=exec_cfg.tier,
             staged=(exec_cfg.strategy == "staged_halo"),
             plan=plan,
+            compute_dtype=compute_dtype,
         )
 
     if exec_cfg.strategy == "tp":

@@ -16,22 +16,48 @@ Structure per spatial layer, inside ``shard_map``:
 4. re-mask rows beyond the owned range to zero (the mask invariant that
    makes halo zeros coincide with global conv padding).
 
-MPI-primitive correspondence: Scatterv -> sharded array construction;
-Irecv/Isend halo -> ppermute; Gatherv -> out_specs concatenation + final
-slice; Barrier/Wtime -> block_until_ready + host timing.
+MPI-primitive correspondence: Scatterv -> ``ppermute`` of row blocks from
+the device that holds the batch (:class:`RowScatteredForward`); Irecv/Isend
+halo -> ppermute; Gatherv -> out_specs concatenation + final slice;
+Barrier/Wtime -> block_until_ready + host timing.
+
+What happens to ``x`` depends on where it lives, which the code can see:
+
+- a concrete array on ONE device (committed or not): the step program
+  itself scatters it. The program's argument is a batch-sharded global
+  array whose shard on the holder is ``x`` as it stands (no copy) and whose
+  other shards are stand-ins that are never read; inside, the holder does
+  ``cast_in`` and the ``scatter`` pad, cuts the result into the ``n`` row
+  blocks ``(N, b0, W, C)`` in the compute type and sends block ``j`` to
+  device ``j`` by a ``ppermute`` with itself as the one source. One
+  dispatch a step, and nothing is replicated (a jitted multi-device program
+  treats a single-device argument as replicated: the whole float32 batch
+  went from the holder to every device before every step);
+- a host (``numpy``) array: cut into row blocks on the host, each block
+  copied straight to its owner, cast there by the step program;
+- an array that already has the row sharding ``P(None, "sp")`` over the
+  padded height: straight into the step program;
+- a tracer (the forward called inside an outer ``jit``: training, the
+  full-AlexNet sharded forward) or an array laid out any other way: the
+  in-graph pad and slice, one program.
+
+Parameters are replicated, and placed on every device once per tree.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax, shard_map
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models.alexnet import BLOCKS12, Blocks12Config
+from ..observability.metrics import registry as metrics_registry
 from ..ops import reference as ops
 from ..ops import scopes
 from ..ops.vma import kernel_check_vma
@@ -121,11 +147,26 @@ def build_sharded_forward(
     with_digests: bool = False,
     plan=None,
     quantized: bool = False,
+    compute_dtype=None,
 ) -> Callable:
-    """Jitted ``(params, x) -> out`` running row-sharded over ``n_shards``.
+    """``(params, x) -> out`` running row-sharded over ``n_shards``.
 
     ``x`` is the full (N, H, W, C) array; output is the full
-    (N, H', W', C') array — scatter/gather are implicit in the shardings.
+    (N, H', W', C') array. With one shard the result is one jitted program.
+    With more it is a :class:`RowScatteredForward`, which brings ``x`` to
+    its owners by where it lives (module docstring): scattered in row blocks
+    from the one device that holds it, inside the step program; cut on the
+    host and copied block by block if it is a host array; passed straight
+    through if it already has the row sharding over the padded height;
+    padded and sliced in-graph if it is a tracer (the forward called inside
+    an outer ``jit``) or laid out any other way. ``.lower(params, x)`` gives
+    the step program lowered for the public signature.
+
+    ``compute_dtype``: the compute type of ``configs.build_forward``'s bf16
+    mode. Parameters and ``x`` are cast to it inside the program under the
+    ``cast_in`` scope (``x`` on its holder, before it is cut, so that the
+    blocks travel in the compute type), and the output comes back float32.
+    ``None`` computes in the dtype of what arrives.
 
     ``with_digests``: additionally return a per-stage activation digest
     tree, ``(out, {layer_name: (n_shards,) float32})`` — one
@@ -270,11 +311,14 @@ def build_sharded_forward(
         check_vma=(tier != "pallas" or kernel_check_vma()),
     )
 
-    h_pad = n * splan.layers[0].b_in  # SPMD needs equal blocks: pad H to n*b0
+    b0 = splan.layers[0].b_in
+    h_pad = n * b0  # SPMD needs equal blocks: pad H to n*b0
     l_final = splan.l_final
+    # What x is cast to before the first layer: int8w runs bf16 activations,
+    # a compute type is the caller's, None leaves x as it comes.
+    x_dtype = jnp.bfloat16 if quantized else compute_dtype
 
-    @jax.jit
-    def fwd(params, x):
+    def cast_params(params):
         if quantized:
             from ..precision.quantize import quantize_conv_params
 
@@ -283,20 +327,215 @@ def build_sharded_forward(
             # shard body's param access pattern is unchanged, "scale"
             # marks the entry quantized.
             with scopes.cast_in():
-                params = {
+                return {
                     name: {"w": e["q"], "scale": e["scale"], "b": e["b"]}
                     for name, e in quantize_conv_params(params).items()
                 }
-                x = x.astype(jnp.bfloat16)
+        if compute_dtype is None:
+            return params
+        with scopes.cast_in():
+            return jax.tree.map(lambda a: a.astype(compute_dtype), params)
+
+    def cast_x(x):
+        if x_dtype is None or x.dtype == x_dtype:
+            return x
+        with scopes.cast_in():
+            return x.astype(x_dtype)
+
+    def pad_rows(x):
         pad = h_pad - x.shape[1]
         if pad:
             with scopes.scatter():
                 x = jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        out = sharded(params, x)  # (N, n*b_final, W', C') [, digests]
+        return x
+
+    def step(params, xb):
+        # xb: (N, n*b0, W, C), zero rows past H; row-sharded where the
+        # input sharding is declared
+        out = sharded(cast_params(params), cast_x(xb))  # (N, n*b_final, W', C') [, digests]
         if with_digests:
             out, digs = out
         with scopes.gather():
             out = out[:, :l_final]
+        if compute_dtype is not None:
+            out = out.astype(jnp.float32)
         return (out, digs) if with_digests else out
 
-    return fwd
+    def fwd(params, x):
+        return step(params, pad_rows(cast_x(x)))
+
+    if n == 1:
+        return jax.jit(fwd)
+
+    def scatter_step_from(src: int):
+        def scatter_body(xl):
+            # xl (N, H, W, C): the batch on device src, its stand-in elsewhere
+            xl = pad_rows(cast_x(xl))
+            with scopes.scatter():
+                blocks = [xl[:, j * b0 : (j + 1) * b0] for j in range(n)]
+                arrived = [
+                    blocks[j] if j == src
+                    else lax.ppermute(blocks[j], AXIS, perm=[(src, j)])
+                    for j in range(n)
+                ]
+                return lax.select_n(lax.axis_index(AXIS), *arrived)
+
+        scatter = shard_map(
+            scatter_body, mesh=mesh, in_specs=P(AXIS), out_specs=P(None, AXIS, None, None)
+        )
+
+        def scatter_step(params, xg):
+            return step(params, scatter(xg))
+
+        return jax.jit(scatter_step, in_shardings=(replicated, NamedSharding(mesh, P(AXIS))))
+
+    replicated = NamedSharding(mesh, P())
+    rows = NamedSharding(mesh, P(None, AXIS, None, None))
+    return RowScatteredForward(
+        whole=jax.jit(fwd),
+        step=jax.jit(step, in_shardings=(replicated, rows)),
+        scatter_step=functools.cache(scatter_step_from),
+        rows=rows,
+        b0=b0,
+        x_dtype=x_dtype,
+    )
+
+
+SCATTERED_CALLS = "sharding.scatter.scattered_calls"
+PLACED_CALLS = "sharding.scatter.placed_calls"
+IN_GRAPH_CALLS = "sharding.scatter.in_graph_calls"
+SCATTERED_BYTES = "sharding.scatter.bytes_off_holder"
+
+
+class RowScatteredForward:
+    """``(params, x) -> out`` over a row-sharded step, with ``x`` brought to
+    its owners by where it lives (module docstring). Three programs, each
+    built when its kind of argument first comes: ``scatter_step(src)``
+    (x on mesh device ``src``: the scatter and the step in one), ``step``
+    (x row-sharded already, or cut on the host) and ``whole`` (the in-graph
+    pad and slice).
+
+    Counts in the process-wide registry (``observability.metrics``), host
+    integers only: ``sharding.scatter.scattered_calls`` (x arrived on one
+    device, or on the host, and went out in row blocks),
+    ``sharding.scatter.bytes_off_holder`` (the bytes that left the device,
+    or host, that held x, summed over those calls: a constant per shape and
+    call), ``sharding.scatter.placed_calls`` (x already had the row
+    sharding) and ``sharding.scatter.in_graph_calls`` (x a tracer, counted
+    once per trace, or laid out some other way).
+    """
+
+    def __init__(self, *, whole, step, scatter_step, rows: NamedSharding, b0: int, x_dtype):
+        self.whole, self.step, self.scatter_step = whole, step, scatter_step
+        self.rows, self.b0, self.x_dtype = rows, b0, x_dtype
+        self.devices = tuple(rows.mesh.devices.flat)  # block i's owner
+        self.h_pad = len(self.devices) * b0
+        self.replicated = NamedSharding(rows.mesh, P())
+        self.batch_sharded = NamedSharding(rows.mesh, P(AXIS))
+        self._stand_ins = {}  # (shape, dtype, src) -> x's stand-ins on the other devices
+        self._placed_params = ((), None)  # (the leaves last seen, their tree on every device)
+
+    def _is_placed(self, x) -> bool:
+        sharding = getattr(x, "sharding", None)
+        return (
+            sharding is not None
+            and x.shape[1] == self.h_pad
+            and sharding.is_equivalent_to(self.rows, len(x.shape))
+        )
+
+    def _on_every_device(self, params):
+        """``params`` replicated over the mesh, placed once for a tree that
+        comes again: left where a caller holds them (on one device,
+        uncommitted) the runtime would send every leaf to every device before
+        every step, 2 ms of host time a call on four v5e chips (PERF.md,
+        PR 26). Arrays are immutable, so the same leaves are the same values;
+        a tree with host leaves is left to the runtime."""
+        leaves = jax.tree.leaves(params)
+        seen, placed = self._placed_params
+        if len(leaves) == len(seen) and all(a is b for a, b in zip(leaves, seen)):
+            return placed
+        if not all(isinstance(a, jax.Array) for a in leaves):
+            return params
+        placed = jax.device_put(params, self.replicated)
+        self._placed_params = (leaves, placed)
+        return placed
+
+    def __call__(self, params, x):
+        reg = metrics_registry()
+        if any(isinstance(a, jax.core.Tracer) for a in jax.tree.leaves((params, x))):
+            reg.counter(IN_GRAPH_CALLS).inc()
+            return self.whole(params, x)
+        params = self._on_every_device(params)
+        if self._is_placed(x):
+            reg.counter(PLACED_CALLS).inc()
+            return self.step(params, x)
+        if not isinstance(x, jax.Array):
+            blocks = self._host_rows(np.asarray(x))
+            reg.counter(SCATTERED_CALLS).inc()
+            reg.counter(SCATTERED_BYTES).inc(sum(b.nbytes for b in blocks))
+            xb = jax.make_array_from_single_device_arrays(
+                (x.shape[0], self.h_pad, *x.shape[2:]),
+                self.rows,
+                list(jax.device_put(blocks, self.devices)),
+            )
+            return self.step(params, xb)
+        if len(x.sharding.device_set) != 1:
+            reg.counter(IN_GRAPH_CALLS).inc()
+            return self.whole(params, x)
+        (holder,) = x.sharding.device_set
+        n = len(self.devices)
+        if holder in self.devices:
+            src = self.devices.index(holder)
+            itemsize = np.dtype(self.x_dtype or x.dtype).itemsize
+            off_holder = (n - 1) * x.shape[0] * self.b0 * math.prod(x.shape[2:]) * itemsize
+        else:  # x leaves its holder whole, for the first device of the mesh
+            src, off_holder = 0, x.nbytes
+            x = jax.device_put(x, self.devices[0])
+        reg.counter(SCATTERED_CALLS).inc()
+        reg.counter(SCATTERED_BYTES).inc(off_holder)
+        shards = list(self._stand_ins_for(x, src))
+        shards.insert(src, x)
+        xg = jax.make_array_from_single_device_arrays(
+            (n * x.shape[0], *x.shape[1:]), self.batch_sharded, shards
+        )
+        return self.scatter_step(src)(params, xg)
+
+    def _stand_ins_for(self, x, src: int):
+        """One array of x's shape on every device but ``src``: an SPMD
+        program's argument has a shard on each device, and only ``src``'s is
+        read (the others send nothing). Made once per shape, on the device
+        that holds x and copied from there, so the host makes nothing."""
+        key = (x.shape, x.dtype, src)
+        if key not in self._stand_ins:
+            others = [d for i, d in enumerate(self.devices) if i != src]
+            self._stand_ins[key] = tuple(jax.device_put([jnp.zeros_like(x)] * len(others), others))
+        return self._stand_ins[key]
+
+    def _host_rows(self, x):
+        """The row blocks of a host array, zero rows past H: views, but for
+        the blocks that reach past the image's end."""
+        blocks = []
+        for i in range(len(self.devices)):
+            block = x[:, i * self.b0 : (i + 1) * self.b0]
+            short = self.b0 - block.shape[1]
+            if short:
+                block = np.pad(block, ((0, 0), (0, short), (0, 0), (0, 0)))
+            blocks.append(block)
+        return tuple(blocks)
+
+    def lower(self, params, x):
+        """The step program lowered for this ``(params, x)`` of the public
+        signature, concrete or ``ShapeDtypeStruct``: the one that holds the
+        scatter, the halos, the layers and the gather, on the abstract input
+        that ``__call__`` would hand it."""
+        if self._is_placed(x) or isinstance(x, np.ndarray):
+            xb = jax.ShapeDtypeStruct(
+                (x.shape[0], self.h_pad, *x.shape[2:]), x.dtype, sharding=self.rows
+            )
+            return self.step.lower(params, xb)
+        holders = getattr(getattr(x, "sharding", None), "device_set", ())
+        src = next((self.devices.index(d) for d in holders if d in self.devices), 0)
+        xg = jax.ShapeDtypeStruct(
+            (len(self.devices) * x.shape[0], *x.shape[1:]), x.dtype, sharding=self.batch_sharded
+        )
+        return self.scatter_step(src).lower(params, xg)

@@ -432,19 +432,14 @@ class ToleranceGate:
                 self._journal(res, key or f"gate-sharded:{pol.name}|n{n_shards}")
                 return res
         want = staged_policy_outputs(params, x, model_cfg, "fp32")["lrn2"]
+        # bf16 rung: the same casts configs.build_forward ships.
+        bf16 = not (pol.quantized or pol.name == "fp32")
         fwd = build_sharded_forward(
             model_cfg, n_shards, tier=tier, staged=staged,
             quantized=pol.quantized,
+            compute_dtype=jnp.bfloat16 if bf16 else None,
         )
-        if pol.quantized or pol.name == "fp32":
-            got = np.asarray(fwd(params, x), np.float32)
-        else:
-            # bf16 rung: the same cast wrapper configs.build_forward ships.
-            pb = {
-                name: {k2: a.astype(jnp.bfloat16) for k2, a in p.items()}
-                for name, p in params.items()
-            }
-            got = np.asarray(fwd(pb, x.astype(jnp.bfloat16)), np.float32)
+        got = np.asarray(fwd(params, x), np.float32)
         stage = f"lrn2@n{n_shards}"
         diff = float(np.max(np.abs(got - want))) if want.size else 0.0
         denom = float(np.max(np.abs(want))) if want.size else 0.0

@@ -134,3 +134,196 @@ def test_sharded_forward_is_differentiable():
     leaves = jax.tree_util.tree_leaves(g)
     assert all(np.all(np.isfinite(np.asarray(l))) for l in leaves)
     assert any(np.abs(np.asarray(l)).max() > 0 for l in leaves)
+
+
+# ---- the scatter (PR 26): x goes to its owners by where it lives ----------
+
+SMALL = dataclasses.replace(BLOCKS12, in_height=63, in_width=63)
+
+
+def _small_case(seed: int = 7, batch: int = 2):
+    kp, kx = jax.random.split(jax.random.PRNGKey(seed))
+    return init_params_random(kp, SMALL), jax.random.uniform(kx, (batch, 63, 63, 3))
+
+
+def _arrive(fwd, x, where: str):
+    """``x`` as one kind of caller hands it over."""
+    if where == "uncommitted":
+        return x
+    if where == "committed_in_mesh":
+        return jax.device_put(x, fwd.devices[-1])
+    if where == "committed_outside_mesh":
+        return jax.device_put(x, jax.devices()[-1])
+    if where == "host":
+        return np.asarray(x)
+    assert where == "row_sharded"
+    import jax.numpy as jnp
+
+    padded = jnp.pad(x, ((0, 0), (0, fwd.h_pad - x.shape[1]), (0, 0), (0, 0)))
+    return jax.device_put(padded, fwd.rows)
+
+
+@pytest.mark.parametrize(
+    "where",
+    ["uncommitted", "committed_in_mesh", "committed_outside_mesh", "row_sharded", "host", "tracer"],
+)
+@pytest.mark.parametrize("compute", ["fp32", "bf16"])
+@pytest.mark.parametrize("n", [2, 3, 4, 8])
+def test_scatter_equals_in_graph_bitwise(n, compute, where):
+    """Wherever x lives, the output is the in-graph program's, bit for bit:
+    63 rows pad to 64 at 2, 4 and 8 shards and not at all at 3."""
+    import jax.numpy as jnp
+
+    params, x = _small_case()
+    fwd = build_sharded_forward(
+        SMALL, n_shards=n, compute_dtype=jnp.bfloat16 if compute == "bf16" else None
+    )
+    want = np.asarray(fwd.whole(params, x))
+    if where == "tracer":
+        got = jax.jit(lambda p, v: fwd(p, v))(params, x)
+    else:
+        got = fwd(params, _arrive(fwd, x, where))
+    assert got.dtype == want.dtype == np.float32
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+@pytest.mark.parametrize(
+    "tier,staged,with_digests,quantized",
+    [
+        ("reference", True, False, False),
+        ("reference", False, True, False),
+        ("reference", False, False, True),
+        ("pallas", False, False, False),
+        ("pallas", True, False, False),
+        ("pallas", False, True, False),
+    ],
+)
+def test_scatter_equals_in_graph_bitwise_every_build(tier, staged, with_digests, quantized):
+    """The tiers, the staged transport, the digest taps and the int8w
+    forward (whose activations travel in bf16) all take the scattered x."""
+    params, x = _small_case(seed=9)
+    fwd = build_sharded_forward(
+        SMALL, n_shards=4, tier=tier, staged=staged,
+        with_digests=with_digests, quantized=quantized,
+    )
+    want, got = fwd.whole(params, x), fwd(params, x)
+    for w, g in zip(jax.tree.leaves(want), jax.tree.leaves(got), strict=True):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    if with_digests:
+        assert all(d.shape == (4,) for d in got[1].values())
+
+
+@pytest.mark.parametrize("abstract", [False, True])
+def test_step_program_moves_only_row_blocks(abstract):
+    """The point of PR 26, held in the compiled step program at four shards
+    and the real geometry: what crosses between devices on the input side is
+    one row block in the compute type to each other device, each by a
+    collective-permute with the holder as its one source; the whole input is
+    never gathered or replicated (each device's parameter is the float32
+    batch, real on the holder alone). And ``fwd.lower`` of the public
+    signature (concrete arguments, or bare ``ShapeDtypeStruct``s as the
+    benchmark's readers pass) gives that program, with the halos, the
+    convolutions and the scopes."""
+    import re
+
+    import jax.numpy as jnp
+
+    params = init_params_deterministic()
+    x = deterministic_input(batch=2)
+    if abstract:
+        params = jax.eval_shape(lambda: params)
+        x = jax.ShapeDtypeStruct(x.shape, jnp.float32)
+    fwd = build_sharded_forward(BLOCKS12, n_shards=4, compute_dtype=jnp.bfloat16)
+    lowered = fwd.lower(params, x)
+    # what is sent, as the program is written (the CPU's compiler widens a
+    # bf16 collective to float32; the TPU's sends it as it is)
+    sent = re.findall(
+        r"stablehlo\.(collective_permute|all_gather|all_to_all|all_reduce)\S*\(.*?"
+        r"(?:source_target_pairs = dense<(\[\[.*?\]\])>.*?)?: \(tensor<(\w+)>\)",
+        lowered.as_text(),
+    )
+    of_the_input = [(op, pairs, t) for op, pairs, t in sent if t.endswith("x227x3xbf16")]
+    assert [(op, pairs) for op, pairs, _t in of_the_input[:3]] == [
+        ("collective_permute", f"[[0, {j}]]") for j in (1, 2, 3)
+    ], sent
+    assert [t for _op, _pairs, t in of_the_input[:3]] == ["2x57x227x3xbf16"] * 3, sent
+    # the rest are conv1's halo rows: nothing else of the input moves
+    assert all(int(t.split("x")[1]) < 10 for _op, _pairs, t in of_the_input[3:]), sent
+    assert not [t for _op, _pairs, t in sent if t.endswith("x227x3xf32")], sent
+    text = lowered.compile().as_text()
+    entry = text[text.index("ENTRY") :]
+    inputs = re.findall(r"= (\w+\[[\d,]*\])\S* parameter\(\d+\), sharding=\{devices=", entry)
+    assert inputs == ["f32[2,227,227,3]"], inputs  # a quarter of the global (4*2)-image array
+    assert "collective-permute" in text and "convolution" in text
+    for layer in ("conv1", "pool1", "conv2", "pool2"):
+        assert f"halo.{layer}" in text
+    assert "/cast_in/" in text and "/scatter/" in text
+
+
+def test_scatter_counters():
+    """Scattered, already-placed and in-graph calls, and the bytes that
+    leave the device that held x, read what each kind of argument gives."""
+    import jax.numpy as jnp
+
+    from cuda_mpi_gpu_cluster_programming_tpu.observability.metrics import registry
+    from cuda_mpi_gpu_cluster_programming_tpu.parallel import sharded
+
+    def read():
+        got = registry().summary()
+        return [
+            got.get(name, 0)
+            for name in (
+                sharded.SCATTERED_CALLS, sharded.PLACED_CALLS,
+                sharded.IN_GRAPH_CALLS, sharded.SCATTERED_BYTES,
+            )
+        ]
+
+    params, x = _small_case()
+    fwd = build_sharded_forward(SMALL, n_shards=4, compute_dtype=jnp.bfloat16)
+    block = 2 * 16 * 63 * 3  # (N, b0, W, C) elements: 64 rows over 4 shards
+    before = read()
+    fwd(params, x)  # held by device 0, which owns block 0: three blocks leave, in bf16
+    fwd(params, x)
+    assert [a - b for a, b in zip(read(), before)] == [2, 0, 0, 2 * 3 * block * 2]
+    before = read()
+    fwd(params, jax.device_put(x, jax.devices()[2]))  # device 2 keeps block 2
+    assert [a - b for a, b in zip(read(), before)] == [1, 0, 0, 3 * block * 2]
+    before = read()
+    fwd(params, jax.device_put(x, jax.devices()[-1]))  # held outside the mesh: x leaves whole
+    assert [a - b for a, b in zip(read(), before)] == [1, 0, 0, x.nbytes]
+    before = read()
+    fwd(params, np.asarray(x))  # from the host: four blocks leave, in float32
+    assert [a - b for a, b in zip(read(), before)] == [1, 0, 0, 4 * block * 4]
+    before = read()
+    fwd(params, _arrive(fwd, x, "row_sharded"))
+    assert [a - b for a, b in zip(read(), before)] == [0, 1, 0, 0]
+    before = read()
+    outer = jax.jit(lambda p, v: fwd(p, v))
+    outer(params, x)
+    outer(params, x)  # a tracer is seen once per trace, not once per run
+    fwd(params, jax.device_put(x, jax.sharding.NamedSharding(fwd.rows.mesh, jax.sharding.PartitionSpec())))
+    assert [a - b for a, b in zip(read(), before)] == [0, 0, 2, 0]
+
+
+def test_parameters_are_placed_once_for_a_tree_that_comes_again():
+    """Parameters left on one device would be sent to every device before
+    every step; the forward places a tree once and knows it again by its
+    leaves. A new tree is placed anew, host leaves are left to the runtime."""
+    params, x = _small_case()
+    fwd = build_sharded_forward(SMALL, n_shards=4)
+    first = np.asarray(fwd(params, x))
+    placed = fwd._on_every_device(params)
+    assert all(
+        leaf.sharding.is_equivalent_to(fwd.replicated, leaf.ndim)
+        for leaf in jax.tree.leaves(placed)
+    )
+    assert fwd._on_every_device(params) is placed
+    assert fwd._on_every_device(placed) is not placed  # other leaves: another tree
+    np.testing.assert_array_equal(np.asarray(fwd(placed, x)), first)
+    other = jax.tree.map(lambda a: a * 2, params)
+    assert fwd._on_every_device(other) is not placed
+    assert not np.array_equal(np.asarray(fwd(other, x)), first)
+    np.testing.assert_array_equal(np.asarray(fwd(params, x)), first)
+    on_host = jax.tree.map(np.asarray, params)
+    assert fwd._on_every_device(on_host) is on_host
+    np.testing.assert_array_equal(np.asarray(fwd(on_host, x)), first)
