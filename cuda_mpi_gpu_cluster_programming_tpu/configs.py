@@ -43,7 +43,7 @@ class ExecConfig:
     tier: str  # "reference" (XLA ops) | "pallas"
     strategy: str  # "single" | "replicated" | "halo" | "staged_halo"
     description: str
-    model: str = "blocks12"  # "blocks12" | "alexnet_full"
+    model: str = "blocks12"  # "blocks12" | "alexnet_full" | "mla_moe"
 
 
 REGISTRY: Dict[str, ExecConfig] = {
@@ -125,6 +125,18 @@ REGISTRY: Dict[str, ExecConfig] = {
             "halo",
             "full AlexNet, row-sharded spatial part + replicated FC head",
             model="alexnet_full",
+        ),
+        # V8: the language-model family. Token ids in, logits out; parameters
+        # stored in the compute type (models.mla_moe).
+        ExecConfig(
+            "v8_mla_moe",
+            "V8 MLA-MoE Share",
+            "reference",
+            "single",
+            "latent-attention MoE decoder as one expert-parallel chip holds it: "
+            "its experts and vocabulary slice, single device, XLA ops with the "
+            "flash-attention and grouped-matmul kernels",
+            model="mla_moe",
         ),
     ]
 }
@@ -275,10 +287,16 @@ def build_forward(
 
     from .ops import scopes
 
+    def to_bf16(a):
+        # Integer inputs (token ids) pass as they are; what is already stored
+        # in bf16 is left alone, so a model held in bf16 is never copied.
+        floating = jnp.issubdtype(a.dtype, jnp.floating)
+        return a.astype(jnp.bfloat16) if floating and a.dtype != jnp.bfloat16 else a
+
     def fwd_bf16(p, x):
         with scopes.cast_in():
-            pb = jax.tree.map(lambda a: a.astype(jnp.bfloat16), p)
-            xb = x.astype(jnp.bfloat16)
+            pb = jax.tree.map(to_bf16, p)
+            xb = to_bf16(x)
         return fwd(pb, xb).astype(jnp.float32)
 
     return _observed(_jit(fwd_bf16, donate), exec_cfg, pol.name, n_shards)
@@ -327,6 +345,14 @@ def _build_forward_fp32(
             f"have {jax.device_count()} (use XLA_FLAGS=--xla_force_host_platform_"
             f"device_count=N on CPU to fake a mesh)"
         )
+
+    if exec_cfg.model == "mla_moe":
+        from .models import mla_moe
+
+        if exec_cfg.strategy != "single":
+            raise ValueError(f"strategy {exec_cfg.strategy!r} not supported for mla_moe")
+        model_cfg = model_cfg or mla_moe.SMALL
+        return _jit(lambda p, ids: mla_moe.forward(p, ids, model_cfg), donate)
 
     if exec_cfg.model == "alexnet_full":
         from .models.alexnet_full import ALEXNET, forward_alexnet

@@ -35,6 +35,15 @@ from typing import Dict, List, Optional
 
 from ..resilience.journal import atomic_write_text
 
+# Routing gauges of the expert tier: the (token, expert) pairs that fell to the
+# experts a chip holds, all pairs routed, and the fullest held expert's load
+# over the held experts' mean. ``models.mla_moe.routing_statistics`` sets them,
+# outside any hot loop (the forward itself syncs nothing to the host).
+MOE_PAIRS_HELD = "moe.pairs_held"
+MOE_PAIRS_ALL = "moe.pairs_all"
+MOE_EXPERT_LOAD_MAX_OVER_MEAN = "moe.expert_load_max_over_mean"
+MOE_ROUTING_GAUGES = (MOE_PAIRS_HELD, MOE_PAIRS_ALL, MOE_EXPERT_LOAD_MAX_OVER_MEAN)
+
 # Prometheus metric-name grammar: [a-zA-Z_:][a-zA-Z0-9_:]* — the dotted
 # registry names ("serve.ok") sanitize to underscores ("serve_ok").
 _PROM_BAD = re.compile(r"[^a-zA-Z0-9_:]")

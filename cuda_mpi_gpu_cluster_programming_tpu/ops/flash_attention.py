@@ -59,8 +59,9 @@ def _fwd_kernel(
 ):
     """One (batch, head, q-block, k-block) program.
 
-    q_ref: (1, 1, bq, D); k_ref/v_ref: (1, 1, bk, D) — ONE k/v block, indexed
-    by the grid (streaming). Running stats live in VMEM scratch across the
+    q_ref: (1, 1, bq, D); k_ref: (1, 1, bk, D); v_ref: (1, 1, bk, Dv) — ONE
+    k/v block, indexed by the grid (streaming); the value width may differ
+    from the query/key width. Running stats live in VMEM scratch across the
     k-block grid axis (sequential on TPU and in interpret mode).
     """
     qi = pl.program_id(2)
@@ -74,34 +75,45 @@ def _fwd_kernel(
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
     # Causal: blocks strictly above the diagonal contribute nothing — skip
-    # the math (the grid still visits them; pl.when skips the compute).
+    # the math (the grid still visits them; pl.when skips the compute). Only
+    # the blocks the diagonal crosses need the mask: a block wholly below it
+    # skips the iota, the compare and the select, which the VPU pays per score.
     contributes = (not causal) or ((qi + 1) * bq - 1 >= ki * bk)
+    crossed = (ki + 1) * bk - 1 > qi * bq  # some key of the block lies after some query
 
-    @pl.when(contributes)
-    def _update():
-        q = q_ref[0, 0].astype(jnp.float32) * scale  # (bq, D)
-        k_blk = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
-        v_blk = v_ref[0, 0].astype(jnp.float32)
+    def update(masked: bool):
+        # Operands go to the MXU in the type they are stored in (bf16 stays
+        # bf16: one pass; float32 runs at HIGHEST), accumulation is float32,
+        # and the scale is applied to the float32 scores.
+        q = q_ref[0, 0]  # (bq, D)
+        k_blk = k_ref[0, 0]  # (bk, D)
+        v_blk = v_ref[0, 0]  # (bk, Dv)
         prec = mxu_precision(q_ref.dtype)
-        s = lax.dot_general(
+        s = scale * lax.dot_general(
             q, k_blk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec,
         )  # (bq, bk)
-        if causal:
+        if masked:
             s = _causal_mask(s, qi, ki, bq, bk)
         m_prev = m_sc[:, 0]  # (bq,)
         den_prev = den_sc[:, 0]
         blk_max = jnp.max(s, axis=-1)
         m_new = jnp.maximum(m_prev, blk_max)
         corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])  # (bq, bk)
+        p = jnp.exp(s - m_new[:, None])  # (bq, bk), float32
         acc_sc[...] = acc_sc[...] * corr[:, None] + lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=prec,
         )
         den_new = den_prev * corr + jnp.sum(p, axis=-1)
         m_sc[...] = jnp.broadcast_to(m_new[:, None], m_sc.shape)
         den_sc[...] = jnp.broadcast_to(den_new[:, None], den_sc.shape)
+
+    if causal:
+        pl.when(contributes & crossed)(lambda: update(True))
+        pl.when(contributes & jnp.logical_not(crossed))(lambda: update(False))
+    else:
+        update(False)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -117,29 +129,49 @@ _STAT_LANES = 128
 
 
 def _flash_forward(q, k, v, *, causal, block_q, block_k, return_lse, vma=None):
-    b, l, h, d = q.shape
+    # (B, L, H, D) -> (B, H, L, D): heads become a grid axis, L contiguous.
+    tr = lambda a: jnp.transpose(a, (0, 2, 1, 3))
+    out, lse = flash_forward_bhld(
+        tr(q), tr(k), tr(v), causal=causal, block_q=block_q, block_k=block_k, vma=vma
+    )
+    return (tr(out), lse) if return_lse else tr(out)
+
+
+def flash_forward_bhld(q, k, v, *, causal, block_q=128, block_k=128, scale=None, vma=None):
+    """The forward kernel on heads-major operands: q, k ``(B, H, L, D)``,
+    v ``(B, H, L, Dv)`` -> ``(out (B, H, L, Dv), lse (B, H, 1, L))``.
+
+    ``Dv`` may differ from ``D`` (latent attention: 192-wide queries and keys,
+    128-wide values) and ``scale`` defaults to ``D**-0.5``. Forward only: the
+    differentiable entry points above take one width for q, k and v.
+    """
+    b, h, l, d = q.shape
+    dv = v.shape[-1]
     bq = min(block_q, l)
     bk = min(block_k, l)
     if l % bq or l % bk:
         raise ValueError(f"sequence length {l} not divisible by blocks ({bq}, {bk})")
-    scale = 1.0 / (d**0.5)  # Python math: stays static under jit tracing
+    if scale is None:
+        scale = 1.0 / (d**0.5)  # Python math: stays static under jit tracing
 
-    # (B, L, H, D) -> (B, H, L, D): heads become a grid axis, L contiguous.
-    qt = jnp.transpose(q, (0, 2, 1, 3))
-    kt = jnp.transpose(k, (0, 2, 1, 3))
-    vt = jnp.transpose(v, (0, 2, 1, 3))
+    if causal:
+        # Blocks above the diagonal are skipped by the kernel; naming the last
+        # block that contributes again keeps the grid from fetching them.
+        kv_at = lambda bi, hi, qi, ki: (bi, hi, jnp.minimum(ki, ((qi + 1) * bq - 1) // bk), 0)
+    else:
+        kv_at = lambda bi, hi, qi, ki: (bi, hi, ki, 0)
 
     kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, causal=causal, scale=scale)
-    out, lse = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid=(b, h, l // bq, l // bk),
         in_specs=[
             _spec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            _spec((1, 1, bk, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
-            _spec((1, 1, bk, d), lambda bi, hi, qi, ki: (bi, hi, ki, 0)),
+            _spec((1, 1, bk, d), kv_at),
+            _spec((1, 1, bk, dv), kv_at),
         ],
         out_specs=[
-            _spec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            _spec((1, 1, bq, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
             # LSE rides as (B, H, 1, L): Mosaic requires the block's last two
             # dims to be (sublane-divisible | equal-to-array), which a
             # (1, 1, bq) block over (B, H, L) violates (H is second-minor).
@@ -149,18 +181,17 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, return_lse, vma=None):
             _spec((1, 1, 1, bq), lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
         ],
         out_shape=[
-            _vma_struct((b, h, l, d), q.dtype, vma),
+            _vma_struct((b, h, l, dv), q.dtype, vma),
             _vma_struct((b, h, 1, l), jnp.float32, vma),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, _STAT_LANES), jnp.float32),  # running max
             pltpu.VMEM((bq, _STAT_LANES), jnp.float32),  # running denominator
-            pltpu.VMEM((bq, d), jnp.float32),  # output accumulator
+            pltpu.VMEM((bq, dv), jnp.float32),  # output accumulator
         ],
         interpret=_interpret(),
-    )(qt, kt, vt)
-    out = jnp.transpose(out, (0, 2, 1, 3))
-    return (out, lse) if return_lse else out
+        name="flash_fwd",
+    )(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -305,6 +336,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, vma=None,
         out_shape=_vma_struct((b, h, l, d), q.dtype, vma),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=_interpret(),
+        name="flash_dq",
     )(qt, kt, vt, gt, lse, delta)
 
     # k-block outer, q-block streamed innermost.
@@ -335,6 +367,7 @@ def _flash_backward(q, k, v, out, lse, g, *, causal, block_q, block_k, vma=None,
             pltpu.VMEM((bk, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name="flash_dkv",
     )(kt, vt, qt, gt, lse, delta)
 
     tr = lambda a: jnp.transpose(a, (0, 2, 1, 3))

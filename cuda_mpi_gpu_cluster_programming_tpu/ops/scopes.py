@@ -20,7 +20,17 @@ import jax
 BLOCKS12_LAYERS = ("conv1", "pool1", "conv2", "pool2", "lrn2")
 ALEXNET_TAIL_LAYERS = ("conv3", "conv4", "conv5", "pool5")
 FC_LAYERS = ("fc6", "fc7", "fc8")
-LAYERS = BLOCKS12_LAYERS + ALEXNET_TAIL_LAYERS + FC_LAYERS
+# The latent-attention mixture-of-experts decoder (``models.mla_moe``), in the
+# order a MoE layer runs them: ``mla.proj`` holds the five projections with
+# their norms and the rotary embedding, ``mla.attn`` scores, softmax and
+# values; ``moe.route`` the router matmul, the group-limited top-k, the sort
+# and the index arithmetic; ``moe.experts`` the gather, the grouped products
+# and the weighted scatter-add; ``head`` the final norm and the output head.
+MLA_MOE_LAYERS = (
+    "embed", "mla.proj", "mla.attn", "dense_mlp",
+    "moe.route", "moe.experts", "moe.shared", "head",
+)
+LAYERS = BLOCKS12_LAYERS + ALEXNET_TAIL_LAYERS + FC_LAYERS + MLA_MOE_LAYERS
 
 # Parameters and input to the compute type: the bf16 wrapper's casts and the
 # int8w quantisation.

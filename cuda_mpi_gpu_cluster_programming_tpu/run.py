@@ -39,6 +39,14 @@ def make_parser() -> argparse.ArgumentParser:
         help="input source: jax = on-device init, native = C++ data pipeline",
     )
     p.add_argument("--seed", type=int, default=0)
+    p.add_argument(
+        "--preset",
+        choices=["small", "ep16_share"],
+        default="small",
+        help="language-model configs only (models.mla_moe.PRESETS): small = the "
+        "CPU tests' size; ep16_share = the published widths as one of 16 "
+        "expert-parallel chips holds them, 2 x 4,096 tokens (the benchmark's shape)",
+    )
     p.add_argument("--repeats", type=int, default=10, help="fenced passes for amortized timing")
     p.add_argument(
         "--warmup", type=int, default=5, help="short-queue passes subtracted by the fence protocol"
@@ -440,6 +448,43 @@ def _run_route(args, blocks_cfg) -> int:
     return 0
 
 
+def _run_language_model(args, exec_cfg) -> int:
+    """One-shot run of a token-driven config: seeded parameters stored in the
+    compute type, seeded ids over the vocabulary slice, fenced passes."""
+    import jax.numpy as jnp
+
+    from .configs import build_forward
+    from .models import mla_moe
+
+    model_cfg, batch, seq = mla_moe.PRESETS[args.preset]
+    compute = args.dtype or args.compute
+    print(f"--- Language model {exec_cfg.version_name} [{exec_cfg.key}] "
+          f"(preset={args.preset}, batch={batch}, seq={seq}) ---")
+    print(f"Devices: {jax.device_count()} x {jax.devices()[0].device_kind} "
+          f"({jax.default_backend()})")
+    print(f"Precision: dtype={compute} source={'dtype' if args.dtype else 'compute'} gate=none")
+    # the chip's own bit generator: its draw compiles in seconds at any size
+    kp, kx = jax.random.split(jax.random.key(args.seed, impl="rbg"))
+    params = mla_moe.init(kp, model_cfg, jnp.bfloat16 if compute == "bf16" else jnp.float32)
+    ids = jax.random.randint(kx, (batch, seq), 0, model_cfg.vocab_size, jnp.int32)
+    fwd = build_forward(exec_cfg, model_cfg, compute=compute)
+    out = jax.block_until_ready(fwd(params, ids))  # compile, and the values printed below
+    t0 = time.perf_counter()
+    for _ in range(max(1, args.repeats)):  # one fenced chain
+        last = fwd(params, ids)
+    jax.block_until_ready(last)
+    per_pass_ms = (time.perf_counter() - t0) * 1e3 / max(1, args.repeats)
+    print(f"Parameters: {mla_moe.param_count(model_cfg)} held here "
+          f"(experts [{model_cfg.experts_first}, {model_cfg.experts_first + model_cfg.experts_held}) "
+          f"of {model_cfg.n_routed_experts})")
+    print(f"Final Output Shape: {'x'.join(str(d) for d in out.shape[1:])}")
+    print("Final Output (first 10 values): "
+          + " ".join(f"{v:.4f}" for v in np.asarray(out[0, -1, :10])))
+    print(f"Forward pass completed in {per_pass_ms:.3f} ms (one fenced chain of {max(1, args.repeats)} "
+          f"passes; {batch * seq / (per_pass_ms / 1e3):.1f} tokens/s)")
+    return 0
+
+
 def main(argv=None) -> int:
     args = make_parser().parse_args(argv)
 
@@ -530,6 +575,11 @@ def main(argv=None) -> int:
         print(f"unknown config {args.config!r}; try --list-configs", file=sys.stderr)
         return 2
     exec_cfg = REGISTRY[args.config]
+    if exec_cfg.model == "mla_moe":
+        if args.serve:
+            print("--serve supports the Blocks 1-2 configs only", file=sys.stderr)
+            return 2
+        return _run_language_model(args, exec_cfg)
 
     blocks_cfg = dataclasses.replace(
         BLOCKS12,

@@ -39,6 +39,15 @@ def _scoped(paths, scope):
 
 def _build(key, compute="fp32"):
     exec_cfg = REGISTRY[key]
+    if exec_cfg.model == "mla_moe":
+        import jax.numpy as jnp
+
+        from cuda_mpi_gpu_cluster_programming_tpu.models import mla_moe
+
+        fwd = build_forward(exec_cfg, mla_moe.SMALL, compute=compute)
+        dtype = jnp.bfloat16 if compute == "bf16" else jnp.float32
+        params = jax.eval_shape(lambda: mla_moe.init(jax.random.key(0), mla_moe.SMALL, dtype))
+        return exec_cfg, fwd, params, jax.ShapeDtypeStruct((2, 32), "int32")
     full = exec_cfg.model == "alexnet_full"
     model_cfg = SMALL_FULL if full else SMALL
     # four shards: the 2 output rows then leave padding for the gather to slice off
@@ -58,6 +67,8 @@ def test_every_layer_of_the_chain_is_named_in_the_compiled_program(key):
     chain = scopes.BLOCKS12_LAYERS
     if exec_cfg.model == "alexnet_full":
         chain += scopes.ALEXNET_TAIL_LAYERS + scopes.FC_LAYERS
+    if exec_cfg.model == "mla_moe":  # a dense layer first, then the MoE layers
+        chain = scopes.MLA_MOE_LAYERS
     for layer in chain:
         assert _scoped(paths, layer), f"{key}: no operation under the scope {layer!r}"
     # in order: the jaxpr is the program as written, before any scheduling
@@ -113,6 +124,19 @@ def test_a_kernel_that_covers_conv_and_pool_says_so():
     paths = _paths(fwd, init_params_deterministic(SMALL), x)
     assert _scoped(paths, "conv1+pool1") and _scoped(paths, "conv2+pool2+lrn2")
     assert not _scoped(paths, "conv1") and not _scoped(paths, "lrn2")
+
+
+def test_token_ids_and_parameters_stored_in_bf16_pass_the_bf16_wrapper_uncast():
+    """``compute="bf16"`` casts floating inputs only: the language model's
+    integer ids and its parameters, already bf16, reach the forward as they
+    are, and every one of its scopes is in the compiled program."""
+    _cfg, fwd, params, ids = _build("v8_mla_moe", "bf16")
+    paths = _paths(fwd, params, ids)
+    assert not _scoped(paths, scopes.CAST_IN)
+    for layer in scopes.MLA_MOE_LAYERS:
+        assert _scoped(paths, layer), layer
+    jaxpr = jax.make_jaxpr(fwd)(params, ids)
+    assert str(jaxpr.jaxpr.invars[-1].aval.dtype) == "int32"
 
 
 def test_a_name_outside_the_vocabulary_is_refused():
