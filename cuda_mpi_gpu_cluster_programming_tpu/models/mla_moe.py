@@ -194,6 +194,16 @@ def _rope(x, cos, sin):
     return jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1).reshape(x.shape)
 
 
+def _rope_halves(a, b, cos, sin):
+    """The rotation of :func:`_rope` on its pairs' two halves, sequence-minor:
+    ``a`` holds every pair's first element and ``b`` its second
+    (``(..., dim/2, S)`` float32 each, the tables ``(dim/2, S)``); the rotated
+    firsts come out before the rotated seconds, ``(..., dim, S)``. Queries
+    and keys laid out alike give the scores of the interleaved form, each the
+    same sum in another order."""
+    return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], axis=-2)
+
+
 # ---------------------------------------------------------------------------
 # Parameters
 # ---------------------------------------------------------------------------
@@ -324,24 +334,28 @@ def _mla(p: Params, x, cfg: MlaMoeConfig):
         u = _rms_norm(x, p["attn_norm"], cfg.rms_norm_eps).astype(dt)
         c_q = _rms_norm(_mm("bsd,dr->bsr", u, p["q_a"]), p["q_norm"], cfg.rms_norm_eps)
         # The nope and rope parts, and keys and values, come from slices of
-        # the (small) weights, not of the (large) activations.
-        nope = cfg.qk_nope_head_dim
+        # the (small) weights, not of the (large) activations; the rope
+        # columns' evens and odds likewise, so that the rotation runs on two
+        # halves and splits no adjacent lanes. The rope parts are
+        # sequence-minor, as the kernel takes them: 32 or 64 columns would
+        # fill a quarter or a half of every lane tile.
+        nope, rank = cfg.qk_nope_head_dim, cfg.kv_lora_rank
         q_nope = _mm("bsr,rhe->bhse", c_q, p["q_b"][..., :nope]).astype(dt)
-        q_rope = _mm("bsr,rhe->bhse", c_q, p["q_b"][..., nope:])  # float32, for the rotation
         kv_a = _mm("bsd,dr->bsr", u, p["kv_a"])  # (B, S, kv_lora + rope)
-        c_kv = _rms_norm(kv_a[..., : cfg.kv_lora_rank], p["kv_norm"], cfg.rms_norm_eps)
+        c_kv = _rms_norm(kv_a[..., :rank], p["kv_norm"], cfg.rms_norm_eps)
         k_nope = _mm("bsr,rhe->bhse", c_kv, p["kv_b"][..., :nope]).astype(dt)
         v = _mm("bsr,rhe->bhse", c_kv, p["kv_b"][..., nope:]).astype(dt)
-        cos, sin = _rope_tables(cfg, seq)
-        q = jnp.concatenate([q_nope, _rope(q_rope, cos, sin).astype(dt)], axis=-1)
-        k_rope = _rope(kv_a[..., cfg.kv_lora_rank :], cos, sin).astype(dt)  # one for all heads
-        k = jnp.concatenate(
-            [k_nope, jnp.broadcast_to(k_rope[:, None], (*k_nope.shape[:3], cfg.qk_rope_head_dim))], axis=-1
-        )
+        cos, sin = (table.T for table in _rope_tables(cfg, seq))
+        q_rope = _rope_halves(
+            _mm("bsr,rhe->bhes", c_q, p["q_b"][..., nope::2]),
+            _mm("bsr,rhe->bhes", c_q, p["q_b"][..., nope + 1 :: 2]), cos, sin,
+        ).astype(dt)
+        k_r = jnp.swapaxes(kv_a[..., rank:], 1, 2)  # (B, rope, S): one for all heads, and small
+        k_rope = _rope_halves(k_r[:, 0::2], k_r[:, 1::2], cos, sin).astype(dt)
     with scopes.layer("mla.attn"):
         attn, _lse = flash_forward_bhld(
-            q, k, v, causal=True, scale=softmax_scale(cfg),
-            block_q=cfg.attn_block, block_k=cfg.attn_block,
+            q_nope, k_nope, v, q_rope=q_rope, k_rope=k_rope, causal=True,
+            scale=softmax_scale(cfg), block_q=cfg.attn_block, block_k=cfg.attn_block,
         )
     with scopes.layer("mla.proj"):
         return x + _mm("bhse,hed->bsd", attn, p["o"])

@@ -54,16 +54,21 @@ def _causal_mask(s, qi, ki, bq, bk):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, den_sc, acc_sc, *, bq, bk, causal, scale
-):
+def _fwd_kernel(*refs, bq, bk, causal, scale, rope):
     """One (batch, head, q-block, k-block) program.
 
     q_ref: (1, 1, bq, D); k_ref: (1, 1, bk, D); v_ref: (1, 1, bk, Dv) — ONE
     k/v block, indexed by the grid (streaming); the value width may differ
-    from the query/key width. Running stats live in VMEM scratch across the
+    from the query/key width. With ``rope`` two more operands follow them,
+    sequence-minor: qr_ref (1, 1, R, bq) and kr_ref (1, R, bk), the part of
+    the queries and keys that is one key for all heads; a score is then the
+    sum of the two products. Running stats live in VMEM scratch across the
     k-block grid axis (sequential on TPU and in interpret mode).
     """
+    q_ref, k_ref, v_ref, *rest = refs
+    if rope:
+        qr_ref, kr_ref, *rest = rest
+    o_ref, lse_ref, m_sc, den_sc, acc_sc = rest
     qi = pl.program_id(2)
     ki = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -89,10 +94,17 @@ def _fwd_kernel(
         k_blk = k_ref[0, 0]  # (bk, D)
         v_blk = v_ref[0, 0]  # (bk, Dv)
         prec = mxu_precision(q_ref.dtype)
-        s = scale * lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec,
-        )  # (bq, bk)
+
+        def qk(a, b, over):  # contract axis ``over`` of both -> (bq, bk)
+            return lax.dot_general(
+                a, b, (((over,), (over,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            )
+
+        s = qk(q, k_blk, 1)
+        if rope:
+            s = s + qk(qr_ref[0, 0], kr_ref[0], 0)  # (R, bq) x (R, bk)
+        s = scale * s
         if masked:
             s = _causal_mask(s, qi, ki, bq, bk)
         m_prev = m_sc[:, 0]  # (bq,)
@@ -137,13 +149,24 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, return_lse, vma=None):
     return (tr(out), lse) if return_lse else tr(out)
 
 
-def flash_forward_bhld(q, k, v, *, causal, block_q=128, block_k=128, scale=None, vma=None):
+def flash_forward_bhld(
+    q, k, v, *, causal, block_q=128, block_k=128, scale=None, vma=None, q_rope=None, k_rope=None
+):
     """The forward kernel on heads-major operands: q, k ``(B, H, L, D)``,
     v ``(B, H, L, Dv)`` -> ``(out (B, H, L, Dv), lse (B, H, 1, L))``.
 
-    ``Dv`` may differ from ``D`` (latent attention: 192-wide queries and keys,
-    128-wide values) and ``scale`` defaults to ``D**-0.5``. Forward only: the
-    differentiable entry points above take one width for q, k and v.
+    ``Dv`` may differ from ``D``. Latent attention hands its queries and keys
+    over in the two parts its projections produce: ``q``, ``k`` the part each
+    head has keys of its own for, and ``q_rope (B, H, R, L)``, ``k_rope
+    (B, R, L)`` the rotated part, whose key is ONE per position for all heads
+    (its block is fetched by position alone). A score is then
+    ``q . k + q_rope . k_rope``: no ``D + R``-wide query or key is assembled
+    and ``k_rope`` is never copied per head. The rope operands are
+    sequence-minor because ``R`` is narrow (64 where a lane tile is 128): so
+    laid out they are dense in HBM and in VMEM, and it is how the compiler
+    writes a product that narrow anyway. ``scale`` defaults to the whole
+    width's ``(D + R)**-0.5``. Forward only: the differentiable entry points
+    above take one width for q, k and v and no rope operands.
     """
     b, h, l, d = q.shape
     dv = v.shape[-1]
@@ -151,34 +174,47 @@ def flash_forward_bhld(q, k, v, *, causal, block_q=128, block_k=128, scale=None,
     bk = min(block_k, l)
     if l % bq or l % bk:
         raise ValueError(f"sequence length {l} not divisible by blocks ({bq}, {bk})")
+    if (q_rope is None) != (k_rope is None):
+        raise ValueError("q_rope and k_rope come together")
+    rope = q_rope is not None
+    r = q_rope.shape[2] if rope else 0
+    if rope and (q_rope.shape != (b, h, r, l) or k_rope.shape != (b, r, l)):
+        raise ValueError(f"q_rope {q_rope.shape} / k_rope {k_rope.shape}: want (B, H, R, L) / (B, R, L)")
     if scale is None:
-        scale = 1.0 / (d**0.5)  # Python math: stays static under jit tracing
+        scale = 1.0 / ((d + r) ** 0.5)  # Python math: stays static under jit tracing
 
     if causal:
         # Blocks above the diagonal are skipped by the kernel; naming the last
         # block that contributes again keeps the grid from fetching them.
-        kv_at = lambda bi, hi, qi, ki: (bi, hi, jnp.minimum(ki, ((qi + 1) * bq - 1) // bk), 0)
+        k_block = lambda qi, ki: jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
     else:
-        kv_at = lambda bi, hi, qi, ki: (bi, hi, ki, 0)
+        k_block = lambda qi, ki: ki
+    q_at = lambda bi, hi, qi, ki: (bi, hi, qi, 0)
+    kv_at = lambda bi, hi, qi, ki: (bi, hi, k_block(qi, ki), 0)
+    q_minor_at = lambda bi, hi, qi, ki: (bi, hi, 0, qi)  # a q-block along the LAST axis
 
-    kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, causal=causal, scale=scale)
+    operands = [q, k, v]
+    in_specs = [_spec((1, 1, bq, d), q_at), _spec((1, 1, bk, d), kv_at), _spec((1, 1, bk, dv), kv_at)]
+    if rope:
+        operands += [q_rope, k_rope]
+        in_specs += [
+            _spec((1, 1, r, bq), q_minor_at),
+            _spec((1, r, bk), lambda bi, hi, qi, ki: (bi, 0, k_block(qi, ki))),
+        ]
+    kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, causal=causal, scale=scale, rope=rope)
     return pl.pallas_call(
         kernel,
         grid=(b, h, l // bq, l // bk),
-        in_specs=[
-            _spec((1, 1, bq, d), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
-            _spec((1, 1, bk, d), kv_at),
-            _spec((1, 1, bk, dv), kv_at),
-        ],
+        in_specs=in_specs,
         out_specs=[
-            _spec((1, 1, bq, dv), lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
+            _spec((1, 1, bq, dv), q_at),
             # LSE rides as (B, H, 1, L): Mosaic requires the block's last two
             # dims to be (sublane-divisible | equal-to-array), which a
             # (1, 1, bq) block over (B, H, L) violates (H is second-minor).
             # The explicit singleton makes the block (1, bq) vs array (1, L)
             # — legal, and caught only on real TPU (interpret mode doesn't
             # enforce tiling).
-            _spec((1, 1, 1, bq), lambda bi, hi, qi, ki: (bi, hi, 0, qi)),
+            _spec((1, 1, 1, bq), q_minor_at),
         ],
         out_shape=[
             _vma_struct((b, h, l, dv), q.dtype, vma),
@@ -191,7 +227,7 @@ def flash_forward_bhld(q, k, v, *, causal, block_q=128, block_k=128, scale=None,
         ],
         interpret=_interpret(),
         name="flash_fwd",
-    )(q, k, v)
+    )(*operands)
 
 
 # ---------------------------------------------------------------------------

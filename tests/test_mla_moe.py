@@ -9,6 +9,7 @@ against closed forms; the two kernels it runs on; the way through
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import sys
 from pathlib import Path
@@ -250,9 +251,14 @@ def test_yarn_frequencies_and_the_score_scale_against_closed_forms(which):
     assert scale == pytest.approx(0.135234, rel=1e-5)  # 0.0721688 x 1.368888**2
 
 
-def test_rotation_keeps_the_scores_of_the_published_layout():
+@pytest.mark.parametrize("form", ["pairs", "halves", "halves_of_the_weights"])
+def test_rotation_keeps_the_scores_of_the_published_layout(form):
     """Pairs rotated in place give the scores the published layout (evens moved
-    before odds, then rotate-half) gives: queries and keys are permuted alike."""
+    before odds, then rotate-half) gives: queries and keys are permuted alike.
+    So does the rotation on halves, the form ``_mla`` runs: the evens and the
+    odds taken apart (of the activations here, or of the projection's weight
+    columns, which is the same product in another order), rotated, the
+    firsts laid before the seconds."""
     cfg = SMALL
     cos, sin = mla_moe._rope_tables(cfg, 8)
     q = jax.random.normal(jax.random.key(0), (8, cfg.qk_rope_head_dim))
@@ -264,8 +270,24 @@ def test_rotation_keeps_the_scores_of_the_published_layout():
         rotated = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
         return x * jnp.concatenate([cos, cos], -1) + rotated * jnp.concatenate([sin, sin], -1)
 
-    ours = mla_moe._rope(q, cos, sin) @ mla_moe._rope(k, cos, sin).T
-    np.testing.assert_allclose(ours, published(q) @ published(k).T, rtol=1e-4, atol=1e-4)
+    want = published(q) @ published(k).T
+    if form == "pairs":
+        ours = mla_moe._rope(q, cos, sin) @ mla_moe._rope(k, cos, sin).T
+    elif form == "halves":
+        halves = lambda x: mla_moe._rope_halves(x[..., 0::2].T, x[..., 1::2].T, cos.T, sin.T)  # (dim, S)
+        ours = halves(q).T @ halves(k)
+        # the same numbers as the pairs' rotation, moved: evens first
+        np.testing.assert_allclose(halves(q)[: q.shape[1] // 2].T, mla_moe._rope(q, cos, sin)[:, 0::2], rtol=1e-6)
+    else:
+        # q and k as projections of a latent: the weights' columns are split, not the results
+        c = jax.random.normal(jax.random.key(2), (8, 12))
+        wq = jax.random.normal(jax.random.key(3), (12, cfg.qk_rope_head_dim))
+        wk = jax.random.normal(jax.random.key(4), (12, cfg.qk_rope_head_dim))
+        mm = functools.partial(jnp.matmul, precision="highest")
+        halves = lambda w: mla_moe._rope_halves(mm(c, w[:, 0::2]).T, mm(c, w[:, 1::2]).T, cos.T, sin.T)
+        ours = mm(halves(wq).T, halves(wk))
+        want = mm(published(mm(c, wq)), published(mm(c, wk)).T)
+    np.testing.assert_allclose(ours, want, rtol=1e-4, atol=1e-4)
 
 
 # ---- build_forward, run.py ---------------------------------------------------
@@ -355,3 +377,48 @@ def test_flash_attention_takes_a_value_width_of_its_own_and_a_scale(dtype, tol):
     np.testing.assert_allclose(
         np.asarray(lse)[:, :, 0, :], np.asarray(jax.scipy.special.logsumexp(s, axis=-1)), rtol=tol, atol=tol
     )
+
+
+ROPE_CASES = {
+    # name: (L, D, R, Dv, block_q, block_k, causal)
+    "causal_unequal_blocks": (32, 24, 8, 16, 8, 16, True),
+    "causal_wide_key_blocks": (32, 16, 8, 16, 16, 8, True),
+    "one_block": (16, 16, 8, 24, 64, 64, True),
+    "value_width_of_its_own": (32, 16, 16, 40, 16, 16, True),
+    "not_causal": (32, 24, 8, 16, 8, 16, False),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6), (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("case", sorted(ROPE_CASES))
+def test_flash_attention_on_parts_equals_the_kernel_on_concatenated_operands(case, dtype, tol):
+    """``q . k + q_rope . k_rope`` with ONE ``k_rope`` for all heads (the rope
+    operands sequence-minor, ``(B, H, R, L)`` and ``(B, R, L)``) is the score
+    of the concatenated query against the concatenated key with ``k_rope``
+    copied per head: output and log-sum-exp both, float32 tightly
+    (the same products summed in another order), bf16 under the tolerance the
+    kernel's other tests state. The scale defaults to the whole width's."""
+    l, d, r, dv, block_q, block_k, causal = ROPE_CASES[case]
+    b, h = 2, 3
+    keys = jax.random.split(jax.random.key(11), 5)
+    draw = lambda key, shape: jax.random.normal(key, shape, jnp.float32).astype(dtype)
+    q, k, v = draw(keys[0], (b, h, l, d)), draw(keys[1], (b, h, l, d)), draw(keys[2], (b, h, l, dv))
+    q_rope, k_rope = draw(keys[3], (b, h, l, r)), draw(keys[4], (b, l, r))
+    blocks = dict(causal=causal, block_q=block_q, block_k=block_k)
+    parts = dict(q_rope=jnp.swapaxes(q_rope, 2, 3), k_rope=jnp.swapaxes(k_rope, 1, 2))
+    whole_q = jnp.concatenate([q, q_rope], axis=-1)
+    whole_k = jnp.concatenate([k, jnp.broadcast_to(k_rope[:, None], (b, h, l, r))], axis=-1)
+    for scale in (None, 0.3):  # the default, and a given one: it reaches both products
+        out, lse = flash_forward_bhld(q, k, v, **parts, scale=scale, **blocks)
+        want, want_lse = flash_forward_bhld(whole_q, whole_k, v, scale=scale, **blocks)
+        assert out.shape == (b, h, l, dv) and out.dtype == dtype and lse.shape == (b, h, 1, l)
+        np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want, np.float32), rtol=tol, atol=tol)
+        np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse), rtol=tol, atol=tol)
+
+
+def test_flash_attention_refuses_one_rope_operand_or_a_key_per_head():
+    q = jnp.zeros((1, 2, 16, 8))
+    with pytest.raises(ValueError, match="together"):
+        flash_forward_bhld(q, q, q, causal=True, q_rope=q)
+    with pytest.raises(ValueError, match=r"\(B, R, L\)"):
+        flash_forward_bhld(q, q, q, causal=True, q_rope=q, k_rope=q)  # a k_rope per head
