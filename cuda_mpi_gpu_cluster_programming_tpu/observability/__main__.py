@@ -1,5 +1,5 @@
-"""CLI: Perfetto export, journal replay, the BENCH regression gate,
-roofline attribution, and the fleet-health report.
+"""CLI: Perfetto export, journal replay, roofline attribution, and the
+fleet-health report.
 
     python -m cuda_mpi_gpu_cluster_programming_tpu.observability \\
         export --journal logs/serve_journal.jsonl --out logs/trace.json
@@ -7,26 +7,21 @@ roofline attribution, and the fleet-health report.
         replay --journal logs/serve_journal.jsonl [--traffic-mult 2] \\
         [--devices 1] [--slo-scale 0.5] [--journal-out replay.jsonl]
     python -m cuda_mpi_gpu_cluster_programming_tpu.observability \\
-        report [--fail-on-regression] [--json] BENCH_r*.json
-    python -m cuda_mpi_gpu_cluster_programming_tpu.observability \\
-        roofline BENCH_r*.json            # committed rows, echo-aware
-    python -m cuda_mpi_gpu_cluster_programming_tpu.observability \\
         roofline --live [--batch N] [--height H --width W]  # measure now
     python -m cuda_mpi_gpu_cluster_programming_tpu.observability \\
         health --journal logs/serve_journal.jsonl \\
         [--json] [--fail-on-budget-burn]
 
-Exit codes (docs/OBSERVABILITY.md "Replay & regression gating" /
-"Roofline attribution" / "Fleet health & compile attribution"):
+Exit codes (docs/OBSERVABILITY.md "Replay" / "Roofline attribution" /
+"Fleet health & compile attribution"):
 
 - ``0`` — clean: trace exported / replay matched (or a what-if ran) /
-  no regression / roofline rendered / health report rendered (budgets
-  intact, or no gate requested).
+  roofline rendered / health report rendered (budgets intact, or no
+  gate requested).
 - ``2`` — usage: missing journal, unreplayable journal (recorded before
-  the replay schema), empty journal, bad arguments, no measurable
-  roofline view.
-- ``3`` — the gate tripped: a >10% regression with
-  ``--fail-on-regression``, a NEUTRAL replay that broke the
+  the replay schema), empty journal, bad arguments, ``roofline``
+  without ``--live`` or on a device outside the spec table.
+- ``3`` — the gate tripped: a NEUTRAL replay that broke the
   determinism contract (per-class accounting or percentile divergence),
   or a blown SLO error budget with ``--fail-on-budget-burn``.
 """
@@ -34,6 +29,7 @@ Exit codes (docs/OBSERVABILITY.md "Replay & regression gating" /
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import sys
 from pathlib import Path
@@ -60,25 +56,6 @@ def make_parser() -> argparse.ArgumentParser:
         default="",
         help="output trace path (default: <journal>.trace.json next to "
         "the input)",
-    )
-    rp = sub.add_parser(
-        "report",
-        help="cross-run report diffing BENCH_r*.json trajectories "
-        "(>10% headline/stage regressions; last_good echoes excluded "
-        "attributably)",
-    )
-    rp.add_argument("bench", nargs="+", help="BENCH_r*.json paths")
-    rp.add_argument(
-        "--fail-on-regression",
-        action="store_true",
-        help="exit 3 when any >threshold regression survives echo "
-        "exclusion — the CI gate mode",
-    )
-    rp.add_argument(
-        "--json",
-        action="store_true",
-        help="print the machine-readable GateVerdict object instead of "
-        "the text report",
     )
     rl = sub.add_parser(
         "replay",
@@ -138,15 +115,9 @@ def make_parser() -> argparse.ArgumentParser:
         "roofline",
         help="per-stage MFU / HBM-bandwidth attribution with "
         "compute-vs-memory-bound verdicts and the predicted fused-block "
-        "ceiling, over committed BENCH_r*.json rows (echo-aware) or a "
-        "live measurement",
-    )
-    rf.add_argument(
-        "bench",
-        nargs="*",
-        help="BENCH_r*.json rows (driver-wrapped, bare objects, or "
-        "JSONL); last_good echoes are marked via the gate's detection "
-        "and never ranked as fresh",
+        "ceiling, over a live host-clock measurement (--live, the only "
+        "mode; it stays until ROADMAP D6 removes the host-clock layer "
+        "timers)",
     )
     rf.add_argument(
         "--live",
@@ -223,22 +194,6 @@ def main(argv=None) -> int:
                 "e.g. run --serve --serve-journal / --trace, for real "
                 "timestamps)"
             )
-        return 0
-    if args.cmd == "report":
-        from .gate import evaluate
-
-        verdict = evaluate(args.bench)
-        if args.json:
-            print(json.dumps(verdict.to_obj()))
-        else:
-            print(verdict.render())
-        if args.fail_on_regression and not verdict.ok:
-            print(
-                f"regression gate: FAIL ({len(verdict.regressions)} "
-                f"regression(s) > {verdict.threshold:.0%})",
-                file=sys.stderr,
-            )
-            return 3
         return 0
     if args.cmd == "replay":
         from .replay import ReplayKnobs, load_recorded_run, replay_recorded
@@ -321,112 +276,46 @@ def main(argv=None) -> int:
 
 
 def _roofline_main(args) -> int:
-    """``roofline`` subcommand: ranked per-stage tables over committed
-    BENCH rows (gate-classified, echoes marked and never ranked as
-    fresh) or a live breakdown measurement."""
-    rendered = 0
-    # Row-per-line artifacts (perf/bench_tuned_*.jsonl — one row PER
-    # config) render every row; round files go through the gate's
-    # classifier so echoes are marked.
-    jsonl = [p for p in args.bench if str(p).endswith(".jsonl")]
-    rounds_paths = [p for p in args.bench if p not in jsonl]
-    for path in jsonl:
-        try:
-            lines = Path(path).read_text().splitlines()
-        except OSError as e:
-            print(f"cannot read {path}: {e}", file=sys.stderr)
-            return 2
-        from .roofline import roofline_from_bench_row
-
-        for i, line in enumerate(lines):
-            line = line.strip()
-            if not line.startswith("{"):
-                continue
-            try:
-                obj = json.loads(line)
-            except ValueError:
-                continue
-            for rep in roofline_from_bench_row(obj):
-                rendered += 1
-                rep.label = f"{obj.get('config', '')} {rep.label}".strip()
-                if args.json:
-                    print(json.dumps({"row": f"{path}:{i + 1}", **rep.to_obj()}))
-                else:
-                    print(f"== {path}:{i + 1}")
-                    print(rep.render())
-    if rounds_paths:
-        from .gate import load_rounds
-        from .roofline import roofline_from_bench_row
-
-        rounds = load_rounds(rounds_paths)
-        if not rounds:
-            print("no parseable BENCH rows", file=sys.stderr)
-            return 2
-        for rr in rounds:
-            print(f"== {rr.name}: {rr.provenance}")
-            if rr.is_echo:
-                # The gate's echo detection, reused: a wedged round
-                # re-reporting an earlier round's number is marked and
-                # skipped — ranking it would double-count stale evidence.
-                print(
-                    f"   echo of {rr.echo_of} — stale carry, not ranked"
-                )
-                continue
-            reports = roofline_from_bench_row(rr.row)
-            if not reports:
-                print("   no measurable roofline view (error-only round)")
-                continue
-            for rep in reports:
-                rendered += 1
-                if args.json:
-                    print(json.dumps({"round": rr.name, **rep.to_obj()}))
-                else:
-                    print(rep.render())
-    if args.live:
-        import jax
-
-        from ..models.alexnet import BLOCKS12
-        from ..models.init import deterministic_input, init_params_deterministic
-        from .roofline import attribute_roofline
-        from .stages import attribute_stages
-
-        from .specs import UnknownDeviceError, spec_for
-
-        import dataclasses as _dc
-
-        device = jax.devices()[0]
-        try:
-            spec_for(device.device_kind)  # before spending the measurement
-        except UnknownDeviceError as e:
-            print(f"roofline --live: {e}", file=sys.stderr)
-            return 2
-        cfg = _dc.replace(
-            BLOCKS12, in_height=args.height, in_width=args.width
-        )
-        att = attribute_stages(
-            init_params_deterministic(cfg),
-            deterministic_input(args.batch, cfg),
-            cfg,
-            compute=args.dtype,
-            repeats=args.repeats,
-            warmup=1,
-        )
-        rep = attribute_roofline(
-            dict(att.stages),
-            dtype=args.dtype,
-            batch=args.batch,
-            device_kind=device.device_kind,
-            cfg=cfg,
-            source="breakdown",
-            total_ms=att.total_ms,
-            label=f"live {device.platform}",
-        )
-        rendered += 1
-        print(json.dumps(rep.to_obj()) if args.json else rep.render())
-    if not args.bench and not args.live:
-        print("roofline: name BENCH rows and/or pass --live", file=sys.stderr)
+    """``roofline`` subcommand: a live per-stage breakdown measurement,
+    attributed against the spec table's roofs."""
+    if not args.live:
+        print("roofline: pass --live", file=sys.stderr)
         return 2
-    return 0 if rendered else 2
+    import jax
+
+    from ..models.alexnet import BLOCKS12
+    from ..models.init import deterministic_input, init_params_deterministic
+    from .roofline import attribute_roofline
+    from .specs import UnknownDeviceError, spec_for
+    from .stages import attribute_stages
+
+    device = jax.devices()[0]
+    try:
+        spec_for(device.device_kind)  # before spending the measurement
+    except UnknownDeviceError as e:
+        print(f"roofline --live: {e}", file=sys.stderr)
+        return 2
+    cfg = dataclasses.replace(BLOCKS12, in_height=args.height, in_width=args.width)
+    att = attribute_stages(
+        init_params_deterministic(cfg),
+        deterministic_input(args.batch, cfg),
+        cfg,
+        compute=args.dtype,
+        repeats=args.repeats,
+        warmup=1,
+    )
+    rep = attribute_roofline(
+        dict(att.stages),
+        dtype=args.dtype,
+        batch=args.batch,
+        device_kind=device.device_kind,
+        cfg=cfg,
+        source="breakdown",
+        total_ms=att.total_ms,
+        label=f"live {device.platform}",
+    )
+    print(json.dumps(rep.to_obj()) if args.json else rep.render())
+    return 0
 
 
 if __name__ == "__main__":

@@ -24,11 +24,6 @@ opens in https://ui.perfetto.dev as one correlated timeline:
 Process rows group by subsystem (span-name prefix / record kind):
 serving, supervisor, tuning, train, journal. ``M`` metadata events name
 every pid/tid.
-
-Also here: :func:`bench_report`, the text face of the cross-run
-``BENCH_r*.json`` regression gate (the structured verdict, echo
-exclusion, and the nonzero-exit CI wiring live in
-:mod:`..observability.gate`).
 """
 
 from __future__ import annotations
@@ -86,8 +81,8 @@ _KIND_PID = {
     # serve.transport span), one serve_reject per 429/413 refusal. Old
     # journals without them export unchanged.
     "serve_transport": "serve", "serve_reject": "serve",
-    # Replay-schema records (ISSUE 12, docs/OBSERVABILITY.md "Replay &
-    # regression gating"): the run-conditions header and the per-request
+    # Replay-schema records (ISSUE 12, docs/OBSERVABILITY.md "Replay"):
+    # the run-conditions header and the per-request
     # arrival records land on the serve lane as instants, so an exported
     # timeline shows the offered schedule beside its dispatches. Old
     # journals without them export unchanged.
@@ -419,16 +414,3 @@ def export_trace(journal_path, out_path) -> dict:
         "events": len(trace["traceEvents"]),
     }
 
-
-# ------------------------------------------------------------ bench report
-
-
-def bench_report(paths) -> str:
-    """Cross-run text report: the BENCH_r*.json trajectory with >10%
-    regressions between consecutive measured rounds flagged (plus
-    per-stage breakdown deltas, with ``last_good``-echo rounds labeled
-    and excluded). The text face of :mod:`..observability.gate` — the
-    structured verdict (and the nonzero-exit CI gate) lives there."""
-    from .gate import evaluate
-
-    return evaluate(paths).render()

@@ -1,17 +1,17 @@
 """Process-wide metrics registry: counters, gauges, histograms.
 
 Every subsystem so far has grown its own ad-hoc counters
-(``ServeStats``, supervisor ``attempts``/``replays``, bench retry
+(``ServeStats``, supervisor ``attempts``/``replays``, the retry
 ``FaultLog``); this registry is the one place a process accumulates
-named metrics so the bench row and the CLI summary lines read from a
+named metrics so the CLI summary lines and the tests read from a
 single source:
 
 - :class:`Counter` (monotonic ``inc``), :class:`Gauge` (last ``set``
   wins), :class:`Histogram` (``observe`` + nearest-rank p50/p99 via the
-  serving helper — the SAME estimator the serve bench reports, so a
-  metrics percentile and a bench percentile of the same stream agree).
-- :meth:`MetricsRegistry.summary` is the compact dict the bench serve
-  row embeds; :meth:`MetricsRegistry.export` writes one JSON line per
+  serving helper — the SAME estimator the journal readers use, so a
+  metrics percentile and a journal percentile of the same stream agree).
+- :meth:`MetricsRegistry.summary` is the compact dict form;
+  :meth:`MetricsRegistry.export` writes one JSON line per
   metric through the PR 3 atomic-write helper (readers see the old
   complete export or the new one, never a torn file).
 
@@ -173,7 +173,7 @@ class MetricsRegistry:
         return {name: m.to_obj() for name, m in sorted(items)}
 
     def summary(self) -> Dict[str, object]:
-        """The compact form the bench row embeds: counters/gauges as bare
+        """The compact form: counters/gauges as bare
         values, histograms as {count, mean, p50, p99}."""
         out: Dict[str, object] = {}
         for name, obj in self.snapshot().items():

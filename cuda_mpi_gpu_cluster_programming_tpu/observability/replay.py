@@ -31,7 +31,7 @@ between adjacent order statistics plus the dispatch poll quantum; wall
 latencies on a shared CPU cannot be bit-identical, order statistics of
 the same schedule must agree to within their own spacing). A neutral
 replay that breaks accounting is a **divergence** — the CLI exits 3 on
-it (docs/OBSERVABILITY.md "Replay & regression gating").
+it (docs/OBSERVABILITY.md "Replay").
 
 What does NOT replay, visibly: grow-back chaos (heal / probation /
 promote records — replay re-drives *losses*, so a recorded run that also
@@ -222,8 +222,8 @@ def recorded_run_from_records(
         raise ValueError(
             f"journal {source or '<records>'} has no serve_submit records — "
             "it was recorded before the replay schema (docs/OBSERVABILITY.md "
-            "'Replay & regression gating'); re-record with a journaled "
-            "server (run --serve --serve-journal / BENCH_MODE=serve)"
+            "'Replay'); re-record with a journaled server "
+            "(run --serve --serve-journal)"
         )
     if config is None:
         raise ValueError(

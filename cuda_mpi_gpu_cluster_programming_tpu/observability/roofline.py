@@ -2,11 +2,12 @@
 predicted fused-block ceiling (docs/OBSERVABILITY.md "Roofline
 attribution").
 
-PR 9's ``stages`` module attributes *time* per stage; ``bench.py``
-computes *whole-pass* MFU. Neither can answer the question ROADMAP
-item 1 actually asks: which stage is compute-bound vs HBM-bound, and
-what is a VMEM-resident fused block worth *before anyone writes it*?
-This module is the efficiency half of the observability stack:
+PR 9's ``stages`` module attributes *time* per stage. It cannot answer
+the question ROADMAP item 1 actually asks: which stage is compute-bound
+vs HBM-bound, and what is a VMEM-resident fused block worth *before
+anyone writes it*? This module is the efficiency half of the
+observability stack (host-clock; the benchmark's per-layer rooflines
+come from the device trace, ``benchmark/README.md``):
 
 - **Analytic ledger** (:func:`stage_ledger` / :func:`pass_ledger`):
   per-stage FLOPs (from ``models.alexnet.stage_flops`` — the SAME
@@ -25,22 +26,17 @@ This module is the efficiency half of the observability stack:
   ceiling per block: the judge every ROADMAP-1 megakernel candidate
   answers to before it exists.
 - **Measured attribution** (:func:`attribute_roofline`): join the
-  ledger with a measured per-stage breakdown (PR 9 ``attribute_stages``
-  or a bench row's ``breakdown``) to emit per-stage achieved FLOP/s,
-  MFU, achieved GB/s, arithmetic intensity, a compute/memory-bound
-  verdict against the device spec's ridge point, and headroom — the ms
-  between the measurement and its binding roof. Rows without a measured
-  breakdown (the committed pre-PR-9 BENCH trail) fall back to a
-  **model split**: ``per_pass_ms`` distributed across stages
-  proportionally to each stage's roofline floor, labeled
+  ledger with a measured per-stage breakdown (PR 9 ``attribute_stages``)
+  to emit per-stage achieved FLOP/s, MFU, achieved GB/s, arithmetic
+  intensity, a compute/memory-bound verdict against the device spec's
+  ridge point, and headroom — the ms between the measurement and its
+  binding roof. A whole-pass time with no measured breakdown can be
+  **model split** (:func:`model_stage_split`): distributed across
+  stages proportionally to each stage's roofline floor, labeled
   ``source="model"`` so nobody mistakes a prediction for a measurement.
-- **Bench-row views** (:func:`roofline_from_bench_row`): committed
-  ``BENCH_r*.json`` rows reproduce their own MFU from their own fields
-  (fresh values, ``last_good`` carries and the ``bf16`` sub-object
-  alike) — the BENCH_r05 bf16 0.5713 is recomputed, not trusted.
 
-Device capability comes from :mod:`.specs` — one table for bench and
-roofline both. Import-light except for the ledger's ``models`` import
+Device capability comes from :mod:`.specs`, the one table.
+Import-light except for the ledger's ``models`` import
 (jax); the CLI lives in ``observability.__main__`` (``roofline``
 subcommand).
 """
@@ -302,8 +298,7 @@ class RooflineReport:
     stages: List[StageRoofline]  # ranked: biggest headroom_ms first
     blocks: List[BlockModel]
     fused_pass_mfu_ceiling: Optional[float] = None
-    label: str = ""  # row context ("bf16@b128", "last_good ...")
-    stale: bool = False  # a last_good carry, not a fresh measurement
+    label: str = ""  # context the caller names ("live tpu")
     granularity: str = "stage"  # "stage" | "block" (megakernel rows)
 
     def to_obj(self) -> dict:
@@ -326,7 +321,6 @@ class RooflineReport:
                 if self.fused_pass_mfu_ceiling is not None
                 else None
             ),
-            "stale": self.stale,
             "label": self.label or None,
             "stages": [s.to_obj() for s in self.stages],
             "blocks": {b.name: b.to_obj() for b in self.blocks},
@@ -346,7 +340,6 @@ class RooflineReport:
         lines.append(
             f"  pass: {self.total_ms:.4f} ms mfu={mfu} source={self.source}"
             f"{' granularity=block' if self.granularity == 'block' else ''}"
-            f"{' STALE (last_good carry)' if self.stale else ''}"
         )
         lines.append(
             "  rank stage    ms      share  AI      TF/s    GB/s    mfu"
@@ -387,8 +380,8 @@ def model_stage_split(
     total_ms: float, entries: List[StageCost], peak_tflops: float, bw_gbps: float
 ) -> Dict[str, float]:
     """Distribute a measured whole-pass time across stages proportionally
-    to each stage's roofline floor — the model-backed attribution for
-    rows that predate the PR 9 breakdown. Sums exactly to ``total_ms``."""
+    to each stage's roofline floor — the model-backed attribution for a
+    pass with no measured breakdown. Sums exactly to ``total_ms``."""
     floors = {
         e.name: _floor_ms(e.flops, e.staged_bytes, peak_tflops, bw_gbps)
         for e in entries
@@ -409,30 +402,18 @@ def attribute_roofline(
     cfg=None,
     source: str = "breakdown",
     total_ms: Optional[float] = None,
-    peak_override: Optional[float] = None,
-    hbm_override: Optional[float] = None,
-    pass_img_s: Optional[float] = None,
     label: str = "",
-    stale: bool = False,
 ) -> RooflineReport:
     """Join measured (or model-split) per-stage ms with the analytic
     ledger and the device spec into the ranked verdict table.
 
-    ``peak_override`` lets a bench row's own ``assumed_peak_tflops``
-    govern (the row must reproduce its committed MFU from its own
-    fields); otherwise the spec table decides. A ``device_kind`` the
-    table does not know raises ``specs.UnknownDeviceError`` — a CPU run
-    is not judged against an assumed chip.
-    ``pass_img_s`` computes the whole-pass MFU the conventional way
-    (img/s x matmul FLOPs per image / peak) — exactly bench's formula.
+    The spec table decides the roofs. A ``device_kind`` the table does
+    not know raises ``specs.UnknownDeviceError`` — a CPU run is not
+    judged against an assumed chip.
     """
     spec = spec_for(device_kind)
-    peak = (
-        float(peak_override)
-        if peak_override
-        else _spec_peak(device_kind, dtype=dtype)
-    )
-    bw = float(hbm_override) if hbm_override else _spec_hbm(device_kind)
+    peak = _spec_peak(device_kind, dtype=dtype)
+    bw = _spec_hbm(device_kind)
     entries = pass_ledger(cfg, dtype=dtype, batch=batch)
     by_name = {e.name: e for e in entries}
     ridge = (peak * 1e12) / (bw * 1e9) if bw else 0.0
@@ -505,11 +486,8 @@ def attribute_roofline(
     # the optimization target list, biggest opportunity first.
     rows.sort(key=lambda s: s.headroom_ms, reverse=True)
     matmul_total = sum(e.matmul_flops for e in entries)
-    if pass_img_s and peak:
-        per_image_matmul = matmul_total / max(1, batch)
-        pass_mfu: Optional[float] = pass_img_s * per_image_matmul / (peak * 1e12)
-    elif total > 0 and peak:
-        pass_mfu = matmul_total / (total / 1e3 * peak * 1e12)
+    if total > 0 and peak:
+        pass_mfu: Optional[float] = matmul_total / (total / 1e3 * peak * 1e12)
     else:
         pass_mfu = None
     fused_total_floor = sum(b.fused_floor_ms for b in blocks)
@@ -533,110 +511,5 @@ def attribute_roofline(
         blocks=blocks,
         fused_pass_mfu_ceiling=fused_pass_ceiling,
         label=label,
-        stale=stale,
         granularity=granularity,
     )
-
-
-# ---------------------------------------------------------- bench rows ---
-
-
-def _num(v) -> Optional[float]:
-    return float(v) if isinstance(v, (int, float)) and v > 0 else None
-
-
-def _view(src: dict, carrier: dict, obj: dict, stale: bool) -> Optional[dict]:
-    """One dtype view of a bench row: the fields roofline needs, pulled
-    from the sub-object first and its carrier row second (the ``bf16``
-    sub-object inherits batch/peak/device from its parent)."""
-    img_s = _num(src.get("value")) or _num(src.get("stale_value"))
-    if img_s is None:
-        return None
-    def pick(key):
-        for d in (src, carrier, obj):
-            v = d.get(key)
-            if v is not None:
-                return v
-        return None
-
-    dtype = src.get("dtype") or src.get("compute") or pick("compute") or "fp32"
-    batch = pick("batch") or 1
-    per_pass = _num(src.get("per_pass_ms")) or (batch / img_s * 1e3)
-    bd = src.get("breakdown") if isinstance(src.get("breakdown"), dict) else None
-    if bd is None and src is carrier and isinstance(obj.get("breakdown"), dict):
-        bd = obj["breakdown"]
-    return {
-        "label": f"{dtype}@b{int(batch)}" + (" last_good" if stale else ""),
-        "dtype": str(dtype),
-        "img_s": img_s,
-        "batch": int(batch),
-        "per_pass_ms": per_pass,
-        "peak": _num(pick("assumed_peak_tflops")),
-        "device_kind": str(pick("device_kind") or ""),
-        "breakdown": bd,
-        "stale": stale,
-    }
-
-
-def row_views(obj: dict) -> List[dict]:
-    """The measurable dtype views a bench row carries: the fresh primary
-    (plus its ``bf16`` sub-object), or the ``last_good`` carry (plus ITS
-    ``bf16``) when the round measured nothing — stale views say so."""
-    views: List[dict] = []
-
-    def add(src, carrier, stale):
-        v = _view(src, carrier, obj, stale)
-        if v is not None:
-            views.append(v)
-
-    if _num(obj.get("value")):
-        add(obj, obj, False)
-        if isinstance(obj.get("bf16"), dict):
-            add(obj["bf16"], obj, False)
-    else:
-        lg = obj.get("last_good")
-        if isinstance(lg, dict):
-            add(lg, lg, True)
-            if isinstance(lg.get("bf16"), dict):
-                add(lg["bf16"], lg, True)
-    return views
-
-
-def roofline_from_bench_row(obj: dict, cfg=None) -> List[RooflineReport]:
-    """Every dtype view of one bench row, attributed. Views with a
-    measured ``breakdown`` join it (``source="breakdown"``); views
-    without one model-split their ``per_pass_ms`` (``source="model"``).
-    The view's own ``assumed_peak_tflops`` governs, so a committed row
-    reproduces its committed MFU from its own fields."""
-    reports: List[RooflineReport] = []
-    for v in row_views(obj):
-        bd = v["breakdown"]
-        stages = bd.get("stages") if isinstance(bd, dict) else None
-        if isinstance(stages, dict) and stages:
-            stages_ms = {n: float(ms) for n, ms in stages.items()}
-            source = "breakdown"
-            total = _num(bd.get("total_ms")) or sum(stages_ms.values())
-        else:
-            entries = pass_ledger(cfg, dtype=v["dtype"], batch=v["batch"])
-            peak = v["peak"] or _spec_peak(v["device_kind"], dtype=v["dtype"])
-            stages_ms = model_stage_split(
-                v["per_pass_ms"], entries, peak, _spec_hbm(v["device_kind"])
-            )
-            source = "model"
-            total = v["per_pass_ms"]
-        reports.append(
-            attribute_roofline(
-                stages_ms,
-                dtype=v["dtype"],
-                batch=v["batch"],
-                device_kind=v["device_kind"],
-                cfg=cfg,
-                source=source,
-                total_ms=total,
-                peak_override=v["peak"],
-                pass_img_s=v["img_s"],
-                label=v["label"],
-                stale=v["stale"],
-            )
-        )
-    return reports

@@ -1,14 +1,11 @@
 """Device spec table: peak TFLOP/s per dtype + HBM bandwidth per TPU
 generation, plus the live device-memory snapshot helper.
 
-Until ISSUE 13 the chip-capability knowledge lived as ``bench.py``'s
-private ``_PEAK_TABLE`` — a bf16-peak-only list no other subsystem could
-consult, which is why the repo could compute whole-pass MFU but never a
-per-stage bandwidth verdict. This module is the ONE source of truth:
-``bench.peak_tflops`` delegates here, and the roofline attribution layer
-(``observability.roofline``) reads the same table for its
-compute-vs-HBM-bound classification, so a bench row's ``assumed_peak``
-and a roofline verdict can never disagree about what the chip can do.
+This module is the ONE source of truth in the package for what a chip
+can do: the roofline attribution layer (``observability.roofline``)
+reads it for its compute-vs-HBM-bound classification. The benchmark
+keeps a copy of the v5e row with its source (``benchmark/peaks.json``);
+``tests/test_specs.py`` holds the two equal.
 
 Numbers come from the public TPU spec sheets, matched against jax's
 ``device_kind`` string ("v5" matches the "TPU v5 lite" spelling v5e
@@ -18,16 +15,15 @@ all rather than one judged against an assumed chip. Per-dtype peaks:
 
 - ``bf16`` — the MXU peak from the table.
 - ``fp32`` — ``bf16 / 6``: ``lax.Precision.HIGHEST`` synthesizes true
-  fp32 MACs out of 6 bf16 MXU passes (the ``fp32_ceiling_fraction``
-  convention bench rows already carry).
+  fp32 MACs out of 6 bf16 MXU passes (an assumption, labelled as one
+  in ``benchmark/peaks.json`` too).
 - ``int8w`` — equals the bf16 peak HERE, deliberately: this repo's
   int8w forward is dequant-free bf16-accumulate (docs/PRECISION.md) —
   the MXU executes bf16 operand passes, so the int8 TOPS column of the
   spec sheet is not the ceiling this codebase can reach. ``int8_tops``
   is still recorded on the spec for reference.
 
-Stdlib-only at module scope (bench imports this before jax exists);
-:func:`device_memory_stats` imports jax lazily. On the CPU backend, which
+Stdlib-only at module scope; :func:`device_memory_stats` imports jax lazily. On the CPU backend, which
 exposes no ``memory_stats()``, it reports the process RSS and says so
 (``source`` names which reading it is).
 """
@@ -35,7 +31,7 @@ exposes no ``memory_stats()``, it reports the process RSS and says so
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Tuple
+from typing import Optional, Tuple
 
 # lax.Precision.HIGHEST fp32 synthesis: 6 bf16 MXU passes per fp32 MAC.
 FP32_SYNTH_FACTOR = 6.0
@@ -67,8 +63,7 @@ class DeviceSpec:
         }
 
 
-# Ordered: longer/newer markers first so "v5p" wins over "v5" (the same
-# first-match discipline bench's private table used).
+# Ordered: longer/newer markers first so "v5p" wins over "v5".
 SPEC_TABLE: Tuple[DeviceSpec, ...] = (
     DeviceSpec("v6", "TPU v6e (Trillium)", 918.0, 1640.0, 1836.0),
     DeviceSpec("v5p", "TPU v5p", 459.0, 2765.0, 918.0),
@@ -104,12 +99,6 @@ def peak_tflops(device_kind: str, dtype: str = "bf16") -> float:
 def hbm_gbps(device_kind: str) -> float:
     """HBM bandwidth (GB/s) for ``device_kind``."""
     return spec_for(device_kind).hbm_gbps
-
-
-def bf16_peak_table() -> List[Tuple[str, float]]:
-    """The historical ``bench._PEAK_TABLE`` shape — ``(marker, bf16
-    TFLOP/s)`` pairs — derived from the one spec table."""
-    return [(s.marker, s.bf16_tflops) for s in SPEC_TABLE]
 
 
 # ------------------------------------------------------- live telemetry ---

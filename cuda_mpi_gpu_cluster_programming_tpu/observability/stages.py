@@ -1,7 +1,7 @@
 """Per-stage latency attribution for the Blocks 1-2 forward.
 
 The reference repo's headline artifact is a staged per-phase breakdown —
-scatter/halo/compute/gather ms per block — while our bench rows report
+scatter/halo/compute/gather ms per block — while a timed run reports
 one ``per_pass_ms``. This module attributes that total across the EXACT
 stage boundaries the in-graph sentinel taps (``with_digests=True``
 compiles digests at conv1/pool1/conv2/pool2/lrn2 inside the shard_map
@@ -16,8 +16,8 @@ repo's amortized work-floor estimator and attributes
 ``stage_k = t(prefix_k) - t(prefix_{k-1})``. The differences telescope,
 so the per-stage breakdown sums EXACTLY to the measured full-chain time
 (noise-negative diffs clamp to zero, then the stages renormalize onto
-the measured total) — the sums-to-total contract the bench ``breakdown``
-sub-object carries.
+the measured total) — the sums-to-total contract of the breakdown
+object (:meth:`StageAttribution.to_obj`).
 Per-stage timing of each stage in isolation (``utils.profiling.
 layer_breakdown``) cannot make that promise: XLA fuses across stage
 boundaries, so isolated stages systematically over-count.
@@ -85,8 +85,7 @@ class StageAttribution:
         return sum(ms for _n, ms in self.stages)
 
     def to_obj(self) -> dict:
-        """The bench ``breakdown`` sub-object — per-stage ms machine-
-        comparable across BENCH_r*.json captures."""
+        """The breakdown as a JSON-ready object (per-stage ms)."""
         return {
             "stages": {name: round(ms, 4) for name, ms in self.stages},
             "stage_sum_ms": round(self.stage_sum_ms, 4),
@@ -200,8 +199,8 @@ def attribute_blocks(
     :func:`attribute_stages`, but the prefixes are the two FUSED passes
     (block1; block1+block2) — the only boundaries a megakernel row
     honestly has. The result carries ``granularity="block"`` and a
-    method string naming the source, so downstream consumers (bench
-    rows, the regression gate) can never mistake it for a per-stage
+    method string naming the source, so downstream consumers can
+    never mistake it for a per-stage
     split the fused pass did not measure.
 
     ``variants``: the per-layer plan the row ran under (conv variant and

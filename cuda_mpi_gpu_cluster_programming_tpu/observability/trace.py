@@ -1,7 +1,7 @@
 """Span tracing: trace/span/parent ids over the crash-consistent journal.
 
-The repo's six journal schemas (``sup_*``, ``serve_*``, ``gate_*``,
-``mesh_shrink``, watchdog, bench rows) each record *that* something
+The repo's journal schemas (``sup_*``, ``serve_*``, ``gate_*``,
+``mesh_shrink``, watchdog) each record *that* something
 happened; none of them records *where the time went* or how one record
 relates to another. This module adds the correlation layer:
 

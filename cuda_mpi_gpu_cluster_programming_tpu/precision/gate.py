@@ -19,8 +19,8 @@ fails the gate for every candidate rather than blessing a matching error.
 Budgets are per-stage max-abs / max-rel pairs; ``rel`` is normalized by
 the oracle stage's max-|value| (elementwise relative error explodes near
 zeros — LRN outputs cross zero). ``margin`` is the fraction of budget left
-(1.0 = exact, 0.0 = at budget, negative = fail): the number bench rows
-carry as ``gate_margin``.
+(1.0 = exact, 0.0 = at budget, negative = fail): the number a tuned
+plan keeps with each verdict under ``gates``.
 """
 
 from __future__ import annotations

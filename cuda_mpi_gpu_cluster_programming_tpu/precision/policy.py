@@ -1,10 +1,12 @@
 """Dtype policies: precision as a first-class, named, per-layer axis.
 
-BENCH_r05 measured bf16 at MFU 0.571 against fp32's 0.123 on the same
-kernels — a ~4.6x ceiling the fp32 default leaves on the table — but until
-now ``dtype`` was only a passive key of the tuning plan that every caller
-had to pin by hand. A :class:`DtypePolicy` makes the choice explicit and
-auditable: per layer it names the dtype operands enter the contraction in
+On the v5e bf16 runs Blocks 1-2 4.9x faster than fp32 on the same
+kernels (105,5xx against 21,65x img/s: PERF_LEDGER.jsonl, cells
+``blocks12_offline`` and ``blocks12_offline_fp32``) — a ceiling the fp32
+default leaves on the table — but until this module ``dtype`` was only a
+passive key of the tuning plan that every caller had to pin by hand. A
+:class:`DtypePolicy` makes the choice explicit and auditable: per layer
+it names the dtype operands enter the contraction in
 (``compute``), the dtype the contraction accumulates in (``accumulate`` —
 threaded as ``preferred_element_type`` so the MXU/XLA accumulation width
 is stated, never inferred), and the dtype parameters are stored in

@@ -20,8 +20,8 @@ scheduling and CheckFreq-style recovery):
   structured ``SDC`` fault class the quarantine/rollback policy consumes.
 - ``journal`` — append-only crash-consistent run journal (fsync'd jsonl
   appends + atomic tmp-write/rename artifact writes) giving idempotent
-  resume to harness sweeps (``--resume``), bench capture (``BENCH_JOURNAL``)
-  and the train CLI (checkpoint-every-N + last-good rollback).
+  resume to harness sweeps (``--resume``) and the train CLI
+  (checkpoint-every-N + last-good rollback).
 - ``supervisor`` — the elastic layer over the in-graph sentinel: forwards
   compiled with per-stage digest taps inside their shard_map bodies, a
   trip (``stage_digest``/``shard_divergence``/``device_loss``) re-plans
@@ -33,12 +33,11 @@ Wired through ``harness`` (DEGRADED triage + bounded timeout re-capture +
 journaled ``--resume``), ``parallel.deploy`` (retrying transports + quorum
 degradation + journaled host states), ``run``
 (``--max-retries/--fallback-chain/--deadline-s``), ``train``
-(``--checkpoint-every`` + sentinel rollback) and ``bench.py``. See
-docs/RESILIENCE.md.
+(``--checkpoint-every`` + sentinel rollback). See docs/RESILIENCE.md.
 
 ``sentinel`` and ``supervisor`` import jax and are therefore NOT
-re-exported here — the stdlib-only consumers (harness, deploy, bench
-parent) import this package without paying a jax import; training/serving
+re-exported here — the stdlib-only consumers (harness, deploy) import
+this package without paying a jax import; training/serving
 callers import ``resilience.sentinel`` / ``resilience.supervisor``
 directly.
 """

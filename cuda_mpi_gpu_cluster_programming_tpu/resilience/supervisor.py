@@ -492,8 +492,8 @@ class Supervisor:
     ) -> ScriptedFault:
         """Schedule a deterministic device-loss incident at supervised
         step ``step`` — the replay harness's re-drive hook
-        (observability.replay, docs/OBSERVABILITY.md "Replay & regression
-        gating"). Unlike the seeded chaos sites this names the EXACT step
+        (observability.replay, docs/OBSERVABILITY.md "Replay"). Unlike the
+        seeded chaos sites this names the EXACT step
         and victim ids a recorded run lost, so a replayed journal trips
         where — and loses what — the record says it did. The fault rides
         the ordinary trip path (``_trip_and_recover``): the replay run

@@ -195,8 +195,8 @@ def make_parser() -> argparse.ArgumentParser:
                    "expired requests are shed explicitly, never dropped")
     p.add_argument("--serve-journal", default="",
                    help="with --serve: journal every warm/batch/shed/"
-                   "degrade record to this jsonl path (the serve bench's "
-                   "p50/p99 source)")
+                   "degrade record to this jsonl path (the p50/p99 "
+                   "source)")
     p.add_argument("--serve-buckets", default="",
                    help="with --serve: comma-separated explicit bucket "
                    "sizes (overrides the powers-of-two/TunePlan-derived "
@@ -263,7 +263,7 @@ def make_parser() -> argparse.ArgumentParser:
         default="",
         metavar="JOURNAL",
         help="re-drive a recorded serve journal through a live server on "
-        "this mesh (docs/OBSERVABILITY.md 'Replay & regression gating'): "
+        "this mesh (docs/OBSERVABILITY.md 'Replay'): "
         "same arrivals, request shapes/classes/deadlines, and chaos "
         "schedule, reconstructed from the journal alone (--config et al. "
         "are ignored — the journal's serve_config record is the truth). "

@@ -46,7 +46,8 @@ evaluation, and every reversal is journaled too.
 
 The controller is inert without an SLO policy (no classes ⇒ no burn, no
 knee) and journals nothing on a calm trace — the calm-path acceptance
-check ``BENCH_MODE=control`` pins.
+check ``tests/test_controller.py::test_calm_trace_replays_with_zero_actions``
+pins.
 
 Threading: every hook runs on the dispatch thread (``note_*`` from the
 ``@off_timed_path`` completion helpers, ``evaluate`` from the

@@ -237,7 +237,7 @@ class FleetController:
 
     def set_capacity_rps(self, rps: Optional[float]) -> None:
         """Operator input: fleet-wide sustainable request rate used as the
-        forecast-burn denominator (e.g. from ``bench.saturating_rate``).
+        forecast-burn denominator (e.g. from ``serving.loadgen.saturating_rate``).
         Not journaled itself — it is recorded as evidence on every forecast
         action it feeds."""
         self._capacity_rps = None if rps is None else float(rps)

@@ -1,4 +1,4 @@
-"""Poisson load generator + latency reporting for the serve bench.
+"""Poisson load generator + latency reporting for the serve drills.
 
 Arrivals are an explicitly seeded Poisson process (``random.Random(seed)``
 exponential inter-arrival gaps — deterministic schedule per seed, the same
@@ -66,7 +66,7 @@ def percentile(xs: List[float], q: float) -> Optional[float]:
 
 @dataclasses.dataclass
 class LoadReport:
-    """One load run's verdict — everything the bench JSON row needs."""
+    """One load run's verdict."""
 
     n_requests: int
     n_ok: int
@@ -228,128 +228,12 @@ def run_shaped_load(
     )
 
 
-# ------------------------------------------------------ saturation sweep ---
-
-
-def locate_knee(rows: List[dict], factor: float = 3.0) -> Optional[float]:
-    """The p99 knee of a saturation sweep: the first offered rate (img/s,
-    ascending) whose journal p99 exceeds ``factor`` x the lowest measured
-    rate's p99 — where the latency curve leaves its flat region and turns
-    vertical. None when every swept rate stayed under the threshold (the
-    sweep never crossed capacity — sweep higher)."""
-    measured = [
-        r for r in sorted(rows, key=lambda r: r["offered_img_s"])
-        if isinstance(r.get("p99_ms"), (int, float))
-    ]
-    if not measured:
-        return None
-    base = measured[0]["p99_ms"]
-    if base <= 0:
-        return None
-    for r in measured[1:]:
-        if r["p99_ms"] > factor * base:
-            return float(r["offered_img_s"])
-    return None
-
-
-def saturation_sweep(
-    server: InferenceServer,
-    rates_rps: List[float],
-    *,
-    duration_s: float,
-    classes: Optional[List[RequestClass]] = None,
-    shape: str = "steady",
-    seed: int = 0,
-    knee_factor: float = 3.0,
-    journal_path: str = "",
-) -> List[dict]:
-    """Sweep offered load past capacity on ONE started server; one row
-    dict per rate, each carrying the located ``knee_rate_img_s``.
-
-    Per rate: the metrics registry is reset (so its ``serve.request_ms``
-    percentiles cover exactly this rate's window), a shaped load runs,
-    and percentiles are computed BOTH from the journal slice this rate
-    appended and from the registry histogram — the same nearest-rank
-    estimator over the same population, so the row can assert they agree
-    (``percentiles_agree``). After the sweep the p99 knee is located
-    (:func:`locate_knee`) and stamped on every row.
-    """
-    from ..observability.metrics import registry as metrics_registry
-    from ..resilience.journal import Journal
-    from .server import class_latencies_from_records, latencies_from_records
-
-    if classes is None:
-        classes = list(default_class_mix(server.buckets))
-    rows: List[dict] = []
-    for rate in sorted(rates_rps):
-        n0 = len(Journal.load(journal_path)) if journal_path else 0
-        misses0 = server.stats.cache_misses
-        metrics_registry().reset()
-        report = run_shaped_load(
-            server, shape=shape, rate_rps=rate, duration_s=duration_s,
-            classes=classes, seed=seed,
-        )
-        # Quiesce before reading: a handle wakes its waiter BEFORE the
-        # dispatch thread's @off_timed_path completion helper finishes
-        # journaling the batch, so the last batch's records can lag the
-        # report by a scheduler slice. The rate's row must cover its whole
-        # population (and the registry must be settled before the next
-        # rate resets it) — poll, bounded.
-        recs: List[dict] = []
-        quiesce = time.monotonic() + 10.0
-        while journal_path:
-            recs = Journal.load(journal_path)[n0:]
-            if (
-                len(latencies_from_records(recs)) >= report.n_ok
-                or time.monotonic() >= quiesce
-            ):
-                break
-            time.sleep(0.01)
-        jlat = latencies_from_records(recs)
-        by_cls = class_latencies_from_records(recs)
-        reg_p99 = metrics_registry().histogram("serve.request_ms").percentile(99)
-        j_p99 = percentile(jlat, 99)
-        rows.append(
-            {
-                "rate_rps": rate,
-                "offered": report.n_requests,
-                "offered_img_s": round(rate * _mean_images(classes), 3),
-                "value": round(report.sustained_img_s, 1),
-                "p50_ms": percentile(jlat, 50),
-                "p99_ms": j_p99,
-                "metrics_p99_ms": reg_p99,
-                "percentiles_agree": (
-                    j_p99 is not None and reg_p99 is not None
-                    and abs(j_p99 - reg_p99) <= max(1e-6, 0.05 * j_p99)
-                ),
-                "classes": {
-                    (n or "default"): {
-                        **report.per_class[n].to_obj(),
-                        "journal_p99_ms": percentile(by_cls.get(n, []), 99),
-                    }
-                    for n in report.per_class
-                },
-                "n_ok": report.n_ok,
-                "n_shed": report.n_shed,
-                "n_failed": report.n_failed,
-                "n_rejected": report.n_rejected,
-                "accounting_closed": report.closed,
-                "cache_misses": server.stats.cache_misses - misses0,
-                "duration_s": round(report.duration_s, 3),
-                "shape": shape,
-                "seed": seed,
-            }
-        )
-    knee = locate_knee(rows, knee_factor)
-    for r in rows:
-        r["knee_rate_img_s"] = knee
-        r["knee_factor"] = knee_factor
-    return rows
+# ------------------------------------------------------ saturating rates ---
 
 
 def _mean_images(classes: List[RequestClass]) -> float:
     """Expected images per request under the mix — converts an arrival
-    rate (req/s) into offered load (img/s), the knee's unit."""
+    rate (req/s) into offered load (img/s)."""
     wsum = sum(c.weight for c in classes) or 1.0
     total = 0.0
     for c in classes:

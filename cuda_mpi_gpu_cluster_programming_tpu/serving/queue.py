@@ -76,7 +76,7 @@ class RequestHandle:
     @property
     def latency_ms(self) -> Optional[float]:
         """submit -> complete wall latency (the user-visible number the
-        serve bench reports percentiles of); None while pending."""
+        load reports take percentiles of); None while pending."""
         if self.completed_at is None:
             return None
         return (self.completed_at - self.submitted_at) * 1e3

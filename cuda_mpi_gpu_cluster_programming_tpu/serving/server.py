@@ -11,7 +11,7 @@ Dispatch discipline (docs/SERVING.md):
   after warmup.
 - **Every batch is journaled** (``serve_batch`` records with per-request
   latencies; ``serve_shed``/``serve_fail`` for the explicit loss paths) via
-  PR 3's fsync'd ``Journal``, so the bench's p50/p99 come from the same
+  PR 3's fsync'd ``Journal``, so the load reports' p50/p99 come from the same
   crash-consistent trail every other artifact uses.
 - **Degradation, not 500s.** With ``supervise=True`` the forward is the
   PR 5 elastic :class:`~..resilience.supervisor.Supervisor`: an SDC trip or
@@ -28,7 +28,7 @@ Dispatch discipline (docs/SERVING.md):
   the ``sup_*``/``mesh_*`` incident records they are exactly what
   ``observability.replay`` needs to re-drive the run — same arrivals,
   same chaos schedule — on a live server (docs/OBSERVABILITY.md
-  "Replay & regression gating").
+  "Replay").
 
 The dispatch loop keeps host syncs out of its body (staticcheck's
 ``host-sync-in-hot-loop`` rule now covers this file): the timed region
@@ -64,7 +64,7 @@ class ServeConfig:
     # tuned bucket set at): a policy name — fp32 | bf16 | int8w
     # (docs/PRECISION.md). ``policy`` records HOW it was chosen
     # (compute|dtype|policy|tuned — the run CLI's Precision source token)
-    # so journals/bench rows stay attributable.
+    # so journals stay attributable.
     compute: str = "fp32"
     policy: str = ""
     max_batch: int = 8
@@ -100,7 +100,7 @@ class ServeConfig:
 
 @dataclasses.dataclass
 class ServeStats:
-    """Steady-state counters the bench row and CLI line surface."""
+    """Steady-state counters the CLI line surfaces."""
 
     n_batches: int = 0
     n_images: int = 0
@@ -868,7 +868,7 @@ class InferenceServer:
 
 def request_latencies_from_journal(path) -> List[float]:
     """All per-request latencies (ms) journaled by ``serve_batch`` records —
-    the crash-consistent source the serve bench computes p50/p99 from (a
+    the crash-consistent source p50/p99 are computed from (a
     killed run's percentiles cover exactly the requests that completed)."""
     return latencies_from_records(Journal.load(path))
 

@@ -41,7 +41,6 @@ DEFAULT_PATHS = [
     "cuda_mpi_gpu_cluster_programming_tpu",
     "tests",
     "scripts",
-    "bench.py",
     "__graft_entry__.py",
 ]
 BASELINE_NAME = "staticcheck_baseline.json"

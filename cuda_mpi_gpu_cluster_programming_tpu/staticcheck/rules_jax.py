@@ -17,8 +17,8 @@ gate that cries wolf gets ``# noqa``'d into uselessness.
                          unsummed.
   host-sync-in-hot-loop — .item()/np.asarray/jax.device_get/
                          block_until_ready inside for/while bodies of the
-                         measurement surfaces (bench.py, harness.py,
-                         training.py, run.py, resilience/supervisor.py);
+                         measurement surfaces (harness.py, training.py,
+                         run.py, resilience/supervisor.py);
                          float(...) too when the loop is a timed region
                          (its body calls time.monotonic/perf_counter/time).
                          Each one is a device round-trip inside the loop
@@ -299,7 +299,7 @@ class UnreducedContractionRule(Rule):
 # observability subsystem lives here too — an instrumentation layer that
 # syncs inside the loops it instruments would corrupt every number it
 # reports. Directory scope, so it covers trace/metrics/stages/export AND
-# the ISSUE 12 replay/gate modules (the replay pacing loop re-drives a
+# the ISSUE 12 replay module (the replay pacing loop re-drives a
 # recorded arrival schedule on the wall clock, where a stray sync or
 # span write would shear the very schedule being reproduced) AND the
 # ISSUE 13 roofline/specs modules the moment they exist — the roofline
@@ -307,7 +307,7 @@ class UnreducedContractionRule(Rule):
 # module's live memory snapshots feed an @off_timed_path telemetry
 # helper on the dispatch loop.
 _HOT_LOOP_FILES = {
-    "bench.py", "harness.py", "training.py", "run.py", "supervisor.py",
+    "harness.py", "training.py", "run.py", "supervisor.py",
     "server.py", "loadgen.py", "batcher.py", "queue.py",
     # The network serving front end + traffic/SLO layers (ISSUE 11): the
     # transport sits directly on the request path, so a host sync or a

@@ -130,7 +130,7 @@ class TunePlan:
         return default if default is not None else KernelVariants()
 
     def plan_hash(self) -> str:
-        """10-hex identity of (key, winners) — the CSV/bench row label that
+        """10-hex identity of (key, winners) — the CSV row label that
         makes tuned measurements attributable to one exact plan."""
         payload = json.dumps(
             {"key": self.key, "layers": {n: v._asdict() for n, v in self.layers}},
@@ -308,8 +308,8 @@ def save_policy(
     ``plans``; the per-dtype kernel winners stay under their own keys).
 
     ``pruned`` records every gate-failed dtype with its attributable
-    reason; ``gates`` the full per-dtype gate verdicts (margin and all) —
-    bench rows read ``gate_margin`` from here. The gate's journaled
+    reason; ``gates`` the full per-dtype gate verdicts (margin and all).
+    The gate's journaled
     ``gate_pass`` record is written by the gate itself at screening time;
     this record points at the same verdict."""
     path = Path(path)

@@ -8,7 +8,7 @@ the analogue is JAX's persistent compilation cache: the first run of a
 — including each harness case subprocess — deserializes the cached
 executable instead.
 
-Enabled by every entry point (run.py, bench.py, train.py, chip_smoke.py,
+Enabled by every entry point (run.py, train.py, chip_smoke.py,
 and ``configs.build_forward`` for library callers). One way to place it:
 ``JAX_COMPILATION_CACHE_DIR``, which JAX reads itself — when it is set this
 module sets no directory. Unset, the cache lives at the fixed

@@ -36,7 +36,7 @@ AREAS: Dict[str, List[str]] = {
     "runtime": [f"{PKG}/*.py", f"{PKG}/utils/*.py"],
     "native": [f"{PKG}/native/__init__.py", f"{PKG}/native/csrc/*.cpp"],
     "examples": [f"{PKG}/examples/*.py"],
-    "harness": ["bench.py", "__graft_entry__.py", "scripts/*.py"],
+    "harness": ["__graft_entry__.py", "scripts/*.py"],
     "tests": ["tests/*.py"],
 }
 

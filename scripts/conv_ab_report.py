@@ -58,8 +58,9 @@ def parse(text: str) -> list[dict]:
 def v1_reference() -> dict[str, float]:
     """v1_jit img/s by compute tier from the committed fresh headline.
 
-    The bar and the A/B grid are defined at v1_jit b=128, but bench.py takes
-    BENCH_CONFIG/BENCH_BATCH from the environment, so bench_latest.json is
+    The bar and the A/B grid are defined at v1_jit b=128, but the frozen
+    record perf/bench_latest.json was captured at whatever config and batch
+    its run named, so it is
     not guaranteed to be that capture (the round-3 headline was b=256) —
     refuse any mismatched baseline rather than judge the bar against it.
     """

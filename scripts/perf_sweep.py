@@ -6,7 +6,7 @@ Run from the repo root on the real chip (ambient env untouched):
     python scripts/perf_sweep.py --quick       # 2 points per dimension
 
 Prints one JSON line per point (machine-parseable, harness-style) and a
-final ranking. The winner is the candidate for bench.py's measured config.
+final ranking (a frozen record: the benchmark is benchmark/run.py).
 """
 
 from __future__ import annotations

@@ -29,7 +29,7 @@ def test_collect_all(tmp_path):
         "=== README.md",
         "=== cuda_mpi_gpu_cluster_programming_tpu/ops/pallas_kernels.py",
         "=== cuda_mpi_gpu_cluster_programming_tpu/parallel/sharded.py",
-        "=== bench.py",
+        "=== __graft_entry__.py",
     ):
         assert marker in text, marker
 

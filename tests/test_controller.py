@@ -17,7 +17,7 @@ never tightened, accounting closed) and the ``replay --controller
 on|off`` A/B over one recorded saturating trace (books closed both
 ways, actions journaled with evidence on the on side, protected-class
 burn strictly lower with the controller on, calm trace => zero
-actions) — the tier-1 gate ``BENCH_MODE=control`` re-runs.
+actions).
 """
 
 import dataclasses
@@ -566,7 +566,7 @@ def test_replay_ab_controller_lowers_protected_burn(
     b_off, b_on = burn(off.journal_path), burn(on.journal_path)
     assert b_off is not None and b_on is not None
     assert b_on < b_off, f"controller on did not help: {b_on} vs {b_off}"
-    # the on-side replay row carries the controller state for the bench row
+    # the on-side replay report carries the controller state
     assert on.to_obj()["controller_state"]["actions"]
 
 

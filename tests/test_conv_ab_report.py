@@ -58,9 +58,9 @@ def test_report_without_reference_is_na():
 
 def test_v1_reference_rejects_mismatched_baseline(tmp_path, monkeypatch):
     """A bench_latest captured under a different config or batch must not
-    become the bar's denominator (review finding: BENCH_CONFIG/BENCH_BATCH
-    are environment-driven, so the committed headline isn't guaranteed to
-    be v1_jit b=128)."""
+    become the bar's denominator (review finding: the capture's config and
+    batch were environment-driven, so the committed headline isn't
+    guaranteed to be v1_jit b=128)."""
     import json
     perf = tmp_path / "perf"
     perf.mkdir()

@@ -219,14 +219,20 @@ def test_floor_reached_when_device_zero_dies(tmp_path):
     assert tree_device_ids(params) == {5}  # the floor is the SURVIVOR
     out = sup.supervise_step(params, opt_state, xs[1], ys[1], step=1)
     assert tree_device_ids(out[0]) == {5}
-    # bit-identical to the same two steps on the default device
+    # Bit-identical to runs PINNED to each topology (the elastic promise,
+    # as in the promote test below): step 0 on the sp=2 rung it ran on,
+    # step 1 on the single-device floor, pinned to the survivor. Not to two
+    # steps on one device: two shards sum the gradient in another order.
     opt2 = optax.sgd(1e-3)
-    _, step2 = make_train_step(CFG, optimizer=opt2)
-    p2, o2 = student, opt2.init(student)
-    for x, y in zip(xs, ys):
-        r = step2(p2, o2, x, y)
-        p2, o2 = r[0], r[1]
-    assert _trees_equal(out[0], p2)
+    _, step_hi = make_train_step(CFG, mesh=make_mesh(2), optimizer=opt2, sp_shards=2)
+    _, step_floor = make_train_step(CFG, optimizer=opt2)
+    r = step_hi(student, opt2.init(student), xs[0], ys[0])
+    (survivor,) = sup.pool.alive()
+    p2, o2 = jax.device_put((r[0], r[1]), survivor)
+    r = step_floor(p2, o2, xs[1], ys[1])
+    assert tree_device_ids(r[0]) == {5}
+    assert _trees_equal(out[0], r[0])
+    assert _trees_equal(out[1], r[1])
 
 
 # --------------------------------------------------------------- reshard ---

@@ -12,19 +12,14 @@ fit plus preshed/release pre-actuation with predicted-vs-realized
 evidence, the calm-trace zero-action contract, the fleet export lane
 (pid pinned; pre-20 journals byte-identical), the health fold
 (max-simultaneously-degraded + phase-decomposed drain incidents), the
-staticcheck hot-loop scope, and the correlated-pressure A/B acceptance
-drill over 3 real backend processes (BENCH_MODE=fleetcontrol).
+and the staticcheck hot-loop scope.
 
-Fast tests drive stub backends (programmable /healthz controller
-payloads) in-process with injected ``now=``; the acceptance drill
-spawns real fleets.
+The tests drive stub backends (programmable /healthz controller
+payloads) in-process with injected ``now=``.
 """
 
 import json
 import math
-import os
-import subprocess
-import sys
 import threading
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from pathlib import Path
@@ -33,7 +28,6 @@ import pytest
 
 from cuda_mpi_gpu_cluster_programming_tpu.observability.export import (
     _PIDS,
-    load_records,
     to_trace_events,
 )
 from cuda_mpi_gpu_cluster_programming_tpu.observability.health import (
@@ -762,56 +756,3 @@ def test_router_config_journals_fleet_header(ctl_trio, tmp_path):
         assert isinstance(router.fleet_controller, FleetController)
     finally:
         _close(router)
-
-
-# --------------------------------------------- acceptance drill (A/B) ---
-
-
-@pytest.mark.slow
-def test_bench_fleetcontrol_ab_acceptance_drill(tmp_path):
-    """THE ISSUE-20 acceptance drill over real processes: the same
-    correlated diurnal swell (chaos ``fleet_pressure``) driven through 3
-    controlled backends twice — fleet control ON, then OFF (N
-    uncoordinated Autopilots). From journaled evidence: ON never
-    all-degrades while OFF does, protected-class fleet-wide burn is
-    strictly lower ON, the calm window journals zero fleet actions, and
-    per-class accounting closes at the router both ways.
-
-    Real timing path over live subprocesses (~1 min), so marked slow —
-    tier-1 covers the controller logic with the injected clock above."""
-    proc = subprocess.run(
-        [sys.executable, str(ROOT / "bench.py")],
-        cwd=ROOT, capture_output=True, text=True, timeout=560,
-        env={
-            **os.environ,
-            "JAX_PLATFORMS": "cpu",
-            "BENCH_MODE": "fleetcontrol",
-            "BENCH_FLEETCTL_JOURNAL": str(tmp_path / "fleetctl"),
-        },
-    )
-    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    lines = [l for l in proc.stdout.splitlines() if l.strip()]
-    row = json.loads(lines[-1])
-    assert row["metric"] == "alexnet_blocks12_fleet_control"
-    assert "error" not in row, row
-    assert row["ok"] is True and row["failures"] == []
-    n = row["n_backends"]
-    assert row["calm_actions"] == 0
-    assert row["max_degraded"]["on"] < n
-    assert row["max_degraded"]["off"] == n
-    assert row["burn_protected"]["on"] < row["burn_protected"]["off"]
-    assert row["accounting_closed"] == {"on": True, "off": True}
-    assert row["fleet_actions"].get("preshed", 0) >= 1
-    # The evidence IS the journal: re-fold it independently.
-    fs_on = fleet_summary(load_records(str(tmp_path / "fleetctl" / "on")))
-    assert fs_on["max_simultaneous_degraded"] == row["max_degraded"]["on"]
-    preshed = [
-        r
-        for r in load_records(str(tmp_path / "fleetctl" / "on"))
-        if r.get("kind") == "fleet_action" and r.get("action") == "preshed"
-    ]
-    assert preshed, "no journaled preshed under the swell"
-    ev = preshed[0]["evidence"]
-    assert ev["capacity_rps"] > 0
-    assert ev["realized_rps"] >= 0
-    assert preshed[0]["cause"] in ("forecast", "realized")

@@ -96,7 +96,7 @@ def test_lint_variant_env_reads_scoped_to_tuning(tmp_path):
         "b = os.environ['TPU_FRAMEWORK_KBLOCK']\n"          # read: flagged
         "c = os.getenv('PALLAS_WHATEVER_KNOB')\n"           # read: flagged
         "os.environ['TPU_FRAMEWORK_CONV'] = 'taps'\n"       # write: fine
-        "d = os.environ.get('BENCH_CONFIG')\n"              # other var: fine
+        "d = os.environ.get('OTHER_CONFIG')\n"              # other var: fine
         "e = os.environ.get('TPU_FRAMEWORK_FUSE')  # noqa: variant-env\n"
     )
     bad = tmp_path / "stray.py"

@@ -259,24 +259,6 @@ def test_roofline_joins_block_names_against_fused_model():
         )
 
 
-def test_bench_breakdown_routes_fused_rows_to_blocks(seeded, monkeypatch):
-    """A pallas row resolved to fuse="block" attributes at block
-    granularity; the staged default keeps the five-stage vocabulary."""
-    import bench
-
-    params, x = seeded
-    monkeypatch.setenv("TPU_FRAMEWORK_FUSE", "block")
-    obj = bench._stage_breakdown(
-        "pallas", "fp32", params, x, "tpu", model_cfg=SMALL)
-    assert obj.get("granularity") == "block"
-    assert set(obj["stages"]) == {"block1", "block2"}
-    monkeypatch.setenv("TPU_FRAMEWORK_FUSE", "none")
-    obj = bench._stage_breakdown(
-        "reference", "fp32", params, x, "cpu", model_cfg=SMALL)
-    assert obj.get("granularity") == "stage"
-    assert "conv1" in obj["stages"]
-
-
 # ------------------------------------------------------- sharded int8w ---
 
 
@@ -308,37 +290,6 @@ def test_sharded_int8w_rungs_build_and_screen(seeded, key, shards):
             staged=(key == "v4_hybrid"),
         )
         assert res.passed, res.reason()
-
-
-# -------------------------------------------------- regression variants ---
-
-
-def test_regression_gate_separates_staged_and_fused_chains(tmp_path):
-    """Staged and fuse="block" rounds are distinct variants: a block round
-    never diffs against a staged round's stages, while same-granularity
-    regressions still fire."""
-    import json
-
-    from cuda_mpi_gpu_cluster_programming_tpu.observability.gate import evaluate
-
-    def row(name, value, stages, gran):
-        (tmp_path / name).write_text(json.dumps({
-            "value": value, "per_pass_ms": 10.0,
-            "breakdown": {"stages": stages, "granularity": gran},
-        }))
-
-    row("BENCH_r01.json", 100.0, {"conv1": 4.0, "conv2": 6.0}, "stage")
-    # Fused round: block1 "worse than conv1" must NOT flag across chains.
-    row("BENCH_r02.json", 120.0, {"block1": 9.0, "block2": 1.0}, "block")
-    row("BENCH_r03.json", 119.0, {"block1": 9.1, "block2": 0.9}, "block")
-    v = evaluate(sorted(tmp_path.glob("BENCH_r*.json")))
-    assert v.ok, [r.to_obj() for r in v.regressions]
-    # A genuine block-vs-block regression still fails the gate.
-    row("BENCH_r04.json", 118.0, {"block1": 12.0, "block2": 0.9}, "block")
-    v = evaluate(sorted(tmp_path.glob("BENCH_r*.json")))
-    assert not v.ok
-    assert [r.stage for r in v.regressions] == ["block1"]
-    assert v.rows[-1].granularity == "block"
 
 
 def test_staticcheck_scope_covers_megakernel():

@@ -27,7 +27,6 @@ from cuda_mpi_gpu_cluster_programming_tpu.observability import (  # noqa: E402
     span,
 )
 from cuda_mpi_gpu_cluster_programming_tpu.observability.export import (  # noqa: E402
-    bench_report,
     export_trace,
     to_trace_events,
 )
@@ -206,8 +205,7 @@ def test_stage_attribution_sums_to_total_within_tolerance():
     fwd = build_forward(REGISTRY["v1_jit"], cfg)
     # Two independent timing passes on a shared CPU container can land a
     # scheduler hiccup apart; re-measure (bounded) before judging the 15%
-    # budget — the same measure-again discipline bench's wedge re-capture
-    # uses. The sums-to-total identity is asserted on every attempt.
+    # budget. The sums-to-total identity is asserted on every attempt.
     for attempt in range(3):
         att = attribute_stages(params, x, cfg, repeats=3, warmup=1)
         assert [n for n, _ in att.stages] == list(
@@ -262,7 +260,9 @@ def _validate_nesting(trace):
         lane.sort(key=lambda e: (e["ts"], -e["dur"]))
         open_stack = []
         for e in lane:
-            while open_stack and open_stack[-1] <= e["ts"]:
+            # an end is a float sum (ts + dur): a sibling that ends where this
+            # one starts may read 2e-10 later, so the same slack as below
+            while open_stack and open_stack[-1] <= e["ts"] + 1e-6:
                 open_stack.pop()
             if open_stack:
                 assert e["ts"] + e["dur"] <= open_stack[-1] + 1e-6, (
@@ -451,30 +451,6 @@ def test_export_cli_subprocess(tmp_path):
         capture_output=True, text=True, cwd=ROOT, timeout=120,
     )
     assert proc.returncode == 0 and "spans=1" in proc.stdout
-
-
-def test_bench_report_flags_regressions(tmp_path):
-    good = {
-        "metric": "m", "value": 1000.0, "per_pass_ms": 1.0,
-        "breakdown": {"stages": {"conv1": 0.6, "conv2": 0.4}},
-    }
-    bad = {
-        "metric": "m", "value": 500.0, "per_pass_ms": 2.0,
-        "breakdown": {"stages": {"conv1": 0.6, "conv2": 1.4}},
-    }
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({"parsed": good}))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(bad))
-    rep = bench_report(
-        [tmp_path / "BENCH_r01.json", tmp_path / "BENCH_r02.json"]
-    )
-    assert "REGRESSION BENCH_r02.json: 1000.0 -> 500.0" in rep
-    assert "REGRESSION BENCH_r02.json stage conv2" in rep
-    # and a clean trajectory flags nothing
-    (tmp_path / "BENCH_r03.json").write_text(json.dumps(good))
-    rep2 = bench_report(
-        [tmp_path / "BENCH_r01.json", tmp_path / "BENCH_r03.json"]
-    )
-    assert "flags: none" in rep2
 
 
 # ---------------------------------------------------------------------------

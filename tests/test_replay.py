@@ -1,4 +1,4 @@
-"""Journal-replay fleet simulator + perf-regression gate (ISSUE 12).
+"""Journal-replay fleet simulator (ISSUE 12).
 
 The determinism contract: a serve journal records its own inputs
 (``serve_config`` conditions + per-request ``serve_submit`` arrivals +
@@ -7,10 +7,7 @@ against its own conditions through a LIVE server must close per-class
 accounting identically and land journal-derived p50/p99 within the
 nearest-rank estimator's resolution. Knobs (``--traffic-mult``,
 ``--devices``, ``--slo-scale``) turn the same harness into a capacity
-what-if whose accounting still closes. The gate half: ``observability
-report --fail-on-regression`` / ``BENCH_MODE=gate`` exit 3 on >10%
-regressions, with ``last_good``-echo rounds excluded attributably —
-asserted over the COMMITTED BENCH_r* trail (the tier-1 gate)."""
+what-if whose accounting still closes."""
 
 import dataclasses
 import json
@@ -428,157 +425,6 @@ def test_run_cli_serve_replay(recorded_journal, tmp_path):
         capture_output=True, text=True, cwd=ROOT, timeout=120, env=ENV,
     )
     assert proc.returncode == 2
-
-
-# ---------------------------------------------------------------------------
-# the regression gate wired into tier-1
-
-
-def test_gate_passes_an_echo_trail_via_echo_exclusion(echo_trail):
-    """THE tier-1 gate: a trail of failed rounds echoing earlier numbers
-    passes, and it passes because the r04 echo is detected and excluded
-    attributably — not because the stale trail happens to be flat."""
-    from cuda_mpi_gpu_cluster_programming_tpu.observability.gate import (
-        evaluate,
-    )
-
-    verdict = evaluate(echo_trail)
-    assert verdict.ok, [r.to_obj() for r in verdict.regressions]
-    by_name = {r.name: r for r in verdict.rows}
-    assert by_name["BENCH_r04.json"].provenance == (
-        "stale (echo of BENCH_r03.json)"
-    )
-    assert by_name["BENCH_r04.json"].echo_of == "BENCH_r03.json"
-    # first-appearance last_good carries stay comparable (measured once)
-    assert by_name["BENCH_r03.json"].provenance == "last_good(stale)"
-    assert by_name["BENCH_r05.json"].provenance == "last_good(stale)"
-    assert verdict.compared >= 1  # r03 -> r05 was actually diffed
-    assert "stale (echo of BENCH_r03.json)" in verdict.render()
-
-
-def test_gate_fails_on_injected_regression_and_cli_exits_3(tmp_path):
-    """An injected >10% stage+headline regression between fresh rounds
-    fails the structured verdict, and report --fail-on-regression exits 3
-    (without the flag: report-only, exit 0 — the PR 9 behavior)."""
-    from cuda_mpi_gpu_cluster_programming_tpu.observability.gate import (
-        evaluate,
-    )
-
-    good = {
-        "metric": "m", "value": 1000.0, "per_pass_ms": 1.0,
-        "breakdown": {"stages": {"conv1": 0.6, "conv2": 0.4}},
-    }
-    bad = {
-        "metric": "m", "value": 500.0, "per_pass_ms": 2.0,
-        "breakdown": {"stages": {"conv1": 0.6, "conv2": 1.4}},
-    }
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps({"parsed": good}))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps({"parsed": bad}))
-    paths = [tmp_path / "BENCH_r01.json", tmp_path / "BENCH_r02.json"]
-    verdict = evaluate(paths)
-    assert not verdict.ok
-    kinds = {(r.kind, r.stage) for r in verdict.regressions}
-    assert ("headline", "") in kinds and ("stage", "conv2") in kinds
-    proc = subprocess.run(
-        [
-            sys.executable, "-m",
-            "cuda_mpi_gpu_cluster_programming_tpu.observability",
-            "report", "--fail-on-regression", "--json",
-        ] + [str(p) for p in paths],
-        capture_output=True, text=True, cwd=ROOT, timeout=120,
-    )
-    assert proc.returncode == 3
-    obj = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert obj["ok"] is False and len(obj["regressions"]) == 2
-    proc = subprocess.run(
-        [
-            sys.executable, "-m",
-            "cuda_mpi_gpu_cluster_programming_tpu.observability",
-            "report",
-        ] + [str(p) for p in paths],
-        capture_output=True, text=True, cwd=ROOT, timeout=120,
-    )
-    assert proc.returncode == 0  # report-only stays an exit-0 viewer
-
-
-def test_gate_echo_cannot_mask_or_manufacture_regressions(tmp_path):
-    """Echo semantics, both directions: (1) an echoed value equal to an
-    earlier round is excluded, so it cannot 'confirm' a flat line; (2) a
-    MARKED carry with a new (lower) value participates and regresses."""
-    from cuda_mpi_gpu_cluster_programming_tpu.observability.gate import (
-        evaluate,
-    )
-
-    fresh = {"metric": "m", "value": 1000.0}
-    echo = {
-        "metric": "m", "value": 0.0, "error": "wedged",
-        "value_last_good": 1000.0, "last_good": {"stale": True},
-    }
-    drop = {
-        "metric": "m", "value": 0.0, "error": "wedged",
-        "value_last_good": 500.0, "last_good": {"stale": True},
-    }
-    (tmp_path / "BENCH_r01.json").write_text(json.dumps(fresh))
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(echo))
-    (tmp_path / "BENCH_r03.json").write_text(json.dumps(drop))
-    verdict = evaluate(sorted(tmp_path.glob("BENCH_r0*.json")))
-    by_name = {r.name: r for r in verdict.rows}
-    assert by_name["BENCH_r02.json"].is_echo
-    assert not by_name["BENCH_r03.json"].is_echo
-    # the r01(1000, fresh) -> r03(500, first-appearance carry) drop is a
-    # regression the r02 echo cannot hide
-    assert not verdict.ok
-    assert verdict.regressions[0].kind == "headline"
-    assert verdict.regressions[0].frm == "BENCH_r01.json"
-    assert verdict.regressions[0].to == "BENCH_r03.json"
-    # two identical FRESH measurements never echo (no staleness marker)
-    (tmp_path / "BENCH_r02.json").write_text(json.dumps(fresh))
-    (tmp_path / "BENCH_r03.json").write_text(json.dumps(fresh))
-    verdict = evaluate(sorted(tmp_path.glob("BENCH_r0*.json")))
-    assert verdict.ok and not verdict.echoes
-
-
-def test_bench_mode_gate_subprocess(echo_trail):
-    """BENCH_MODE=gate over a bench trail: one parseable verdict row,
-    exit 0 — the wiring CI consumes."""
-    proc = subprocess.run(
-        [sys.executable, "bench.py"],
-        capture_output=True, text=True, cwd=ROOT, timeout=120,
-        env={
-            **os.environ,
-            "BENCH_MODE": "gate",
-            "BENCH_GATE_PATHS": str(echo_trail[0].parent / "BENCH_r0*.json"),
-        },
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    row = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert row["metric"] == "alexnet_blocks12_bench_gate"
-    assert row["ok"] is True
-    assert "BENCH_r04.json" in row["echoes"]
-
-
-def test_bench_mode_replay_smoke(recorded_journal, tmp_path):
-    """BENCH_MODE=replay: the bench surface emits one JSON row with the
-    accounting diff and exits 0 on a clean neutral replay."""
-    jp, report = recorded_journal
-    proc = subprocess.run(
-        [sys.executable, "bench.py"],
-        capture_output=True, text=True, cwd=ROOT, timeout=300,
-        env={
-            **ENV,
-            "BENCH_MODE": "replay",
-            "BENCH_REPLAY_JOURNAL": str(jp),
-            "BENCH_REPLAY_OUT": str(tmp_path / "rj.jsonl"),
-        },
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    row = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert row["metric"] == "alexnet_blocks12_serve_replay"
-    assert row["accounting_matches"] is True and row["diverged"] is False
-    offered = sum(
-        c["replay"]["offered"] for c in row["classes"].values()
-    )
-    assert offered == report.n_requests
 
 
 # ---------------------------------------------------------------------------

@@ -7,15 +7,15 @@ both), staged-vs-fused byte-model monotonicity with the delta equal to
 the intermediates' write+read round-trips, compute/memory-bound
 classification against the spec table's ridge point, the CPU-mesh
 integration joining a REAL ``attribute_stages`` breakdown into a ranked
-report, the committed-BENCH acceptance (roofline-over-BENCH_r05
-reproduces the bf16 MFU 0.5713 from the row's own fields), the
-echo-aware CLI, the one-source-of-truth spec table bench delegates to,
+report, the CLI's usage exit, the one-source-of-truth spec table,
 the serve telemetry records (``serve_gauges``/``mem_snapshot``), the
 Perfetto counter tracks, and the Prometheus exposition.
 """
 
 import dataclasses
+import inspect
 import json
+import os
 import subprocess
 import sys
 from pathlib import Path
@@ -41,8 +41,6 @@ from cuda_mpi_gpu_cluster_programming_tpu.observability.roofline import (  # noq
     fused_blocks,
     model_stage_split,
     pass_ledger,
-    roofline_from_bench_row,
-    row_views,
     stage_ledger,
 )
 
@@ -128,13 +126,13 @@ def test_block_structure_matches_the_megakernel_plan():
 # ----------------------------------------------------------------- specs ---
 
 
-def test_spec_table_is_the_one_source_bench_delegates_to():
-    import bench
-
-    # the historical bench surface delegates: same answers, one table
-    assert bench.peak_tflops("TPU v5 lite") == 197.0
-    assert bench.peak_tflops("TPU v4") == 275.0
-    assert bench._PEAK_TABLE == specs.bf16_peak_table()
+def test_spec_table_is_the_one_source():
+    assert specs.peak_tflops("TPU v5 lite") == 197.0
+    assert specs.peak_tflops("TPU v4") == 275.0
+    assert [(s.marker, s.bf16_tflops) for s in specs.SPEC_TABLE] == [
+        ("v6", 918.0), ("v5p", 459.0), ("v5", 197.0),
+        ("v4", 275.0), ("v3", 123.0), ("v2", 45.0),
+    ]
     # per-dtype peaks: fp32 is the bf16 peak / 6 (HIGHEST synthesis);
     # int8w runs bf16 MXU passes in this repo (dequant-free forward)
     assert specs.peak_tflops("TPU v5 lite", "fp32") == pytest.approx(197.0 / 6)
@@ -146,21 +144,18 @@ def test_spec_table_is_the_one_source_bench_delegates_to():
     assert specs.spec_for("TPU v5p").bf16_tflops == 459.0
 
 
-def test_unknown_device_is_an_error_not_a_default(monkeypatch):
+def test_unknown_device_is_an_error_not_a_default():
     """A device outside the table has no peak: nothing is judged against
     an assumed chip, and no environment variable overrides the table."""
-    import bench
-
     for kind in ("cpu", "weird-device", ""):
         with pytest.raises(specs.UnknownDeviceError, match="not in the spec table"):
             specs.spec_for(kind)
     with pytest.raises(specs.UnknownDeviceError):
-        bench.peak_tflops("weird-device")
+        specs.peak_tflops("weird-device")
     with pytest.raises(specs.UnknownDeviceError):
         specs.hbm_gbps("cpu")
-    monkeypatch.setenv("BENCH_PEAK_TFLOPS", "100")
-    monkeypatch.setenv("BENCH_PEAK_HBM_GBPS", "500")
-    assert bench.peak_tflops("TPU v5 lite") == 197.0
+    assert "environ" not in inspect.getsource(specs)  # reads no variable
+    assert specs.peak_tflops("TPU v5 lite") == 197.0
     assert specs.hbm_gbps("TPU v5 lite") == 819.0
 
 
@@ -263,85 +258,7 @@ def test_cpu_mesh_integration_joins_a_real_breakdown():
     assert "roofline" in rep.render() and "fused block1" in rep.render()
 
 
-# ------------------------------------------------------------ bench rows ---
-
-
-def test_roofline_over_a_bench_row_reproduces_its_mfu(echo_trail):
-    """THE acceptance: a bench row's bf16 and fp32 MFU recomputed from the
-    row's OWN fields — throughput x matmul FLOPs / its peak — not read
-    back from the mfu field."""
-    obj = json.loads(echo_trail[4].read_text())["parsed"]
-    reports = {r.dtype: r for r in roofline_from_bench_row(obj)}
-    assert set(reports) == {"fp32", "bf16"}
-    bf16 = reports["bf16"]
-    assert round(bf16.pass_mfu, 4) == obj["last_good"]["bf16"]["mfu"] > 0.5
-    assert round(reports["fp32"].pass_mfu, 4) == obj["last_good"]["mfu"] > 0.1
-    for rep in reports.values():
-        assert rep.stale  # a last_good carry says so
-        assert rep.source == "model"  # pre-PR-9 row: no measured breakdown
-        assert rep.device_kind == "TPU v5 lite"
-        assert {s.name for s in rep.stages} == set(STAGES)
-        assert sum(s.ms for s in rep.stages) == pytest.approx(rep.total_ms)
-    # per_pass_ms derived for views without it: batch/img_s
-    assert bf16.total_ms == pytest.approx(
-        obj["last_good"]["bf16"]["per_pass_ms"]
-    )
-
-
-def test_row_views_fresh_vs_stale_and_bf16_inheritance():
-    fresh = {
-        "value": 100.0, "compute": "fp32", "batch": 8,
-        "device_kind": "TPU v4", "assumed_peak_tflops": 275.0,
-        "matmul_flops_per_image": 1,
-        "bf16": {"value": 300.0, "compute": "bf16"},
-    }
-    views = row_views(fresh)
-    assert [v["dtype"] for v in views] == ["fp32", "bf16"]
-    assert all(not v["stale"] for v in views)
-    assert views[1]["batch"] == 8  # inherited from the carrier row
-    assert views[1]["device_kind"] == "TPU v4"
-    # an error round with no last_good has no measurable view
-    assert row_views({"value": 0.0, "error": "wedged"}) == []
-
-
-def test_roofline_cli_over_an_echo_trail_marks_echoes(echo_trail):
-    """The CLI acceptance: over a BENCH_r*.json trail the roofline CLI
-    ranks the five stages with MFU + bound verdicts, marks the r04 echo
-    attributably (gate.py's detection, reused), and never ranks it as
-    fresh."""
-    bf16_mfu = json.loads(echo_trail[4].read_text())["parsed"]["last_good"]["bf16"]["mfu"]
-    proc = subprocess.run(
-        [
-            sys.executable, "-m",
-            "cuda_mpi_gpu_cluster_programming_tpu.observability",
-            "roofline", *(str(p) for p in echo_trail),
-        ],
-        capture_output=True, text=True, cwd=ROOT, timeout=300,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    out = proc.stdout
-    assert "stale (echo of BENCH_r03.json)" in out
-    assert "echo of BENCH_r03.json — stale carry, not ranked" in out
-    for stage in STAGES:
-        assert stage in out
-    assert f"mfu={bf16_mfu:.4f}" in out  # the bf16 headline, recomputed
-    assert "STALE (last_good carry)" in out  # carries are labeled
-    assert "fused block2 (conv2+pool2+lrn2)" in out
-    assert "bound" in out and "compute" in out and "memory" in out
-    # --json emits one machine-readable object per rendered view
-    proc = subprocess.run(
-        [
-            sys.executable, "-m",
-            "cuda_mpi_gpu_cluster_programming_tpu.observability",
-            "roofline", "--json", str(echo_trail[4]),
-        ],
-        capture_output=True, text=True, cwd=ROOT, timeout=300,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rows = [json.loads(l) for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert {r["dtype"] for r in rows} == {"fp32", "bf16"}
-    assert all(r["round"] == "BENCH_r05.json" for r in rows)
-    assert all(r["stale"] for r in rows)
+# ------------------------------------------------------------------- CLI ---
 
 
 def test_roofline_cli_usage_rc2(tmp_path):
@@ -354,7 +271,8 @@ def test_roofline_cli_usage_rc2(tmp_path):
         capture_output=True, text=True, cwd=ROOT, timeout=120,
     )
     assert proc.returncode == 2
-    assert "BENCH rows" in proc.stderr
+    assert "pass --live" in proc.stderr
+    # the positional rows mode is gone: a path is a usage error too
     bad = tmp_path / "nothing.json"
     bad.write_text("not json at all")
     proc = subprocess.run(
@@ -366,6 +284,18 @@ def test_roofline_cli_usage_rc2(tmp_path):
         capture_output=True, text=True, cwd=ROOT, timeout=120,
     )
     assert proc.returncode == 2
+    # --live on a device outside the spec table refuses before measuring
+    proc = subprocess.run(
+        [
+            sys.executable, "-m",
+            "cuda_mpi_gpu_cluster_programming_tpu.observability",
+            "roofline", "--live",
+        ],
+        capture_output=True, text=True, cwd=ROOT, timeout=120,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
+    assert proc.returncode == 2
+    assert "not in the spec table" in proc.stderr
 
 
 # --------------------------------------------------------- live telemetry ---

@@ -13,7 +13,7 @@ and stitch every journal into one valid Perfetto timeline with the
 outage folded into a phase-decomposed backend_down incident.
 
 Fast tests drive stub backends (programmable wire verdicts) in-process;
-the acceptance drill and CLI/bench smokes spawn real fleets.
+the acceptance drill and the CLI smoke spawn real fleets.
 """
 
 import http.client
@@ -752,7 +752,7 @@ def test_host_loss_drill_across_process_boundary(tmp_path, monkeypatch):
         fleet.stop()
 
 
-# ------------------------------------------------------- CLI + bench ---
+# --------------------------------------------------------------- CLI ---
 
 
 def test_run_route_cli_smoke(tmp_path):
@@ -781,33 +781,3 @@ def test_run_route_cli_smoke(tmp_path):
     assert "Health: " in out
     assert (tmp_path / "route" / "router.jsonl").exists()
     assert (tmp_path / "route" / "backend_0.jsonl").exists()
-
-
-def test_bench_route_mode_smoke(tmp_path):
-    """BENCH_MODE=route: exactly one JSON row with the drill fields —
-    pre/post-loss img/s, redirects, unroutable, recovery_ms, and the
-    router's closed accounting."""
-    proc = subprocess.run(
-        [sys.executable, str(ROOT / "bench.py")],
-        cwd=ROOT, capture_output=True, text=True, timeout=420,
-        env={
-            **os.environ,
-            "JAX_PLATFORMS": "cpu",
-            "BENCH_MODE": "route",
-            "BENCH_ROUTE_N": "2",
-            "BENCH_ROUTE_RATE": "15",
-            "BENCH_ROUTE_DURATION": "1.0",
-            "BENCH_ROUTE_JOURNAL": str(tmp_path / "route"),
-        },
-    )
-    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    lines = [l for l in proc.stdout.splitlines() if l.strip()]
-    row = json.loads(lines[-1])
-    assert row["metric"] == "alexnet_blocks12_route_host_loss"
-    assert "error" not in row, row
-    assert row["accounting_closed"] is True
-    assert row["pre_loss_img_s"] > 0 and row["post_loss_img_s"] > 0
-    assert row["killed"] == "b0"  # seed=0 % 2 — deterministic victim
-    assert row["recovery_ms"] is not None and row["recovery_ms"] > 0
-    assert row["backends"] == {"b0": "up", "b1": "up"}
-    assert row["health"].get("summary")

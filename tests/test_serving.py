@@ -8,8 +8,7 @@ record, never a silent drop), the TunePlan-derived bucket set, the
 zero-cache-miss dispatch discipline, the seeded ``device_loss`` chaos
 drill (in-flight requests finish via supervisor replay, bit-identical to
 an unfaulted run pinned to the degraded rung), the Poisson load generator,
-and the two CLI surfaces: ``run --serve`` and the ``bench.py`` serve mode
-(the tier-1 CPU-mesh serve smoke).
+and the CLI surface ``run --serve`` (the tier-1 CPU-mesh serve smoke).
 """
 
 import dataclasses
@@ -468,7 +467,12 @@ def test_grow_back_drill_promotes_with_zero_misses_bit_identical(
 
 
 def test_threaded_poisson_load_accounts_for_every_request(tmp_path):
+    from cuda_mpi_gpu_cluster_programming_tpu.observability.metrics import (
+        registry,
+    )
+
     jpath = tmp_path / "serve.jsonl"
+    registry().reset()
     srv = InferenceServer(
         ServeConfig(config="v1_jit", max_batch=4, model_cfg=CFG,
                     journal_path=str(jpath))
@@ -488,6 +492,17 @@ def test_threaded_poisson_load_accounts_for_every_request(tmp_path):
     assert srv.stats.cache_misses == 0
     # the journaled latencies are the same population the report saw
     assert len(request_latencies_from_journal(jpath)) == report.n_ok
+    # and so is the process registry's: one count per answered request,
+    # one batch time per dispatch
+    metrics = registry().summary()
+    assert metrics["serve.ok"] == report.n_ok
+    assert metrics["serve.batch_ms"]["count"] >= 1
+    assert metrics["serve.batch_ms"]["p50"] > 0
+    # same estimator over the same population: the registry's p99 is the
+    # journal's (which rounds each latency to a microsecond)
+    reg_p99 = registry().histogram("serve.request_ms").percentile(99)
+    j_p99 = percentile(request_latencies_from_journal(jpath), 99)
+    assert abs(reg_p99 - j_p99) <= 1e-3
 
 
 # ----------------------------------------------------------- CLI surfaces ---
@@ -522,92 +537,3 @@ def test_run_cli_serve_rejects_full_model():
     )
     assert proc.returncode == 2
     assert "Blocks 1-2 configs only" in proc.stderr
-
-
-def test_bench_serve_mode_cpu_smoke(tmp_path):
-    """The tier-1 CPU-mesh serve smoke (ISSUE 6 CI satellite): a journaled
-    Poisson run reporting p50/p99 + sustained img/s with ZERO post-warmup
-    compile-cache misses, plus the in-load device_loss drill finishing all
-    in-flight requests via supervisor replay, bit-identically."""
-    jpath = tmp_path / "serve_bench.jsonl"
-    env = {
-        **os.environ,
-        "JAX_PLATFORMS": "cpu",
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-        "BENCH_MODE": "serve",
-        "BENCH_SERVE_HEIGHT": "63",
-        "BENCH_SERVE_WIDTH": "63",
-        "BENCH_SERVE_DURATION": "0.5",
-        "BENCH_SERVE_RATE": "40",
-        "BENCH_SERVE_MAX_BATCH": "4",
-        "BENCH_SERVE_JOURNAL": str(jpath),
-    }
-    proc = subprocess.run(
-        [sys.executable, "bench.py"], capture_output=True, text=True,
-        cwd=ROOT, timeout=540, env=env,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    line = [l for l in proc.stdout.splitlines() if l.startswith("{")][-1]
-    row = json.loads(line)
-    assert row["metric"] == "alexnet_blocks12_serve_images_per_sec"
-    assert "error" not in row
-    assert row["value"] > 0
-    assert row["p50_ms"] > 0 and row["p99_ms"] >= row["p50_ms"]
-    assert row["cache_misses_post_warmup"] == 0
-    assert row["n_ok"] == row["n_requests"]
-    assert row["buckets"] == [1, 2, 4]
-    drill = row["drill"]
-    assert drill["completed"] == drill["n_requests"]
-    assert drill["trips"] == ["device_loss"]
-    assert drill["replayed_in_flight"] is True
-    assert drill["bit_identical"] is True
-    # ISSUE 8: the drill sub-object's mesh_shrink row — the elastic path's
-    # machine-comparable trajectory across BENCH_r* rounds.
-    shrink = drill["mesh_shrink"]
-    assert shrink["completed"] == shrink["n_requests"]
-    assert shrink["trips"] == ["mesh_shrink"]
-    assert shrink["devices_after"] < shrink["devices_before"]
-    assert shrink["replayed"] == 1
-    assert shrink["rewarm_ms"] > 0
-    assert shrink["cache_misses_post_rewarm"] == 0
-    # ISSUE 10: the drill sub-object's mesh_grow row — lose, heal,
-    # probation, PROMOTE, with the throughput-recovery verdict.
-    grow = drill["mesh_grow"]
-    assert grow["completed"] == grow["n_requests"]
-    assert grow["promotions"] == 1
-    assert grow["trips"] == ["mesh_shrink"]
-    assert grow["promoted_entry"] != grow["degraded_entry"]
-    assert grow["recovered"] is True
-    assert grow["recovery_ms"] > 0
-    assert grow["pre_img_s"] > 0 and grow["post_img_s"] > 0
-    assert grow["cache_misses_post_promote"] == 0
-    assert grow["cache_misses_total"] == 0
-    # the journal backs the reported percentiles
-    assert len(request_latencies_from_journal(jpath)) == row["n_ok"]
-    # ISSUE 9 CI satellite: serve rows carry a NON-EMPTY per-stage
-    # breakdown (sentinel tap boundaries) alongside the zero-cache-miss
-    # assertion above, the process metrics summary, and the trace id the
-    # journal's spans correlate on.
-    bd = row["breakdown"]
-    assert set(bd["stages"]) == {"conv1", "pool1", "conv2", "pool2", "lrn2"}
-    assert bd["stage_sum_ms"] > 0
-    # The roofline join rides beside the breakdown on a chip in the spec
-    # table; this CPU smoke has no roof to be judged against and says so.
-    assert "not in the spec table" in row["roofline"]["skipped"]
-    metrics = row["metrics"]
-    assert metrics["serve.ok"] == row["n_ok"]
-    assert metrics["serve.batch_ms"]["count"] >= 1
-    assert metrics["serve.batch_ms"]["p50"] > 0
-    assert row["trace_id"]
-    # the serve journal doubles as the span trail: dispatch + queue-wait
-    # spans landed beside their serve_batch records, exportable as one
-    # Perfetto timeline
-    from cuda_mpi_gpu_cluster_programming_tpu.resilience.journal import Journal
-
-    recs = Journal.load(jpath)
-    span_names = {r["name"] for r in recs if r["kind"] == "span"}
-    assert {"serve.dispatch", "serve.queue_wait", "serve.warmup"} <= span_names
-    batches = [r for r in recs if r["kind"] == "serve_batch"]
-    assert batches and all(r.get("trace_id") == row["trace_id"] for r in batches)
-    kinds = {r["kind"] for r in recs}
-    assert {"serve_gauges", "mem_snapshot"} <= kinds

@@ -8,8 +8,7 @@ queue contract exactly (429 backpressure, 413 oversize, 400 malformed,
 records, traffic shapes (seeded diurnal/burst/flash arrivals, heavy-
 tailed class mixes), SLO-aware shed-by-class under a flash crowd with
 per-class CLOSED accounting, the ``QueueStats.oldest_wait_ms`` gauge,
-the saturation sweep's p99 knee with journal==registry percentile
-agreement, and the chaos drills riding through the front end unchanged.
+and the chaos drills riding through the front end unchanged.
 """
 
 import dataclasses
@@ -49,10 +48,8 @@ from cuda_mpi_gpu_cluster_programming_tpu.serving.frontend import (
     http_fleet_load,
 )
 from cuda_mpi_gpu_cluster_programming_tpu.serving.loadgen import (
-    locate_knee,
     percentile,
     run_shaped_load,
-    saturation_sweep,
 )
 from cuda_mpi_gpu_cluster_programming_tpu.serving.queue import (
     OK,
@@ -527,52 +524,6 @@ def test_flash_crowd_sheds_by_class_accounting_closes(tmp_path):
     assert gauge.value is not None
 
 
-# ------------------------------------------------------ saturation study ---
-
-
-def test_saturation_sweep_finds_knee_and_percentiles_agree(tmp_path):
-    """The in-process saturation study: sweep past CPU capacity, locate
-    the p99 knee, close accounting at every rate, and agree between the
-    journal slice and the metrics-registry histogram (same estimator,
-    same population)."""
-    jpath = tmp_path / "serve.jsonl"
-    mix = list(default_class_mix((1, 2, 4)))
-    scfg = ServeConfig(config="v1_jit", max_batch=4, model_cfg=CFG,
-                       journal_path=str(jpath), slo=slo_policy(mix))
-    srv = InferenceServer(scfg).start()
-    try:
-        rows = saturation_sweep(
-            srv, [25.0, 500.0], duration_s=0.4, classes=mix, seed=5,
-            journal_path=str(jpath),
-        )
-    finally:
-        srv.stop()
-    assert len(rows) == 2
-    low, high = rows
-    assert low["rate_rps"] == 25.0 and high["rate_rps"] == 500.0
-    for r in rows:
-        assert r["accounting_closed"] is True
-        assert r["cache_misses"] == 0
-        assert r["percentiles_agree"] is True
-        assert r["knee_rate_img_s"] == high["offered_img_s"]  # knee located
-        assert set(r["classes"]) == {"interactive", "batch", "bulk"}
-    assert high["p99_ms"] > 3.0 * low["p99_ms"]  # the knee is real
-    # reproducible under the fixed seed: the offered schedule is identical
-    assert low["offered"] == len(shaped_arrivals("steady", 25.0, 0.4, 5))
-
-
-def test_locate_knee_edge_cases():
-    rows = [
-        {"offered_img_s": 10.0, "p99_ms": 10.0},
-        {"offered_img_s": 20.0, "p99_ms": 12.0},
-        {"offered_img_s": 40.0, "p99_ms": 100.0},
-    ]
-    assert locate_knee(rows, 3.0) == 40.0
-    assert locate_knee(rows[:2], 3.0) is None  # never crossed: no knee
-    assert locate_knee([], 3.0) is None
-    assert locate_knee([{"offered_img_s": 1.0, "p99_ms": None}], 3.0) is None
-
-
 # ----------------------------------------------------------- CLI surfaces ---
 
 
@@ -615,46 +566,3 @@ def test_run_cli_rejects_bad_traffic_shape():
     )
     assert proc.returncode == 2
     assert "unknown traffic shape" in proc.stderr
-
-
-def test_bench_saturate_mode_cpu_smoke(tmp_path):
-    """BENCH_MODE=saturate tier-1 smoke: one JSON row per swept rate,
-    accounting closed, journal==registry percentiles, zero cache misses,
-    and the p99 knee located (the sweep crossed CPU capacity)."""
-    jpath = tmp_path / "saturate.jsonl"
-    env = {
-        **os.environ,
-        "JAX_PLATFORMS": "cpu",
-        "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-        "BENCH_MODE": "saturate",
-        "BENCH_SERVE_HEIGHT": "63",
-        "BENCH_SERVE_WIDTH": "63",
-        "BENCH_SERVE_MAX_BATCH": "4",
-        "BENCH_SAT_RATES": "30,600",
-        "BENCH_SAT_DURATION": "0.6",
-        "BENCH_SERVE_JOURNAL": str(jpath),
-        "BENCH_SERVE_SEED": "7",
-    }
-    proc = subprocess.run(
-        [sys.executable, "bench.py"], capture_output=True, text=True,
-        cwd=ROOT, timeout=540, env=env,
-    )
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    rows = [json.loads(l) for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert len(rows) == 2
-    for row in rows:
-        assert row["metric"] == "alexnet_blocks12_serve_saturation"
-        assert "error" not in row
-        assert row["accounting_closed"] is True
-        assert row["percentiles_agree"] is True
-        assert row["cache_misses"] == 0
-        assert row["cache_misses_post_warmup"] == 0
-        assert row["seed"] == 7
-        assert row["knee_rate_img_s"] is not None  # knee located
-        assert row["trace_id"]
-    low, high = sorted(rows, key=lambda r: r["rate_rps"])
-    assert high["p99_ms"] > 3.0 * low["p99_ms"]
-    assert high["knee_rate_img_s"] == high["offered_img_s"]
-    # the journal backs the rows: batches + SLO sheds landed there
-    kinds = {r["kind"] for r in Journal.load(jpath)}
-    assert "serve_batch" in kinds

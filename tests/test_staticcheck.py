@@ -71,7 +71,7 @@ _UNREDUCED = (
     7,
 )
 _HOST_SYNC = (
-    "bench.py",  # the rule is scoped to the measurement surfaces by name
+    "harness.py",  # the rule is scoped to the measurement surfaces by name
     "import time\n"
     "def measure(fn, x, steps):\n"
     "    times = []\n"
@@ -858,11 +858,11 @@ def test_observability_scope_and_shipped_modules_clean():
         )
     # ISSUE 12/13/15: the directory scope grows with the subsystem — the
     # replay pacing loop (a timed loop re-driving a recorded arrival
-    # schedule), the gate, the roofline/specs modules, and the fleet
+    # schedule), the roofline/specs modules, and the fleet
     # health analyzer are covered the moment they exist, and ship clean.
     for mod in (
         "trace.py", "metrics.py", "stages.py", "export.py",
-        "replay.py", "gate.py", "roofline.py", "specs.py", "health.py",
+        "replay.py", "roofline.py", "specs.py", "health.py",
     ):
         for rule in (HostSyncInHotLoopRule(), SpanWriteInTimedRegionRule()):
             assert rule.applies(Path(f"{obs}/{mod}"))
@@ -873,7 +873,6 @@ def test_observability_scope_and_shipped_modules_clean():
     for rel in (
         "cuda_mpi_gpu_cluster_programming_tpu/serving/server.py",
         "cuda_mpi_gpu_cluster_programming_tpu/resilience/supervisor.py",
-        "bench.py",
     ):
         assert findings_for(ROOT / rel, "span-write-in-timed-region") == []
 
