@@ -1027,7 +1027,9 @@ def _lrn_kernel(x_ref, o_ref, *, size: int, alpha: float, beta: float, k: float,
     """Cross-channel LRN over one (LRN_ROWS, C) tile of pixels; the
     channel-window sum of squares is a banded 0/1-matrix matmul on the MXU
     — no lane-dimension slicing, and the band edges implement the
-    reference's window truncation exactly."""
+    reference's window truncation exactly. Both tiers share the
+    formulation: ``ops.reference.lrn`` takes the same product through
+    ``lax.dot_general`` and lets XLA fuse the rest round it."""
     # All math in fp32 regardless of the activation dtype: the band matmul
     # must be dtype-homogeneous (Mosaic rejects a bf16 lhs against the f32
     # band — "Bad lhs type"), and the scale/power path is precision-critical.

@@ -10,8 +10,9 @@ layout — C maps to VPU lanes) and weights are HWIO ``(F, F, C, K)``.
 Converters to/from the reference layout live in ``models.init``.
 
 Everything here is jit-friendly: static shapes, no Python control flow on
-traced values, so XLA can fuse bias+ReLU into the conv and tile the matmuls
-onto the MXU.
+traced values, so XLA can fuse bias+ReLU into the conv, tile the matmuls
+onto the MXU, and make the whole LRN one fusion round its band product
+(squares in, scale, power and divide in the epilogue).
 """
 
 from __future__ import annotations
@@ -126,17 +127,32 @@ def lrn(
     V3/V4 log in the reference's regression corpus was produced with. The
     CPU-vs-CUDA divide-vs-``powf(scale,-beta)`` discrepancy is standardized
     here on the divide form across all tiers.
+
+    The window sum is a product of the squares with a banded 0/1 matrix
+    (``band[i, j] = |i - j| <= size//2``; its edges are the truncated
+    windows), the formulation ``pallas_kernels._lrn_kernel`` has always
+    used: channels are the lane axis, a ``reduce_window`` along it cost the
+    v5e 0.088 ms for this layer's 5.5M elements (PERF.md, PR 30), and as a
+    matmul it is 2.5% of conv2's MXU work, with the squares, the scale, the
+    power and the divide fused round it into one kernel. Precision by the
+    tier's rule (``mxu_precision``): fp32 squares at HIGHEST, bf16 squares
+    native. The sum accumulates in float32 and the scale, power and divide
+    run on it in float32 in every compute type (a bf16 ``reduce_window``
+    added in bf16); the result is cast back to ``x.dtype``.
     """
     half = size // 2
-    sq = x * x
-    ssum = lax.reduce_window(
-        sq,
-        0.0,
-        lax.add,
-        window_dimensions=(1, 1, 1, size),
-        window_strides=(1, 1, 1, 1),
-        padding=[(0, 0), (0, 0), (0, 0), (half, half)],
+    c = x.shape[-1]
+    ci = lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    cj = lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    band = (jnp.abs(ci - cj) <= half).astype(x.dtype)
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    ssum = lax.dot_general(
+        x * x,
+        band,
+        (((x.ndim - 1,), (0,)), ((), ())),
+        precision=mxu_precision(x.dtype),
+        preferred_element_type=acc,
     )
     a = alpha / size if alpha_over_size else alpha
     scale = k + a * ssum
-    return x / scale**beta
+    return (x.astype(acc) / scale**beta).astype(x.dtype)

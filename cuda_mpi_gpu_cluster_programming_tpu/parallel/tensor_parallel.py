@@ -122,10 +122,20 @@ def build_tp_forward(
             z = tap(pool2, maxpool(z, window=cfg.pool2.window, stride=cfg.pool2.stride))
         with scopes.layer(lrn2):
             # LRN crosses channels: exchange `half` neighbor channels,
-            # normalize, keep the owned slice.
+            # normalize, keep the owned slice. The slab is laid into zeros
+            # of the full channel width at its own offset first: the LRN's
+            # window sum is a matmul over channels, and a backend may group
+            # a contraction's terms by its width (XLA:CPU does), so each
+            # term must meet the index it meets on one device for the
+            # bitwise claim above to hold; the zeros add exactly.
             if n_shards > 1:
                 with scopes.halo(lrn2):
                     zp = _channel_halo(z, half, axis_name, n_shards)
+                k2 = cfg.conv2.out_channels
+                start = lax.axis_index(axis_name) * local2
+                wide = jnp.zeros((*z.shape[:-1], k2 + 2 * half), z.dtype)
+                wide = lax.dynamic_update_slice_in_dim(wide, zp, start, axis=-1)
+                zp = wide[..., half : half + k2]
             else:
                 zp = z
             zl = lrn(
@@ -136,7 +146,7 @@ def build_tp_forward(
                 k=cfg.lrn2.k,
                 alpha_over_size=cfg.lrn2.alpha_over_size,
             )
-            out = zl[..., half:-half] if n_shards > 1 else zl
+            out = lax.dynamic_slice_in_dim(zl, start, local2, axis=-1) if n_shards > 1 else zl
         tap(lrn2, out)
         return (out, digs) if with_digests else out
 
