@@ -43,7 +43,7 @@ class ExecConfig:
     tier: str  # "reference" (XLA ops) | "pallas"
     strategy: str  # "single" | "replicated" | "halo" | "staged_halo"
     description: str
-    model: str = "blocks12"  # "blocks12" | "alexnet_full" | "mla_moe"
+    model: str = "blocks12"  # "blocks12" | "alexnet_full" | "mla_moe" | "kda_moe"
 
 
 REGISTRY: Dict[str, ExecConfig] = {
@@ -138,8 +138,30 @@ REGISTRY: Dict[str, ExecConfig] = {
             "flash-attention and grouped-matmul kernels",
             model="mla_moe",
         ),
+        ExecConfig(
+            "v9_kda_moe",
+            "V9 KDA-MoE Share",
+            "reference",
+            "single",
+            "hybrid linear-attention (gated delta rule) / gated softmax GQA MoE decoder "
+            "as one expert-parallel chip holds it, single device, XLA ops with the "
+            "chunked-scan, flash-attention and grouped-matmul kernels",
+            model="kda_moe",
+        ),
     ]
 }
+
+# The language-model families: token ids in, logits out, parameters stored in
+# the compute type. ``ExecConfig.model`` names the module under ``models``.
+LANGUAGE_MODELS = ("mla_moe", "kda_moe")
+
+
+def language_model(exec_cfg: ExecConfig):
+    """The model module of a language-model config (``SMALL``, ``PRESETS``,
+    ``init``, ``param_count``, ``forward``)."""
+    import importlib
+
+    return importlib.import_module(f".models.{exec_cfg.model}", __package__)
 
 
 def _resolve_variants(plan):
@@ -346,13 +368,12 @@ def _build_forward_fp32(
             f"device_count=N on CPU to fake a mesh)"
         )
 
-    if exec_cfg.model == "mla_moe":
-        from .models import mla_moe
-
+    if exec_cfg.model in LANGUAGE_MODELS:
+        model = language_model(exec_cfg)
         if exec_cfg.strategy != "single":
-            raise ValueError(f"strategy {exec_cfg.strategy!r} not supported for mla_moe")
-        model_cfg = model_cfg or mla_moe.SMALL
-        return _jit(lambda p, ids: mla_moe.forward(p, ids, model_cfg), donate)
+            raise ValueError(f"strategy {exec_cfg.strategy!r} not supported for {exec_cfg.model}")
+        model_cfg = model_cfg or model.SMALL
+        return _jit(lambda p, ids: model.forward(p, ids, model_cfg), donate)
 
     if exec_cfg.model == "alexnet_full":
         from .models.alexnet_full import ALEXNET, forward_alexnet

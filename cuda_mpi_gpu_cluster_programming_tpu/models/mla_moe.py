@@ -12,24 +12,19 @@ group-limited top-k routed experts beside a shared expert):
   (nope + rope); ``[c_kv, k_r] = u W_kva``; ``[k_nope, v] = RMSNorm(c_kv) W_kvb``;
   rotary embedding on ``q_rope`` and on the one ``k_r`` all heads share; scores
   ``(q_nope.k_nope + q_rope.k_r) * softmax_scale``, causal, softmax in float32.
-- *Router*: ``s = sigmoid(u W_r)`` in float32; selection on ``s + bias``: a
-  group's score is the sum of its top 2, the top ``topk_group`` groups are
-  kept, the top ``num_experts_per_tok`` experts among them chosen; weights are
-  the unbiased ``s`` of the chosen, normalised to sum 1, times
-  ``routed_scaling_factor``.
-- *MoE*: ``sum_i w_i Expert_i(u) + Shared(u)``.
+- *Router* and *MoE* (``models.moe_share``, which the families share):
+  ``s = sigmoid(u W_r)`` in float32; selection on ``s + bias``: a group's score
+  is the sum of its top 2, the top ``topk_group`` groups are kept, the top
+  ``num_experts_per_tok`` experts among them chosen; weights are the unbiased
+  ``s`` of the chosen, normalised to sum 1, times ``routed_scaling_factor``;
+  ``sum_i w_i Expert_i(u) + Shared(u)``.
 
 **The chip's share.** The layer is told which experts it holds
-(``[experts_first, experts_first + experts_held)``). It routes over all
-``n_routed_experts``, computes only the (token, expert) pairs whose expert it
-holds — dropless, no capacity: the pairs are sorted by expert, each expert's
-rows padded to whole tiles, ``ops.grouped_matmul`` runs over as many chunks of
-rows as were routed here, and every token gathers its experts' rows back and
-weights them — adds the shared expert, and that partial sum goes on to the
-next layer. What the absent experts would add arrives, in
-a deployment, by the exchange of ``parallel.expert``; nothing here stands in
-for it. The vocabulary is the chip's slice: ids are drawn from it and the
-logits are over it.
+(``[experts_first, experts_first + experts_held)``), routes over all
+``n_routed_experts`` and computes only the pairs whose expert it holds,
+dropless (``models.moe_share`` says how); nothing here stands in for the
+absent experts. The vocabulary is the chip's slice: ids are drawn from it and
+the logits are over it.
 
 Numerics follow the parameters' type. Stored in bf16, operands go to the MXU
 in bf16 and every product accumulates in float32
@@ -47,19 +42,17 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import Any, Dict, List, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
 
 from ..ops import scopes
 from ..ops.flash_attention import flash_forward_bhld
-from ..ops.grouped_matmul import grouped_matmul
-from ..ops.reference import mxu_precision
-
-Params = Dict[str, Any]
+from . import moe_share
+from .moe_share import Params, _mm, _moe, _rms_norm, _swiglu
+from .moe_share import _is_leaf, route  # noqa: unused-import — the MoE share's, under the names they had here
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,16 +95,7 @@ class MlaMoeConfig:
     expert_span_rows: int = 32  # rows of results held until their tokens gather them back
 
     def __post_init__(self):
-        if self.n_routed_experts % self.n_group:
-            raise ValueError("n_routed_experts must divide into n_group groups")
-        if not 0 <= self.experts_first <= self.n_routed_experts - self.experts_held:
-            raise ValueError("the experts held must lie inside the router's width")
-        if self.expert_chunk_rows % self.expert_tile_rows:
-            raise ValueError("expert_chunk_rows must be whole tiles")
-        if self.expert_span_rows % self.expert_chunk_rows:
-            raise ValueError("expert_span_rows must be whole chunks")
-        if self.n_shared_experts != 1:
-            raise ValueError("one shared expert is what this model computes")
+        moe_share.check_share(self)
 
     @property
     def qk_head_dim(self) -> int:
@@ -213,14 +197,7 @@ def param_shapes(cfg: MlaMoeConfig) -> Params:
     """The parameter tree as ``(shape, fan_in)`` leaves; ``fan_in`` 0 marks a
     norm gain (drawn as 1) and -1 the router's selection bias."""
     d, h = cfg.hidden_size, cfg.num_attention_heads
-    f, fe, e = cfg.intermediate_size, cfg.moe_intermediate_size, cfg.experts_held
-
-    def swiglu(width, lead=()):
-        return {
-            "gate": ((*lead, d, width), d),
-            "up": ((*lead, d, width), d),
-            "down": ((*lead, width, d), width),
-        }
+    f, fe = cfg.intermediate_size, cfg.moe_intermediate_size
 
     def layer(kind: str) -> Params:
         out = {
@@ -235,14 +212,9 @@ def param_shapes(cfg: MlaMoeConfig) -> Params:
             "ffn_norm": ((d,), 0),
         }
         if kind == "dense":
-            out["mlp"] = swiglu(f)
+            out["mlp"] = moe_share.swiglu_shapes(d, f)
         else:
-            out["moe"] = {
-                "router": ((d, cfg.n_routed_experts), d),
-                "bias": ((cfg.n_routed_experts,), -1),
-                "experts": swiglu(fe, (e,)),
-                "shared": swiglu(fe),
-            }
+            out["moe"] = moe_share.moe_shapes(d, fe, cfg)
         return out
 
     return {
@@ -253,77 +225,20 @@ def param_shapes(cfg: MlaMoeConfig) -> Params:
     }
 
 
-def _is_leaf(node) -> bool:
-    return isinstance(node, tuple) and len(node) == 2 and isinstance(node[1], int)
-
-
-# The selection bias is drawn small: enough that selection (biased) and
-# weighting (unbiased) differ, little enough that it unbalances no expert's
-# load by more than a tenth (a trained bias is there to balance the load).
-BIAS_SCALE = 0.005
-
-
-def _draw_leaf(key, shape, fan_in, dtype):
-    if fan_in == 0:
-        return jnp.ones(shape, dtype)
-    scale = BIAS_SCALE if fan_in < 0 else fan_in**-0.5
-    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
-
-
-def _draw_tree(key, shapes, dtype):
-    leaves, treedef = jax.tree.flatten(shapes, is_leaf=_is_leaf)
-    keys = jax.random.split(key, len(leaves))
-    return jax.tree.unflatten(
-        treedef, [_draw_leaf(k, shape, fan_in, dtype) for k, (shape, fan_in) in zip(keys, leaves)]
-    )
-
-
 def init(key, cfg: MlaMoeConfig = SMALL, dtype=jnp.bfloat16) -> Params:
     """Seeded parameters stored in ``dtype``: normal weights of scale
-    ``fan_in**-0.5``, norm gains 1, a small selection bias. One jitted draw per
-    layer (one program per kind of layer) and one for the embedding, the final
-    norm and the head, so the draw's peak is a layer and never the model."""
-    shapes = param_shapes(cfg)
-    layer_shapes = shapes.pop("layers")
-    kinds = cfg.layer_kinds()
-    draw = {
-        kind: jax.jit(functools.partial(_draw_tree, shapes=layer_shapes[kinds.index(kind)], dtype=dtype))
-        for kind in set(kinds)
-    }
-    keys = jax.random.split(key, len(layer_shapes) + 1)
-    params = jax.jit(functools.partial(_draw_tree, shapes=shapes, dtype=dtype))(keys[0])
-    params["layers"] = [draw[kind](k) for kind, k in zip(kinds, keys[1:])]
-    return params
+    ``fan_in**-0.5``, norm gains 1, a small selection bias, drawn layer by
+    layer (``moe_share.init_by_layer``)."""
+    return moe_share.init_by_layer(key, param_shapes(cfg), cfg.layer_kinds(), dtype)
 
 
 def param_count(cfg: MlaMoeConfig) -> int:
-    leaves = jax.tree.leaves(param_shapes(cfg), is_leaf=_is_leaf)
-    return sum(math.prod(shape) for shape, _fan_in in leaves)
+    return moe_share.count(param_shapes(cfg))
 
 
 # ---------------------------------------------------------------------------
 # Layers
 # ---------------------------------------------------------------------------
-
-
-def _rms_norm(x, gain, eps: float):
-    """RMSNorm with float32 statistics; float32 out."""
-    xf = x.astype(jnp.float32)
-    return xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps) * gain.astype(jnp.float32)
-
-
-def _mm(spec: str, x, w):
-    """``einsum`` with the operands in the parameters' type and a float32 result."""
-    return jnp.einsum(
-        spec, x.astype(w.dtype), w,
-        preferred_element_type=jnp.float32, precision=mxu_precision(w.dtype),
-    )
-
-
-def _swiglu(p: Params, u):
-    """``down(silu(gate u) * up u)``; ``u`` in the parameters' type, float32 out."""
-    hidden = jax.nn.silu(_mm("td,df->tf", u, p["gate"])) * _mm("td,df->tf", u, p["up"])
-    return _mm("tf,fd->td", hidden, p["down"])
 
 
 def _mla(p: Params, x, cfg: MlaMoeConfig):
@@ -361,109 +276,6 @@ def _mla(p: Params, x, cfg: MlaMoeConfig):
         return x + _mm("bhse,hed->bsd", attn, p["o"])
 
 
-def route(p: Params, u, cfg: MlaMoeConfig):
-    """``(chosen experts (T, k) int32, their weights (T, k) float32)`` of the
-    tokens ``u (T, D)``: sigmoid scores, selection on the biased scores
-    limited to the best groups, weights from the unbiased ones."""
-    scores = jax.nn.sigmoid(_mm("td,de->te", u, p["router"]))
-    biased = scores + p["bias"].astype(jnp.float32)
-    groups = biased.reshape(-1, cfg.n_group, cfg.n_routed_experts // cfg.n_group)
-    group_score = lax.top_k(groups, 2)[0].sum(axis=-1)  # (T, n_group)
-    _best, kept = lax.top_k(group_score, cfg.topk_group)
-    keep = jnp.any(kept[..., None] == jnp.arange(cfg.n_group), axis=1)  # (T, n_group)
-    candidates = jnp.where(keep[..., None], groups, -jnp.inf).reshape(biased.shape)
-    _top, chosen = lax.top_k(candidates, cfg.num_experts_per_tok)
-    weights = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
-    return chosen.astype(jnp.int32), weights
-
-
-def _dispatch(chosen, cfg: MlaMoeConfig):
-    """The (token, expert) pairs whose expert is held here, sorted by expert:
-    ``(order (P,), sizes, start, pad_start, pad_end, row (P,))`` with
-    ``P = T * k`` pairs in all; ``order`` lists pair indices expert by expert
-    (pairs of absent experts last), ``sizes[e]`` counts expert ``e``'s pairs,
-    ``start`` is its first place in ``order`` and ``[pad_start, pad_end)`` its
-    rows once every expert's rows are padded to whole tiles; ``row[p]`` is the
-    padded row of pair ``p``, -1 where its expert is absent."""
-    local = chosen.reshape(-1) - cfg.experts_first
-    key = jnp.where((local >= 0) & (local < cfg.experts_held), local, cfg.experts_held)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    mine = key[:, None] == jnp.arange(cfg.experts_held)  # (P, held)
-    sizes = jnp.sum(mine, axis=0, dtype=jnp.int32)
-    tm = cfg.expert_tile_rows
-    padded = (sizes + tm - 1) // tm * tm
-    pad_end = jnp.cumsum(padded)
-    pad_start = pad_end - padded
-    # the sort is stable, so a pair's rank among its expert's is its count so far
-    rank = jnp.cumsum(mine, axis=0, dtype=jnp.int32) - 1
-    row = jnp.sum(jnp.where(mine, pad_start[None, :] + rank, 0), axis=1) - (key == cfg.experts_held)
-    return order, sizes, jnp.cumsum(sizes) - sizes, pad_start, pad_end, row.astype(jnp.int32)
-
-
-def _routed_experts(p: Params, u, weights, dispatch, cfg: MlaMoeConfig):
-    """``sum_i w_i Expert_i(u)`` over the pairs whose expert is held here,
-    float32 ``(T, D)``. Span by span of the padded rows (one span holds a
-    usual load): chunk by chunk, gather the rows' tokens and run the three
-    grouped products into the span's results; then every token gathers the
-    rows of its own pairs back, one gather per place among its experts, and
-    weights them. A scatter-add of rows this wide costs the chip many times
-    more, and the gathers' cost follows what was routed far less than a
-    product over the padded rows does."""
-    order, _sizes, start, pad_start, pad_end, row = dispatch
-    tm, chunk, span, k = cfg.expert_tile_rows, cfg.expert_chunk_rows, cfg.expert_span_rows, cfg.num_experts_per_tok
-    n_pairs, rows_all, last = order.shape[0], pad_end[-1], cfg.experts_held - 1
-    row = row.reshape(-1, k)
-
-    def chunk_results(first_row):
-        tile_first = first_row + jnp.arange(chunk // tm, dtype=jnp.int32) * tm
-        # the expert whose padded rows hold the tile: how many experts end at or before it
-        tile_group = jnp.minimum(
-            jnp.sum(tile_first[:, None] >= pad_end[None, :], axis=1, dtype=jnp.int32), last
-        )
-        group = jnp.repeat(tile_group, tm)
-        rank = first_row + jnp.arange(chunk, dtype=jnp.int32) - pad_start[group]
-        # a padding row computes some token's row again; no pair points at it
-        pair = order[jnp.clip(start[group] + rank, 0, n_pairs - 1)]
-        x_rows = u[pair // k]
-        hidden = jax.nn.silu(
-            grouped_matmul(x_rows, p["gate"], tile_group, tile_rows=tm)
-        ) * grouped_matmul(x_rows, p["up"], tile_group, tile_rows=tm)
-        return grouped_matmul(hidden.astype(u.dtype), p["down"], tile_group, tile_rows=tm).astype(u.dtype)
-
-    def one_span(s, y):
-        base = s * span
-        n_chunks = jnp.minimum((rows_all - base + chunk - 1) // chunk, span // chunk)
-        results = lax.fori_loop(
-            0, n_chunks,
-            lambda c, held: lax.dynamic_update_slice(held, chunk_results(base + c * chunk), (c * chunk, 0)),
-            jnp.zeros((span, u.shape[1]), u.dtype),
-        )
-        inside = (row >= base) & (row < base + span)  # (T, k): the pairs whose rows this span holds
-        for place in range(k):
-            rows = results[jnp.where(inside[:, place], row[:, place] - base, 0)].astype(jnp.float32)
-            y = y + jnp.where(inside[:, place, None], rows * weights[:, place, None], 0.0)
-        return y
-
-    n_spans = (rows_all + span - 1) // span
-    return lax.fori_loop(0, n_spans, one_span, jnp.zeros(u.shape, jnp.float32))
-
-
-def _moe(p: Params, h, cfg: MlaMoeConfig, with_sizes: bool = False):
-    """``h + routed + shared`` on the float32 residual stream ``(B, S, D)``."""
-    dt = p["router"].dtype
-    flat = h.reshape(-1, h.shape[-1])
-    with scopes.layer("moe.route"):
-        u = _rms_norm(flat, p["ffn_norm"], cfg.rms_norm_eps).astype(dt)
-        chosen, weights = route(p, u, cfg)
-        dispatch = _dispatch(chosen, cfg)
-    with scopes.layer("moe.experts"):
-        routed = _routed_experts(p["experts"], u, weights, dispatch, cfg)
-    with scopes.layer("moe.shared"):
-        out = (flat + routed + _swiglu(p["shared"], u)).reshape(h.shape)
-    return (out, dispatch[1]) if with_sizes else out
-
-
 def _dense(p: Params, h, cfg: MlaMoeConfig):
     with scopes.layer("dense_mlp"):
         flat = h.reshape(-1, h.shape[-1])
@@ -498,27 +310,7 @@ def forward(params: Params, ids, cfg: MlaMoeConfig = SMALL):
 
 def routing_statistics(params: Params, ids, cfg: MlaMoeConfig = SMALL) -> Dict[str, float]:
     """Route ``ids`` layer by layer (one jitted program per kind of layer,
-    outside any hot loop), count the pairs that fell to the experts held
-    here and fill the metrics registry: ``moe.pairs_held``, ``moe.pairs_all``
-    (tokens x experts per token, over the MoE layers) and
-    ``moe.expert_load_max_over_mean`` (the fullest held expert's pairs over
-    the held experts' mean). Returns the three values."""
-    from ..observability import metrics
-
-    embed = jax.jit(lambda e, i: e[i].astype(jnp.float32))
+    outside any hot loop) and fill the metrics registry's three ``moe.*``
+    gauges (``moe_share.routing_statistics``). Returns the three values."""
     block = jax.jit(functools.partial(_block, cfg=cfg, with_sizes=True))
-    x = embed(params["embed"], ids)
-    loads: List[np.ndarray] = []
-    for p in params["layers"]:
-        x, sizes = block(p, x)
-        if sizes is not None:
-            loads.append(np.asarray(sizes, np.int64))
-    held = np.sum(loads, axis=0) if loads else np.zeros(cfg.experts_held, np.int64)
-    stats = {
-        metrics.MOE_PAIRS_HELD: float(held.sum()),
-        metrics.MOE_PAIRS_ALL: float(len(loads) * ids.size * cfg.num_experts_per_tok),
-        metrics.MOE_EXPERT_LOAD_MAX_OVER_MEAN: float(held.max() / held.mean()) if held.sum() else 0.0,
-    }
-    for name, value in stats.items():
-        metrics.registry().gauge(name).set(value)
-    return stats
+    return moe_share.routing_statistics(params, ids, cfg, block)

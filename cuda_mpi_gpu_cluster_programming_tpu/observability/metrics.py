@@ -37,12 +37,21 @@ from ..resilience.journal import atomic_write_text
 
 # Routing gauges of the expert tier: the (token, expert) pairs that fell to the
 # experts a chip holds, all pairs routed, and the fullest held expert's load
-# over the held experts' mean. ``models.mla_moe.routing_statistics`` sets them,
-# outside any hot loop (the forward itself syncs nothing to the host).
+# over the held experts' mean. ``models.moe_share.routing_statistics`` sets them
+# for either decoder family, outside any hot loop (the forward itself syncs
+# nothing to the host).
 MOE_PAIRS_HELD = "moe.pairs_held"
 MOE_PAIRS_ALL = "moe.pairs_all"
 MOE_EXPERT_LOAD_MAX_OVER_MEAN = "moe.expert_load_max_over_mean"
 MOE_ROUTING_GAUGES = (MOE_PAIRS_HELD, MOE_PAIRS_ALL, MOE_EXPERT_LOAD_MAX_OVER_MEAN)
+# Gauges of the linear-attention layers (``models.kda_moe.layer_statistics``,
+# one batch, outside any hot loop): the most negative log decay summed over one
+# chunk of the scan, over every layer, head and channel (what the scan must
+# never exponentiate alone: float32 overflows past 88), and the mean write
+# strength ``beta``.
+KDA_CHUNK_LOG_DECAY_MIN = "kda.chunk_log_decay_min"
+KDA_BETA_MEAN = "kda.beta_mean"
+KDA_GAUGES = (KDA_CHUNK_LOG_DECAY_MIN, KDA_BETA_MEAN)
 
 # Prometheus metric-name grammar: [a-zA-Z_:][a-zA-Z0-9_:]* — the dotted
 # registry names ("serve.ok") sanitize to underscores ("serve_ok").

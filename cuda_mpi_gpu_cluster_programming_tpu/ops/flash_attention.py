@@ -152,10 +152,14 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, return_lse, vma=None):
 def flash_forward_bhld(
     q, k, v, *, causal, block_q=128, block_k=128, scale=None, vma=None, q_rope=None, k_rope=None
 ):
-    """The forward kernel on heads-major operands: q, k ``(B, H, L, D)``,
-    v ``(B, H, L, Dv)`` -> ``(out (B, H, L, Dv), lse (B, H, 1, L))``.
+    """The forward kernel on heads-major operands: q ``(B, H, L, D)``, k
+    ``(B, Hk, L, D)``, v ``(B, Hk, L, Dv)`` -> ``(out (B, H, L, Dv), lse
+    (B, H, 1, L))``.
 
-    ``Dv`` may differ from ``D``. Latent attention hands its queries and keys
+    ``Hk`` divides ``H`` (grouped-query attention): query head ``h`` reads
+    key/value head ``h // (H // Hk)`` through the block index map, so no key
+    or value is repeated in HBM; with ``Hk == H`` the kernel built is the one
+    equal head counts always built. ``Dv`` may differ from ``D``. Latent attention hands its queries and keys
     over in the two parts its projections produce: ``q``, ``k`` the part each
     head has keys of its own for, and ``q_rope (B, H, R, L)``, ``k_rope
     (B, R, L)`` the rotated part, whose key is ONE per position for all heads
@@ -169,11 +173,15 @@ def flash_forward_bhld(
     above take one width for q, k and v and no rope operands.
     """
     b, h, l, d = q.shape
-    dv = v.shape[-1]
+    hk, dv = k.shape[1], v.shape[-1]
     bq = min(block_q, l)
     bk = min(block_k, l)
     if l % bq or l % bk:
         raise ValueError(f"sequence length {l} not divisible by blocks ({bq}, {bk})")
+    if h % hk or k.shape != (b, hk, l, d) or v.shape != (b, hk, l, dv):
+        raise ValueError(
+            f"q {q.shape}, k {k.shape}, v {v.shape}: want (B, H, L, D), (B, Hk, L, D), (B, Hk, L, Dv), Hk | H"
+        )
     if (q_rope is None) != (k_rope is None):
         raise ValueError("q_rope and k_rope come together")
     rope = q_rope is not None
@@ -189,8 +197,10 @@ def flash_forward_bhld(
         k_block = lambda qi, ki: jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
     else:
         k_block = lambda qi, ki: ki
+    group = h // hk  # query heads that share one key/value head
+    kv_head = (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
     q_at = lambda bi, hi, qi, ki: (bi, hi, qi, 0)
-    kv_at = lambda bi, hi, qi, ki: (bi, hi, k_block(qi, ki), 0)
+    kv_at = lambda bi, hi, qi, ki: (bi, kv_head(hi), k_block(qi, ki), 0)
     q_minor_at = lambda bi, hi, qi, ki: (bi, hi, 0, qi)  # a q-block along the LAST axis
 
     operands = [q, k, v]

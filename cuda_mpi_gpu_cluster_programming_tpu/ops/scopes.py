@@ -30,7 +30,22 @@ MLA_MOE_LAYERS = (
     "embed", "mla.proj", "mla.attn", "dense_mlp",
     "moe.route", "moe.experts", "moe.shared", "head",
 )
-LAYERS = BLOCKS12_LAYERS + ALEXNET_TAIL_LAYERS + FC_LAYERS + MLA_MOE_LAYERS
+# The hybrid linear-attention mixture-of-experts decoder (``models.kda_moe``);
+# the MoE's and the ends' names are the ones above. ``gqa.proj``: the norm, the
+# q/k/v/gate projections, the gate's sigmoid and product, the output
+# projection; ``gqa.attn`` the flash kernel. ``kda.proj``: the norm, the q/k/v
+# projections, both low-rank pairs, the write strength's projection, the output
+# norm, gate and projection; ``kda.mix`` the short convolution, silu, l2norm,
+# the decay's softplus and the write strength's sigmoid (elementwise);
+# ``kda.scan`` the chunked scan's kernel.
+KDA_MOE_LAYERS = (
+    "embed", "gqa.proj", "gqa.attn", "kda.proj", "kda.mix", "kda.scan",
+    "moe.route", "moe.experts", "moe.shared", "head",
+)
+LAYERS = (
+    BLOCKS12_LAYERS + ALEXNET_TAIL_LAYERS + FC_LAYERS + MLA_MOE_LAYERS
+    + tuple(name for name in KDA_MOE_LAYERS if name not in MLA_MOE_LAYERS)
+)
 
 # Parameters and input to the compute type: the bf16 wrapper's casts and the
 # int8w quantisation.

@@ -41,11 +41,13 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--seed", type=int, default=0)
     p.add_argument(
         "--preset",
-        choices=["small", "ep16_share"],
+        choices=["small", "ep16_share", "solar_ep8"],
         default="small",
-        help="language-model configs only (models.mla_moe.PRESETS): small = the "
-        "CPU tests' size; ep16_share = the published widths as one of 16 "
-        "expert-parallel chips holds them, 2 x 4,096 tokens (the benchmark's shape)",
+        help="language-model configs only (the PRESETS of the config's model module, "
+        "models.<ExecConfig.model>): small = the CPU tests' size; the published widths "
+        "as one expert-parallel chip holds them, at the benchmark's shape: ep16_share "
+        "(v8_mla_moe: one of 16 chips, 2 x 4,096 tokens), solar_ep8 (v9_kda_moe: one of "
+        "8 chips, 2 x 8,192 tokens)",
     )
     p.add_argument("--repeats", type=int, default=10, help="fenced passes for amortized timing")
     p.add_argument(
@@ -453,10 +455,14 @@ def _run_language_model(args, exec_cfg) -> int:
     compute type, seeded ids over the vocabulary slice, fenced passes."""
     import jax.numpy as jnp
 
-    from .configs import build_forward
-    from .models import mla_moe
+    from .configs import build_forward, language_model
 
-    model_cfg, batch, seq = mla_moe.PRESETS[args.preset]
+    model = language_model(exec_cfg)
+    if args.preset not in model.PRESETS:
+        print(f"--preset {args.preset} is not one of {exec_cfg.key}'s ({', '.join(model.PRESETS)})",
+              file=sys.stderr)
+        return 2
+    model_cfg, batch, seq = model.PRESETS[args.preset]
     compute = args.dtype or args.compute
     print(f"--- Language model {exec_cfg.version_name} [{exec_cfg.key}] "
           f"(preset={args.preset}, batch={batch}, seq={seq}) ---")
@@ -465,7 +471,7 @@ def _run_language_model(args, exec_cfg) -> int:
     print(f"Precision: dtype={compute} source={'dtype' if args.dtype else 'compute'} gate=none")
     # the chip's own bit generator: its draw compiles in seconds at any size
     kp, kx = jax.random.split(jax.random.key(args.seed, impl="rbg"))
-    params = mla_moe.init(kp, model_cfg, jnp.bfloat16 if compute == "bf16" else jnp.float32)
+    params = model.init(kp, model_cfg, jnp.bfloat16 if compute == "bf16" else jnp.float32)
     ids = jax.random.randint(kx, (batch, seq), 0, model_cfg.vocab_size, jnp.int32)
     fwd = build_forward(exec_cfg, model_cfg, compute=compute)
     out = jax.block_until_ready(fwd(params, ids))  # compile, and the values printed below
@@ -474,7 +480,7 @@ def _run_language_model(args, exec_cfg) -> int:
         last = fwd(params, ids)
     jax.block_until_ready(last)
     per_pass_ms = (time.perf_counter() - t0) * 1e3 / max(1, args.repeats)
-    print(f"Parameters: {mla_moe.param_count(model_cfg)} held here "
+    print(f"Parameters: {model.param_count(model_cfg)} held here "
           f"(experts [{model_cfg.experts_first}, {model_cfg.experts_first + model_cfg.experts_held}) "
           f"of {model_cfg.n_routed_experts})")
     print(f"Final Output Shape: {'x'.join(str(d) for d in out.shape[1:])}")
@@ -501,7 +507,7 @@ def main(argv=None) -> int:
 
     enable_persistent_cache()
 
-    from .configs import REGISTRY, build_forward
+    from .configs import LANGUAGE_MODELS, REGISTRY, build_forward
     from .models.alexnet import BLOCKS12
     from .models.init import (
         deterministic_input,
@@ -575,7 +581,7 @@ def main(argv=None) -> int:
         print(f"unknown config {args.config!r}; try --list-configs", file=sys.stderr)
         return 2
     exec_cfg = REGISTRY[args.config]
-    if exec_cfg.model == "mla_moe":
+    if exec_cfg.model in LANGUAGE_MODELS:
         if args.serve:
             print("--serve supports the Blocks 1-2 configs only", file=sys.stderr)
             return 2

@@ -237,3 +237,89 @@ def test_shape_dtype_struct_vma_kwarg_exists():
     s = jax.ShapeDtypeStruct((2, 2), "float32", vma=frozenset({"sp"}))
     assert s.vma == frozenset({"sp"})
     assert jax.ShapeDtypeStruct((2, 2), "float32").vma is None
+
+
+# ---- grouped key/value heads (forward kernel on heads-major operands) --------
+
+GQA_CASES = {
+    # name: (H, Hk, L, D, Dv, block_q, block_k, causal)
+    "eight_to_one": (8, 1, 32, 16, 16, 16, 16, True),
+    "four_to_two_unequal_blocks": (4, 2, 32, 24, 16, 8, 16, True),
+    "six_to_three_one_block": (6, 3, 16, 16, 24, 64, 64, True),
+    "four_to_two_not_causal": (4, 2, 32, 16, 16, 16, 8, False),
+    "equal_heads": (4, 4, 32, 16, 16, 16, 16, True),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("case", sorted(GQA_CASES))
+def test_grouped_key_value_heads_equal_the_reference_on_repeated_heads(case, dtype, tol):
+    """Query head ``h`` reads key/value head ``h // (H / Hk)`` through the
+    block index map: the output is ``ops.attention`` on keys and values
+    repeated per query head, and no repeat is ever built for the kernel."""
+    from cuda_mpi_gpu_cluster_programming_tpu.ops.flash_attention import flash_forward_bhld
+
+    h, hk, l, d, dv, block_q, block_k, causal = GQA_CASES[case]
+    b = 2
+    keys = jax.random.split(jax.random.key(21), 3)
+    draw = lambda key, shape: jax.random.normal(key, shape, jnp.float32).astype(dtype)
+    q, k, v = draw(keys[0], (b, h, l, d)), draw(keys[1], (b, hk, l, d)), draw(keys[2], (b, hk, l, dv))
+    out, lse = flash_forward_bhld(q, k, v, causal=causal, block_q=block_q, block_k=block_k)
+    assert out.shape == (b, h, l, dv) and out.dtype == dtype and lse.shape == (b, h, 1, l)
+    lhd = lambda x: jnp.transpose(x, (0, 2, 1, 3))  # (B, H, L, D) -> (B, L, H, D)
+    repeated_k = jnp.repeat(k, h // hk, axis=1)
+    if d == dv:
+        want = lhd(attention(lhd(q), lhd(repeated_k), lhd(jnp.repeat(v, h // hk, axis=1)), causal=causal))
+    else:  # the reference op takes one width: the kernel itself on repeated heads
+        want, _lse = flash_forward_bhld(
+            q, repeated_k, jnp.repeat(v, h // hk, axis=1), causal=causal, block_q=block_q, block_k=block_k
+        )
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want, np.float32), rtol=tol, atol=tol)
+    # each query head reads ITS key/value head: moving another head's keys moves nothing of it
+    if hk > 1:
+        other = flash_forward_bhld(q, k.at[:, 1].add(1.0), v, causal=causal, block_q=block_q, block_k=block_k)[0]
+        mine = slice(0, h // hk)  # the query heads of key/value head 0
+        assert np.array_equal(np.asarray(out[:, mine], np.float32), np.asarray(other[:, mine], np.float32))
+        rest = slice(h // hk, None)
+        assert not np.array_equal(np.asarray(out[:, rest], np.float32), np.asarray(other[:, rest], np.float32))
+
+
+def test_key_value_heads_that_do_not_divide_the_query_heads_are_refused():
+    from cuda_mpi_gpu_cluster_programming_tpu.ops.flash_attention import flash_forward_bhld
+
+    q, kv = jnp.zeros((1, 4, 16, 8)), jnp.zeros((1, 3, 16, 8))
+    with pytest.raises(ValueError, match=r"Hk \| H"):
+        flash_forward_bhld(q, kv, kv, causal=True)
+    with pytest.raises(ValueError, match=r"Hk \| H"):
+        flash_forward_bhld(q, kv[:, :2], kv[:, :1], causal=True)  # keys and values disagree
+
+
+# The step program of ``v8_mla_moe`` at the small preset as jax 0.9.0 lowers it
+# (``.lower(...).as_text()``: the program as traced, before any compiler of a
+# particular machine touches it; the kernels are in it as the interpreter
+# discharges them, their block index maps too), as the commit BEFORE grouped
+# key/value heads built it (PR 30's tree, 62892c5): sha256 of the text. Equal
+# head counts must still build that kernel and that program (the dots cell's
+# step may not change under it). A change that means to alter that program
+# records the new digests here and says so.
+EQUAL_HEADS_STEP_SHA256 = {
+    "bf16": "7d67169888c1a7da7ab634382942b31f13fa11b8a478c911af78dc9d260a518f",
+    "fp32": "2f16325032e0cf8f2758752f97924dd1aa244cceaee34146fce7acfe1b64c16f",
+}
+
+
+@pytest.mark.parametrize("compute", sorted(EQUAL_HEADS_STEP_SHA256))
+def test_with_equal_head_counts_the_program_built_is_the_one_built_before(compute):
+    import hashlib
+
+    from cuda_mpi_gpu_cluster_programming_tpu.configs import REGISTRY, build_forward
+    from cuda_mpi_gpu_cluster_programming_tpu.models import mla_moe
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests are of jax 0.9.0's lowering")
+    dtype = jnp.bfloat16 if compute == "bf16" else jnp.float32
+    params = jax.eval_shape(lambda: mla_moe.init(jax.random.key(0), mla_moe.SMALL, dtype))
+    ids = jax.ShapeDtypeStruct((2, 32), jnp.int32)
+    text = build_forward(REGISTRY["v8_mla_moe"], mla_moe.SMALL, compute=compute).lower(params, ids).as_text()
+    assert "loc(" not in text  # no source location in it: moving code changes nothing
+    assert hashlib.sha256(text.encode()).hexdigest() == EQUAL_HEADS_STEP_SHA256[compute]

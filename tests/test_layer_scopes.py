@@ -11,7 +11,12 @@ import re
 import jax
 import pytest
 
-from cuda_mpi_gpu_cluster_programming_tpu.configs import REGISTRY, build_forward
+from cuda_mpi_gpu_cluster_programming_tpu.configs import (
+    LANGUAGE_MODELS,
+    REGISTRY,
+    build_forward,
+    language_model,
+)
 from cuda_mpi_gpu_cluster_programming_tpu.models.alexnet import Blocks12Config
 from cuda_mpi_gpu_cluster_programming_tpu.models.alexnet_full import (
     AlexNetConfig,
@@ -39,15 +44,15 @@ def _scoped(paths, scope):
 
 def _build(key, compute="fp32"):
     exec_cfg = REGISTRY[key]
-    if exec_cfg.model == "mla_moe":
+    if exec_cfg.model in LANGUAGE_MODELS:
         import jax.numpy as jnp
 
-        from cuda_mpi_gpu_cluster_programming_tpu.models import mla_moe
-
-        fwd = build_forward(exec_cfg, mla_moe.SMALL, compute=compute)
+        model = language_model(exec_cfg)
+        small, batch, seq = model.PRESETS["small"]
+        fwd = build_forward(exec_cfg, small, compute=compute)
         dtype = jnp.bfloat16 if compute == "bf16" else jnp.float32
-        params = jax.eval_shape(lambda: mla_moe.init(jax.random.key(0), mla_moe.SMALL, dtype))
-        return exec_cfg, fwd, params, jax.ShapeDtypeStruct((2, 32), "int32")
+        params = jax.eval_shape(lambda: model.init(jax.random.key(0), small, dtype))
+        return exec_cfg, fwd, params, jax.ShapeDtypeStruct((batch, seq), "int32")
     full = exec_cfg.model == "alexnet_full"
     model_cfg = SMALL_FULL if full else SMALL
     # four shards: the 2 output rows then leave padding for the gather to slice off
@@ -69,6 +74,10 @@ def test_every_layer_of_the_chain_is_named_in_the_compiled_program(key):
         chain += scopes.ALEXNET_TAIL_LAYERS + scopes.FC_LAYERS
     if exec_cfg.model == "mla_moe":  # a dense layer first, then the MoE layers
         chain = scopes.MLA_MOE_LAYERS
+    if exec_cfg.model == "kda_moe":  # a softmax layer with its MoE first, then the linear layers
+        names = scopes.KDA_MOE_LAYERS
+        chain = names[:3] + names[6:9] + names[3:6] + names[9:]
+        assert sorted(chain) == sorted(names)
     for layer in chain:
         assert _scoped(paths, layer), f"{key}: no operation under the scope {layer!r}"
     # in order: the jaxpr is the program as written, before any scheduling
@@ -126,14 +135,15 @@ def test_a_kernel_that_covers_conv_and_pool_says_so():
     assert not _scoped(paths, "conv1") and not _scoped(paths, "lrn2")
 
 
-def test_token_ids_and_parameters_stored_in_bf16_pass_the_bf16_wrapper_uncast():
-    """``compute="bf16"`` casts floating inputs only: the language model's
+@pytest.mark.parametrize("key,layers", [("v8_mla_moe", scopes.MLA_MOE_LAYERS), ("v9_kda_moe", scopes.KDA_MOE_LAYERS)])
+def test_token_ids_and_parameters_stored_in_bf16_pass_the_bf16_wrapper_uncast(key, layers):
+    """``compute="bf16"`` casts floating inputs only: a language model's
     integer ids and its parameters, already bf16, reach the forward as they
     are, and every one of its scopes is in the compiled program."""
-    _cfg, fwd, params, ids = _build("v8_mla_moe", "bf16")
+    _cfg, fwd, params, ids = _build(key, "bf16")
     paths = _paths(fwd, params, ids)
     assert not _scoped(paths, scopes.CAST_IN)
-    for layer in scopes.MLA_MOE_LAYERS:
+    for layer in layers:
         assert _scoped(paths, layer), layer
     jaxpr = jax.make_jaxpr(fwd)(params, ids)
     assert str(jaxpr.jaxpr.invars[-1].aval.dtype) == "int32"
