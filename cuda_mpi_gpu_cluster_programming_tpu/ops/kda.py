@@ -29,9 +29,16 @@ between ``s`` and ``r``: at most 1 each, and an underflow is a product that
 is zero in float32 anyway. One product per level (``log2(chunk)`` of them)
 masked to that level's pairs gives ``A`` and the scores; every exponent is
 a row of one 0/1 matrix times ``g`` (:func:`decay_plan`). ``(I + A)^-1`` is
-built over the same hierarchy: the inverse of a block is
-``[[T11, 0], [-T22 A21 T11, T22]]`` of its halves' inverses, as stable as
-forward substitution is by blocks and all on the MXU.
+built over the same hierarchy (:func:`block_inverse`): the inverse of a block
+is ``[[T11, 0], [-T22 A21 T11, T22]]`` of its halves' inverses, as stable as
+forward substitution is by blocks and all on the MXU in float32. A level
+writes only the upper halves' rows, and its blocks lie side by side in as
+many rows as a half-block has, so each level does the work its blocks hold:
+level 1 is ``I - A`` in closed form; levels 2 and 3 update the diagonal blocks
+of 8 as one strip of 8 rows; level ``l >= 4`` streams the ``2^(l-1)`` rows of
+the upper halves (8, 16, 32, 64 at chunk 128) against the inverse so far and
+writes those rows back. Two products a level, 272 rows in all at chunk 128
+where whole-matrix updates streamed 1,536.
 
 Grid ``(batch, head block, chunk)``, the chunk axis sequential with the state
 of every head of the block in VMEM scratch. Operands go to the MXU in the type
@@ -77,6 +84,50 @@ def decay_plan(chunk: int):
     return np.concatenate(blocks).astype(np.float32), np.where(r >= j, parted, -1).astype(np.int32)
 
 
+_SUBLANES = 8  # a float32 tile's rows: the fewest a product streams
+
+
+def _dot32(x, y):  # the solve: float32 operands, six bf16 passes
+    return jnp.dot(x, y, preferred_element_type=jnp.float32, precision=lax.Precision.HIGHEST)
+
+
+def block_inverse(a, level):
+    """``(I + a)^-1`` in float32 for ``a (C, C)`` strictly lower triangular and
+    ``level`` as :func:`decay_plan` gives it, every product at ``HIGHEST``.
+
+    The inverse of a block of ``2^l`` tokens is ``[[T11, 0], [-T22 A21 T11,
+    T22]]`` of its halves' inverses: level ``l`` writes ``N = T22 A21 T11``
+    where ``level == l`` and nothing else, and ``A21``, ``T22`` and ``N`` of
+    ALL its blocks lie side by side in as many rows as one half-block has,
+    since the blocks' columns are disjoint. Such a strip ``X`` against a
+    block-diagonal ``Y`` as it stands is the strip of ``X Y``, and a product
+    costs what its left operand has rows:
+
+    * level 1: a pair's inverse is ``I - A``, no product;
+    * level ``l >= 2``: ``fold`` adds the upper halves' rows of every block
+      into one strip of ``2^(l-1)`` rows (8, 16, 32, 64 for levels 4 to 7 at
+      chunk 128; at levels 2 and 3, where a half-block is less than a tile of
+      8 rows, the whole tiles of 8 that hold the blocks), ``P = A21 T11`` is
+      ``a``'s strip against ``inv`` as it stands, ``lay`` puts a strip back
+      into the rows it came from, each block's entries in its own columns
+      and zero elsewhere, and ``N`` is ``inv``'s strip against ``P`` laid
+      back.
+
+    Two products a level of 8 to ``C / 2`` rows (272 rows in all at chunk 128)
+    where ``inv - inv (A_l inv)`` on whole matrices streamed ``C`` rows in
+    each. Row slices are whole sublane tiles; nothing is gathered."""
+    c = a.shape[0]
+    inv = jnp.where(level == 0, 1.0, 0.0) - jnp.where(level == 1, a, 0.0)
+    for lv in range(2, c.bit_length()):
+        rows = max(1 << lv - 1, _SUBLANES)  # the strip's
+        size = max(1 << lv, _SUBLANES)  # of a block, or of the tile that holds it
+        at = level == lv
+        fold = lambda x: x.reshape(c // size, size, c)[:, size - rows :].sum(axis=0)
+        lay = lambda s: jnp.where(at, jnp.concatenate([s] * (c // rows)), 0.0)
+        inv = inv - lay(_dot32(fold(inv), lay(_dot32(fold(jnp.where(at, a, 0.0)), inv))))
+    return inv
+
+
 def _one_chunk(q, k, v, g, beta_row, state, plan, level, *, scale):
     """One chunk of one head: ``q, k (C, dk)``, ``v (C, dv)``, ``g (C, dk)``
     float32, ``beta_row (1, C)`` float32, ``state (dv, dk)`` float32 (the
@@ -88,9 +139,6 @@ def _one_chunk(q, k, v, g, beta_row, state, plan, level, *, scale):
 
     def dot(a, b, over):  # operands in the stored type, float32 out
         return lax.dot_general(a, b, (over, ((), ())), preferred_element_type=f32, precision=prec)
-
-    def dot32(a, b):  # the solve: float32 operands
-        return jnp.dot(a, b, preferred_element_type=f32, precision=lax.Precision.HIGHEST)
 
     nt = ((1,), (1,))  # contract the last axis of both
     # plan @ g in three passes: the plan is 0/1, exact in bf16, and g is the
@@ -117,13 +165,10 @@ def _one_chunk(q, k, v, g, beta_row, state, plan, level, *, scale):
         kk = kk + jnp.where(parted, pairs[c:], 0.0)
     a = beta * kk
 
-    # (I + A)^-1: a block's inverse from its halves' inverses; a pair's is I - A
-    inv = jnp.where(diagonal, 1.0, 0.0) - jnp.where(level == 1, a, 0.0)
-    for lv in range(2, c.bit_length()):
-        inv = inv - dot32(inv, dot32(jnp.where(level == lv, a, 0.0), inv))
+    inv = block_inverse(a, level)
 
     held = state.astype(dt)
-    written = dot32(inv, beta * (v.astype(f32) - dot((kf * since).astype(dt), held, nt)))  # U (C, dv)
+    written = _dot32(inv, beta * (v.astype(f32) - dot((kf * since).astype(dt), held, nt)))  # U (C, dv)
     u = written.astype(dt)
     o = scale * (dot((qf * since).astype(dt), held, nt) + dot(qk.astype(dt), u, ((1,), (0,))))
     decay_all = jnp.exp(jnp.sum(g, axis=0, keepdims=True))  # (1, dk)

@@ -3,16 +3,29 @@ the kernel against the plain recurrence, one token at a time, over batch,
 head, chunk and head-block shapes; under decays so steep that ``exp(-G)``
 would overflow float32 inside a chunk; with the write strength near 0 and near
 2 and with identical keys (where ``I - beta k k^T`` has its eigenvalue -1); in
-float32 and bf16; causality; the shapes it refuses; its decay plan by hand."""
+float32 and bf16; causality; the shapes it refuses; its decay plan by hand; the
+block inverse alone against the whole-matrix update it replaced and against
+float64; and, from the traced chunk, what its products stream (rows x passes),
+so that the full-width float32 products cannot come back unnoticed."""
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from cuda_mpi_gpu_cluster_programming_tpu.ops.kda import decay_plan, kda_chunked, kda_recurrence
+from jax import lax
+
+from cuda_mpi_gpu_cluster_programming_tpu.ops.kda import (
+    _one_chunk,
+    block_inverse,
+    decay_plan,
+    kda_chunked,
+    kda_recurrence,
+)
 
 
 def operands(seed, b, h, l, dk, dv, *, decay, beta="uniform", dtype=jnp.float32, same_keys=False):
@@ -45,10 +58,12 @@ SHAPES = {
     "chunk_64": (1, 4, 128, 32, 32, 64, 4),
     "value_width_of_its_own": (1, 2, 64, 16, 24, 32, 1),
     "all_heads_in_one_program": (2, 3, 64, 16, 16, 16, 3),
+    "chunk_128": (1, 4, 256, 128, 128, 128, 4),  # the cell's tiles: every level of the inverse, four heads a program
 }
 
 
-CASES = [(shape, 30.0) for shape in sorted(SHAPES)] + [("chunk_64", 1.6), ("chunk_64", 0.05), ("many_chunks", 0.05)]
+CASES = [(shape, 30.0) for shape in sorted(SHAPES)]
+CASES += [("chunk_64", 1.6), ("chunk_64", 0.05), ("many_chunks", 0.05), ("chunk_128", 0.05)]
 
 
 @pytest.mark.parametrize("shape,decay", CASES, ids=[f"{shape}-decay_{decay:g}" for shape, decay in CASES])
@@ -155,3 +170,107 @@ def test_decay_plan_by_hand():
     # so a pair's two factors multiply to exp(G_r - G_s): e.g. r = 3, s = 1 at level 2
     g = np.array([-0.3, -0.5, -0.7, -1.1])
     assert np.exp(halves[3] @ g) * np.exp(halves[1] @ g) == pytest.approx(np.exp(g[2] + g[3]))
+
+
+def whole_matrix_inverse(a, level):
+    """What ``block_inverse`` replaced (PR 31's form): every level updates the
+    whole matrix, ``inv - inv (A_l inv)``, two full-width float32 products."""
+    dot32 = lambda x, y: jnp.dot(x, y, preferred_element_type=jnp.float32, precision=lax.Precision.HIGHEST)
+    inv = jnp.where(level == 0, 1.0, 0.0) - jnp.where(level == 1, a, 0.0)
+    for lv in range(2, a.shape[0].bit_length()):
+        inv = inv - dot32(inv, dot32(jnp.where(level == lv, a, 0.0), inv))
+    return inv
+
+
+@pytest.mark.parametrize("keys", ["unit_keys", "one_key"])
+@pytest.mark.parametrize("chunk", [16, 32, 64, 128])
+def test_block_inverse_alone(chunk, keys):
+    """``A = beta * strictly_lower(K K^T)`` with ``beta`` up to 2: of unit keys
+    (entries of either sign, most small) and of ONE key for every token with
+    ``beta`` 1.999 throughout (every entry 1.999, the inverse's entries
+    alternate in sign and ``I + A`` is as ill-conditioned as the kernel meets
+    it: parts in 1e5 at chunk 128, as the kernel's own test of that case
+    allows). The strips give what the whole-matrix update gave, and both give
+    float64's inverse."""
+    rng = np.random.default_rng(chunk)
+    k = rng.normal(size=(1 if keys == "one_key" else chunk, 16))
+    k = np.broadcast_to(k / np.linalg.norm(k, axis=1, keepdims=True), (chunk, 16))
+    beta = np.full((chunk, 1), 1.999) if keys == "one_key" else 2.0 * rng.uniform(size=(chunk, 1))
+    a = beta * np.tril(k @ k.T, -1)
+    assert np.abs(a).max() > (1.99 if keys == "one_key" else 1.0)
+    want = np.linalg.inv(np.eye(chunk) + a)
+    a32, level = jnp.asarray(a, jnp.float32), jnp.asarray(decay_plan(chunk)[1])
+    got, before = jax.jit(block_inverse)(a32, level), jax.jit(whole_matrix_inverse)(a32, level)
+    assert got.shape == (chunk, chunk) and got.dtype == jnp.float32
+    assert np.array_equal(np.triu(np.asarray(got), 1), np.zeros((chunk, chunk)))  # nothing above the diagonal
+    limit = 1e-4 if keys == "one_key" else 1e-6
+    assert rel_err(got, want) < limit and rel_err(before, want) < limit
+    assert rel_err(got, before) < limit
+
+
+def products_of(jaxpr):
+    """Every ``dot_general`` of a jaxpr and of what it calls: ``(rows its left
+    operand streams, whether the operands are float32, its precision)``."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            lhs = eqn.invars[0].aval
+            (contract, _rhs), (batch, _) = eqn.params["dimension_numbers"]
+            rows = int(np.prod([n for axis, n in enumerate(lhs.shape) if axis not in (*contract, *batch)]))
+            is_f32 = jnp.float32 in (lhs.dtype, eqn.invars[1].aval.dtype)
+            found.append((rows, is_f32, eqn.params["precision"]))
+        for value in eqn.params.values():
+            for inner in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    found += products_of(inner)
+    return found
+
+
+def at_highest(precision) -> bool:
+    return all(p == lax.Precision.HIGHEST for p in (precision if isinstance(precision, tuple) else (precision,)))
+
+
+@functools.cache
+def traced_chunk(chunk=128, d=128, dtype=jnp.bfloat16):
+    """``_one_chunk`` as the cell runs it (bf16 operands, chunk 128, dk = dv = 128), traced on the CPU."""
+    plan, level = decay_plan(chunk)
+    x, f = jax.ShapeDtypeStruct((chunk, d), dtype), jax.ShapeDtypeStruct((chunk, d), jnp.float32)
+    beta_row = jax.ShapeDtypeStruct((1, chunk), jnp.float32)
+    trace = jax.make_jaxpr(lambda *args: _one_chunk(*args, scale=1.0))
+    return trace(x, x, x, f, beta_row, f, jnp.asarray(plan, jnp.bfloat16), jnp.asarray(level)).jaxpr
+
+
+# rows x passes of one chunk and head at chunk 128, as shipped (8,160): the plan 3 x 1,152, seven level products of
+# 256, the inverse (6 x 8 + 2 x 16 + 2 x 32 + 2 x 64) x 6, its product with the values 128 x 6, four of 128 in bf16
+ROW_PASSES = 3 * 1152 + 7 * 256 + 272 * 6 + 128 * 6 + 4 * 128
+
+
+def test_row_passes_of_a_chunk_are_what_was_shipped():
+    """A bf16 product streams its left operand's rows once, a float32 one at
+    ``HIGHEST`` six times. PR 31 had 15,744 a chunk and head; the inverse by
+    strips leaves 8,160, and ISSUE 32 set 12,800 as the most a half-done job
+    could leave."""
+    total = sum(rows * (6 if is_f32 else 1) for rows, is_f32, _ in products_of(traced_chunk()))
+    assert total <= ROW_PASSES < 12800
+
+
+def test_every_float32_product_of_a_chunk_is_at_highest():
+    f32_products = [p for p in products_of(traced_chunk()) if p[1]]
+    assert len(f32_products) == 13 and all(at_highest(precision) for _, _, precision in f32_products)
+    # and with float32 operands stored, the other products too (ops.reference.mxu_precision)
+    assert all(at_highest(precision) for _, is_f32, precision in products_of(traced_chunk(dtype=jnp.float32)) if is_f32)
+
+
+@pytest.mark.parametrize("chunk", [16, 32, 64, 128])
+def test_no_product_of_the_inverse_streams_the_whole_chunk(chunk):
+    """Levels 2 and 3 stream one tile of 8 rows, level ``l >= 4`` the
+    ``2^(l-1)`` rows of the upper halves: never the chunk's ``C`` rows (the one
+    float32 product of a chunk that does is the inverse times the values)."""
+    a, level = jax.ShapeDtypeStruct((chunk, chunk), jnp.float32), jnp.asarray(decay_plan(chunk)[1])
+    rows = [rows for rows, is_f32, _ in products_of(jax.make_jaxpr(block_inverse)(a, level).jaxpr) if is_f32]
+    assert rows == [8] * 4 + [1 << lv - 1 for lv in range(4, chunk.bit_length()) for _ in range(2)]
+    assert max(rows) <= chunk // 2
+    if chunk == 128:
+        whole = [rows for rows, is_f32, _ in products_of(traced_chunk()) if is_f32 and rows >= chunk]
+        assert whole == [chunk]
