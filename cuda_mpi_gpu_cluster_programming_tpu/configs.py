@@ -43,7 +43,7 @@ class ExecConfig:
     tier: str  # "reference" (XLA ops) | "pallas"
     strategy: str  # "single" | "replicated" | "halo" | "staged_halo"
     description: str
-    model: str = "blocks12"  # "blocks12" | "alexnet_full" | "mla_moe" | "kda_moe"
+    model: str = "blocks12"  # "blocks12" | "alexnet_full" | "mla_moe" | "kda_moe" | "cca_moe"
 
 
 REGISTRY: Dict[str, ExecConfig] = {
@@ -148,12 +148,23 @@ REGISTRY: Dict[str, ExecConfig] = {
             "chunked-scan, flash-attention and grouped-matmul kernels",
             model="kda_moe",
         ),
+        ExecConfig(
+            "v10_cca_moe",
+            "V10 CCA-MoE Share",
+            "reference",
+            "single",
+            "compressed-convolutional-attention MoE decoder (latent attention mixed by causal "
+            "convolutions; an MLP router over a state carried down the depth, top-1 with a skip "
+            "output) as one expert-parallel chip holds it: one scan over its layers, single "
+            "device, XLA ops with the flash-attention and grouped-matmul kernels",
+            model="cca_moe",
+        ),
     ]
 }
 
 # The language-model families: token ids in, logits out, parameters stored in
 # the compute type. ``ExecConfig.model`` names the module under ``models``.
-LANGUAGE_MODELS = ("mla_moe", "kda_moe")
+LANGUAGE_MODELS = ("mla_moe", "kda_moe", "cca_moe")
 
 
 def language_model(exec_cfg: ExecConfig):

@@ -100,7 +100,7 @@ class KdaMoeConfig:
     expert_span_rows: int = 32  # rows of results held until their tokens gather them back
 
     def __post_init__(self):
-        moe_share.check_share(self)
+        moe_share.check_sigmoid_moe(self)
         if self.num_attention_heads % self.num_key_value_heads:
             raise ValueError("num_key_value_heads must divide num_attention_heads")
         if not all(0 <= layer < self.num_layers for layer in self.gqa_layers):
@@ -309,7 +309,7 @@ def _block_balancing(p: Params, x, cfg: KdaMoeConfig):
     input)``, ``x`` computed under that bias."""
     h, moe, _decays = _mix(p, x, cfg)
     u = _rms_norm(h.reshape(-1, h.shape[-1]), moe["ffn_norm"], cfg.rms_norm_eps).astype(moe["router"].dtype)
-    bias = moe_share.balanced_bias(moe, u, cfg)
+    bias = moe_share.balanced_bias(moe["bias"], lambda b: moe_share.route({**moe, "bias": b}, u, cfg)[0])
     return _moe({**moe, "bias": bias}, h, cfg), bias
 
 

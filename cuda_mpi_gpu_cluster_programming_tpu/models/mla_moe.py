@@ -95,7 +95,7 @@ class MlaMoeConfig:
     expert_span_rows: int = 32  # rows of results held until their tokens gather them back
 
     def __post_init__(self):
-        moe_share.check_share(self)
+        moe_share.check_sigmoid_moe(self)
 
     @property
     def qk_head_dim(self) -> int:

@@ -1,14 +1,16 @@
-"""A chip's share of a sigmoid-routed mixture-of-experts layer, and what the
-decoder families built on it have in common: the float32 RMSNorm, the product
-in the parameters' type, SwiGLU, the seeded draw of a parameter tree and the
-routing statistics.
+"""A chip's share of a mixture-of-experts layer, a sigmoid router for it, and
+what the decoder families built on it have in common: the float32 RMSNorm, the
+product in the parameters' type, SwiGLU, the seeded draw of a parameter tree
+and the routing statistics.
 
 - *Router*: ``s = sigmoid(u W_r)`` in float32; selection on ``s + bias``: a
   group's score is the sum of its top 2, the top ``topk_group`` groups are
   kept, the top ``num_experts_per_tok`` experts among them chosen (one group
   of all the experts is the plain top-k); weights are the unbiased ``s`` of
   the chosen, normalised to sum 1, times ``routed_scaling_factor``.
-- *MoE*: ``sum_i w_i Expert_i(u) + Shared(u)``.
+- *MoE*: ``sum_i w_i Expert_i(u) + Shared(u)`` (``_moe``). A model with a
+  router of its own hands its ``(chosen, weights)`` to ``_routed`` and adds
+  what else its layer has: the routed sum needs no shared expert.
 
 **The chip's share.** The layer is told which experts it holds
 (``[experts_first, experts_first + experts_held)``). It routes over all
@@ -17,12 +19,15 @@ holds — dropless, no capacity: the pairs are sorted by expert, each expert's
 rows padded to whole tiles, ``ops.grouped_matmul`` runs over as many chunks of
 rows as were routed here, and every token gathers its experts' rows back and
 weights them — adds the shared expert, and that partial sum goes on to the
-next layer. What the absent experts would add arrives, in a deployment, by the
+next layer. An index outside the held experts, whatever it stands for (another
+chip's expert, a router's output that means "no expert"), is a pair of no
+work. What the absent experts would add arrives, in a deployment, by the
 exchange of ``parallel.expert``; nothing here stands in for it.
 
 Nothing here knows which model calls it: a model hands over its parameters and
 a configuration with the fields of :class:`MoeShareConfig`
-(``models.mla_moe.MlaMoeConfig``, ``models.kda_moe.KdaMoeConfig``).
+(``models.mla_moe.MlaMoeConfig``, ``models.kda_moe.KdaMoeConfig``) or, for the
+routed sum alone, of :class:`RoutedShareConfig`.
 """
 
 from __future__ import annotations
@@ -43,16 +48,12 @@ from ..ops.reference import mxu_precision
 Params = Dict[str, Any]
 
 
-class MoeShareConfig(Protocol):
-    """What the functions below read of a model's configuration."""
+class RoutedShareConfig(Protocol):
+    """What the routed sum (``_routed``, ``check_share``, the statistics)
+    reads of a model's configuration."""
 
-    rms_norm_eps: float
     n_routed_experts: int  # the router's width: every expert of the layer
-    n_group: int
-    topk_group: int
     num_experts_per_tok: int
-    routed_scaling_factor: float
-    n_shared_experts: int
     experts_held: int  # the experts this chip holds ...
     experts_first: int  # ... are [experts_first, experts_first + experts_held)
     expert_tile_rows: int  # rows of one tile of the grouped product
@@ -60,16 +61,32 @@ class MoeShareConfig(Protocol):
     expert_span_rows: int  # rows of results held until their tokens gather them back
 
 
-def check_share(cfg: MoeShareConfig) -> None:
+class MoeShareConfig(RoutedShareConfig, Protocol):
+    """What ``route`` and ``_moe`` read besides: the sigmoid router's groups,
+    the norm and the shared expert."""
+
+    rms_norm_eps: float
+    n_group: int
+    topk_group: int
+    routed_scaling_factor: float
+    n_shared_experts: int
+
+
+def check_share(cfg: RoutedShareConfig) -> None:
     """What a configuration's ``__post_init__`` asks of its share."""
-    if cfg.n_routed_experts % cfg.n_group:
-        raise ValueError("n_routed_experts must divide into n_group groups")
     if not 0 <= cfg.experts_first <= cfg.n_routed_experts - cfg.experts_held:
         raise ValueError("the experts held must lie inside the router's width")
     if cfg.expert_chunk_rows % cfg.expert_tile_rows:
         raise ValueError("expert_chunk_rows must be whole tiles")
     if cfg.expert_span_rows % cfg.expert_chunk_rows:
         raise ValueError("expert_span_rows must be whole chunks")
+
+
+def check_sigmoid_moe(cfg: MoeShareConfig) -> None:
+    """What ``_moe`` asks besides: whole groups, and the one shared expert it adds."""
+    check_share(cfg)
+    if cfg.n_routed_experts % cfg.n_group:
+        raise ValueError("n_routed_experts must divide into n_group groups")
     if cfg.n_shared_experts != 1:
         raise ValueError("one shared expert is what this model computes")
 
@@ -191,9 +208,11 @@ def route(p: Params, u, cfg: MoeShareConfig):
     return chosen.astype(jnp.int32), weights
 
 
-def balanced_bias(p: Params, u, cfg: MoeShareConfig, rounds: int = 8, step: float = 0.04):
-    """The selection bias that balances the experts' load on the tokens
-    ``u (T, D)``. A trained bias is there to balance the load (it is nudged
+def balanced_bias(bias, choose: Callable[[Any], Any], rounds: int = 8, step: float = 0.04):
+    """The selection bias that balances the load of a router's outputs, one
+    per entry of ``bias``, where ``choose(bias) -> chosen (T, k)`` is the
+    router's choice for some tokens under a bias (``route``'s first result, or
+    a model's own). A trained bias is there to balance the load (it is nudged
     against each expert's excess, step after step of training); seeded weights
     have none,
     and where the stream carries a token-independent part the router then
@@ -202,18 +221,17 @@ def balanced_bias(p: Params, u, cfg: MoeShareConfig, rounds: int = 8, step: floa
     scores are chosen, ``ln(load)`` moves by about 19 per unit of bias, so
     ``step`` 0.04 is a damped Newton step); only the selection moves, the
     weights of the chosen stay their unbiased scores."""
-    experts = jnp.arange(cfg.n_routed_experts)
+    outputs = jnp.arange(bias.shape[0])
 
-    def one_round(bias, _):
-        chosen, _weights = route({**p, "bias": bias}, u, cfg)
-        load = jnp.sum(chosen[..., None] == experts, axis=(0, 1)).astype(jnp.float32)
-        return bias - step * jnp.log((load + 1.0) / (load.mean() + 1.0)), None
+    def one_round(b, _):
+        load = jnp.sum(choose(b)[..., None] == outputs, axis=(0, 1)).astype(jnp.float32)
+        return b - step * jnp.log((load + 1.0) / (load.mean() + 1.0)), None
 
-    bias, _ = lax.scan(one_round, p["bias"].astype(jnp.float32), None, length=rounds)
-    return bias.astype(p["bias"].dtype)
+    balanced, _ = lax.scan(one_round, bias.astype(jnp.float32), None, length=rounds)
+    return balanced.astype(bias.dtype)
 
 
-def _dispatch(chosen, cfg: MoeShareConfig):
+def _dispatch(chosen, cfg: RoutedShareConfig):
     """The (token, expert) pairs whose expert is held here, sorted by expert:
     ``(order (P,), sizes, start, pad_start, pad_end, row (P,))`` with
     ``P = T * k`` pairs in all; ``order`` lists pair indices expert by expert
@@ -236,9 +254,13 @@ def _dispatch(chosen, cfg: MoeShareConfig):
     return order, sizes, jnp.cumsum(sizes) - sizes, pad_start, pad_end, row.astype(jnp.int32)
 
 
-def _routed_experts(p: Params, u, weights, dispatch, cfg: MoeShareConfig):
+def _routed_experts(p: Params, u, weights, dispatch, cfg: RoutedShareConfig, group_base=None):
     """``sum_i w_i Expert_i(u)`` over the pairs whose expert is held here,
-    float32 ``(T, D)``. Span by span of the padded rows (one span holds a
+    float32 ``(T, D)``. ``p`` holds the held experts' matrices ``(held, ...)``
+    or, with ``group_base``, a stack of several layers' ``(layers * held,
+    ...)`` of which this layer's begin at ``group_base`` (a loop over layers
+    then hands every layer the one stack, and no layer's experts are copied
+    out of it for the kernel). Span by span of the padded rows (one span holds a
     usual load): chunk by chunk, gather the rows' tokens and run the three
     grouped products into the span's results; then every token gathers the
     rows of its own pairs back, one gather per place among its experts, and
@@ -261,10 +283,11 @@ def _routed_experts(p: Params, u, weights, dispatch, cfg: MoeShareConfig):
         # a padding row computes some token's row again; no pair points at it
         pair = order[jnp.clip(start[group] + rank, 0, n_pairs - 1)]
         x_rows = u[pair // k]
+        matrix = tile_group if group_base is None else tile_group + group_base
         hidden = jax.nn.silu(
-            grouped_matmul(x_rows, p["gate"], tile_group, tile_rows=tm)
-        ) * grouped_matmul(x_rows, p["up"], tile_group, tile_rows=tm)
-        return grouped_matmul(hidden.astype(u.dtype), p["down"], tile_group, tile_rows=tm).astype(u.dtype)
+            grouped_matmul(x_rows, p["gate"], matrix, tile_rows=tm)
+        ) * grouped_matmul(x_rows, p["up"], matrix, tile_rows=tm)
+        return grouped_matmul(hidden.astype(u.dtype), p["down"], matrix, tile_rows=tm).astype(u.dtype)
 
     def one_span(s, y):
         base = s * span
@@ -284,6 +307,18 @@ def _routed_experts(p: Params, u, weights, dispatch, cfg: MoeShareConfig):
     return lax.fori_loop(0, n_spans, one_span, jnp.zeros(u.shape, jnp.float32))
 
 
+def _routed(experts: Params, u, chosen, weights, cfg: RoutedShareConfig, group_base=None):
+    """``(sum_i w_i Expert_i(u) over the pairs whose expert is held here,
+    float32 (T, D); the held experts' pair counts)`` for the tokens ``u (T,
+    D)`` and any router's ``chosen (T, k) int32`` and ``weights (T, k)``: the
+    sort and the index arithmetic under the scope ``moe.route``, the products
+    under ``moe.experts``. ``group_base`` as ``_routed_experts`` says."""
+    with scopes.layer("moe.route"):
+        dispatch = _dispatch(chosen, cfg)
+    with scopes.layer("moe.experts"):
+        return _routed_experts(experts, u, weights, dispatch, cfg, group_base), dispatch[1]
+
+
 def _moe(p: Params, h, cfg: MoeShareConfig, with_sizes: bool = False):
     """``h + routed + shared`` on the float32 residual stream ``(B, S, D)``."""
     dt = p["router"].dtype
@@ -291,12 +326,10 @@ def _moe(p: Params, h, cfg: MoeShareConfig, with_sizes: bool = False):
     with scopes.layer("moe.route"):
         u = _rms_norm(flat, p["ffn_norm"], cfg.rms_norm_eps).astype(dt)
         chosen, weights = route(p, u, cfg)
-        dispatch = _dispatch(chosen, cfg)
-    with scopes.layer("moe.experts"):
-        routed = _routed_experts(p["experts"], u, weights, dispatch, cfg)
+    routed, sizes = _routed(p["experts"], u, chosen, weights, cfg)
     with scopes.layer("moe.shared"):
         out = (flat + routed + _swiglu(p["shared"], u)).reshape(h.shape)
-    return (out, dispatch[1]) if with_sizes else out
+    return (out, sizes) if with_sizes else out
 
 
 # ---------------------------------------------------------------------------
@@ -311,7 +344,7 @@ def embed_tokens(table, ids):
 
 
 def routing_statistics(
-    params: Params, ids, cfg: MoeShareConfig, block: Callable[[Params, Any], Tuple[Any, Optional[Any]]]
+    params: Params, ids, cfg: RoutedShareConfig, block: Callable[[Params, Any], Tuple[Any, Optional[Any]]]
 ) -> Dict[str, float]:
     """Route ``ids`` layer by layer through ``block(layer parameters, x) ->
     (x, the held experts' pair counts or None)`` (the model's own block,
@@ -320,18 +353,25 @@ def routing_statistics(
     ``moe.pairs_held``, ``moe.pairs_all`` (tokens x experts per token, over
     the MoE layers) and ``moe.expert_load_max_over_mean`` (the fullest held
     expert's pairs over the held experts' mean). Returns the three values."""
-    from ..observability import metrics
-
     x = embed_tokens(params["embed"], ids)
     loads: List[np.ndarray] = []
     for p in params["layers"]:
         x, sizes = block(p, x)
         if sizes is not None:
             loads.append(np.asarray(sizes, np.int64))
-    held = np.sum(loads, axis=0) if loads else np.zeros(cfg.experts_held, np.int64)
+    return set_routing_gauges(loads, ids.size, cfg)
+
+
+def set_routing_gauges(loads: Sequence[np.ndarray], tokens: int, cfg: RoutedShareConfig) -> Dict[str, float]:
+    """Fill the three ``moe.*`` routing gauges from ``loads``, the held
+    experts' pair counts of each MoE layer that routed ``tokens`` tokens, and
+    return their values."""
+    from ..observability import metrics
+
+    held = np.sum(loads, axis=0, dtype=np.int64) if len(loads) else np.zeros(cfg.experts_held, np.int64)
     stats = {
         metrics.MOE_PAIRS_HELD: float(held.sum()),
-        metrics.MOE_PAIRS_ALL: float(len(loads) * ids.size * cfg.num_experts_per_tok),
+        metrics.MOE_PAIRS_ALL: float(len(loads) * tokens * cfg.num_experts_per_tok),
         metrics.MOE_EXPERT_LOAD_MAX_OVER_MEAN: float(held.max() / held.mean()) if held.sum() else 0.0,
     }
     for name, value in stats.items():

@@ -52,6 +52,14 @@ MOE_ROUTING_GAUGES = (MOE_PAIRS_HELD, MOE_PAIRS_ALL, MOE_EXPERT_LOAD_MAX_OVER_ME
 KDA_CHUNK_LOG_DECAY_MIN = "kda.chunk_log_decay_min"
 KDA_BETA_MEAN = "kda.beta_mean"
 KDA_GAUGES = (KDA_CHUNK_LOG_DECAY_MIN, KDA_BETA_MEAN)
+# Gauges of the decoder whose router is an MLP over a state carried down the
+# depth (``models.cca_moe.layer_statistics``, one batch, outside any hot loop):
+# the tokens whose top-1 is the router's skip output over all routed tokens,
+# every layer together, and the rms of the last layer's router state (what the
+# additions of the carried state come to).
+MOE_SKIP_SHARE = "moe.skip_share"
+ROUTER_STATE_RMS_LAST = "router.state_rms_last"
+CCA_GAUGES = (MOE_SKIP_SHARE, ROUTER_STATE_RMS_LAST)
 
 # Prometheus metric-name grammar: [a-zA-Z_:][a-zA-Z0-9_:]* — the dotted
 # registry names ("serve.ok") sanitize to underscores ("serve_ok").

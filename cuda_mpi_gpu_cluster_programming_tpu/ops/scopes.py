@@ -42,9 +42,24 @@ KDA_MOE_LAYERS = (
     "embed", "gqa.proj", "gqa.attn", "kda.proj", "kda.mix", "kda.scan",
     "moe.route", "moe.experts", "moe.shared", "head",
 )
+# The compressed-convolutional-attention mixture-of-experts decoder
+# (``models.cca_moe``). ``cca.proj``: the norm, the q/k/v1/v2 projections into
+# the latent, the output projection and the merge; ``cca.mix``: both causal
+# convolutions (with the per-head products inside it), the q-k mean, the value
+# shift, the q/k normalisation with ``tau``, the rotary embedding and its
+# tables; ``cca.attn`` the flash kernel. ``moe.route`` here holds the norm, the
+# carried state, the router's MLP, softmax and top-1 before the sort and the
+# index arithmetic; ``moe.experts`` the merge after the weighted combine.
+# ``layer_loop`` is the scan over the layers itself (the ``while``, its counter
+# and the slicing of a layer's parameters out of the stack): every operation of
+# a layer keeps its own scope inside it, and the innermost counts.
+CCA_MOE_LAYERS = (
+    "embed", "layer_loop", "cca.proj", "cca.mix", "cca.attn", "moe.route", "moe.experts", "head",
+)
 LAYERS = (
     BLOCKS12_LAYERS + ALEXNET_TAIL_LAYERS + FC_LAYERS + MLA_MOE_LAYERS
     + tuple(name for name in KDA_MOE_LAYERS if name not in MLA_MOE_LAYERS)
+    + tuple(name for name in CCA_MOE_LAYERS if name not in MLA_MOE_LAYERS)
 )
 
 # Parameters and input to the compute type: the bf16 wrapper's casts and the

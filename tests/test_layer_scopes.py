@@ -78,6 +78,10 @@ def test_every_layer_of_the_chain_is_named_in_the_compiled_program(key):
         names = scopes.KDA_MOE_LAYERS
         chain = names[:3] + names[6:9] + names[3:6] + names[9:]
         assert sorted(chain) == sorted(names)
+    if exec_cfg.model == "cca_moe":  # the rotary tables (cca.mix) are made once, before the loop over the layers
+        names = scopes.CCA_MOE_LAYERS
+        chain = names[:1] + names[3:4] + names[1:3] + names[4:]
+        assert sorted(chain) == sorted(names)
     for layer in chain:
         assert _scoped(paths, layer), f"{key}: no operation under the scope {layer!r}"
     # in order: the jaxpr is the program as written, before any scheduling
@@ -135,7 +139,14 @@ def test_a_kernel_that_covers_conv_and_pool_says_so():
     assert not _scoped(paths, "conv1") and not _scoped(paths, "lrn2")
 
 
-@pytest.mark.parametrize("key,layers", [("v8_mla_moe", scopes.MLA_MOE_LAYERS), ("v9_kda_moe", scopes.KDA_MOE_LAYERS)])
+@pytest.mark.parametrize(
+    "key,layers",
+    [
+        ("v8_mla_moe", scopes.MLA_MOE_LAYERS),
+        ("v9_kda_moe", scopes.KDA_MOE_LAYERS),
+        ("v10_cca_moe", scopes.CCA_MOE_LAYERS),
+    ],
+)
 def test_token_ids_and_parameters_stored_in_bf16_pass_the_bf16_wrapper_uncast(key, layers):
     """``compute="bf16"`` casts floating inputs only: a language model's
     integer ids and its parameters, already bf16, reach the forward as they
