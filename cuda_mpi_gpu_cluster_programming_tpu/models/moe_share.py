@@ -17,9 +17,11 @@ and the routing statistics.
 ``n_routed_experts``, computes only the (token, expert) pairs whose expert it
 holds — dropless, no capacity: the pairs are sorted by expert, each expert's
 rows padded to whole tiles, ``ops.grouped_matmul`` runs over as many chunks of
-rows as were routed here, and every token gathers its experts' rows back and
-weights them — adds the shared expert, and that partial sum goes on to the
-next layer. An index outside the held experts, whatever it stands for (another
+rows as were routed here, and every token collects the rows of its own pairs,
+weighted (``ops.moe_combine``: one kernel that moves only the rows that were
+computed, or one gather per place where the shapes leave it nothing to save)
+— adds the shared expert, and that partial sum goes on to the next layer.
+An index outside the held experts, whatever it stands for (another
 chip's expert, a router's output that means "no expert"), is a pair of no
 work. What the absent experts would add arrives, in a deployment, by the
 exchange of ``parallel.expert``; nothing here stands in for it.
@@ -43,6 +45,7 @@ from jax import lax
 
 from ..ops import scopes
 from ..ops.grouped_matmul import grouped_matmul
+from ..ops.moe_combine import moe_combine, moe_combine_reference, row_slab, worth_a_kernel
 from ..ops.reference import mxu_precision
 
 Params = Dict[str, Any]
@@ -262,15 +265,24 @@ def _routed_experts(p: Params, u, weights, dispatch, cfg: RoutedShareConfig, gro
     then hands every layer the one stack, and no layer's experts are copied
     out of it for the kernel). Span by span of the padded rows (one span holds a
     usual load): chunk by chunk, gather the rows' tokens and run the three
-    grouped products into the span's results; then every token gathers the
-    rows of its own pairs back, one gather per place among its experts, and
-    weights them. A scatter-add of rows this wide costs the chip many times
-    more, and the gathers' cost follows what was routed far less than a
-    product over the padded rows does."""
+    grouped products into the span's results; then the combine adds to every
+    token the rows of its own pairs, weighted, in place order. Where a row is
+    wide and a token has many places (``worth_a_kernel``, from the shapes
+    alone) that is ``moe_combine``: the results are kept as slabs, a row in
+    whole tiles of its own, and the kernel fetches each held row by one DMA,
+    so a span costs the rows it holds and not ``k`` gathers over every token;
+    else the gathers stand (one a place, and a float32 multiply-add after
+    it). A scatter-add of rows this wide costs the chip many times more than
+    either."""
     order, _sizes, start, pad_start, pad_end, row = dispatch
     tm, chunk, span, k = cfg.expert_tile_rows, cfg.expert_chunk_rows, cfg.expert_span_rows, cfg.num_experts_per_tok
     n_pairs, rows_all, last = order.shape[0], pad_end[-1], cfg.experts_held - 1
     row = row.reshape(-1, k)
+    if worth_a_kernel(u.shape[0], k, u.shape[1], span):
+        combine, slab = moe_combine, row_slab(u.shape[1])  # a row as whole tiles of its own: what one DMA moves
+    else:
+        combine, slab = moe_combine_reference, u.shape[1:]
+    origin = (0,) * len(slab)
 
     def chunk_results(first_row):
         tile_first = first_row + jnp.arange(chunk // tm, dtype=jnp.int32) * tm
@@ -287,24 +299,19 @@ def _routed_experts(p: Params, u, weights, dispatch, cfg: RoutedShareConfig, gro
         hidden = jax.nn.silu(
             grouped_matmul(x_rows, p["gate"], matrix, tile_rows=tm)
         ) * grouped_matmul(x_rows, p["up"], matrix, tile_rows=tm)
-        return grouped_matmul(hidden.astype(u.dtype), p["down"], matrix, tile_rows=tm).astype(u.dtype)
+        rows = grouped_matmul(hidden.astype(u.dtype), p["down"], matrix, tile_rows=tm)
+        return rows.astype(u.dtype).reshape(chunk, *slab)
 
     def one_span(s, y):
         base = s * span
         n_chunks = jnp.minimum((rows_all - base + chunk - 1) // chunk, span // chunk)
-        results = lax.fori_loop(
-            0, n_chunks,
-            lambda c, held: lax.dynamic_update_slice(held, chunk_results(base + c * chunk), (c * chunk, 0)),
-            jnp.zeros((span, u.shape[1]), u.dtype),
-        )
-        inside = (row >= base) & (row < base + span)  # (T, k): the pairs whose rows this span holds
-        for place in range(k):
-            rows = results[jnp.where(inside[:, place], row[:, place] - base, 0)].astype(jnp.float32)
-            y = y + jnp.where(inside[:, place, None], rows * weights[:, place, None], 0.0)
-        return y
+        put = lambda c, held: lax.dynamic_update_slice(held, chunk_results(base + c * chunk), (c * chunk, *origin))
+        results = lax.fori_loop(0, n_chunks, put, jnp.zeros((span, *slab), u.dtype))
+        return combine(results, row, weights, y, base)
 
     n_spans = (rows_all + span - 1) // span
-    return lax.fori_loop(0, n_spans, one_span, jnp.zeros(u.shape, jnp.float32))
+    y = lax.fori_loop(0, n_spans, one_span, jnp.zeros((u.shape[0], *slab), jnp.float32))
+    return y.reshape(u.shape)
 
 
 def _routed(experts: Params, u, chosen, weights, cfg: RoutedShareConfig, group_base=None):

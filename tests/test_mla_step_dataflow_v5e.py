@@ -80,11 +80,11 @@ def topo():
 @pytest.fixture(scope="module")
 def step_text(topo):
     """The compiled text of ``build_forward``'s step at the cell's shapes,
-    the two kernels through Mosaic (steered here, not by an option)."""
+    the three kernels through Mosaic (steered here, not by an option)."""
     from jax.experimental.compilation_cache import compilation_cache
 
     from cuda_mpi_gpu_cluster_programming_tpu.configs import REGISTRY, build_forward
-    from cuda_mpi_gpu_cluster_programming_tpu.ops import flash_attention, grouped_matmul
+    from cuda_mpi_gpu_cluster_programming_tpu.ops import flash_attention, grouped_matmul, moe_combine
 
     one_chip = SingleDeviceSharding(topo.devices[0])
     params = jax.tree.map(
@@ -98,6 +98,7 @@ def step_text(topo):
     with pytest.MonkeyPatch.context() as patch:
         patch.setattr(flash_attention, "_interpret", lambda: False)
         patch.setattr(grouped_matmul, "_interpret", lambda: False)
+        patch.setattr(moe_combine, "_interpret", lambda: False)
         try:
             fwd = build_forward(REGISTRY["v8_mla_moe"], CFG, n_shards=1, compute="bf16")
             return fwd.lower(params, ids).compile().as_text()
