@@ -371,12 +371,12 @@ def _moe(p: Params, stack: Params, x, r, layer, cfg: CcaMoeConfig, bias_for: Opt
     bias, p) -> bias`` replaces the selection bias by one made from this
     layer's probabilities."""
     flat = x.reshape(-1, x.shape[-1])
-    with scopes.layer("moe.route"):
+    with scopes.layer("moe.route"), scopes.phase("route.score"):
         u, r, probs = _router(p, flat, r, cfg)
         bias = p["bias"] if bias_for is None else bias_for(p["bias"], probs)
         chosen, weights = _top1(probs, bias)
     routed, sizes = moe_share._routed(stack, u, chosen, weights, cfg, group_base=layer * cfg.experts_held)
-    with scopes.layer("moe.experts"):
+    with scopes.layer("moe.experts"), scopes.phase("experts.combine"):
         out = _merge(p["moe_merge"], flat, routed).reshape(x.shape)
     return out, r, (bias, chosen[:, 0], sizes)
 
@@ -439,12 +439,12 @@ def balance_routers(params: Params, ids, cfg: CcaMoeConfig = SMALL) -> Params:
 
 def layer_statistics(params: Params, ids, cfg: CcaMoeConfig = SMALL) -> Dict[str, float]:
     """Run ``ids`` through the layers (one program, outside any hot loop) and
-    fill the metrics registry: the three ``moe.*`` routing gauges
+    fill the metrics registry: the four ``moe.*`` routing gauges
     (``moe_share.set_routing_gauges``; ``moe.pairs_all`` is tokens x layers x
     1), ``moe.skip_share`` (the tokens whose top-1 is the skip output over all
     routed tokens, every layer together) and ``router.state_rms_last`` (the
     rms of the last layer's ``r``: what the additions of the carried state
-    come to). Returns the five values."""
+    come to). Returns the six values."""
     from ..observability import metrics
 
     run = jax.jit(lambda p, i: _layers(p, i, cfg, with_routing=True)[1:])

@@ -347,11 +347,11 @@ def balance_routers(params: Params, ids, cfg: KdaMoeConfig = SMALL) -> Params:
 
 def layer_statistics(params: Params, ids, cfg: KdaMoeConfig = SMALL) -> Dict[str, float]:
     """Run ``ids`` layer by layer (one jitted program per kind of layer, outside
-    any hot loop) and fill the metrics registry: the three ``moe.*`` routing
+    any hot loop) and fill the metrics registry: the four ``moe.*`` routing
     gauges (``moe_share.routing_statistics``), ``kda.chunk_log_decay_min``
     (the most negative log decay summed over one chunk, over every KDA layer,
     head and channel: what no factor of the scan may exponentiate alone) and
-    ``kda.beta_mean``. Returns the five values."""
+    ``kda.beta_mean``. Returns the six values."""
     from ..observability import metrics
 
     run = jax.jit(functools.partial(_block_with_stats, cfg=cfg))

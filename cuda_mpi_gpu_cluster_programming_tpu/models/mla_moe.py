@@ -310,7 +310,7 @@ def forward(params: Params, ids, cfg: MlaMoeConfig = SMALL):
 
 def routing_statistics(params: Params, ids, cfg: MlaMoeConfig = SMALL) -> Dict[str, float]:
     """Route ``ids`` layer by layer (one jitted program per kind of layer,
-    outside any hot loop) and fill the metrics registry's three ``moe.*``
-    gauges (``moe_share.routing_statistics``). Returns the three values."""
+    outside any hot loop) and fill the metrics registry's four ``moe.*``
+    gauges (``moe_share.routing_statistics``). Returns the four values."""
     block = jax.jit(functools.partial(_block, cfg=cfg, with_sizes=True))
     return moe_share.routing_statistics(params, ids, cfg, block)

@@ -273,7 +273,14 @@ def _routed_experts(p: Params, u, weights, dispatch, cfg: RoutedShareConfig, gro
     so a span costs the rows it holds and not ``k`` gathers over every token;
     else the gathers stand (one a place, and a float32 multiply-add after
     it). A scatter-add of rows this wide costs the chip many times more than
-    either."""
+    either.
+
+    Four phases, named inside the caller's ``moe.experts`` (``scopes.PHASES``):
+    ``experts.gather`` a chunk's index arithmetic and the gather of its rows'
+    tokens, ``experts.products`` the three grouped products with silu and the
+    multiply, ``experts.layout`` the rows cast and laid out as the combine
+    wants them, their place in the span's results and both zero fills,
+    ``experts.combine`` the combine."""
     order, _sizes, start, pad_start, pad_end, row = dispatch
     tm, chunk, span, k = cfg.expert_tile_rows, cfg.expert_chunk_rows, cfg.expert_span_rows, cfg.num_experts_per_tok
     n_pairs, rows_all, last = order.shape[0], pad_end[-1], cfg.experts_held - 1
@@ -285,42 +292,59 @@ def _routed_experts(p: Params, u, weights, dispatch, cfg: RoutedShareConfig, gro
     origin = (0,) * len(slab)
 
     def chunk_results(first_row):
-        tile_first = first_row + jnp.arange(chunk // tm, dtype=jnp.int32) * tm
-        # the expert whose padded rows hold the tile: how many experts end at or before it
-        tile_group = jnp.minimum(
-            jnp.sum(tile_first[:, None] >= pad_end[None, :], axis=1, dtype=jnp.int32), last
-        )
-        group = jnp.repeat(tile_group, tm)
-        rank = first_row + jnp.arange(chunk, dtype=jnp.int32) - pad_start[group]
-        # a padding row computes some token's row again; no pair points at it
-        pair = order[jnp.clip(start[group] + rank, 0, n_pairs - 1)]
-        x_rows = u[pair // k]
-        matrix = tile_group if group_base is None else tile_group + group_base
-        hidden = jax.nn.silu(
-            grouped_matmul(x_rows, p["gate"], matrix, tile_rows=tm)
-        ) * grouped_matmul(x_rows, p["up"], matrix, tile_rows=tm)
-        rows = grouped_matmul(hidden.astype(u.dtype), p["down"], matrix, tile_rows=tm)
-        return rows.astype(u.dtype).reshape(chunk, *slab)
+        with scopes.phase("experts.gather"):
+            tile_first = first_row + jnp.arange(chunk // tm, dtype=jnp.int32) * tm
+            # the expert whose padded rows hold the tile: how many experts end at or before it
+            tile_group = jnp.minimum(
+                jnp.sum(tile_first[:, None] >= pad_end[None, :], axis=1, dtype=jnp.int32), last
+            )
+            group = jnp.repeat(tile_group, tm)
+            rank = first_row + jnp.arange(chunk, dtype=jnp.int32) - pad_start[group]
+            # a padding row computes some token's row again; no pair points at it
+            pair = order[jnp.clip(start[group] + rank, 0, n_pairs - 1)]
+            x_rows = u[pair // k]
+            matrix = tile_group if group_base is None else tile_group + group_base
+        with scopes.phase("experts.products"):
+            hidden = jax.nn.silu(
+                grouped_matmul(x_rows, p["gate"], matrix, tile_rows=tm)
+            ) * grouped_matmul(x_rows, p["up"], matrix, tile_rows=tm)
+            rows = grouped_matmul(hidden.astype(u.dtype), p["down"], matrix, tile_rows=tm)
+        with scopes.phase("experts.layout"):
+            return rows.astype(u.dtype).reshape(chunk, *slab)
 
     def one_span(s, y):
         base = s * span
         n_chunks = jnp.minimum((rows_all - base + chunk - 1) // chunk, span // chunk)
-        put = lambda c, held: lax.dynamic_update_slice(held, chunk_results(base + c * chunk), (c * chunk, *origin))
-        results = lax.fori_loop(0, n_chunks, put, jnp.zeros((span, *slab), u.dtype))
-        return combine(results, row, weights, y, base)
+
+        def put(c, held):
+            rows = chunk_results(base + c * chunk)
+            with scopes.phase("experts.layout"):
+                return lax.dynamic_update_slice(held, rows, (c * chunk, *origin))
+
+        with scopes.phase("experts.layout"):
+            empty = jnp.zeros((span, *slab), u.dtype)
+        results = lax.fori_loop(0, n_chunks, put, empty)
+        with scopes.phase("experts.combine"):
+            return combine(results, row, weights, y, base)
 
     n_spans = (rows_all + span - 1) // span
-    y = lax.fori_loop(0, n_spans, one_span, jnp.zeros((u.shape[0], *slab), jnp.float32))
-    return y.reshape(u.shape)
+    with scopes.phase("experts.layout"):
+        nothing = jnp.zeros((u.shape[0], *slab), jnp.float32)
+    y = lax.fori_loop(0, n_spans, one_span, nothing)
+    with scopes.phase("experts.layout"):
+        return y.reshape(u.shape)
 
 
 def _routed(experts: Params, u, chosen, weights, cfg: RoutedShareConfig, group_base=None):
     """``(sum_i w_i Expert_i(u) over the pairs whose expert is held here,
     float32 (T, D); the held experts' pair counts)`` for the tokens ``u (T,
     D)`` and any router's ``chosen (T, k) int32`` and ``weights (T, k)``: the
-    sort and the index arithmetic under the scope ``moe.route``, the products
-    under ``moe.experts``. ``group_base`` as ``_routed_experts`` says."""
-    with scopes.layer("moe.route"):
+    sort and the index arithmetic under the scope ``moe.route``, as its phase
+    ``route.sort`` (the router before it is the caller's ``route.score``); the
+    rest under ``moe.experts``, in the four phases ``_routed_experts`` names
+    (``experts.gather``, ``experts.products``, ``experts.layout``,
+    ``experts.combine``). ``group_base`` as ``_routed_experts`` says."""
+    with scopes.layer("moe.route"), scopes.phase("route.sort"):
         dispatch = _dispatch(chosen, cfg)
     with scopes.layer("moe.experts"):
         return _routed_experts(experts, u, weights, dispatch, cfg, group_base), dispatch[1]
@@ -330,7 +354,7 @@ def _moe(p: Params, h, cfg: MoeShareConfig, with_sizes: bool = False):
     """``h + routed + shared`` on the float32 residual stream ``(B, S, D)``."""
     dt = p["router"].dtype
     flat = h.reshape(-1, h.shape[-1])
-    with scopes.layer("moe.route"):
+    with scopes.layer("moe.route"), scopes.phase("route.score"):
         u = _rms_norm(flat, p["ffn_norm"], cfg.rms_norm_eps).astype(dt)
         chosen, weights = route(p, u, cfg)
     routed, sizes = _routed(p["experts"], u, chosen, weights, cfg)
@@ -356,10 +380,11 @@ def routing_statistics(
     """Route ``ids`` layer by layer through ``block(layer parameters, x) ->
     (x, the held experts' pair counts or None)`` (the model's own block,
     jitted once per kind of layer, outside any hot loop), count the pairs that
-    fell to the experts held here and fill the metrics registry:
-    ``moe.pairs_held``, ``moe.pairs_all`` (tokens x experts per token, over
-    the MoE layers) and ``moe.expert_load_max_over_mean`` (the fullest held
-    expert's pairs over the held experts' mean). Returns the three values."""
+    fell to the experts held here and fill the metrics registry
+    (``set_routing_gauges``): ``moe.pairs_held``, ``moe.pairs_all`` (tokens x
+    experts per token, over the MoE layers), ``moe.expert_load_max_over_mean``
+    (the fullest held expert's pairs over the held experts' mean) and
+    ``moe.rows_padded``. Returns the four values."""
     x = embed_tokens(params["embed"], ids)
     loads: List[np.ndarray] = []
     for p in params["layers"]:
@@ -370,16 +395,22 @@ def routing_statistics(
 
 
 def set_routing_gauges(loads: Sequence[np.ndarray], tokens: int, cfg: RoutedShareConfig) -> Dict[str, float]:
-    """Fill the three ``moe.*`` routing gauges from ``loads``, the held
+    """Fill the four ``moe.*`` routing gauges from ``loads``, the held
     experts' pair counts of each MoE layer that routed ``tokens`` tokens, and
-    return their values."""
+    return their values. ``moe.rows_padded`` is what ``_dispatch`` calls
+    ``pad_end[-1]``, summed over the layers: every held expert's pairs rounded
+    up to whole tiles of ``expert_tile_rows``, the rows the grouped products
+    run."""
     from ..observability import metrics
 
     held = np.sum(loads, axis=0, dtype=np.int64) if len(loads) else np.zeros(cfg.experts_held, np.int64)
+    tm = cfg.expert_tile_rows
+    padded = sum(int(np.sum((np.asarray(load, np.int64) + tm - 1) // tm * tm)) for load in loads)
     stats = {
         metrics.MOE_PAIRS_HELD: float(held.sum()),
         metrics.MOE_PAIRS_ALL: float(len(loads) * tokens * cfg.num_experts_per_tok),
         metrics.MOE_EXPERT_LOAD_MAX_OVER_MEAN: float(held.max() / held.mean()) if held.sum() else 0.0,
+        metrics.MOE_ROWS_PADDED: float(padded),
     }
     for name, value in stats.items():
         metrics.registry().gauge(name).set(value)

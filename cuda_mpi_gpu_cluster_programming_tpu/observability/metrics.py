@@ -36,14 +36,17 @@ from typing import Dict, List, Optional
 from ..resilience.journal import atomic_write_text
 
 # Routing gauges of the expert tier: the (token, expert) pairs that fell to the
-# experts a chip holds, all pairs routed, and the fullest held expert's load
-# over the held experts' mean. ``models.moe_share.routing_statistics`` sets them
-# for either decoder family, outside any hot loop (the forward itself syncs
-# nothing to the host).
+# experts a chip holds, all pairs routed, the fullest held expert's load over
+# the held experts' mean, and the rows the grouped products run for those pairs
+# (each held expert's pairs rounded up to whole tiles, every MoE layer
+# together: ``pairs_held / rows_padded`` of them are no padding).
+# ``models.moe_share.set_routing_gauges`` sets them for every decoder family,
+# outside any hot loop (the forward itself syncs nothing to the host).
 MOE_PAIRS_HELD = "moe.pairs_held"
 MOE_PAIRS_ALL = "moe.pairs_all"
 MOE_EXPERT_LOAD_MAX_OVER_MEAN = "moe.expert_load_max_over_mean"
-MOE_ROUTING_GAUGES = (MOE_PAIRS_HELD, MOE_PAIRS_ALL, MOE_EXPERT_LOAD_MAX_OVER_MEAN)
+MOE_ROWS_PADDED = "moe.rows_padded"
+MOE_ROUTING_GAUGES = (MOE_PAIRS_HELD, MOE_PAIRS_ALL, MOE_EXPERT_LOAD_MAX_OVER_MEAN, MOE_ROWS_PADDED)
 # Gauges of the linear-attention layers (``models.kda_moe.layer_statistics``,
 # one batch, outside any hot loop): the most negative log decay summed over one
 # chunk of the scan, over every layer, head and channel (what the scan must

@@ -24,8 +24,11 @@ FC_LAYERS = ("fc6", "fc7", "fc8")
 # order a MoE layer runs them: ``mla.proj`` holds the five projections with
 # their norms and the rotary embedding, ``mla.attn`` scores, softmax and
 # values; ``moe.route`` the router matmul, the group-limited top-k, the sort
-# and the index arithmetic; ``moe.experts`` the gather, the grouped products
-# and the weighted scatter-add; ``head`` the final norm and the output head.
+# and the index arithmetic; ``moe.experts`` the gather, the grouped products,
+# their results laid out for the way back and the combine (``ops.moe_combine``'s
+# kernel of row DMAs, or one gather and multiply-add per place where a row is
+# too narrow to pay for a DMA); ``head`` the final norm and the output head.
+# What stands inside the two ``moe.*`` scopes is named by ``PHASES`` below.
 MLA_MOE_LAYERS = (
     "embed", "mla.proj", "mla.attn", "dense_mlp",
     "moe.route", "moe.experts", "moe.shared", "head",
@@ -62,6 +65,20 @@ LAYERS = (
     + tuple(name for name in CCA_MOE_LAYERS if name not in MLA_MOE_LAYERS)
 )
 
+# A second, nested level: the phases of a layer, which stand only inside that
+# layer's scope (``moe.experts/experts.products/...``), as a halo exchange
+# stands inside its layer's. ``moe_share._routed_experts`` and ``_dispatch``
+# say what runs under each.
+PHASE_LAYER = {
+    "experts.gather": "moe.experts",  # a chunk's index arithmetic and the gather of its rows' tokens
+    "experts.products": "moe.experts",  # the three grouped products, silu and the multiply
+    "experts.layout": "moe.experts",  # the rows laid out for the way back, and the zero fills
+    "experts.combine": "moe.experts",  # every token collects its own rows, weighted (and a model's merge after it)
+    "route.score": "moe.route",  # the norm, the router, the scores, the choice, the weights
+    "route.sort": "moe.route",  # the pairs sorted by expert, the counts, the padded rows
+}
+PHASES = tuple(PHASE_LAYER)
+
 # Parameters and input to the compute type: the bf16 wrapper's casts and the
 # int8w quantisation.
 CAST_IN = "cast_in"
@@ -85,6 +102,14 @@ def layer(*names: str):
     for name in names:
         _check(name)
     return jax.named_scope("+".join(names))
+
+
+def phase(name: str):
+    """The scope of one phase of a layer, to be entered inside that layer's
+    own scope (``PHASE_LAYER`` says which)."""
+    if name not in PHASES:
+        raise ValueError(f"{name!r} is not a phase name ({', '.join(PHASES)})")
+    return jax.named_scope(name)
 
 
 def halo(layer_name: str):

@@ -85,7 +85,7 @@ def test_every_layer_of_the_chain_is_named_in_the_compiled_program(key):
     for layer in chain:
         assert _scoped(paths, layer), f"{key}: no operation under the scope {layer!r}"
     # in order: the jaxpr is the program as written, before any scheduling
-    stacks = list(_name_stacks(jax.make_jaxpr(fwd)(params, x).jaxpr))
+    stacks = [stack for _eqn, stack in _full_stacks(jax.make_jaxpr(fwd)(params, x).jaxpr)]
     seen = []
     for stack in stacks:
         for part in stack.split("/"):
@@ -107,12 +107,14 @@ def test_every_layer_of_the_chain_is_named_in_the_compiled_program(key):
         assert [p for p in paths if "/lrn2/halo.lrn2/" in p]  # the LRN's channel halo
 
 
-def _name_stacks(jaxpr):
-    """The name stack of every equation, through nested jaxprs, in order."""
+def _full_stacks(jaxpr, prefix=""):
+    """``(equation, its whole name stack)`` through nested jaxprs, in order: a
+    loop's or a call's body counts its names from the equation that holds it."""
     for eqn in jaxpr.eqns:
-        yield str(eqn.source_info.name_stack)
+        stack = "/".join(part for part in (prefix, str(eqn.source_info.name_stack)) if part)
+        yield eqn, stack
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _name_stacks(sub)
+            yield from _full_stacks(sub, stack)
 
 
 @pytest.mark.parametrize("key,compute", [("v1_jit", "bf16"), ("v1_jit", "int8w"), ("v2.2_sharded", "int8w")])
@@ -158,6 +160,94 @@ def test_token_ids_and_parameters_stored_in_bf16_pass_the_bf16_wrapper_uncast(ke
         assert _scoped(paths, layer), layer
     jaxpr = jax.make_jaxpr(fwd)(params, ids)
     assert str(jaxpr.jaxpr.invars[-1].aval.dtype) == "int32"
+
+
+LANGUAGE_KEYS = ("v8_mla_moe", "v9_kda_moe", "v10_cca_moe")
+
+
+@pytest.mark.parametrize("combine", ["gathers", "kernel"])
+@pytest.mark.parametrize("key", LANGUAGE_KEYS)
+def test_the_routed_experts_and_the_route_name_their_phases_inside_their_layers(key, combine, monkeypatch):
+    """Every phase of ``scopes.PHASES`` is in the step program, nested in its
+    layer and nowhere else, and holds what its name says: the three grouped
+    products under ``experts.products``, the combine (the kernel where the
+    shapes say so, steered here: the small presets' do not) under
+    ``experts.combine``, the rows' place in the span and both zero fills under
+    ``experts.layout``, the tokens' gather under ``experts.gather``, the sort
+    under ``route.sort`` and the router's top-k or argmax under
+    ``route.score``."""
+    from cuda_mpi_gpu_cluster_programming_tpu.models import moe_share
+
+    if combine == "kernel":
+        monkeypatch.setattr(moe_share, "worth_a_kernel", lambda *shape: True)
+    _cfg, fwd, params, ids = _build(key, "bf16")
+    paths = _paths(fwd, params, ids)
+    for phase, layer in scopes.PHASE_LAYER.items():
+        under = [p.split("/")[:-1] for p in paths if phase in p.split("/")[:-1]]
+        assert under, f"{key}: nothing under {phase}"
+        # inside its layer (a loop's ``while/body`` may stand between them), and inside no other layer since
+        for parts in under:
+            outer = [part for part in parts[: parts.index(phase)] if part in scopes.LAYERS and part != "layer_loop"]
+            assert outer and outer[-1] == layer, f"{key}: {phase} under {'/'.join(parts)}"
+    held: dict = {}
+    for eqn, stack in _full_stacks(jax.make_jaxpr(fwd)(params, ids).jaxpr):
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            name = str(eqn.params.get("name_and_src_info") or eqn.params["name"]).split(" ")[0]
+        held.setdefault(name, set()).add(tuple(part for part in stack.split("/") if part in scopes.PHASES))
+    assert held["grouped_matmul"] == {("experts.products",)}
+    assert held.get("moe_combine") == ({("experts.combine",)} if combine == "kernel" else None)
+    assert held["sort"] == {("route.sort",)}
+    assert ("experts.layout",) in held["dynamic_update_slice"] and ("experts.layout",) in held["broadcast_in_dim"]
+    assert ("experts.gather",) in held["gather"] and ("experts.products",) not in held["gather"]
+    assert ("route.score",) in held["argmax" if key == "v10_cca_moe" else "top_k"]
+    # a phase never stands inside another
+    assert all(len(phases) <= 1 for stacks in held.values() for phases in stacks)
+
+
+def _strip_metadata(text: str) -> str:
+    """``benchmark/tools/record_step_hlo.py``'s: every ``metadata={...}`` and
+    the module's tables of source locations."""
+    import importlib.util
+    import sys
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "benchmark" / "tools" / "record_step_hlo.py"
+    spec = importlib.util.spec_from_file_location("record_step_hlo", path)
+    module = importlib.util.module_from_spec(spec)
+    sys_path = list(sys.path)
+    try:
+        spec.loader.exec_module(module)
+    finally:
+        sys.path[:] = sys_path
+    return module.strip_metadata(text)
+
+
+@pytest.mark.parametrize("key", LANGUAGE_KEYS)
+def test_the_phases_are_metadata_and_change_nothing_the_compiler_builds(key, monkeypatch):
+    """The compiled step with its phases and the same step built with
+    ``scopes.phase`` naming nothing are one program once the metadata is
+    stripped; and the phases are in the first and not in the second."""
+    import contextlib
+
+    def text():
+        _cfg, fwd, params, ids = _build(key, "bf16")
+        return fwd.lower(params, ids).compile().as_text()
+
+    named = text()
+    monkeypatch.setattr(scopes, "phase", lambda name: contextlib.nullcontext())
+    plain = text()
+    assert not [phase for phase in scopes.PHASES if f"/{phase}/" not in named]
+    assert not [phase for phase in scopes.PHASES if f"/{phase}/" in plain]
+    assert "/moe.experts/" in plain and _strip_metadata(named) == _strip_metadata(plain)
+
+
+@pytest.mark.parametrize("name", ["experts.scatter", "moe.experts", "conv1", "halo.experts.gather"])
+def test_a_phase_outside_the_vocabulary_is_refused(name):
+    with pytest.raises(ValueError, match="not a phase name"):
+        scopes.phase(name)
+    assert name not in scopes.PHASES and set(scopes.PHASE_LAYER.values()) <= set(scopes.LAYERS)
+    assert not set(scopes.PHASES) & set(scopes.LAYERS)
 
 
 def test_a_name_outside_the_vocabulary_is_refused():

@@ -104,3 +104,54 @@ def test_balanced_bias_takes_the_routing_function_it_balances():
     assert balanced.shape == (5,) and balanced.dtype == start.dtype
     assert load(start).max() / load(start).mean() > 1.8
     assert load(balanced).max() / load(balanced).mean() < 1.25
+
+
+def _share(tile: int, held: int = 3, k: int = 2):
+    return types.SimpleNamespace(
+        n_routed_experts=held + 2, num_experts_per_tok=k, experts_held=held, experts_first=1,
+        expert_tile_rows=tile, expert_chunk_rows=2 * tile, expert_span_rows=4 * tile,
+    )
+
+
+@pytest.mark.parametrize(
+    "loads,tile,want",
+    [
+        ([[1, 256, 257]], 256, 1024),  # 256 + 256 + 512: a pair past a whole tile costs a tile
+        ([[1, 256, 257], [300, 0, 5]], 256, 1024 + 768),  # layer by layer, not of the layers' sum
+        ([[1, 256, 257]], 320, 960),
+        ([[0, 0, 0]], 256, 0),  # an expert nobody chose pads nothing
+        ([], 256, 0),  # no MoE layer at all
+    ],
+)
+def test_the_padded_rows_gauge_is_the_tile_arithmetic_of_the_loads(loads, tile, want):
+    """``moe.rows_padded`` rounds every held expert's pairs of every layer up
+    to whole tiles; the three gauges beside it read as they did."""
+    from cuda_mpi_gpu_cluster_programming_tpu.observability import metrics
+
+    cfg, tokens = _share(tile), 400
+    stats = moe_share.set_routing_gauges([np.asarray(load) for load in loads], tokens, cfg)
+    held = np.sum(loads, axis=0) if loads else np.zeros(3)
+    assert stats == {
+        metrics.MOE_PAIRS_HELD: float(held.sum()),
+        metrics.MOE_PAIRS_ALL: float(len(loads) * tokens * cfg.num_experts_per_tok),
+        metrics.MOE_EXPERT_LOAD_MAX_OVER_MEAN: float(held.max() / held.mean()) if held.sum() else 0.0,
+        metrics.MOE_ROWS_PADDED: float(want),
+    }
+    assert tuple(stats) == metrics.MOE_ROUTING_GAUGES
+    summary = metrics.registry().summary()
+    assert {name: summary[name] for name in metrics.MOE_ROUTING_GAUGES} == stats
+
+
+@pytest.mark.parametrize("tile", [8, 16])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_padded_rows_gauge_is_what_the_dispatch_pads_to(tile, seed):
+    """From the held experts' pair counts alone the gauge is ``_dispatch``'s
+    ``pad_end[-1]``, the rows the grouped products run."""
+    from cuda_mpi_gpu_cluster_programming_tpu.observability import metrics
+
+    cfg = _share(tile)
+    chosen = jax.random.randint(jax.random.key(seed), (50, cfg.num_experts_per_tok), 0, cfg.n_routed_experts)
+    _order, sizes, _start, _pad_start, pad_end, row = moe_share._dispatch(chosen, cfg)
+    stats = moe_share.set_routing_gauges([np.asarray(sizes)], chosen.shape[0], cfg)
+    assert stats[metrics.MOE_ROWS_PADDED] == float(pad_end[-1]) >= stats[metrics.MOE_PAIRS_HELD] > 0
+    assert int(row.max()) < int(pad_end[-1])
