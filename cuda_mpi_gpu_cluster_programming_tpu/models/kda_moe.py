@@ -32,6 +32,14 @@ norms, softmax, the router, the short convolution, the decays, ``beta``,
 l2norm and the scan's state are float32. Stored in float32, every product
 runs at HIGHEST.
 
+A KDA layer's ``q``, ``k`` and ``v`` are each made in one pass over the
+float32 projection by ``ops.kda_mix``'s kernel (the convolution's shifted
+terms, silu and the l2norm in VMEM, the stored type out) where the head's
+channels fill whole lanes and the sequence whole registers
+(``kda_mix.fits``: the published widths), and by the ``jax.numpy`` form
+(``_conv_mix_plain``: the kernel's oracle, the same terms in the same order)
+otherwise (the small preset's 16 channels a head).
+
 ``forward`` syncs nothing to the host. ``layer_statistics`` reads the routing
 and the decays back, outside any hot loop, into the metrics registry.
 """
@@ -47,13 +55,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..ops import scopes
+from ..ops import kda_mix, scopes
 from ..ops.flash_attention import flash_forward_bhld
 from ..ops.kda import kda_chunked
 from . import moe_share
 from .moe_share import Params, _mm, _moe, _rms_norm
 
-L2_EPS = 1e-6
+L2_EPS = kda_mix.L2_EPS
 # Marks of ``param_shapes`` beyond ``moe_share``'s: the decay's rate and its step
 A_LOG, DT_BIAS = -2, -3
 A_RANGE = (1.0, 16.0)  # A_log = log U(1, 16)
@@ -247,10 +255,29 @@ def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
 
 
+def _conv_mix_plain(x, taps, dtype, *, l2norm: bool):
+    """``silu(conv(x))``, l2-normalised where ``l2norm``, stored in ``dtype``,
+    in ``jax.numpy``: a float32 intermediate between its passes."""
+    y = jax.nn.silu(_short_conv(x, taps))
+    return (_l2norm(y) if l2norm else y).astype(dtype)
+
+
+def _conv_mix(x, taps, dtype, *, l2norm: bool):
+    """:func:`_conv_mix_plain` in one pass of ``ops.kda_mix``'s kernel over the
+    float32 projection where its shapes fit the kernel (the same terms in the
+    same order), else that form itself."""
+    if kda_mix.fits(x.shape[2], x.shape[3], taps.shape[0]):
+        return kda_mix.short_conv_mix(x, taps, l2norm=l2norm, out_dtype=dtype)
+    return _conv_mix_plain(x, taps, dtype, l2norm=l2norm)
+
+
 def _kda(p: Params, x, cfg: KdaMoeConfig):
     """``(x + KDA(RMSNorm(x)), g, beta)`` on the float32 residual stream
     ``(B, S, D)``: the layer's output, and the log decays and write strengths
-    it ran on (for the statistics; a caller that drops them pays nothing)."""
+    it ran on (for the statistics; a caller that drops them pays nothing).
+    The projections write float32; ``kda.mix`` reads each of q, k, v once
+    (``_conv_mix``) and writes it in the parameters' type, and makes the
+    decay and ``beta`` elementwise."""
     dt = p["q"].dtype
     with scopes.layer("kda.proj"):
         u = _rms_norm(x, p["attn_norm"], cfg.rms_norm_eps).astype(dt)
@@ -258,9 +285,9 @@ def _kda(p: Params, x, cfg: KdaMoeConfig):
         rate = _mm("bsr,rhe->bhse", _mm("bsd,dr->bsr", u, p["f_a"]), p["f_b"])
         write = jnp.swapaxes(_mm("bsd,dh->bsh", u, p["beta"]), 1, 2)  # (B, H, S), and small
     with scopes.layer("kda.mix"):
-        q = _l2norm(jax.nn.silu(_short_conv(q, p["conv_q"]))).astype(dt)
-        k = _l2norm(jax.nn.silu(_short_conv(k, p["conv_k"]))).astype(dt)
-        v = jax.nn.silu(_short_conv(v, p["conv_v"])).astype(dt)
+        q = _conv_mix(q, p["conv_q"], dt, l2norm=True)
+        k = _conv_mix(k, p["conv_k"], dt, l2norm=True)
+        v = _conv_mix(v, p["conv_v"], dt, l2norm=False)
         g = -jnp.exp(p["a_log"].astype(jnp.float32))[:, None, None] * jax.nn.softplus(
             rate + p["dt_bias"].astype(jnp.float32)[:, None, :]
         )
@@ -350,8 +377,10 @@ def layer_statistics(params: Params, ids, cfg: KdaMoeConfig = SMALL) -> Dict[str
     any hot loop) and fill the metrics registry: the four ``moe.*`` routing
     gauges (``moe_share.routing_statistics``), ``kda.chunk_log_decay_min``
     (the most negative log decay summed over one chunk, over every KDA layer,
-    head and channel: what no factor of the scan may exponentiate alone) and
-    ``kda.beta_mean``. Returns the six values."""
+    head and channel: what no factor of the scan may exponentiate alone),
+    ``kda.beta_mean`` and ``kda.mix_fused_layers`` (the KDA layers whose q, k
+    and v took ``ops.kda_mix``'s kernel at this batch's shapes: all of them or
+    none). Returns the seven values."""
     from ..observability import metrics
 
     run = jax.jit(functools.partial(_block_with_stats, cfg=cfg))
@@ -365,7 +394,11 @@ def layer_statistics(params: Params, ids, cfg: KdaMoeConfig = SMALL) -> Dict[str
 
     out = moe_share.routing_statistics(params, ids, cfg, block)
     decay_min, beta_mean = (np.min(kda, axis=0)[0], np.mean(kda, axis=0)[1]) if kda else (0.0, 0.0)
-    out.update({metrics.KDA_CHUNK_LOG_DECAY_MIN: float(decay_min), metrics.KDA_BETA_MEAN: float(beta_mean)})
+    fused = kda_mix.fits(ids.shape[1], cfg.linear_attn_head_dim, cfg.short_conv_kernel_size)
+    out.update({
+        metrics.KDA_CHUNK_LOG_DECAY_MIN: float(decay_min), metrics.KDA_BETA_MEAN: float(beta_mean),
+        metrics.KDA_MIX_FUSED_LAYERS: float(len(kda) if fused else 0),
+    })
     for name in metrics.KDA_GAUGES:
         metrics.registry().gauge(name).set(out[name])
     return out

@@ -50,11 +50,15 @@ MOE_ROUTING_GAUGES = (MOE_PAIRS_HELD, MOE_PAIRS_ALL, MOE_EXPERT_LOAD_MAX_OVER_ME
 # Gauges of the linear-attention layers (``models.kda_moe.layer_statistics``,
 # one batch, outside any hot loop): the most negative log decay summed over one
 # chunk of the scan, over every layer, head and channel (what the scan must
-# never exponentiate alone: float32 overflows past 88), and the mean write
-# strength ``beta``.
+# never exponentiate alone: float32 overflows past 88), the mean write
+# strength ``beta``, and the layers whose q, k and v were made by
+# ``ops.kda_mix``'s kernel (one pass over each projection) and not by the
+# ``jax.numpy`` form: a matter of the batch's shapes alone (``kda_mix.fits``),
+# so every linear-attention layer or none.
 KDA_CHUNK_LOG_DECAY_MIN = "kda.chunk_log_decay_min"
 KDA_BETA_MEAN = "kda.beta_mean"
-KDA_GAUGES = (KDA_CHUNK_LOG_DECAY_MIN, KDA_BETA_MEAN)
+KDA_MIX_FUSED_LAYERS = "kda.mix_fused_layers"
+KDA_GAUGES = (KDA_CHUNK_LOG_DECAY_MIN, KDA_BETA_MEAN, KDA_MIX_FUSED_LAYERS)
 # Gauges of the decoder whose router is an MLP over a state carried down the
 # depth (``models.cca_moe.layer_statistics``, one batch, outside any hot loop):
 # the tokens whose top-1 is the router's skip output over all routed tokens,

@@ -38,9 +38,10 @@ MLA_MOE_LAYERS = (
 # q/k/v/gate projections, the gate's sigmoid and product, the output
 # projection; ``gqa.attn`` the flash kernel. ``kda.proj``: the norm, the q/k/v
 # projections, both low-rank pairs, the write strength's projection, the output
-# norm, gate and projection; ``kda.mix`` the short convolution, silu, l2norm,
-# the decay's softplus and the write strength's sigmoid (elementwise);
-# ``kda.scan`` the chunked scan's kernel.
+# norm, gate and projection; ``kda.mix`` the short convolution, silu and l2norm
+# of q, k and v (``ops.kda_mix``'s kernel, one call an array, where the shapes
+# fit it; elementwise passes otherwise), the decay's softplus and the write
+# strength's sigmoid (elementwise); ``kda.scan`` the chunked scan's kernel.
 KDA_MOE_LAYERS = (
     "embed", "gqa.proj", "gqa.attn", "kda.proj", "kda.mix", "kda.scan",
     "moe.route", "moe.experts", "moe.shared", "head",

@@ -224,6 +224,9 @@ def test_layer_statistics_fill_the_gauges_and_agree_with_the_reference():
     assert stats["moe.pairs_all"] == ids.size * SMALL.num_experts_per_tok * SMALL.num_layers
     # the decays summed over a chunk of 16: by the reference's own g of the three linear layers
     assert stats["kda.chunk_log_decay_min"] < -1.0 and 0.5 < stats["kda.beta_mean"] < 1.5
+    # 16 channels a head fill no lane: every layer's mix takes the jax.numpy form (at 128 channels
+    # the three linear layers take the kernel: tests/test_kda_mix.py)
+    assert stats["kda.mix_fused_layers"] == 0
     summary = metrics.registry().summary()
     assert {name: summary[name] for name in metrics.MOE_ROUTING_GAUGES + metrics.KDA_GAUGES} == stats
 
