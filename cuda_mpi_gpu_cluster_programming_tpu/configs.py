@@ -43,7 +43,7 @@ class ExecConfig:
     tier: str  # "reference" (XLA ops) | "pallas"
     strategy: str  # "single" | "replicated" | "halo" | "staged_halo"
     description: str
-    model: str = "blocks12"  # "blocks12" | "alexnet_full" | "mla_moe" | "kda_moe" | "cca_moe"
+    model: str = "blocks12"  # "blocks12" | "alexnet_full" | "mla_moe" | "kda_moe" | "cca_moe" | "scmoe_mla"
 
 
 REGISTRY: Dict[str, ExecConfig] = {
@@ -159,12 +159,24 @@ REGISTRY: Dict[str, ExecConfig] = {
             "device, XLA ops with the flash-attention and grouped-matmul kernels",
             model="cca_moe",
         ),
+        ExecConfig(
+            "v11_scmoe_mla",
+            "V11 ScMoE-MLA Share",
+            "reference",
+            "single",
+            "shortcut-connected MoE decoder over latent attention (a layer is two MLA + dense-FFN "
+            "sublayers with the MoE as a branch from the first to the layer's end; a softmax router "
+            "over the experts and zero-computation identity experts) as one expert-parallel chip "
+            "holds it: one scan over its layers, single device, XLA ops with the flash-attention, "
+            "grouped-matmul and combine kernels",
+            model="scmoe_mla",
+        ),
     ]
 }
 
 # The language-model families: token ids in, logits out, parameters stored in
 # the compute type. ``ExecConfig.model`` names the module under ``models``.
-LANGUAGE_MODELS = ("mla_moe", "kda_moe", "cca_moe")
+LANGUAGE_MODELS = ("mla_moe", "kda_moe", "cca_moe", "scmoe_mla")
 
 
 def language_model(exec_cfg: ExecConfig):

@@ -54,7 +54,6 @@ rotary tables are float32. ``forward`` syncs nothing to the host.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Callable, Dict, Optional
 
 import jax
@@ -185,10 +184,9 @@ def param_shapes(cfg: CcaMoeConfig) -> Params:
         "experts": experts,
         "moe_merge": merge(),
     }
-    stacked = jax.tree.map(
-        lambda leaf: ((cfg.num_layers, *leaf[0]), leaf[1]), layer, is_leaf=moe_share._is_leaf
-    )
-    return {"embed": ((cfg.vocab_size, d), 1), "layers": stacked, "final_norm": ((d,), 0)}
+    return {
+        "embed": ((cfg.vocab_size, d), 1), "layers": moe_share.stacked(layer, cfg.num_layers), "final_norm": ((d,), 0),
+    }
 
 
 def _draw_leaf(key, shape, fan_in, dtype):
@@ -209,20 +207,8 @@ def init(key, cfg: CcaMoeConfig = SMALL, dtype=jnp.bfloat16) -> Params:
     grows by half again every layer, and some fifteen layers down every
     token of a sequence is the same vector, which a router then sends to
     one or two experts whatever its bias. The layers are drawn one at a
-    time inside one program (``lax.map`` over the layers' keys, each straight
-    into its place in the stack), so the draw's peak is the stack and one
-    layer, never two models."""
-    shapes = dict(param_shapes(cfg))
-    one_layer = jax.tree.map(
-        lambda leaf: (leaf[0][1:], leaf[1]), shapes.pop("layers"), is_leaf=moe_share._is_leaf
-    )
-    draw = functools.partial(moe_share._draw_tree, dtype=dtype, draw_leaf=_draw_leaf)
-    k_rest, k_layers = jax.random.split(key)
-    params = jax.jit(functools.partial(draw, shapes=shapes))(k_rest)
-    params["layers"] = jax.jit(
-        lambda keys: lax.map(functools.partial(draw, shapes=one_layer), keys)
-    )(jax.random.split(k_layers, cfg.num_layers))
-    return params
+    time, straight into the stack (``moe_share.init_stacked``)."""
+    return moe_share.init_stacked(key, param_shapes(cfg), cfg.num_layers, dtype, _draw_leaf)
 
 
 def param_count(cfg: CcaMoeConfig) -> int:

@@ -193,24 +193,29 @@ def _rope_halves(a, b, cos, sin):
 # ---------------------------------------------------------------------------
 
 
+def mla_shapes(cfg) -> Params:
+    """What ``_mla`` reads of a layer, as ``(shape, fan_in)`` leaves."""
+    d, h = cfg.hidden_size, cfg.num_attention_heads
+    return {
+        "attn_norm": ((d,), 0),
+        "q_a": ((d, cfg.q_lora_rank), d),
+        "q_norm": ((cfg.q_lora_rank,), 0),
+        "q_b": ((cfg.q_lora_rank, h, cfg.qk_head_dim), cfg.q_lora_rank),
+        "kv_a": ((d, cfg.kv_lora_rank + cfg.qk_rope_head_dim), d),
+        "kv_norm": ((cfg.kv_lora_rank,), 0),
+        "kv_b": ((cfg.kv_lora_rank, h, cfg.qk_nope_head_dim + cfg.v_head_dim), cfg.kv_lora_rank),
+        "o": ((h, cfg.v_head_dim, d), h * cfg.v_head_dim),
+    }
+
+
 def param_shapes(cfg: MlaMoeConfig) -> Params:
     """The parameter tree as ``(shape, fan_in)`` leaves; ``fan_in`` 0 marks a
     norm gain (drawn as 1) and -1 the router's selection bias."""
-    d, h = cfg.hidden_size, cfg.num_attention_heads
+    d = cfg.hidden_size
     f, fe = cfg.intermediate_size, cfg.moe_intermediate_size
 
     def layer(kind: str) -> Params:
-        out = {
-            "attn_norm": ((d,), 0),
-            "q_a": ((d, cfg.q_lora_rank), d),
-            "q_norm": ((cfg.q_lora_rank,), 0),
-            "q_b": ((cfg.q_lora_rank, h, cfg.qk_head_dim), cfg.q_lora_rank),
-            "kv_a": ((d, cfg.kv_lora_rank + cfg.qk_rope_head_dim), d),
-            "kv_norm": ((cfg.kv_lora_rank,), 0),
-            "kv_b": ((cfg.kv_lora_rank, h, cfg.qk_nope_head_dim + cfg.v_head_dim), cfg.kv_lora_rank),
-            "o": ((h, cfg.v_head_dim, d), h * cfg.v_head_dim),
-            "ffn_norm": ((d,), 0),
-        }
+        out = {**mla_shapes(cfg), "ffn_norm": ((d,), 0)}
         if kind == "dense":
             out["mlp"] = moe_share.swiglu_shapes(d, f)
         else:
@@ -241,13 +246,19 @@ def param_count(cfg: MlaMoeConfig) -> int:
 # ---------------------------------------------------------------------------
 
 
-def _mla(p: Params, x, cfg: MlaMoeConfig):
-    """``x + MLA(RMSNorm(x))`` on the float32 residual stream ``(B, S, D)``."""
+def _mla(p: Params, x, cfg: MlaMoeConfig, q_scale: float = 1.0, kv_scale: float = 1.0):
+    """``x + MLA(RMSNorm(x))`` on the float32 residual stream ``(B, S, D)``.
+    ``q_scale`` and ``kv_scale`` multiply the two normed latents ``c_q`` and
+    ``c_kv`` (so queries, and keys' nope part and values; not ``k_r``) for a
+    family that scales them by ``sqrt(hidden / rank)``; at 1 nothing is
+    multiplied and the program built is the one built without them."""
     dt = p["q_a"].dtype
     seq = x.shape[1]
     with scopes.layer("mla.proj"):
         u = _rms_norm(x, p["attn_norm"], cfg.rms_norm_eps).astype(dt)
         c_q = _rms_norm(_mm("bsd,dr->bsr", u, p["q_a"]), p["q_norm"], cfg.rms_norm_eps)
+        if q_scale != 1.0:
+            c_q = c_q * q_scale
         # The nope and rope parts, and keys and values, come from slices of
         # the (small) weights, not of the (large) activations; the rope
         # columns' evens and odds likewise, so that the rotation runs on two
@@ -258,6 +269,8 @@ def _mla(p: Params, x, cfg: MlaMoeConfig):
         q_nope = _mm("bsr,rhe->bhse", c_q, p["q_b"][..., :nope]).astype(dt)
         kv_a = _mm("bsd,dr->bsr", u, p["kv_a"])  # (B, S, kv_lora + rope)
         c_kv = _rms_norm(kv_a[..., :rank], p["kv_norm"], cfg.rms_norm_eps)
+        if kv_scale != 1.0:
+            c_kv = c_kv * kv_scale
         k_nope = _mm("bsr,rhe->bhse", c_kv, p["kv_b"][..., :nope]).astype(dt)
         v = _mm("bsr,rhe->bhse", c_kv, p["kv_b"][..., nope:]).astype(dt)
         cos, sin = (table.T for table in _rope_tables(cfg, seq))

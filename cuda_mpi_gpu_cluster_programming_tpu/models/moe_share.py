@@ -125,6 +125,9 @@ def _is_leaf(node) -> bool:
 # The selection bias is drawn small: enough that selection (biased) and
 # weighting (unbiased) differ, little enough that it unbalances no expert's
 # load by more than a tenth (a trained bias is there to balance the load).
+# The scale is a SIGMOID router's, whose scores lie about 0.5; a softmax
+# score over hundreds of outputs is a hundred times smaller, and a family
+# with such a router draws its bias at a scale of its own (``draw_leaf``).
 BIAS_SCALE = 0.005
 
 
@@ -162,6 +165,28 @@ def init_by_layer(key, shapes: Params, kinds: Sequence[str], dtype, draw_leaf=_d
     params = jax.jit(functools.partial(_draw_tree, shapes=shapes, dtype=dtype, draw_leaf=draw_leaf))(keys[0])
     params["layers"] = [draw[kind](k) for kind, k in zip(kinds, keys[1:])]
     return params
+
+
+def init_stacked(key, shapes: Params, num_layers: int, dtype, draw_leaf=_draw_leaf) -> Params:
+    """As :func:`init_by_layer` for a tree whose ``"layers"`` is ONE layer's
+    tree with the layers as every leaf's first axis (what a ``lax.scan`` over
+    the depth takes): the layers are drawn one at a time inside one program
+    (``lax.map`` over the layers' keys, each straight into its place in the
+    stack), so the draw's peak is the stack and one layer, never two models."""
+    shapes = dict(shapes)
+    one_layer = jax.tree.map(lambda leaf: (leaf[0][1:], leaf[1]), shapes.pop("layers"), is_leaf=_is_leaf)
+    draw = functools.partial(_draw_tree, dtype=dtype, draw_leaf=draw_leaf)
+    k_rest, k_layers = jax.random.split(key)
+    params = jax.jit(functools.partial(draw, shapes=shapes))(k_rest)
+    params["layers"] = jax.jit(
+        lambda keys: lax.map(functools.partial(draw, shapes=one_layer), keys)
+    )(jax.random.split(k_layers, num_layers))
+    return params
+
+
+def stacked(layer: Params, num_layers: int) -> Params:
+    """One layer's ``(shape, fan_in)`` tree with ``num_layers`` as every leaf's first axis."""
+    return jax.tree.map(lambda leaf: ((num_layers, *leaf[0]), leaf[1]), layer, is_leaf=_is_leaf)
 
 
 def count(shapes: Params) -> int:
@@ -208,6 +233,19 @@ def route(p: Params, u, cfg: MoeShareConfig):
     _top, chosen = lax.top_k(candidates, cfg.num_experts_per_tok)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling_factor
+    return chosen.astype(jnp.int32), weights
+
+
+def route_softmax(p: Params, u, cfg):
+    """``(chosen outputs (T, k) int32, their weights (T, k) float32)`` of the
+    tokens ``u (T, D)`` for a router that is a softmax over all its outputs
+    (``p["router"]``'s columns: experts, and whatever else the family routes
+    to), no groups: the top ``num_experts_per_tok`` of ``scores + bias``,
+    weighted by their unbiased scores times ``routed_scaling_factor``, not
+    renormalised."""
+    scores = jax.nn.softmax(_mm("td,de->te", u, p["router"]), axis=-1)
+    _top, chosen = lax.top_k(scores + p["bias"].astype(jnp.float32), cfg.num_experts_per_tok)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1) * cfg.routed_scaling_factor
     return chosen.astype(jnp.int32), weights
 
 

@@ -67,6 +67,15 @@ KDA_GAUGES = (KDA_CHUNK_LOG_DECAY_MIN, KDA_BETA_MEAN, KDA_MIX_FUSED_LAYERS)
 MOE_SKIP_SHARE = "moe.skip_share"
 ROUTER_STATE_RMS_LAST = "router.state_rms_last"
 CCA_GAUGES = (MOE_SKIP_SHARE, ROUTER_STATE_RMS_LAST)
+# Gauges of the decoder whose router also chooses among zero-computation
+# (identity) experts (``models.scmoe_mla.routing_statistics``, one batch,
+# outside any hot loop): the chosen places that are identity experts over all
+# places, every layer together, and the most and the fewest real experts one
+# token ran in one layer (how far compute per token varies).
+MOE_ZERO_PAIR_SHARE = "moe.zero_pair_share"
+MOE_REAL_EXPERTS_PER_TOKEN_MAX = "moe.real_experts_per_token_max"
+MOE_REAL_EXPERTS_PER_TOKEN_MIN = "moe.real_experts_per_token_min"
+SCMOE_GAUGES = (MOE_ZERO_PAIR_SHARE, MOE_REAL_EXPERTS_PER_TOKEN_MAX, MOE_REAL_EXPERTS_PER_TOKEN_MIN)
 
 # Prometheus metric-name grammar: [a-zA-Z_:][a-zA-Z0-9_:]* — the dotted
 # registry names ("serve.ok") sanitize to underscores ("serve_ok").

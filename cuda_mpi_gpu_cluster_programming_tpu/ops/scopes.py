@@ -60,10 +60,23 @@ KDA_MOE_LAYERS = (
 CCA_MOE_LAYERS = (
     "embed", "layer_loop", "cca.proj", "cca.mix", "cca.attn", "moe.route", "moe.experts", "head",
 )
+# The shortcut-connected mixture-of-experts decoder over latent attention
+# (``models.scmoe_mla``): a layer is two sublayers of ``mla.proj``, ``mla.attn``
+# and ``dense_mlp`` with the MoE as a branch beside them. ``moe.route`` holds
+# the norm after the first attention, the softmax router over the experts and
+# the identity experts, the top-k, then the sort; ``moe.experts`` the routed
+# sum over the held pairs; ``moe.zero`` the identity experts' term (the chosen
+# identities' weights summed, times the token's normed input) and its sum with
+# the routed part: the branch as the layer's end takes it. ``dense_mlp`` also
+# holds that last addition. ``layer_loop`` as above.
+SCMOE_MLA_LAYERS = (
+    "embed", "layer_loop", "mla.proj", "mla.attn", "dense_mlp", "moe.route", "moe.experts", "moe.zero", "head",
+)
 LAYERS = (
     BLOCKS12_LAYERS + ALEXNET_TAIL_LAYERS + FC_LAYERS + MLA_MOE_LAYERS
     + tuple(name for name in KDA_MOE_LAYERS if name not in MLA_MOE_LAYERS)
     + tuple(name for name in CCA_MOE_LAYERS if name not in MLA_MOE_LAYERS)
+    + ("moe.zero",)
 )
 
 # A second, nested level: the phases of a layer, which stand only inside that

@@ -41,13 +41,14 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--seed", type=int, default=0)
     p.add_argument(
         "--preset",
-        choices=["small", "ep16_share", "solar_ep8", "zaya1_ep2"],
+        choices=["small", "ep16_share", "solar_ep8", "zaya1_ep2", "longcat_ep32"],
         default="small",
         help="language-model configs only (the PRESETS of the config's model module, "
         "models.<ExecConfig.model>): small = the CPU tests' size; the published widths "
         "as one expert-parallel chip holds them, at the benchmark's shape: ep16_share "
         "(v8_mla_moe: one of 16 chips, 2 x 4,096 tokens), solar_ep8 (v9_kda_moe: one of "
-        "8 chips, 2 x 8,192 tokens), zaya1_ep2 (v10_cca_moe: one of 2 chips, 1 x 4,096 tokens)",
+        "8 chips, 2 x 8,192 tokens), zaya1_ep2 (v10_cca_moe: one of 2 chips, 1 x 4,096 tokens), "
+        "longcat_ep32 (v11_scmoe_mla: one of 32 chips, 2 x 4,096 tokens)",
     )
     p.add_argument("--repeats", type=int, default=10, help="fenced passes for amortized timing")
     p.add_argument(

@@ -82,6 +82,10 @@ def test_every_layer_of_the_chain_is_named_in_the_compiled_program(key):
         names = scopes.CCA_MOE_LAYERS
         chain = names[:1] + names[3:4] + names[1:3] + names[4:]
         assert sorted(chain) == sorted(names)
+    if exec_cfg.model == "scmoe_mla":  # the MoE branches off before the first dense FFN; the branch's sum ends it
+        names = scopes.SCMOE_MLA_LAYERS
+        chain = names[:4] + names[5:8] + names[4:5] + names[8:]
+        assert sorted(chain) == sorted(names)
     for layer in chain:
         assert _scoped(paths, layer), f"{key}: no operation under the scope {layer!r}"
     # in order: the jaxpr is the program as written, before any scheduling
@@ -147,6 +151,7 @@ def test_a_kernel_that_covers_conv_and_pool_says_so():
         ("v8_mla_moe", scopes.MLA_MOE_LAYERS),
         ("v9_kda_moe", scopes.KDA_MOE_LAYERS),
         ("v10_cca_moe", scopes.CCA_MOE_LAYERS),
+        ("v11_scmoe_mla", scopes.SCMOE_MLA_LAYERS),
     ],
 )
 def test_token_ids_and_parameters_stored_in_bf16_pass_the_bf16_wrapper_uncast(key, layers):
@@ -162,7 +167,7 @@ def test_token_ids_and_parameters_stored_in_bf16_pass_the_bf16_wrapper_uncast(ke
     assert str(jaxpr.jaxpr.invars[-1].aval.dtype) == "int32"
 
 
-LANGUAGE_KEYS = ("v8_mla_moe", "v9_kda_moe", "v10_cca_moe")
+LANGUAGE_KEYS = ("v8_mla_moe", "v9_kda_moe", "v10_cca_moe", "v11_scmoe_mla")
 
 
 @pytest.mark.parametrize("combine", ["gathers", "kernel"])
