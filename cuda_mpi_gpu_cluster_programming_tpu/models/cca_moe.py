@@ -430,7 +430,8 @@ def layer_statistics(params: Params, ids, cfg: CcaMoeConfig = SMALL) -> Dict[str
     1), ``moe.skip_share`` (the tokens whose top-1 is the skip output over all
     routed tokens, every layer together) and ``router.state_rms_last`` (the
     rms of the last layer's ``r``: what the additions of the carried state
-    come to). Returns the six values."""
+    come to), and ``flash.masked_score_share``
+    (``moe_share.set_attention_gauge``). Returns the seven values."""
     from ..observability import metrics
 
     run = jax.jit(lambda p, i: _layers(p, i, cfg, with_routing=True)[1:])
@@ -440,4 +441,5 @@ def layer_statistics(params: Params, ids, cfg: CcaMoeConfig = SMALL) -> Dict[str
     out[metrics.ROUTER_STATE_RMS_LAST] = float(np.sqrt(np.mean(np.square(np.asarray(r, np.float64)))))
     for name in metrics.CCA_GAUGES:
         metrics.registry().gauge(name).set(out[name])
+    out.update(moe_share.set_attention_gauge(ids.shape[1], cfg.attn_block))
     return out

@@ -380,7 +380,8 @@ def layer_statistics(params: Params, ids, cfg: KdaMoeConfig = SMALL) -> Dict[str
     head and channel: what no factor of the scan may exponentiate alone),
     ``kda.beta_mean`` and ``kda.mix_fused_layers`` (the KDA layers whose q, k
     and v took ``ops.kda_mix``'s kernel at this batch's shapes: all of them or
-    none). Returns the seven values."""
+    none), and the softmax layers' ``flash.masked_score_share``
+    (``moe_share.set_attention_gauge``). Returns the eight values."""
     from ..observability import metrics
 
     run = jax.jit(functools.partial(_block_with_stats, cfg=cfg))
@@ -401,4 +402,5 @@ def layer_statistics(params: Params, ids, cfg: KdaMoeConfig = SMALL) -> Dict[str
     })
     for name in metrics.KDA_GAUGES:
         metrics.registry().gauge(name).set(out[name])
+    out.update(moe_share.set_attention_gauge(ids.shape[1], cfg.attn_block))
     return out

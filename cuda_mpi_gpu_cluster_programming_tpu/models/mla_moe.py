@@ -324,6 +324,9 @@ def forward(params: Params, ids, cfg: MlaMoeConfig = SMALL):
 def routing_statistics(params: Params, ids, cfg: MlaMoeConfig = SMALL) -> Dict[str, float]:
     """Route ``ids`` layer by layer (one jitted program per kind of layer,
     outside any hot loop) and fill the metrics registry's four ``moe.*``
-    gauges (``moe_share.routing_statistics``). Returns the four values."""
+    gauges (``moe_share.routing_statistics``) and ``flash.masked_score_share``
+    (``moe_share.set_attention_gauge``). Returns the five values."""
     block = jax.jit(functools.partial(_block, cfg=cfg, with_sizes=True))
-    return moe_share.routing_statistics(params, ids, cfg, block)
+    out = moe_share.routing_statistics(params, ids, cfg, block)
+    out.update(moe_share.set_attention_gauge(ids.shape[1], cfg.attn_block))
+    return out

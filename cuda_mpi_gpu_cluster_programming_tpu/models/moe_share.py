@@ -44,6 +44,7 @@ import numpy as np
 from jax import lax
 
 from ..ops import scopes
+from ..ops.flash_attention import causal_plan
 from ..ops.grouped_matmul import grouped_matmul
 from ..ops.moe_combine import moe_combine, moe_combine_reference, row_slab, worth_a_kernel
 from ..ops.reference import mxu_precision
@@ -453,3 +454,15 @@ def set_routing_gauges(loads: Sequence[np.ndarray], tokens: int, cfg: RoutedShar
     for name, value in stats.items():
         metrics.registry().gauge(name).set(value)
     return stats
+
+
+def set_attention_gauge(seq_len: int, attn_block: int) -> Dict[str, float]:
+    """Fill ``flash.masked_score_share`` — of the scores ``flash_fwd`` computes
+    for one head of ``seq_len`` tokens at blocks of ``attn_block``, the share
+    the causal mask throws away (``flash_attention.causal_plan``: the shapes
+    alone decide it) — and return it under its name."""
+    from ..observability import metrics
+
+    share = causal_plan(seq_len, attn_block, attn_block).masked_score_share
+    metrics.registry().gauge(metrics.FLASH_MASKED_SCORE_SHARE).set(share)
+    return {metrics.FLASH_MASKED_SCORE_SHARE: share}

@@ -310,8 +310,9 @@ def routing_statistics(params: Params, ids, cfg: ScmoeMlaConfig = SMALL) -> Dict
     the identity experts' too), ``moe.zero_pair_share`` (the chosen places
     that are identity experts over all places, every layer together) and
     ``moe.real_experts_per_token_max`` / ``_min`` (the most and the fewest real
-    experts one token ran in one layer: how far compute per token varies).
-    Returns the seven values."""
+    experts one token ran in one layer: how far compute per token varies),
+    and ``flash.masked_score_share`` (``moe_share.set_attention_gauge``).
+    Returns the eight values."""
     from ..observability import metrics
 
     chosen, sizes = jax.jit(lambda p, i: _layers(p, i, cfg, with_routing=True)[1])(params, ids)
@@ -323,4 +324,5 @@ def routing_statistics(params: Params, ids, cfg: ScmoeMlaConfig = SMALL) -> Dict
     out[metrics.MOE_REAL_EXPERTS_PER_TOKEN_MIN] = float(per_token.min())
     for name in metrics.SCMOE_GAUGES:
         metrics.registry().gauge(name).set(out[name])
+    out.update(moe_share.set_attention_gauge(ids.shape[1], cfg.attn_block))
     return out

@@ -76,6 +76,13 @@ MOE_ZERO_PAIR_SHARE = "moe.zero_pair_share"
 MOE_REAL_EXPERTS_PER_TOKEN_MAX = "moe.real_experts_per_token_max"
 MOE_REAL_EXPERTS_PER_TOKEN_MIN = "moe.real_experts_per_token_min"
 SCMOE_GAUGES = (MOE_ZERO_PAIR_SHARE, MOE_REAL_EXPERTS_PER_TOKEN_MAX, MOE_REAL_EXPERTS_PER_TOKEN_MIN)
+# Gauge of the causal attention kernel (``models.moe_share.set_attention_gauge``,
+# beside the routing gauges of every decoder family that calls ``flash_fwd``,
+# outside any hot loop): of the scores the forward computes for one head, the
+# share the mask then throws away. A matter of the sequence length and the
+# blocks alone (``ops.flash_attention.causal_plan``): what is left of the
+# blocks the diagonal crosses once they are computed in row slabs.
+FLASH_MASKED_SCORE_SHARE = "flash.masked_score_share"
 
 # Prometheus metric-name grammar: [a-zA-Z_:][a-zA-Z0-9_:]* — the dotted
 # registry names ("serve.ok") sanitize to underscores ("serve_ok").

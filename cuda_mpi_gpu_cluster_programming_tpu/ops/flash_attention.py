@@ -4,12 +4,16 @@ The hot-op counterpart of ``ops.attention.attention`` (which materializes
 the full (L, L) score matrix in HBM). Forward and backward are both O(L)
 memory:
 
-- **Forward**: grid (batch, head, q-block, k-block) — K/V are *streamed
-  through VMEM one block at a time by the grid* (only (block, D) tiles are
-  ever resident, not whole-L), carrying the numerically-stable running
-  (max, numerator, denominator) in VMEM scratch across the k-block axis.
-  QK^T and PV ride the MXU with fp32 accumulation. The forward also emits
-  the per-row log-sum-exp (LSE) for the backward.
+- **Forward**: grid (batch, head, pair) — one step per (q-block, k-block)
+  pair that contributes, q-block-major (causal: the pairs at or below the
+  diagonal and no others). K/V are *streamed through VMEM one block at a
+  time by the grid* (only (block, D) tiles are ever resident, not
+  whole-L), carrying the numerically-stable running (max, numerator,
+  denominator) in VMEM scratch across a q-block's pairs. QK^T and PV ride
+  the MXU with fp32 accumulation. A block the diagonal crosses corner to
+  corner is computed in row slabs, each against the keys up to its own
+  last row (``causal_plan``). The forward also emits the per-row
+  log-sum-exp (LSE) for the backward.
 - **Backward**: FlashAttention-2-style recompute — no residual score
   matrix. Two kernels: dQ (stream K/V per q-block) and dK/dV (stream Q/dO
   per k-block), each recomputing the normalized probabilities from Q, K and
@@ -27,9 +31,11 @@ exercises the identical code path (tests/test_flash_attention.py).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -54,24 +60,36 @@ def _causal_mask(s, qi, ki, bq, bk):
 # ---------------------------------------------------------------------------
 
 
-def _fwd_kernel(*refs, bq, bk, causal, scale, rope):
-    """One (batch, head, q-block, k-block) program.
+def _slab_mask(s, row0):
+    """The mask of rows ``row0 ...`` of a block on the diagonal against its
+    keys from the first on (where the block's first query is its first key)."""
+    rows = row0 + lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    keys = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return jnp.where(rows >= keys, s, NEG_INF)
+
+
+def _fwd_kernel(qi_ref, ki_ref, *refs, bq, bk, nk, causal, scale, rope, slab):
+    """One (batch, head, pair) program: k-block ``ki_ref[pair]`` against
+    q-block ``qi_ref[pair]`` (the two scalar-prefetched tables of the pairs
+    that contribute, q-block-major).
 
     q_ref: (1, 1, bq, D); k_ref: (1, 1, bk, D); v_ref: (1, 1, bk, Dv) — ONE
     k/v block, indexed by the grid (streaming); the value width may differ
     from the query/key width. With ``rope`` two more operands follow them,
     sequence-minor: qr_ref (1, 1, R, bq) and kr_ref (1, R, bk), the part of
     the queries and keys that is one key for all heads; a score is then the
-    sum of the two products. Running stats live in VMEM scratch across the
-    k-block grid axis (sequential on TPU and in interpret mode).
+    sum of the two products. Running stats live in VMEM scratch across a
+    q-block's pairs (the grid is sequential on TPU and in interpret mode).
+    ``slab`` < bq: a block on the diagonal is computed in row slabs of that
+    height.
     """
     q_ref, k_ref, v_ref, *rest = refs
     if rope:
         qr_ref, kr_ref, *rest = rest
     o_ref, lse_ref, m_sc, den_sc, acc_sc = rest
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    pair = pl.program_id(2)
+    qi = qi_ref[pair]
+    ki = ki_ref[pair]
 
     @pl.when(ki == 0)
     def _init():
@@ -79,60 +97,129 @@ def _fwd_kernel(*refs, bq, bk, causal, scale, rope):
         den_sc[...] = jnp.zeros_like(den_sc)
         acc_sc[...] = jnp.zeros_like(acc_sc)
 
-    # Causal: blocks strictly above the diagonal contribute nothing — skip
-    # the math (the grid still visits them; pl.when skips the compute). Only
-    # the blocks the diagonal crosses need the mask: a block wholly below it
-    # skips the iota, the compare and the select, which the VPU pays per score.
-    contributes = (not causal) or ((qi + 1) * bq - 1 >= ki * bk)
-    crossed = (ki + 1) * bk - 1 > qi * bq  # some key of the block lies after some query
-
-    def update(masked: bool):
-        # Operands go to the MXU in the type they are stored in (bf16 stays
-        # bf16: one pass; float32 runs at HIGHEST), accumulation is float32,
-        # and the scale is applied to the float32 scores.
-        q = q_ref[0, 0]  # (bq, D)
-        k_blk = k_ref[0, 0]  # (bk, D)
-        v_blk = v_ref[0, 0]  # (bk, Dv)
+    def update(*pieces):
+        # ``pieces``: the static (rows, keys, mask) parts of the block this
+        # step computes — the whole block, or the row slabs of a block on the
+        # diagonal, each against the keys up to its own last row, so that no
+        # row is touched twice; ``mask`` is applied to a piece's scores, or is
+        # None. Operands go to the MXU in the type they are
+        # stored in (bf16 stays bf16: one pass; float32 runs at HIGHEST),
+        # accumulation is float32, and the scale is applied to the float32
+        # scores. Every piece's products stand before any piece's softmax:
+        # the MXU runs the next slab's scores under this slab's vector work.
         prec = mxu_precision(q_ref.dtype)
 
-        def qk(a, b, over):  # contract axis ``over`` of both -> (bq, bk)
+        def qk(a, b, over):  # contract axis ``over`` of both -> (rows, keys)
             return lax.dot_general(
                 a, b, (((over,), (over,)), ((), ())),
                 preferred_element_type=jnp.float32, precision=prec,
             )
 
-        s = qk(q, k_blk, 1)
-        if rope:
-            s = s + qk(qr_ref[0, 0], kr_ref[0], 0)  # (R, bq) x (R, bk)
-        s = scale * s
-        if masked:
-            s = _causal_mask(s, qi, ki, bq, bk)
-        m_prev = m_sc[:, 0]  # (bq,)
-        den_prev = den_sc[:, 0]
-        blk_max = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, blk_max)
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])  # (bq, bk), float32
-        acc_sc[...] = acc_sc[...] * corr[:, None] + lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=prec,
-        )
-        den_new = den_prev * corr + jnp.sum(p, axis=-1)
-        m_sc[...] = jnp.broadcast_to(m_new[:, None], m_sc.shape)
-        den_sc[...] = jnp.broadcast_to(den_new[:, None], den_sc.shape)
+        scored = []
+        for rows, keys, mask in pieces:
+            q = q_ref[0, 0, rows, :]  # (rows, D)
+            k_blk = k_ref[0, 0, keys, :]  # (keys, D)
+            v_blk = v_ref[0, 0, keys, :]  # (keys, Dv)
+            s = qk(q, k_blk, 1)
+            if rope:
+                s = s + qk(qr_ref[0, 0, :, rows], kr_ref[0, :, keys], 0)  # (R, rows) x (R, keys)
+            s = scale * s
+            if mask is not None:
+                s = mask(s)
+            scored.append((rows, s, v_blk))
+        for rows, s, v_blk in scored:
+            m_prev = m_sc[rows, 0]  # (rows,)
+            den_prev = den_sc[rows, 0]
+            blk_max = jnp.max(s, axis=-1)
+            m_new = jnp.maximum(m_prev, blk_max)
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, None])  # (rows, keys), float32
+            acc_sc[rows, :] = acc_sc[rows, :] * corr[:, None] + lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=prec,
+            )
+            den_new = den_prev * corr + jnp.sum(p, axis=-1)
+            stat = (s.shape[0], m_sc.shape[1])
+            m_sc[rows, :] = jnp.broadcast_to(m_new[:, None], stat)
+            den_sc[rows, :] = jnp.broadcast_to(den_new[:, None], stat)
 
-    if causal:
-        pl.when(contributes & crossed)(lambda: update(True))
-        pl.when(contributes & jnp.logical_not(crossed))(lambda: update(False))
+    # Causal: the grid holds no pair above the diagonal. Only the blocks the
+    # diagonal crosses need the mask: a block wholly below it skips the iota,
+    # the compare and the select, which the VPU pays per score.
+    whole = lambda mask=None: update((slice(0, bq), slice(0, bk), mask))
+    if not causal:
+        whole()
+    elif slab < bq:  # square blocks: the diagonal crosses (qi, qi) corner to corner, and no other
+        slabs = [
+            (slice(r, r + slab), slice(0, r + slab), functools.partial(_slab_mask, row0=r)) for r in range(0, bq, slab)
+        ]
+        pl.when(ki == qi)(lambda: update(*slabs))
+        pl.when(ki < qi)(whole)
     else:
-        update(False)
+        crossed = (ki + 1) * bk - 1 > qi * bq  # some key of the block lies after some query
+        pl.when(crossed)(lambda: whole(lambda s: _causal_mask(s, qi, ki, bq, bk)))
+        pl.when(jnp.logical_not(crossed))(whole)
 
-    @pl.when(ki == nk - 1)
+    last = jnp.minimum(nk - 1, ((qi + 1) * bq - 1) // bk) if causal else nk - 1
+
+    @pl.when(ki == last)
     def _finalize():
         m = m_sc[:, 0]
         den = jnp.maximum(den_sc[:, 0], 1e-30)
         o_ref[0, 0] = (acc_sc[...] / den[:, None]).astype(o_ref.dtype)
         lse_ref[0, 0, 0] = m + jnp.log(den)
+
+
+def _pairs(l, bq, bk, causal):
+    """The (q-block, k-block) pairs that contribute, q-block-major: all of
+    them, or causal those with a key at or before the block's last query."""
+    return [
+        (qi, ki) for qi in range(l // bq) for ki in range(l // bk) if not causal or (qi + 1) * bq - 1 >= ki * bk
+    ]
+
+
+class CausalPlan(NamedTuple):
+    """What the causal forward does for ONE head of ``l`` tokens."""
+
+    pairs: int  # (q-block, k-block) pairs computed
+    diag_slab: int  # rows of the slabs a block on the diagonal is computed in (block_q: whole)
+    scores_computed: int
+    scores_kept: int  # the scores the mask leaves: l (l + 1) / 2
+
+    @property
+    def grid_steps(self) -> int:
+        """Steps of the grid: one per pair computed, none for a pair the mask empties."""
+        return self.pairs
+
+    @property
+    def masked_score_share(self) -> float:
+        """Scores computed that the mask throws away, over scores computed."""
+        return 1.0 - self.scores_kept / self.scores_computed
+
+
+def _diag_slab(bq: int, bk: int) -> int:
+    """Rows of the slabs a block on the diagonal is computed in: one lane tile
+    of 128 rows (at most eight slabs a block: a multiple of it from blocks of
+    2,048 up) where a square block is whole slabs and more than one, else the
+    block whole (``bq``: unequal blocks, blocks of 128, heights no tile
+    divides). Chosen on the chip at the four prefill cells' shapes, blocks of
+    1,024 (PR 38: ``scripts/flash_causal_ab.py``, ``PERF.md`` section 6)."""
+    slab = 128 * max(1, bq // 1024)
+    return slab if bq == bk and bq > slab and bq % slab == 0 else bq
+
+
+def causal_plan(l: int, block_q: int = 128, block_k: int = 128) -> CausalPlan:
+    """The work ``flash_forward_bhld(..., causal=True)`` does for one head of
+    ``l`` tokens at these blocks, from the shapes alone (the kernel's own
+    ``_diag_slab`` among them); ``flash.masked_score_share`` is this plan's."""
+    bq, bk = min(block_q, l), min(block_k, l)
+    if l % bq or l % bk:
+        raise ValueError(f"sequence length {l} not divisible by blocks ({bq}, {bk})")
+    nq, pairs = l // bq, len(_pairs(l, bq, bk, causal=True))
+    w = _diag_slab(bq, bk)
+    n = bq // w  # slab i of n sees (i + 1) w keys: w^2 n (n + 1) / 2 = bq^2 (n + 1) / (2n) scores a block
+    on_diagonal = nq * w * w * n * (n + 1) // 2 if n > 1 else nq * bq * bk
+    return CausalPlan(pairs, w, (pairs - nq) * bq * bk + on_diagonal, l * (l + 1) // 2)
 
 
 # Lane width of the (bq,)-shaped running stats held in VMEM scratch: Mosaic
@@ -191,17 +278,17 @@ def flash_forward_bhld(
     if scale is None:
         scale = 1.0 / ((d + r) ** 0.5)  # Python math: stays static under jit tracing
 
-    if causal:
-        # Blocks above the diagonal are skipped by the kernel; naming the last
-        # block that contributes again keeps the grid from fetching them.
-        k_block = lambda qi, ki: jnp.minimum(ki, ((qi + 1) * bq - 1) // bk)
-    else:
-        k_block = lambda qi, ki: ki
+    # The grid's last axis runs over the (q-block, k-block) pairs that
+    # contribute, q-block-major: causal, those at or below the diagonal. Two
+    # scalar-prefetched tables name each step's blocks, so that no step is
+    # spent on a pair the mask empties and none of its blocks is fetched.
+    pairs = _pairs(l, bq, bk, causal)
+    tables = [jnp.asarray(np.asarray(column, np.int32)) for column in zip(*pairs)]
     group = h // hk  # query heads that share one key/value head
     kv_head = (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
-    q_at = lambda bi, hi, qi, ki: (bi, hi, qi, 0)
-    kv_at = lambda bi, hi, qi, ki: (bi, kv_head(hi), k_block(qi, ki), 0)
-    q_minor_at = lambda bi, hi, qi, ki: (bi, hi, 0, qi)  # a q-block along the LAST axis
+    q_at = lambda bi, hi, pi, qis, kis: (bi, hi, qis[pi], 0)
+    kv_at = lambda bi, hi, pi, qis, kis: (bi, kv_head(hi), kis[pi], 0)
+    q_minor_at = lambda bi, hi, pi, qis, kis: (bi, hi, 0, qis[pi])  # a q-block along the LAST axis
 
     operands = [q, k, v]
     in_specs = [_spec((1, 1, bq, d), q_at), _spec((1, 1, bk, d), kv_at), _spec((1, 1, bk, dv), kv_at)]
@@ -209,35 +296,41 @@ def flash_forward_bhld(
         operands += [q_rope, k_rope]
         in_specs += [
             _spec((1, 1, r, bq), q_minor_at),
-            _spec((1, r, bk), lambda bi, hi, qi, ki: (bi, 0, k_block(qi, ki))),
+            _spec((1, r, bk), lambda bi, hi, pi, qis, kis: (bi, 0, kis[pi])),
         ]
-    kernel = functools.partial(_fwd_kernel, bq=bq, bk=bk, causal=causal, scale=scale, rope=rope)
+    kernel = functools.partial(
+        _fwd_kernel, bq=bq, bk=bk, nk=l // bk, causal=causal, scale=scale, rope=rope,
+        slab=_diag_slab(bq, bk) if causal else bq,
+    )
     return pl.pallas_call(
         kernel,
-        grid=(b, h, l // bq, l // bk),
-        in_specs=in_specs,
-        out_specs=[
-            _spec((1, 1, bq, dv), q_at),
-            # LSE rides as (B, H, 1, L): Mosaic requires the block's last two
-            # dims to be (sublane-divisible | equal-to-array), which a
-            # (1, 1, bq) block over (B, H, L) violates (H is second-minor).
-            # The explicit singleton makes the block (1, bq) vs array (1, L)
-            # — legal, and caught only on real TPU (interpret mode doesn't
-            # enforce tiling).
-            _spec((1, 1, 1, bq), q_minor_at),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, h, len(pairs)),
+            in_specs=in_specs,
+            out_specs=[
+                _spec((1, 1, bq, dv), q_at),
+                # LSE rides as (B, H, 1, L): Mosaic requires the block's last two
+                # dims to be (sublane-divisible | equal-to-array), which a
+                # (1, 1, bq) block over (B, H, L) violates (H is second-minor).
+                # The explicit singleton makes the block (1, bq) vs array (1, L)
+                # — legal, and caught only on real TPU (interpret mode doesn't
+                # enforce tiling).
+                _spec((1, 1, 1, bq), q_minor_at),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bq, _STAT_LANES), jnp.float32),  # running max
+                pltpu.VMEM((bq, _STAT_LANES), jnp.float32),  # running denominator
+                pltpu.VMEM((bq, dv), jnp.float32),  # output accumulator
+            ],
+        ),
         out_shape=[
             _vma_struct((b, h, l, dv), q.dtype, vma),
             _vma_struct((b, h, 1, l), jnp.float32, vma),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq, _STAT_LANES), jnp.float32),  # running max
-            pltpu.VMEM((bq, _STAT_LANES), jnp.float32),  # running denominator
-            pltpu.VMEM((bq, dv), jnp.float32),  # output accumulator
-        ],
         interpret=_interpret(),
         name="flash_fwd",
-    )(*operands)
+    )(*tables, *operands)
 
 
 # ---------------------------------------------------------------------------

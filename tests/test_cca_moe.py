@@ -357,7 +357,8 @@ def test_layer_statistics_fill_the_gauges_and_agree_with_the_reference(params32)
     assert stats["moe.pairs_held"] <= (1 - stats["moe.skip_share"]) * stats["moe.pairs_all"]
     assert 1.0 < stats["router.state_rms_last"] < 4.0  # four unit-rms projections added up: about 2
     summary = metrics.registry().summary()
-    assert {name: summary[name] for name in metrics.MOE_ROUTING_GAUGES + metrics.CCA_GAUGES} == stats
+    gauges = metrics.MOE_ROUTING_GAUGES + metrics.CCA_GAUGES + (metrics.FLASH_MASKED_SCORE_SHARE,)
+    assert {name: summary[name] for name in gauges} == stats
 
 
 def test_balancing_the_routers_evens_the_outputs_and_moves_only_the_selection_bias(params32):

@@ -70,7 +70,12 @@ def test_jit():
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("l,block_q,block_k", [(256, 64, 64), (192, 48, 64), (24, 8, 12)])
+@pytest.mark.parametrize(
+    "l,block_q,block_k",
+    # the last: blocks of 256 are computed in row slabs on the diagonal, and the
+    # backward kernels read that forward's lse
+    [(256, 64, 64), (192, 48, 64), (24, 8, 12), (512, 256, 256)],
+)
 def test_grad_matches_reference_blocked(causal, l, block_q, block_k):
     """Pallas recompute backward vs the O(L^2) oracle, incl. non-dividing
     block ratios and causal masking."""
@@ -294,17 +299,195 @@ def test_key_value_heads_that_do_not_divide_the_query_heads_are_refused():
         flash_forward_bhld(q, kv[:, :2], kv[:, :1], causal=True)  # keys and values disagree
 
 
+# ---- a block on the diagonal in row slabs; the grid over the pairs that contribute ----
+
+SLAB_CASES = {
+    # name: (H, Hk, L, D, Dv, R, block): square blocks of 256 (two slabs of 128 rows) or 512 (four)
+    "equal_heads_l512_b256": (2, 2, 512, 32, 32, 0, 256),
+    "equal_heads_l1024_b512": (2, 2, 1024, 16, 16, 0, 512),
+    "grouped_heads_l1024_b256": (4, 1, 1024, 16, 16, 0, 256),
+    "value_width_differs_l512_b256": (2, 2, 512, 32, 16, 0, 256),
+    "rope_operands_l512_b256": (2, 2, 512, 32, 16, 8, 256),
+    "rope_operands_grouped_l1024_b512": (4, 2, 1024, 16, 32, 8, 512),
+}
+WHOLE_CASES = {
+    # name: (H, Hk, L, D, Dv, R, block_q, block_k, causal): shapes that admit no slabs
+    "unequal_blocks": (2, 2, 512, 32, 32, 0, 256, 128, True),
+    "unequal_blocks_wider_keys": (2, 1, 512, 16, 32, 8, 128, 256, True),
+    "blocks_of_128": (2, 2, 512, 32, 32, 0, 128, 128, True),
+    "not_causal": (2, 2, 512, 32, 16, 8, 256, 256, False),
+}
+
+
+def _operands(h, hk, l, d, dv, r, dtype, seed=31):
+    keys = jax.random.split(jax.random.key(seed), 5)
+    draw = lambda key, shape: jax.random.normal(key, shape, jnp.float32).astype(dtype)
+    q, k, v = draw(keys[0], (1, h, l, d)), draw(keys[1], (1, hk, l, d)), draw(keys[2], (1, hk, l, dv))
+    rope = dict(q_rope=draw(keys[3], (1, h, r, l)), k_rope=draw(keys[4], (1, r, l))) if r else {}
+    return q, k, v, rope
+
+
+def _reference_out_and_lse(q, k, v, rope, causal):
+    """``ops.attention.attention`` in float32 on the whole ``D + R``-wide
+    queries and keys, keys and values repeated per query head, and the
+    log-sum-exp of the same scaled scores."""
+    h, hk, l = q.shape[1], k.shape[1], q.shape[2]
+    f32 = lambda x: x.astype(jnp.float32)
+    k, v = jnp.repeat(f32(k), h // hk, axis=1), jnp.repeat(f32(v), h // hk, axis=1)
+    q = f32(q)
+    if rope:
+        q = jnp.concatenate([q, jnp.swapaxes(f32(rope["q_rope"]), 2, 3)], axis=-1)
+        k_rope = jnp.broadcast_to(jnp.swapaxes(f32(rope["k_rope"]), 1, 2)[:, None], (1, h, l, rope["k_rope"].shape[1]))
+        k = jnp.concatenate([k, k_rope], axis=-1)
+    lhd = lambda x: jnp.transpose(x, (0, 2, 1, 3))
+    out = lhd(attention(lhd(q), lhd(k), lhd(v), causal=causal))
+    s = jnp.einsum("bhld,bhmd->bhlm", q, k, precision="highest") / jnp.sqrt(jnp.float32(q.shape[-1]))
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((l, l), bool)), s, -jnp.inf)
+    return out, jax.scipy.special.logsumexp(s, axis=-1)
+
+
+def _products(fn):
+    return str(jax.make_jaxpr(lambda: fn())()).count("dot_general")  # a new function: traced anew
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("case", sorted(SLAB_CASES))
+def test_the_diagonal_in_row_slabs_equals_the_reference(case, dtype, tol, monkeypatch):
+    """Square blocks of 256 and up: a block on the diagonal is computed as row
+    slabs, each against the keys up to its own last row. Output AND lse equal
+    the float32 reference, and — every row still meets its keys in one update,
+    in the same order, less only scores the mask zeroed — the kernel that
+    computes the block whole, bit for bit."""
+    from cuda_mpi_gpu_cluster_programming_tpu.ops import flash_attention as fa
+
+    h, hk, l, d, dv, r, block = SLAB_CASES[case]
+    q, k, v, rope = _operands(h, hk, l, d, dv, r, dtype)
+    run = lambda: fa.flash_forward_bhld(q, k, v, causal=True, block_q=block, block_k=block, **rope)
+    plan = fa.causal_plan(l, block, block)
+    n = block // plan.diag_slab
+    assert n == (4 if block == 512 else 2) and plan.scores_computed < plan.pairs * block * block
+    per_update = 2 + bool(r)  # scores (two products with rope operands) and values
+    assert _products(run) == per_update * (n + 1)  # the slabs of the diagonal's branch + the block below it
+    out, lse = run()
+    assert out.shape == (1, h, l, dv) and out.dtype == dtype and lse.shape == (1, h, 1, l)
+    want, want_lse = _reference_out_and_lse(q, k, v, rope, causal=True)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want), rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(lse[:, :, 0]), np.asarray(want_lse), rtol=tol, atol=tol)
+    monkeypatch.setattr(fa, "_diag_slab", lambda bq, bk: bq)  # here, not by an option: the block whole
+    assert _products(run) == per_update * 2
+    whole_out, whole_lse = run()
+    assert np.array_equal(np.asarray(out, np.float32), np.asarray(whole_out, np.float32))
+    assert np.array_equal(np.asarray(lse), np.asarray(whole_lse))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)])
+@pytest.mark.parametrize("case", sorted(WHOLE_CASES))
+def test_shapes_that_admit_no_slabs_keep_the_whole_block_update(case, dtype, tol, monkeypatch):
+    """Unequal blocks, blocks of 128 and attention without a mask: the plan
+    names the block whole, the kernel traced holds the two updates it always
+    held (masked and unmasked; one without a mask) and the result is that
+    kernel's — the same with the slabs' rule taken away — and the reference's."""
+    from cuda_mpi_gpu_cluster_programming_tpu.ops import flash_attention as fa
+
+    h, hk, l, d, dv, r, block_q, block_k, causal = WHOLE_CASES[case]
+    q, k, v, rope = _operands(h, hk, l, d, dv, r, dtype, seed=37)
+    run = lambda: fa.flash_forward_bhld(q, k, v, causal=causal, block_q=block_q, block_k=block_k, **rope)
+    if causal:
+        plan = fa.causal_plan(l, block_q, block_k)
+        assert plan.diag_slab == block_q and plan.scores_computed == plan.pairs * block_q * block_k
+    assert _products(run) == (2 + bool(r)) * (2 if causal else 1)
+    out, lse = run()
+    want, want_lse = _reference_out_and_lse(q, k, v, rope, causal=causal)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want), rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(lse[:, :, 0]), np.asarray(want_lse), rtol=tol, atol=tol)
+    monkeypatch.setattr(fa, "_diag_slab", lambda bq, bk: bq)
+    whole_out, whole_lse = run()
+    assert np.array_equal(np.asarray(out, np.float32), np.asarray(whole_out, np.float32))
+    assert np.array_equal(np.asarray(lse), np.asarray(whole_lse))
+
+
+CAUSAL_PLANS = {
+    # (l, block_q, block_k): (grid steps the (l/bq) x (l/bk) grid took, pairs computed, blocks on the
+    # diagonal, slabs a block): the numbers under ISSUE 38's Motivation
+    (4096, 1024, 1024): (16, 10, 4, 8),
+    (8192, 1024, 1024): (64, 36, 8, 8),
+    (1024, 256, 256): (16, 10, 4, 2),
+    (512, 128, 128): (16, 10, 4, 1),
+    (512, 256, 128): (8, 6, 2, 1),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(CAUSAL_PLANS))
+def test_causal_plan_counts_the_steps_the_pairs_and_the_scores(shape):
+    """The plan is the kernel's, from the shapes alone: one grid step per pair
+    at or below the diagonal (where the rectangular grid visited every pair),
+    whole blocks below the diagonal, ``bq^2 (n + 1) / (2n)`` scores of a block
+    on it in ``n`` slabs, and ``l (l + 1) / 2`` scores kept."""
+    from cuda_mpi_gpu_cluster_programming_tpu.ops.flash_attention import causal_plan
+
+    l, bq, bk = shape
+    rectangle, pairs, on_diagonal, n = CAUSAL_PLANS[shape]
+    plan = causal_plan(l, bq, bk)
+    assert rectangle == (l // bq) * (l // bk)
+    assert (plan.grid_steps, plan.pairs, bq // plan.diag_slab) == (pairs, pairs, n)
+    whole = pairs * bq * bk  # what the kernel computed with every block whole
+    assert plan.scores_kept == l * (l + 1) // 2
+    if n == 1:
+        assert plan.scores_computed == whole
+    else:
+        assert plan.scores_computed == whole - on_diagonal * (bq * bq - bq * bq * (n + 1) // (2 * n))
+    assert plan.masked_score_share == pytest.approx(1 - plan.scores_kept / plan.scores_computed)
+    if shape == (4096, 1024, 1024):
+        assert (whole, plan.scores_kept) == (10_485_760, 8_390_656)  # 20.0% thrown away ...
+        assert 1 - plan.scores_kept / whole == pytest.approx(0.200, abs=5e-4)
+        assert plan.masked_score_share == pytest.approx(0.0301, abs=5e-5)  # ... and 3.0%
+    if shape == (8192, 1024, 1024):
+        assert 1 - plan.scores_kept / whole == pytest.approx(0.111, abs=5e-4)
+        assert plan.masked_score_share == pytest.approx(0.0153, abs=5e-5)
+    with pytest.raises(ValueError, match="not divisible"):
+        causal_plan(l + 8, bq, bk)
+
+
+def test_masked_score_share_is_read_through_a_family_s_statistics(monkeypatch):
+    """``flash.masked_score_share`` beside the routing gauges of a decoder
+    family that calls the kernel: four blocks of 256 a head throw away a fifth
+    of their scores computed whole, and what ``causal_plan`` says in slabs."""
+    import dataclasses
+
+    from cuda_mpi_gpu_cluster_programming_tpu.models import mla_moe
+    from cuda_mpi_gpu_cluster_programming_tpu.observability import metrics
+    from cuda_mpi_gpu_cluster_programming_tpu.ops import flash_attention as fa
+
+    cfg = dataclasses.replace(mla_moe.SMALL, attn_block=256)
+    params = mla_moe.init(jax.random.key(0), cfg, jnp.float32)
+    ids = jax.random.randint(jax.random.key(1), (1, 1024), 0, cfg.vocab_size, jnp.int32)
+    metrics.registry().reset()
+    stats = mla_moe.routing_statistics(params, ids, cfg)
+    share = stats[metrics.FLASH_MASKED_SCORE_SHARE]
+    assert share == fa.causal_plan(1024, 256, 256).masked_score_share == pytest.approx(0.1102, abs=5e-5)
+    assert metrics.registry().summary()[metrics.FLASH_MASKED_SCORE_SHARE] == share
+    monkeypatch.setattr(fa, "_diag_slab", lambda bq, bk: bq)  # the blocks whole, as they were
+    whole = mla_moe.routing_statistics(params, ids, cfg)[metrics.FLASH_MASKED_SCORE_SHARE]
+    assert whole == pytest.approx(0.200, abs=1e-3) and share < whole
+    metrics.registry().reset()
+
+
 # The step program of ``v8_mla_moe`` at the small preset as jax 0.9.0 lowers it
 # (``.lower(...).as_text()``: the program as traced, before any compiler of a
 # particular machine touches it; the kernels are in it as the interpreter
-# discharges them, their block index maps too), as the commit BEFORE grouped
-# key/value heads built it (PR 30's tree, 62892c5): sha256 of the text. Equal
-# head counts must still build that kernel and that program (the dots cell's
-# step may not change under it). A change that means to alter that program
-# records the new digests here and says so.
+# discharges them, their block index maps too): sha256 of the text. Equal head
+# counts must keep building this kernel and this program (the dots cell's
+# step may not change under a change made for another caller). A change that
+# means to alter that program records the new digests here and says so: these
+# are PR 38's, whose forward runs its grid over the contributing (q-block,
+# k-block) pairs alone, two scalar-prefetched tables naming them (until then
+# the digests were those of PR 30's tree, 62892c5, before grouped key/value
+# heads: 7d671698... and 2f163250...). The small preset's one block of 32
+# tokens admits no slabs, so the update in it is the one that was there.
 EQUAL_HEADS_STEP_SHA256 = {
-    "bf16": "7d67169888c1a7da7ab634382942b31f13fa11b8a478c911af78dc9d260a518f",
-    "fp32": "2f16325032e0cf8f2758752f97924dd1aa244cceaee34146fce7acfe1b64c16f",
+    "bf16": "09df0f200b51a915e954d5006f09577a4270a349c057b3df562f85d5de421d0c",
+    "fp32": "c20b1fb4479faacdb60ba4992df73c267f9ed2bcf3c8979b66ce1b75dab0daba",
 }
 
 

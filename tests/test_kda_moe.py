@@ -228,7 +228,8 @@ def test_layer_statistics_fill_the_gauges_and_agree_with_the_reference():
     # the three linear layers take the kernel: tests/test_kda_mix.py)
     assert stats["kda.mix_fused_layers"] == 0
     summary = metrics.registry().summary()
-    assert {name: summary[name] for name in metrics.MOE_ROUTING_GAUGES + metrics.KDA_GAUGES} == stats
+    gauges = metrics.MOE_ROUTING_GAUGES + metrics.KDA_GAUGES + (metrics.FLASH_MASKED_SCORE_SHARE,)
+    assert {name: summary[name] for name in gauges} == stats
 
 
 def test_balancing_the_routers_evens_the_load_and_moves_only_the_selection_bias():

@@ -223,7 +223,8 @@ def test_routing_statistics_fill_the_registry_and_agree_with_the_reference():
     assert stats["moe.pairs_held"] == pairs
     assert stats["moe.expert_load_max_over_mean"] >= 1.0
     summary = metrics.registry().summary()
-    assert {summary[name] for name in metrics.MOE_ROUTING_GAUGES} == set(stats.values())
+    gauges = metrics.MOE_ROUTING_GAUGES + (metrics.FLASH_MASKED_SCORE_SHARE,)
+    assert {summary[name] for name in gauges} == set(stats.values())
 
 
 # ---- closed forms ------------------------------------------------------------

@@ -133,8 +133,9 @@ def test_k_rope_is_not_copied_per_head(step_text):
     shared_key = (BATCH, CFG.qk_rope_head_dim, SEQ)  # sequence-minor
     for line in kernels:
         operands = line.split("custom-call(", 1)[1].split(")", 1)[0]
-        assert len(operands.split(",")) == 5, operands
-        # the operands' shapes stand in the text before the call: look the fifth one up
+        # the two scalar-prefetched tables of the grid's (q-block, k-block) pairs, then q, k, v, q_rope, k_rope
+        assert len(operands.split(",")) == 2 + 5, operands
+        # the operands' shapes stand in the text before the call: look the last one up
         name = operands.split(",")[-1].strip()
         defined = next(ln for ln in step_text.splitlines() if ln.lstrip().startswith(f"{name} = "))
         assert shared_key in _dims(_result(defined)[0]), defined[:200]

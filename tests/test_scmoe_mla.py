@@ -384,12 +384,14 @@ def test_run_py_runs_the_small_preset_and_refuses_to_serve(capsys):
 # The step program of ``v8_mla_moe`` at the dots cell's real shapes (the
 # ``ep16_share`` preset, 2 x 4,096 ids, bf16) as jax 0.9.0 lowers it
 # (``.lower(...).as_text()``: the program as traced, before any compiler of a
-# particular machine touches it, no source location in it), as the commit
-# BEFORE this family built it (PR 36's tree, 8e338f2): sha256 of the text.
-# ``_mla`` got two scales for this family; at 1 it must build what it built. A
-# change that means to alter the dots step records the new digest here and
-# says so.
-DOTS_STEP_SHA256 = "79b582f0dcf6e1d54a08940220d71b9be507b74ec00cfe68b8c399420a51da04"
+# particular machine touches it, no source location in it): sha256 of the
+# text. ``_mla`` got two scales for this family; at 1 it must build what it
+# built. A change that means to alter the dots step records the new digest
+# here and says so: this is PR 38's, which changed ``flash_fwd`` for every
+# caller (the grid over the contributing pairs alone, a block on the diagonal
+# in row slabs); until then it was 79b582f0..., the text of PR 36's tree
+# (8e338f2), the commit BEFORE this family.
+DOTS_STEP_SHA256 = "a7e34e95a624285f51ec9dd45b5c6f178ec9509f80114a001aea1f1d9fb62c9a"
 
 
 def test_the_dots_cells_step_program_is_the_one_the_parent_lowered():
