@@ -43,7 +43,7 @@ class ExecConfig:
     tier: str  # "reference" (XLA ops) | "pallas"
     strategy: str  # "single" | "replicated" | "halo" | "staged_halo"
     description: str
-    model: str = "blocks12"  # "blocks12" | "alexnet_full" | "mla_moe" | "kda_moe" | "cca_moe" | "scmoe_mla"
+    model: str = "blocks12"  # "blocks12" | "alexnet_full" | "mla_moe" | "kda_moe" | "cca_moe" | "scmoe_mla" | "sambay"
 
 
 REGISTRY: Dict[str, ExecConfig] = {
@@ -171,12 +171,24 @@ REGISTRY: Dict[str, ExecConfig] = {
             "grouped-matmul and combine kernels",
             model="scmoe_mla",
         ),
+        ExecConfig(
+            "v12_sambay",
+            "V12 SambaY Dense",
+            "reference",
+            "single",
+            "decoder-hybrid-decoder, a dense model whole on one device: (Mamba scan, windowed "
+            "differential attention) pairs, a Mamba layer and a causal differential attention layer "
+            "that hand their scan output and their keys and values down, then (gated memory unit, "
+            "differential cross-attention) pairs that read them; two scans over stacked pairs, XLA "
+            "ops with the selective-scan and flash-attention kernels",
+            model="sambay",
+        ),
     ]
 }
 
 # The language-model families: token ids in, logits out, parameters stored in
 # the compute type. ``ExecConfig.model`` names the module under ``models``.
-LANGUAGE_MODELS = ("mla_moe", "kda_moe", "cca_moe", "scmoe_mla")
+LANGUAGE_MODELS = ("mla_moe", "kda_moe", "cca_moe", "scmoe_mla", "sambay")
 
 
 def language_model(exec_cfg: ExecConfig):

@@ -76,6 +76,23 @@ MOE_ZERO_PAIR_SHARE = "moe.zero_pair_share"
 MOE_REAL_EXPERTS_PER_TOKEN_MAX = "moe.real_experts_per_token_max"
 MOE_REAL_EXPERTS_PER_TOKEN_MIN = "moe.real_experts_per_token_min"
 SCMOE_GAUGES = (MOE_ZERO_PAIR_SHARE, MOE_REAL_EXPERTS_PER_TOKEN_MAX, MOE_REAL_EXPERTS_PER_TOKEN_MIN)
+# Gauges of the decoder of state-space scans and differential attention
+# (``models.sambay.layer_statistics``, one batch, outside any hot loop): the
+# most negative ``Delta A`` summed over one chunk of the selective scan, over
+# every Mamba layer, channel and state (what a chunked form would exponentiate,
+# and why ``ops.selective_scan`` exponentiates one token's alone), the mean
+# step ``Delta``, the least and the greatest ``lambda`` of the differential
+# attention layers, and ``flash.masked_score_share``'s twin for the windowed
+# layers (``flash_attention.causal_plan`` with the window: of the scores the
+# band's blocks compute, the share the two masks throw away).
+SSM_CHUNK_LOG_DECAY_MIN = "ssm.chunk_log_decay_min"
+SSM_DT_MEAN = "ssm.dt_mean"
+DIFF_LAMBDA_MIN = "diff.lambda_min"
+DIFF_LAMBDA_MAX = "diff.lambda_max"
+FLASH_WINDOW_MASKED_SCORE_SHARE = "flash.window_masked_score_share"
+SAMBAY_GAUGES = (
+    SSM_CHUNK_LOG_DECAY_MIN, SSM_DT_MEAN, DIFF_LAMBDA_MIN, DIFF_LAMBDA_MAX, FLASH_WINDOW_MASKED_SCORE_SHARE
+)
 # Gauge of the causal attention kernel (``models.moe_share.set_attention_gauge``,
 # beside the routing gauges of every decoder family that calls ``flash_fwd``,
 # outside any hot loop): of the scores the forward computes for one head, the

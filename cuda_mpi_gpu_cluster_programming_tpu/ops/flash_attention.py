@@ -68,7 +68,15 @@ def _slab_mask(s, row0):
     return jnp.where(rows >= keys, s, NEG_INF)
 
 
-def _fwd_kernel(qi_ref, ki_ref, *refs, bq, bk, nk, causal, scale, rope, slab):
+def _band_mask(s, qi, ki, bq, bk, window):
+    """The causal mask and the window's: a query sees itself and the
+    ``window - 1`` tokens before it."""
+    q_pos = qi * bq + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    k_pos = ki * bk + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+    return jnp.where((q_pos >= k_pos) & (q_pos - k_pos < window), s, NEG_INF)
+
+
+def _fwd_kernel(qi_ref, ki_ref, *refs, bq, bk, nk, causal, scale, rope, slab, window=None):
     """One (batch, head, pair) program: k-block ``ki_ref[pair]`` against
     q-block ``qi_ref[pair]`` (the two scalar-prefetched tables of the pairs
     that contribute, q-block-major).
@@ -81,7 +89,9 @@ def _fwd_kernel(qi_ref, ki_ref, *refs, bq, bk, nk, causal, scale, rope, slab):
     sum of the two products. Running stats live in VMEM scratch across a
     q-block's pairs (the grid is sequential on TPU and in interpret mode).
     ``slab`` < bq: a block on the diagonal is computed in row slabs of that
-    height.
+    height. ``window`` (causal only): the pairs are the band's, a q-block's
+    first k-block is the one that holds its first query's farthest key, and a
+    block the band's lower edge crosses is masked as the diagonal's are.
     """
     q_ref, k_ref, v_ref, *rest = refs
     if rope:
@@ -91,7 +101,9 @@ def _fwd_kernel(qi_ref, ki_ref, *refs, bq, bk, nk, causal, scale, rope, slab):
     qi = qi_ref[pair]
     ki = ki_ref[pair]
 
-    @pl.when(ki == 0)
+    first = 0 if window is None else jnp.maximum(qi * bq - (window - 1), 0) // bk
+
+    @pl.when(ki == first)
     def _init():
         m_sc[...] = jnp.full_like(m_sc, NEG_INF)
         den_sc[...] = jnp.zeros_like(den_sc)
@@ -147,13 +159,25 @@ def _fwd_kernel(qi_ref, ki_ref, *refs, bq, bk, nk, causal, scale, rope, slab):
     # diagonal crosses need the mask: a block wholly below it skips the iota,
     # the compare and the select, which the VPU pays per score.
     whole = lambda mask=None: update((slice(0, bq), slice(0, bk), mask))
+    # a block on the diagonal in row slabs, each against the keys up to its own last row
+    diagonal = lambda: update(*[
+        (slice(r, r + slab), slice(0, r + slab), functools.partial(_slab_mask, row0=r)) for r in range(0, bq, slab)
+    ])
     if not causal:
         whole()
+    elif window is not None:
+        # the diagonal crosses a block with a key after some query; the band's lower edge one
+        # whose first key lies more than ``window - 1`` before its last query
+        crossed = ((ki + 1) * bk - 1 > qi * bq) | ((qi + 1) * bq - 1 - ki * bk > window - 1)
+        if slab < bq <= window:  # the lower edge never reaches a block on the diagonal
+            pl.when(ki == qi)(diagonal)
+            crossed = crossed & (ki != qi)
+            pl.when(jnp.logical_not(crossed) & (ki != qi))(whole)
+        else:
+            pl.when(jnp.logical_not(crossed))(whole)
+        pl.when(crossed)(lambda: whole(lambda s: _band_mask(s, qi, ki, bq, bk, window)))
     elif slab < bq:  # square blocks: the diagonal crosses (qi, qi) corner to corner, and no other
-        slabs = [
-            (slice(r, r + slab), slice(0, r + slab), functools.partial(_slab_mask, row0=r)) for r in range(0, bq, slab)
-        ]
-        pl.when(ki == qi)(lambda: update(*slabs))
+        pl.when(ki == qi)(diagonal)
         pl.when(ki < qi)(whole)
     else:
         crossed = (ki + 1) * bk - 1 > qi * bq  # some key of the block lies after some query
@@ -170,11 +194,15 @@ def _fwd_kernel(qi_ref, ki_ref, *refs, bq, bk, nk, causal, scale, rope, slab):
         lse_ref[0, 0, 0] = m + jnp.log(den)
 
 
-def _pairs(l, bq, bk, causal):
+def _pairs(l, bq, bk, causal, window=None):
     """The (q-block, k-block) pairs that contribute, q-block-major: all of
-    them, or causal those with a key at or before the block's last query."""
+    them, or causal those with a key at or before the block's last query;
+    with a ``window``, of those the ones with a key no more than ``window -
+    1`` before the block's first query."""
     return [
-        (qi, ki) for qi in range(l // bq) for ki in range(l // bk) if not causal or (qi + 1) * bq - 1 >= ki * bk
+        (qi, ki) for qi in range(l // bq) for ki in range(l // bk)
+        if (not causal or (qi + 1) * bq - 1 >= ki * bk)
+        and (window is None or (ki + 1) * bk - 1 >= qi * bq - (window - 1))
     ]
 
 
@@ -184,7 +212,7 @@ class CausalPlan(NamedTuple):
     pairs: int  # (q-block, k-block) pairs computed
     diag_slab: int  # rows of the slabs a block on the diagonal is computed in (block_q: whole)
     scores_computed: int
-    scores_kept: int  # the scores the mask leaves: l (l + 1) / 2
+    scores_kept: int  # the scores the mask leaves: l (l + 1) / 2, or with a window sum_q min(q + 1, window)
 
     @property
     def grid_steps(self) -> int:
@@ -208,18 +236,27 @@ def _diag_slab(bq: int, bk: int) -> int:
     return slab if bq == bk and bq > slab and bq % slab == 0 else bq
 
 
-def causal_plan(l: int, block_q: int = 128, block_k: int = 128) -> CausalPlan:
-    """The work ``flash_forward_bhld(..., causal=True)`` does for one head of
-    ``l`` tokens at these blocks, from the shapes alone (the kernel's own
-    ``_diag_slab`` among them); ``flash.masked_score_share`` is this plan's."""
+def _window_slab(bq: int, bk: int, window) -> int:
+    """``_diag_slab`` where the band's lower edge never reaches a block on the
+    diagonal (``window`` None, or at least a block), else the block whole."""
+    return _diag_slab(bq, bk) if window is None or bq <= window else bq
+
+
+def causal_plan(l: int, block_q: int = 128, block_k: int = 128, window=None) -> CausalPlan:
+    """The work ``flash_forward_bhld(..., causal=True, window=window)`` does
+    for one head of ``l`` tokens at these blocks, from the shapes alone (the
+    kernel's own ``_diag_slab`` among them); ``flash.masked_score_share`` is
+    this plan's, ``flash.window_masked_score_share`` the windowed one's."""
     bq, bk = min(block_q, l), min(block_k, l)
     if l % bq or l % bk:
         raise ValueError(f"sequence length {l} not divisible by blocks ({bq}, {bk})")
-    nq, pairs = l // bq, len(_pairs(l, bq, bk, causal=True))
-    w = _diag_slab(bq, bk)
+    nq, pairs = l // bq, len(_pairs(l, bq, bk, True, window))
+    w = _window_slab(bq, bk, window)
     n = bq // w  # slab i of n sees (i + 1) w keys: w^2 n (n + 1) / 2 = bq^2 (n + 1) / (2n) scores a block
     on_diagonal = nq * w * w * n * (n + 1) // 2 if n > 1 else nq * bq * bk
-    return CausalPlan(pairs, w, (pairs - nq) * bq * bk + on_diagonal, l * (l + 1) // 2)
+    seen = l if window is None else min(window, l)  # keys the last query sees
+    kept = seen * (seen + 1) // 2 + (l - seen) * seen
+    return CausalPlan(pairs, w, (pairs - nq) * bq * bk + on_diagonal, kept)
 
 
 # Lane width of the (bq,)-shaped running stats held in VMEM scratch: Mosaic
@@ -237,7 +274,7 @@ def _flash_forward(q, k, v, *, causal, block_q, block_k, return_lse, vma=None):
 
 
 def flash_forward_bhld(
-    q, k, v, *, causal, block_q=128, block_k=128, scale=None, vma=None, q_rope=None, k_rope=None
+    q, k, v, *, causal, block_q=128, block_k=128, scale=None, vma=None, q_rope=None, k_rope=None, window=None
 ):
     """The forward kernel on heads-major operands: q ``(B, H, L, D)``, k
     ``(B, Hk, L, D)``, v ``(B, Hk, L, Dv)`` -> ``(out (B, H, L, Dv), lse
@@ -256,8 +293,13 @@ def flash_forward_bhld(
     sequence-minor because ``R`` is narrow (64 where a lane tile is 128): so
     laid out they are dense in HBM and in VMEM, and it is how the compiler
     writes a product that narrow anyway. ``scale`` defaults to the whole
-    width's ``(D + R)**-0.5``. Forward only: the differentiable entry points
-    above take one width for q, k and v and no rope operands.
+    width's ``(D + R)**-0.5``. ``window`` (with ``causal``): a query sees
+    itself and the ``window - 1`` tokens before it; the grid then runs over the
+    (q-block, k-block) pairs the band touches and no others
+    (``causal_plan(l, bq, bk, window)`` says what is computed), and with
+    ``window=None`` the kernel built is the one built without the argument.
+    Forward only: the differentiable entry points above take one width for q,
+    k and v and no rope operands.
     """
     b, h, l, d = q.shape
     hk, dv = k.shape[1], v.shape[-1]
@@ -271,6 +313,8 @@ def flash_forward_bhld(
         )
     if (q_rope is None) != (k_rope is None):
         raise ValueError("q_rope and k_rope come together")
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window {window}: a window is at least one token and comes with causal=True")
     rope = q_rope is not None
     r = q_rope.shape[2] if rope else 0
     if rope and (q_rope.shape != (b, h, r, l) or k_rope.shape != (b, r, l)):
@@ -282,7 +326,7 @@ def flash_forward_bhld(
     # contribute, q-block-major: causal, those at or below the diagonal. Two
     # scalar-prefetched tables name each step's blocks, so that no step is
     # spent on a pair the mask empties and none of its blocks is fetched.
-    pairs = _pairs(l, bq, bk, causal)
+    pairs = _pairs(l, bq, bk, causal, window)
     tables = [jnp.asarray(np.asarray(column, np.int32)) for column in zip(*pairs)]
     group = h // hk  # query heads that share one key/value head
     kv_head = (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
@@ -302,6 +346,8 @@ def flash_forward_bhld(
         _fwd_kernel, bq=bq, bk=bk, nk=l // bk, causal=causal, scale=scale, rope=rope,
         slab=_diag_slab(bq, bk) if causal else bq,
     )
+    if window is not None:
+        kernel = functools.partial(kernel, slab=_window_slab(bq, bk, window), window=window)
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -72,11 +72,30 @@ CCA_MOE_LAYERS = (
 SCMOE_MLA_LAYERS = (
     "embed", "layer_loop", "mla.proj", "mla.attn", "dense_mlp", "moe.route", "moe.experts", "moe.zero", "head",
 )
+# The decoder-hybrid-decoder of state-space scans, differential attention and
+# gated memory units (``models.sambay``), a dense model. ``mamba.proj``: the
+# norm, the input projection, the low-rank projections of the step and of the
+# input and output maps, the gate and the output projection; ``mamba.mix`` the
+# short causal convolution with its bias, silu, the step's softplus and ``A``
+# (elementwise); ``mamba.scan`` the selective scan's kernel. ``gmu``: a gated
+# memory unit whole (norm, both products, the gate by the memory).
+# ``diff.proj``: the norm, the q/k/v (or q) projection with its bias, the
+# heads laid out for the kernel, ``lambda``, the difference of the two
+# softmaxes' outputs, the per-head norm and the output projection;
+# ``diff.attn_window`` the flash kernel of the windowed layers,
+# ``diff.attn_full`` of the causal layer that keeps its keys and values and of
+# the cross layers that read them. ``dense_mlp`` holds its norm and residual
+# addition too. ``layer_loop`` as above, two loops.
+SAMBAY_LAYERS = (
+    "embed", "layer_loop", "mamba.proj", "mamba.mix", "mamba.scan", "gmu",
+    "diff.proj", "diff.attn_window", "diff.attn_full", "dense_mlp", "head",
+)
 LAYERS = (
     BLOCKS12_LAYERS + ALEXNET_TAIL_LAYERS + FC_LAYERS + MLA_MOE_LAYERS
     + tuple(name for name in KDA_MOE_LAYERS if name not in MLA_MOE_LAYERS)
     + tuple(name for name in CCA_MOE_LAYERS if name not in MLA_MOE_LAYERS)
     + ("moe.zero",)
+    + tuple(name for name in SAMBAY_LAYERS if name not in MLA_MOE_LAYERS + CCA_MOE_LAYERS)
 )
 
 # A second, nested level: the phases of a layer, which stand only inside that

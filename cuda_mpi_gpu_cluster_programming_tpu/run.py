@@ -41,14 +41,15 @@ def make_parser() -> argparse.ArgumentParser:
     p.add_argument("--seed", type=int, default=0)
     p.add_argument(
         "--preset",
-        choices=["small", "ep16_share", "solar_ep8", "zaya1_ep2", "longcat_ep32"],
+        choices=["small", "ep16_share", "solar_ep8", "zaya1_ep2", "longcat_ep32", "phi4_mini_flash"],
         default="small",
         help="language-model configs only (the PRESETS of the config's model module, "
         "models.<ExecConfig.model>): small = the CPU tests' size; the published widths "
         "as one expert-parallel chip holds them, at the benchmark's shape: ep16_share "
         "(v8_mla_moe: one of 16 chips, 2 x 4,096 tokens), solar_ep8 (v9_kda_moe: one of "
         "8 chips, 2 x 8,192 tokens), zaya1_ep2 (v10_cca_moe: one of 2 chips, 1 x 4,096 tokens), "
-        "longcat_ep32 (v11_scmoe_mla: one of 32 chips, 2 x 4,096 tokens)",
+        "longcat_ep32 (v11_scmoe_mla: one of 32 chips, 2 x 4,096 tokens); phi4_mini_flash "
+        "(v12_sambay: the dense model whole on one chip, 1 x 4,096 tokens)",
     )
     p.add_argument("--repeats", type=int, default=10, help="fenced passes for amortized timing")
     p.add_argument(
@@ -481,9 +482,11 @@ def _run_language_model(args, exec_cfg) -> int:
         last = fwd(params, ids)
     jax.block_until_ready(last)
     per_pass_ms = (time.perf_counter() - t0) * 1e3 / max(1, args.repeats)
-    print(f"Parameters: {model.param_count(model_cfg)} held here "
-          f"(experts [{model_cfg.experts_first}, {model_cfg.experts_first + model_cfg.experts_held}) "
-          f"of {model_cfg.n_routed_experts})")
+    held = (
+        f"experts [{model_cfg.experts_first}, {model_cfg.experts_first + model_cfg.experts_held}) "
+        f"of {model_cfg.n_routed_experts}" if hasattr(model_cfg, "experts_held") else "a dense model, whole"
+    )
+    print(f"Parameters: {model.param_count(model_cfg)} held here ({held})")
     print(f"Final Output Shape: {'x'.join(str(d) for d in out.shape[1:])}")
     print("Final Output (first 10 values): "
           + " ".join(f"{v:.4f}" for v in np.asarray(out[0, -1, :10])))

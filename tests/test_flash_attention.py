@@ -506,3 +506,102 @@ def test_with_equal_head_counts_the_program_built_is_the_one_built_before(comput
     text = build_forward(REGISTRY["v8_mla_moe"], mla_moe.SMALL, compute=compute).lower(params, ids).as_text()
     assert "loc(" not in text  # no source location in it: moving code changes nothing
     assert hashlib.sha256(text.encode()).hexdigest() == EQUAL_HEADS_STEP_SHA256[compute]
+
+
+# ---- the window mask (PR 39) ---------------------------------------------------
+
+
+def _dense_window(q, k, v, window):
+    """Masked dense softmax, grouped heads: a query sees itself and the
+    ``window - 1`` tokens before it (``window`` None: causal alone)."""
+    group, l = q.shape[1] // k.shape[1], q.shape[2]
+    k, v = jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k, precision="highest") * q.shape[-1] ** -0.5
+    rows, keys = jnp.arange(l)[:, None], jnp.arange(l)[None, :]
+    seen = rows >= keys if window is None else (rows >= keys) & (rows - keys < window)
+    probs = jax.nn.softmax(jnp.where(seen, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bhkd->bhqd", probs, v, precision="highest")
+
+
+@pytest.mark.parametrize(
+    "l,bq,bk,window",
+    [
+        (64, 16, 16, 8), (64, 16, 16, 16), (64, 16, 16, 24), (64, 16, 16, 37), (64, 16, 16, 1), (64, 16, 16, 64),
+        (64, 16, 16, 100), (64, 16, 8, 5), (64, 8, 16, 40), (512, 256, 256, 256), (512, 256, 256, 300),
+        (512, 256, 256, 100),
+    ],
+    ids=lambda v: str(v),
+)
+def test_the_windowed_forward_is_the_masked_dense_softmax(l, bq, bk, window):
+    """Windows below, at and above a block and no multiple of one, at heads
+    half as wide as their values over grouped key/value heads; at square blocks
+    over 128 the diagonal runs in slabs where the window is at least a block."""
+    from cuda_mpi_gpu_cluster_programming_tpu.ops.flash_attention import causal_plan, flash_forward_bhld
+
+    keys = jax.random.split(jax.random.key(l + bq + window), 3)
+    q = jax.random.normal(keys[0], (1, 4, l, 16))
+    k, v = jax.random.normal(keys[1], (1, 2, l, 16)), jax.random.normal(keys[2], (1, 2, l, 32))
+    out, lse = flash_forward_bhld(q, k, v, causal=True, block_q=bq, block_k=bk, window=window)
+    assert out.shape == (1, 4, l, 32) and lse.shape == (1, 4, 1, l)
+    np.testing.assert_allclose(out, _dense_window(q, k, v, window), atol=2e-6)
+    plan = causal_plan(l, bq, bk, window)
+    rows, cols = np.arange(l)[:, None], np.arange(l)[None, :]
+    assert plan.scores_kept == int(((rows >= cols) & (rows - cols < window)).sum())
+    band = [(qi, ki) for qi in range(l // bq) for ki in range(l // bk)
+            if ((rows >= cols) & (rows - cols < window))[qi * bq : (qi + 1) * bq, ki * bk : (ki + 1) * bk].any()]
+    assert plan.pairs == len(band)  # the pairs the band touches and no others
+    assert plan.diag_slab == (128 if (bq, bk) == (256, 256) and window >= 256 else bq)
+    assert plan.scores_kept <= plan.scores_computed <= plan.pairs * bq * bk
+    if window >= l:  # a window that holds the sequence is the causal mask
+        assert plan == causal_plan(l, bq, bk)
+
+
+def test_the_window_at_the_published_shape_cuts_the_causal_scores_four_times():
+    from cuda_mpi_gpu_cluster_programming_tpu.ops.flash_attention import causal_plan, flash_forward_bhld
+
+    window, causal = causal_plan(4096, 512, 512, 512), causal_plan(4096, 1024, 1024)
+    assert window.scores_kept == 512 * 513 // 2 + 3584 * 512 == 1_966_336
+    assert causal.scores_kept / window.scores_kept == pytest.approx(4.27, abs=5e-3)
+    assert window.pairs == 1 + 7 * 2 and window.diag_slab == 128
+    # 7 blocks whole and 8 on the diagonal in four slabs of 128 (10 / 16 of a block): 3,145,728 scores for 1,966,336
+    assert window.scores_computed == 7 * 262144 + 8 * 163840
+    assert window.masked_score_share == pytest.approx(0.3749, abs=5e-5)
+    smaller = causal_plan(4096, 256, 256, 512)  # blocks of 256 mask less and run slower (PERF.md section 6)
+    assert smaller.pairs == 1 + 2 + 14 * 3 and smaller.masked_score_share == pytest.approx(0.2682, abs=5e-5)
+    q = jnp.zeros((1, 2, 64, 16))
+    with pytest.raises(ValueError, match="window"):
+        flash_forward_bhld(q, q, q, causal=False, block_q=16, block_k=16, window=8)
+    with pytest.raises(ValueError, match="window"):
+        flash_forward_bhld(q, q, q, causal=True, block_q=16, block_k=16, window=0)
+
+
+# The step programs of the four accepted language-model cells at their real
+# shapes (the benchmark's presets, bf16) as jax 0.9.0 lowers them
+# (``.lower(...).as_text()``, no source location in it): sha256 of the text, as
+# PR 38's tree (b04fa42), the commit BEFORE the window, lowers them. With
+# ``window=None`` the kernel built is the one that was built: no accepted cell
+# can move under the band's code. A change that means to alter one of these
+# steps records the new digest here and says so.
+ACCEPTED_STEP_SHA256 = {
+    ("v8_mla_moe", "ep16_share"): "a7e34e95a624285f51ec9dd45b5c6f178ec9509f80114a001aea1f1d9fb62c9a",
+    ("v9_kda_moe", "solar_ep8"): "26d613a94daf0abc378e791f3e67ea338ec5b04b2e84e4b020d16976a5deea57",
+    ("v10_cca_moe", "zaya1_ep2"): "4eb995d440fdbda4bffeb79b736be536a8b2febf1d8c32671e9c70303871344b",
+    ("v11_scmoe_mla", "longcat_ep32"): "40f94650c47f306b0c538b0e3f1b670fbe907769515b2ab9ee5253b1cb9b9ca9",
+}
+
+
+@pytest.mark.parametrize("key,preset", sorted(ACCEPTED_STEP_SHA256))
+def test_without_a_window_the_accepted_cells_step_programs_are_the_parents(key, preset):
+    import hashlib
+
+    from cuda_mpi_gpu_cluster_programming_tpu.configs import REGISTRY, build_forward, language_model
+
+    if jax.__version__ != "0.9.0":
+        pytest.skip("the digests are of jax 0.9.0's lowering")
+    model = language_model(REGISTRY[key])
+    cfg, batch, seq = model.PRESETS[preset]
+    params = jax.eval_shape(lambda: model.init(jax.random.key(0), cfg, jnp.bfloat16))
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    text = build_forward(REGISTRY[key], cfg, n_shards=1, compute="bf16").lower(params, ids).as_text()
+    assert "loc(" not in text  # no source location in it: moving code changes nothing
+    assert hashlib.sha256(text.encode()).hexdigest() == ACCEPTED_STEP_SHA256[(key, preset)]

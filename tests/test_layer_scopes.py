@@ -86,6 +86,10 @@ def test_every_layer_of_the_chain_is_named_in_the_compiled_program(key):
         names = scopes.SCMOE_MLA_LAYERS
         chain = names[:4] + names[5:8] + names[4:5] + names[8:]
         assert sorted(chain) == sorted(names)
+    if exec_cfg.model == "sambay":  # an MLP after the first scan; the memory units only after both hand-off layers
+        names = scopes.SAMBAY_LAYERS
+        chain = names[:5] + names[9:10] + names[6:9] + names[5:6] + names[10:]
+        assert sorted(chain) == sorted(names)
     for layer in chain:
         assert _scoped(paths, layer), f"{key}: no operation under the scope {layer!r}"
     # in order: the jaxpr is the program as written, before any scheduling
@@ -152,6 +156,7 @@ def test_a_kernel_that_covers_conv_and_pool_says_so():
         ("v9_kda_moe", scopes.KDA_MOE_LAYERS),
         ("v10_cca_moe", scopes.CCA_MOE_LAYERS),
         ("v11_scmoe_mla", scopes.SCMOE_MLA_LAYERS),
+        ("v12_sambay", scopes.SAMBAY_LAYERS),
     ],
 )
 def test_token_ids_and_parameters_stored_in_bf16_pass_the_bf16_wrapper_uncast(key, layers):
