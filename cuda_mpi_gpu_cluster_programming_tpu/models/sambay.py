@@ -283,11 +283,30 @@ def _layer_norm(x, p: Params, eps: float):
     return scaled * p["gain"].astype(jnp.float32) + p["bias"].astype(jnp.float32)
 
 
-def _mlp(p: Params, x, cfg: SambayConfig):
-    """``x + MLP(LN(x))`` on the float32 stream ``(B, S, D)``."""
+def _w1_halves(w1, pair=None):
+    """The ``(gate, up)`` column halves of an MLP's first matrix, the
+    checkpoint's ``gate_up_proj`` (gate's columns first): of one layer's
+    ``(D, 2F)``, or with ``pair`` of that layer of a loop's stack ``(n, D,
+    2F)``, each half its own slice of the STACK. Its product then reads it
+    there; a layer sliced out whole has the two products for readers, and the
+    compiler copies it out first (0.32 ms an MLP; ``PERF.md``, PR 40)."""
+    if pair is None:
+        return jnp.split(w1, 2, axis=-1)
+    _n, d, width = w1.shape
+    return [lax.dynamic_slice(w1, (pair, 0, k * width // 2), (1, d, width // 2))[0] for k in (0, 1)]
+
+
+def _mlp(p: Params, x, cfg: SambayConfig, halves=None):
+    """``x + MLP(LN(x))`` on the float32 stream ``(B, S, D)``; ``halves`` are
+    ``w1``'s where the caller took them from a stack (:func:`_w1_halves`).
+    Each half is a product of its own, so that the gating is the second
+    product's epilogue and ``w2`` reads the hidden in the parameters' type: one
+    product of both halves leaves a float32 pair that ``w2`` gates in its
+    prologue."""
     with scopes.layer("dense_mlp"):
         u = _layer_norm(x, p["norm"], cfg.layer_norm_eps)
-        gate, up = jnp.split(_mm("bsd,df->bsf", u, p["w1"]), 2, axis=-1)
+        gate_w, up_w = halves or _w1_halves(p["w1"])
+        gate, up = _mm("bsd,df->bsf", u, gate_w), _mm("bsd,df->bsf", u, up_w)
         return x + _mm("bsf,fd->bsd", up * jax.nn.silu(gate), p["w2"])
 
 
@@ -404,15 +423,20 @@ def _layers(params: Params, ids, cfg: SambayConfig, with_statistics: bool = Fals
     with scopes.layer("embed"):
         x = params["embed"][ids].astype(jnp.float32)
 
+    def mlp(stack, p, x, pair):
+        """An MLP of a loop: ``p`` is the scan's slice of ``stack`` for this
+        ``pair``, of which ``w1`` is not read: its halves come from the stack."""
+        return _mlp(p, x, cfg, _w1_halves(stack["w1"], pair))
+
     def first_pair(x, inputs):
-        p, layer = inputs
+        p, pair = inputs
         x, _y, seen = _mamba(p["mamba"], x, cfg)
-        x = _mlp(p["mlp_a"], x, cfg)
-        x, _kv = _diff_attn(p["attn"], x, layer + 1, cfg, window=cfg.sliding_window)
-        return _mlp(p["mlp_b"], x, cfg), stats(*seen)
+        x = mlp(params["first"]["mlp_a"], p["mlp_a"], x, pair)
+        x, _kv = _diff_attn(p["attn"], x, 2 * pair + 1, cfg, window=cfg.sliding_window)
+        return mlp(params["first"]["mlp_b"], p["mlp_b"], x, pair), stats(*seen)
 
     with scopes.layer("layer_loop"):
-        x, seen_first = lax.scan(first_pair, x, (params["first"], 2 * jnp.arange(cfg.first_pairs, dtype=jnp.int32)))
+        x, seen_first = lax.scan(first_pair, x, (params["first"], jnp.arange(cfg.first_pairs, dtype=jnp.int32)))
     mid = params["mid"]
     x, memory, seen = _mamba(mid["mamba"], x, cfg)
     x = _mlp(mid["mlp_a"], x, cfg)
@@ -420,15 +444,13 @@ def _layers(params: Params, ids, cfg: SambayConfig, with_statistics: bool = Fals
     x = _mlp(mid["mlp_b"], x, cfg)
 
     def last_pair(x, inputs):
-        p, layer = inputs
-        x = _mlp(p["mlp_a"], _gmu(p["gmu"], x, memory, cfg), cfg)
-        x, _kv = _diff_attn(p["attn"], x, layer + 1, cfg, kv=kv)
-        return _mlp(p["mlp_b"], x, cfg), None
+        p, pair = inputs
+        x = mlp(params["last"]["mlp_a"], p["mlp_a"], _gmu(p["gmu"], x, memory, cfg), pair)
+        x, _kv = _diff_attn(p["attn"], x, half + 2 * pair + 3, cfg, kv=kv)
+        return mlp(params["last"]["mlp_b"], p["mlp_b"], x, pair), None
 
     with scopes.layer("layer_loop"):
-        x, _ = lax.scan(
-            last_pair, x, (params["last"], half + 2 + 2 * jnp.arange(cfg.last_pairs, dtype=jnp.int32))
-        )
+        x, _ = lax.scan(last_pair, x, (params["last"], jnp.arange(cfg.last_pairs, dtype=jnp.int32)))
     if not with_statistics:
         return x, None
     with scopes.layer("mamba.mix"):

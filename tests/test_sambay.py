@@ -141,6 +141,43 @@ def test_each_kind_of_layer_is_the_references_layer(kind, compute):
     assert _err(got - x, want - x) < tol, kind  # the mixer's own addition to the stream
 
 
+def _mlp_one_product(p, x, halves=lambda gate, up: (gate, up)):
+    """The form ``sambay._mlp`` replaced: ONE product of the stored ``(D, 2F)``
+    matrix, split into gate and up (``halves`` may swap them)."""
+    u = sambay._layer_norm(x, p["norm"], SMALL.layer_norm_eps)
+    gate, up = halves(*jnp.split(moe_share._mm("bsd,df->bsf", u, p["w1"]), 2, axis=-1))
+    return x + moe_share._mm("bsf,fd->bsd", up * jax.nn.silu(gate), p["w2"])
+
+
+@pytest.mark.parametrize("compute", ["fp32", "bf16"])
+def test_the_mlp_of_two_products_is_the_one_product_split(compute):
+    # (B, S, D, F) = (2, 32, 64, 128); the stream is of unit size, the MLP's addition a fraction of it
+    p, x = _mid_and_pairs(_params(7, DTYPES[compute]))[1]["mlp_b"], _stream(7)
+    got, want = sambay._mlp(p, x, SMALL) - x, _mlp_one_product(p, x) - x
+    # float32 at HIGHEST: the same sums in another order; bf16: the hidden may round the other way, once
+    assert _err(got, want) < (1e-6 if compute == "fp32" else 2.0**-8)
+
+
+def test_the_mlp_takes_the_gate_from_the_first_half_of_w1_and_up_from_the_second():
+    # the draw's halves differ, and silu(g) * u is not silu(u) * g: the swapped form is another function
+    p, x = _mid_and_pairs(_params(8))[1]["mlp_a"], _stream(8)
+    width = p["w1"].shape[-1] // 2
+    assert float(jnp.abs(p["w1"][:, :width] - p["w1"][:, width:]).max()) > 0.1
+    got = sambay._mlp(p, x, SMALL) - x
+    assert _err(got, _mlp_one_product(p, x) - x) < 1e-6
+    assert _err(got, _mlp_one_product(p, x, halves=lambda gate, up: (up, gate)) - x) > 0.1
+
+
+@pytest.mark.parametrize("pair", [0, 2])
+def test_a_stacks_halves_are_the_halves_of_that_pair_s_matrix(pair):
+    # what a loop's MLP reads: each half sliced out of the stack (n, D, 2F) at a traced index
+    stack = _params(9)["first"]["mlp_b"]["w1"]
+    gate, up = jax.jit(sambay._w1_halves)(stack, jnp.int32(pair))
+    want_gate, want_up = sambay._w1_halves(stack[pair])
+    assert np.array_equal(gate, want_gate) and np.array_equal(up, want_up) and not np.array_equal(gate, up)
+    assert np.array_equal(jnp.concatenate([gate, up], axis=-1), stack[pair])
+
+
 def test_the_differential_head_is_its_dense_formula_with_lambda_from_the_four_vectors():
     params = _params(6)
     p, x, layer = params["mid"]["attn"], _stream(6, batch=1), HALF + 1
