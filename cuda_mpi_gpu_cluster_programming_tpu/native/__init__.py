@@ -30,8 +30,8 @@ from typing import Iterator, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..models.alexnet import Blocks12Config, ConvSpec, LrnSpec, PoolSpec
-from ..parallel.plan import LayerPlan, ShardPlan
+from ..models.alexnet import Blocks12Config
+from ..parallel.plan import LayerPlan, ShardPlan, layer_geometry
 
 _SRC_DIR = Path(__file__).parent / "csrc"
 _BUILD_DIR = Path(__file__).parent / "_build"
@@ -107,8 +107,6 @@ def _load() -> ctypes.CDLL:
         lib.sp_conv_out_dim.argtypes = [ctypes.c_int] * 4
         lib.sp_pool_out_dim.restype = ctypes.c_int
         lib.sp_pool_out_dim.argtypes = [ctypes.c_int] * 3
-        lib.sp_plan_layer.restype = ctypes.c_int
-        lib.sp_plan_layer.argtypes = [ctypes.c_int] * 6 + [ctypes.POINTER(_LayerPlanC)]
         lib.sp_plan_chain.restype = ctypes.c_int
         lib.sp_plan_chain.argtypes = [
             ctypes.c_int,
@@ -159,15 +157,9 @@ def pool_out_dim(d: int, f: int, s: int) -> int:
 def _chain_arrays(cfg: Blocks12Config):
     names, kinds, fs, ss, ps = [], [], [], [], []
     for name, spec in cfg.layer_chain():
+        kind, f, s, p = layer_geometry(spec)
         names.append(name)
-        if isinstance(spec, ConvSpec):
-            kinds.append(0); fs.append(spec.filter_size); ss.append(spec.stride); ps.append(spec.padding)
-        elif isinstance(spec, PoolSpec):
-            kinds.append(1); fs.append(spec.window); ss.append(spec.stride); ps.append(0)
-        elif isinstance(spec, LrnSpec):
-            kinds.append(2); fs.append(1); ss.append(1); ps.append(0)
-        else:
-            raise TypeError(f"unknown layer spec {spec!r}")
+        kinds.append(_KIND_CODE[kind]); fs.append(f); ss.append(s); ps.append(p)
     return names, kinds, fs, ss, ps
 
 

@@ -12,6 +12,7 @@
 
 #include <algorithm>
 #include <cstdint>
+#include <vector>
 
 namespace {
 
@@ -61,22 +62,14 @@ enum {
   SP_ERR_BAD_ARG = -3,      // n_shards < 1 or unknown kind
 };
 
-// Mirrors parallel/plan.py _plan_spatial_layer (kind 0/1) and the pointwise
-// branch of make_shard_plan (kind 2).
-int sp_plan_layer(int kind, int l_in, int n, int f, int s, int p,
-                  sp_layer_plan* out) {
-  if (n < 1 || kind < 0 || kind > 2 || out == nullptr) return SP_ERR_BAD_ARG;
+// Mirrors parallel/plan.py _plan_spatial_layer (kind 0/1) and its pointwise
+// branch (kind 2), on the blocks make_shard_plan hands it.
+static int plan_layer(int kind, int l_in, int l_out, int n, int f, int s, int p,
+                      int b_in, int b_out, sp_layer_plan* out) {
   if (kind == 2) {  // pointwise (LRN): block-identical geometry, no halo
-    int b = ceil_div(l_in, n);
-    *out = {2, 1, 1, 0, l_in, l_in, b, b, 0, 0, 0, 0, b, 0};
+    *out = {2, 1, 1, 0, l_in, l_out, b_in, b_out, 0, 0, 0, 0, b_out, 0};
     return SP_OK;
   }
-  int l_out = kind == 0 ? sp_conv_out_dim(l_in, f, p, s) : sp_pool_out_dim(l_in, f, s);
-  if (l_out <= 0) return SP_ERR_DEGENERATE;
-  if (kind == 1) p = 0;
-  int b_in = ceil_div(l_in, n);
-  int b_out = ceil_div(l_out, n);
-
   int h_top = 0, h_bot = 0;
   for (int i = 0; i < n; ++i) {
     int own_start = i * b_out;
@@ -108,17 +101,34 @@ int sp_plan_layer(int kind, int l_in, int n, int f, int s, int p,
   return SP_OK;
 }
 
-// Plan a chain of layers: layer i consumes layer i-1's l_out. kinds/fs/ss/ps
+// Mirrors parallel/plan.py make_shard_plan: layer i consumes layer i-1's
+// l_out; the lengths run forward, the blocks backward from the last layer's
+// ceil(L/n), each earlier one max(ceil(L/n), S * the next). kinds/fs/ss/ps
 // are parallel arrays of length n_layers. Returns SP_OK or the first error.
 int sp_plan_chain(int n_layers, const int32_t* kinds, const int32_t* fs,
                   const int32_t* ss, const int32_t* ps, int l0, int n_shards,
                   sp_layer_plan* out) {
-  if (n_layers < 1 || !kinds || !fs || !ss || !ps || !out) return SP_ERR_BAD_ARG;
-  int l_cur = l0;
+  if (n_layers < 1 || n_shards < 1 || !kinds || !fs || !ss || !ps || !out) return SP_ERR_BAD_ARG;
+  std::vector<int> len(n_layers + 1), blk(n_layers + 1);
+  len[0] = l0;
   for (int i = 0; i < n_layers; ++i) {
-    int rc = sp_plan_layer(kinds[i], l_cur, n_shards, fs[i], ss[i], ps[i], &out[i]);
+    int k = kinds[i], f = fs[i], s = ss[i], p = ps[i];
+    if (k < 0 || k > 2) return SP_ERR_BAD_ARG;
+    int l = k == 0 ? sp_conv_out_dim(len[i], f, p, s)
+          : k == 1 ? sp_pool_out_dim(len[i], f, s) : len[i];
+    if (l <= 0) return SP_ERR_DEGENERATE;
+    len[i + 1] = l;
+  }
+  blk[n_layers] = ceil_div(len[n_layers], n_shards);
+  for (int i = n_layers - 1; i >= 0; --i) {
+    int s = kinds[i] == 2 ? 1 : ss[i];
+    blk[i] = std::max(ceil_div(len[i], n_shards), s * blk[i + 1]);
+  }
+  for (int i = 0; i < n_layers; ++i) {
+    int p = kinds[i] == 1 ? 0 : ps[i];
+    int rc = plan_layer(kinds[i], len[i], len[i + 1], n_shards, fs[i], ss[i], p,
+                        blk[i], blk[i + 1], &out[i]);
     if (rc != SP_OK) return rc;
-    l_cur = out[i].l_out;
   }
   return SP_OK;
 }

@@ -11,10 +11,17 @@ v4_mpi_cuda/src/alexnet_mpi_cuda.cu:27-38,58-83). This planner implements
 that exact-ownership semantics, SPMD-statically, and never computes invalid
 rows in the first place:
 
-- Every layer's rows are partitioned into fixed-size blocks of
-  ``ceil(L/n)`` rows per shard (SPMD needs equal block shapes); shard ``i``
-  *owns* global output rows ``[i*B_out, min((i+1)*B_out, L_out))`` — rows
-  past the end are dead and kept zero (the "mask invariant").
+- Every layer's rows are partitioned into fixed-size blocks per shard
+  (SPMD needs equal block shapes); shard ``i`` *owns* global output rows
+  ``[i*B_out, min((i+1)*B_out, L_out))`` — rows past the end are dead and
+  kept zero (the "mask invariant").
+- The blocks are drawn backward from the last layer, whose block is
+  ``ceil(L/n)``: every earlier activation's block is what its consumer reads
+  without drift, ``B = max(ceil(L/n), S*B_next)``. Where ``S*B_next`` covers
+  the layer, shard ``i``'s window starts at ``i*B_in - P`` for every ``i``
+  and the halo is the layer's natural ``P`` rows on top and ``F - S - P``
+  below; only where ``ceil`` wins does the window drift with ``i`` and the
+  halo grow with it (227 rows over 7 shards).
 - For a conv/pool with (F, S, P), shard ``i``'s owned output rows need
   global input rows ``[i*B_out*S - P, (end_own-1)*S - P + F)``. The planner
   turns that into static top/bottom halo widths (max over shards) plus a
@@ -79,16 +86,21 @@ class ShardPlan:
         return self.layers[-1].l_out
 
 
-def _plan_spatial_layer(name: str, kind: str, l_in: int, n: int, f: int, s: int, p: int) -> LayerPlan:
+def _out_len(name: str, kind: str, l_in: int, f: int, s: int, p: int) -> int:
     if kind == "conv":
         l_out = conv_out_dim(l_in, f, p, s)
-    else:
+    elif kind == "pool":
         l_out = pool_out_dim(l_in, f, s)
+    else:
+        l_out = l_in
     if l_out <= 0:
         raise ValueError(f"layer {name}: degenerate output length {l_out} (l_in={l_in}, f={f}, s={s}, p={p})")
-    b_in = math.ceil(l_in / n)
-    b_out = math.ceil(l_out / n)
+    return l_out
 
+
+def _plan_spatial_layer(
+    name: str, kind: str, l_in: int, l_out: int, n: int, f: int, s: int, p: int, b_in: int, b_out: int
+) -> LayerPlan:
     h_top = 0
     h_bot = 0
     for i in range(n):
@@ -148,29 +160,38 @@ def _plan_spatial_layer(name: str, kind: str, l_in: int, n: int, f: int, s: int,
     )
 
 
+def layer_geometry(spec) -> Tuple[str, int, int, int]:
+    """(kind, F, S, P) of one layer of the chain."""
+    if isinstance(spec, ConvSpec):
+        return "conv", spec.filter_size, spec.stride, spec.padding
+    if isinstance(spec, PoolSpec):
+        return "pool", spec.window, spec.stride, 0
+    if isinstance(spec, LrnSpec):
+        return "pointwise", 1, 1, 0
+    raise TypeError(f"unknown layer spec {spec!r}")
+
+
 def make_shard_plan(cfg: Blocks12Config, n_shards: int) -> ShardPlan:
-    """Plan every spatial layer of Blocks 1-2 for an ``n_shards`` row mesh."""
+    """Plan every spatial layer of Blocks 1-2 for an ``n_shards`` row mesh:
+    the lengths forward, the blocks backward from the last layer's."""
     if n_shards < 1:
         raise ValueError("n_shards must be >= 1")
+    chain = [(name, *layer_geometry(spec)) for name, spec in cfg.layer_chain()]
+    lengths = [cfg.in_height]
+    for name, kind, f, s, p in chain:
+        lengths.append(_out_len(name, kind, lengths[-1], f, s, p))
+    blocks = [math.ceil(length / n_shards) for length in lengths]
+    for k in reversed(range(len(chain))):
+        stride = chain[k][3]
+        blocks[k] = max(blocks[k], stride * blocks[k + 1])
     layers: List[LayerPlan] = []
-    l_cur = cfg.in_height
-    for name, spec in cfg.layer_chain():
-        if isinstance(spec, ConvSpec):
-            lp = _plan_spatial_layer(
-                name, "conv", l_cur, n_shards, spec.filter_size, spec.stride, spec.padding
-            )
-        elif isinstance(spec, PoolSpec):
-            lp = _plan_spatial_layer(name, "pool", l_cur, n_shards, spec.window, spec.stride, 0)
-        elif isinstance(spec, LrnSpec):
-            prev_out = layers[-1].l_out if layers else l_cur
-            b = math.ceil(prev_out / n_shards)
-            lp = LayerPlan(
-                name, "pointwise", 1, 1, 0, prev_out, prev_out, b, b, 0, 0, 0, 0, b, 0
-            )
+    for k, (name, kind, f, s, p) in enumerate(chain):
+        l_in, l_out, b_in, b_out = lengths[k], lengths[k + 1], blocks[k], blocks[k + 1]
+        if kind == "pointwise":
+            lp = LayerPlan(name, kind, 1, 1, 0, l_in, l_out, b_in, b_out, 0, 0, 0, 0, b_out, 0)
         else:
-            raise TypeError(f"unknown layer spec {spec!r}")
+            lp = _plan_spatial_layer(name, kind, l_in, l_out, n_shards, f, s, p, b_in, b_out)
         layers.append(lp)
-        l_cur = lp.l_out
     return ShardPlan(n_shards=n_shards, layers=tuple(layers))
 
 

@@ -27,9 +27,11 @@ What happens to ``x`` depends on where it lives, which the code can see:
   itself scatters it. The program's argument is a batch-sharded global
   array whose shard on the holder is ``x`` as it stands (no copy) and whose
   other shards are stand-ins that are never read; inside, the holder does
-  ``cast_in`` and the ``scatter`` pad, cuts the result into the ``n`` row
-  blocks ``(N, b0, W, C)`` in the compute type and sends block ``j`` to
-  device ``j`` by a ``ppermute`` with itself as the one source. One
+  ``cast_in``, cuts the ``H`` real rows into the ``n`` row blocks in the
+  compute type and sends block ``j`` to device ``j`` by a ``ppermute``
+  with itself as the one source; a block short of ``b0`` rows (the last,
+  where ``n*b0 > H``) is zero-padded where it arrives, so no zero row
+  travels. One
   dispatch a step, and nothing is replicated (a jitted multi-device program
   treats a single-device argument as replicated: the whole float32 batch
   went from the holder to every device before every step);
@@ -61,6 +63,7 @@ from ..observability.metrics import registry as metrics_registry
 from ..ops import reference as ops
 from ..ops import scopes
 from ..ops.vma import kernel_check_vma
+from .breakdown import comm_compute_breakdown
 from .halo import exchange
 from .mesh import make_mesh
 from .plan import LayerPlan, make_shard_plan
@@ -352,7 +355,15 @@ def build_sharded_forward(
     def step(params, xb):
         # xb: (N, n*b0, W, C), zero rows past H; row-sharded where the
         # input sharding is declared
-        out = sharded(cast_params(params), cast_x(xb))  # (N, n*b_final, W', C') [, digests]
+        xb = cast_x(xb)
+        if n > 1:
+            # set as the program is traced: the plan's halo rows times the
+            # block widths, for this batch and compute type
+            halo = comm_compute_breakdown(
+                model_cfg, n, batch=xb.shape[0], dtype_bytes=xb.dtype.itemsize, staged=staged
+            )
+            metrics_registry().gauge(HALO_BYTES).set(sum(r.halo_bytes for r in halo))
+        out = sharded(cast_params(params), xb)  # (N, n*b_final, W', C') [, digests]
         if with_digests:
             out, digs = out
         with scopes.gather():
@@ -370,14 +381,17 @@ def build_sharded_forward(
     def scatter_step_from(src: int):
         def scatter_body(xl):
             # xl (N, H, W, C): the batch on device src, its stand-in elsewhere
-            xl = pad_rows(cast_x(xl))
+            xl = cast_x(xl)
+            h = xl.shape[1]
             with scopes.scatter():
-                blocks = [xl[:, j * b0 : (j + 1) * b0] for j in range(n)]
-                arrived = [
-                    blocks[j] if j == src
-                    else lax.ppermute(blocks[j], AXIS, perm=[(src, j)])
-                    for j in range(n)
-                ]
+                arrived = []
+                for j in range(n):
+                    # the real rows of block j alone; zeros only where it lands
+                    block = xl[:, min(j * b0, h) : min((j + 1) * b0, h)]
+                    if j != src and block.shape[1]:
+                        block = lax.ppermute(block, AXIS, perm=[(src, j)])
+                    short = b0 - block.shape[1]
+                    arrived.append(jnp.pad(block, ((0, 0), (0, short), (0, 0), (0, 0))))
                 return lax.select_n(lax.axis_index(AXIS), *arrived)
 
         scatter = shard_map(
@@ -405,6 +419,7 @@ SCATTERED_CALLS = "sharding.scatter.scattered_calls"
 PLACED_CALLS = "sharding.scatter.placed_calls"
 IN_GRAPH_CALLS = "sharding.scatter.in_graph_calls"
 SCATTERED_BYTES = "sharding.scatter.bytes_off_holder"
+HALO_BYTES = "sharding.halo_bytes"
 
 
 class RowScatteredForward:
@@ -419,10 +434,14 @@ class RowScatteredForward:
     integers only: ``sharding.scatter.scattered_calls`` (x arrived on one
     device, or on the host, and went out in row blocks),
     ``sharding.scatter.bytes_off_holder`` (the bytes that left the device,
-    or host, that held x, summed over those calls: a constant per shape and
-    call), ``sharding.scatter.placed_calls`` (x already had the row
-    sharding) and ``sharding.scatter.in_graph_calls`` (x a tracer, counted
-    once per trace, or laid out some other way).
+    or host, that held x, summed over those calls: the real rows of the
+    blocks that leave a device, the padded blocks that leave the host; a
+    constant per shape and call), ``sharding.scatter.placed_calls`` (x
+    already had the row sharding) and ``sharding.scatter.in_graph_calls``
+    (x a tracer, counted once per trace, or laid out some other way). The
+    gauge ``sharding.halo_bytes`` is set as a step program is traced: the
+    bytes one interior chip receives by halo in one step
+    (``breakdown.comm_compute_breakdown``'s total for that batch and type).
     """
 
     def __init__(self, *, whole, step, scatter_step, rows: NamedSharding, b0: int, x_dtype):
@@ -487,7 +506,9 @@ class RowScatteredForward:
         if holder in self.devices:
             src = self.devices.index(holder)
             itemsize = np.dtype(self.x_dtype or x.dtype).itemsize
-            off_holder = (n - 1) * x.shape[0] * self.b0 * math.prod(x.shape[2:]) * itemsize
+            # every real row but those of the holder's own block
+            own = max(0, min((src + 1) * self.b0, x.shape[1]) - src * self.b0)
+            off_holder = x.shape[0] * (x.shape[1] - own) * math.prod(x.shape[2:]) * itemsize
         else:  # x leaves its holder whole, for the first device of the mesh
             src, off_holder = 0, x.nbytes
             x = jax.device_put(x, self.devices[0])

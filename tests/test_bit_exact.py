@@ -26,8 +26,6 @@ disagreed structurally (the CUDA LRN drops the /N scale entirely —
 v3_cuda_only/src/layers_cuda.cu:139 vs v1_serial/src/layers_serial.cpp:151).
 """
 
-import warnings
-
 import jax
 import numpy as np
 import pytest
@@ -109,18 +107,14 @@ def test_pallas_tier_sharding_under_g8(workload, monkeypatch, n):
     """Pre-adoption guard for the queued g8 chip A/B: shard-vs-single
     under the phase-packed conv.
 
-    Measured behavior (this test found it): the contract is
-    parity-sensitive. A shard whose global output-row start is EVEN keeps
-    local phase parity == global parity and matches the single run
-    bitwise (n=1, 2, 4: conv1 row starts 0/28/14·k). An ODD start (n=3:
-    55 rows split 19/18/18, shard 1 starts at 19) flips the local parity,
+    The contract is parity-sensitive (this test found it): a shard whose
+    global conv1 output-row start is ODD flips the local phase parity,
     which moves the zero-padding layout inside the phase weight frames —
-    same real products, different reduction grouping — so the middle
-    shard's rows drift by last-ulps (measured 2.3e-7 rel max). Values are
-    correct; bit-exactness would require even-aligning each shard's g8
-    row base (compute one extra garbage row and crop) — the named
-    adoption requirement if the chip A/B ever makes g8 the sharded-tier
-    default (docs/PALLAS_PERF.md).
+    same real products, different reduction grouping — and drifted by
+    last-ulps (2.3e-7 rel max) when 3 shards split conv1's 55 rows
+    19/18/18. Since PR 41 the plan draws conv1's block from pool1's
+    (``2 * b_pool1``: 20 rows at 3 shards), so every start is even and
+    every case here is bitwise (docs/PALLAS_PERF.md).
 
     The single-device side passes ``variants`` EXPLICITLY: a bare
     ``jax.jit(forward_blocks12_pallas)`` after the fixture already traced
@@ -138,21 +132,4 @@ def test_pallas_tier_sharding_under_g8(workload, monkeypatch, n):
     got = np.asarray(
         build_forward(REGISTRY["v5_collective"], BLOCKS12, n_shards=n)(params, x)
     )
-    if n == 3:  # odd-start shard: reduction-order tolerance, not bitwise
-        np.testing.assert_allclose(got, single, rtol=2e-6, atol=2e-6)
-        if not (got != single).any():
-            # Canary, not a gate (ADVICE round-5 item 2): the drift is a
-            # measured property of the CPU-interpret backend's reduction
-            # grouping, not a contract — a JAX/XLA upgrade that happens to
-            # make the odd-start shard bitwise-equal is a numerics
-            # IMPROVEMENT and must not hard-fail CI. The warning keeps the
-            # signal: when it fires on the measuring backend, tighten this
-            # branch back to assert_array_equal.
-            warnings.warn(
-                f"n=3 now matches bitwise on backend {jax.default_backend()!r}"
-                " — the g8 parity sensitivity is gone; tighten this branch "
-                "back to assert_array_equal",
-                RuntimeWarning,
-            )
-    else:
-        np.testing.assert_array_equal(got, single)
+    np.testing.assert_array_equal(got, single)

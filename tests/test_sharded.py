@@ -43,14 +43,78 @@ def test_plan_covers_all_rows():
             assert covered == list(range(lp.l_out)), (n, lp.name)
 
 
-@pytest.mark.parametrize("n", [1, 2, 4, 8])
+def _geometry(lp):
+    return (
+        lp.b_in, lp.b_out, lp.h_top, lp.h_bot, lp.s0_coef, lp.s0_const, lp.win_rows, lp.pad_bot
+    )
+
+
+def test_plan_at_four_shards_is_drawn_from_the_consumer():
+    """Each block is what the next layer reads: 64/16/8/8/4 rows, every
+    window start static and every halo the layer's natural P / F-S-P rows —
+    pool2's 1 bottom row, not 4, and conv1's one exchange of 7, not 3 + 6."""
+    plan = make_shard_plan(BLOCKS12, 4)
+    got = {lp.name: (lp.b_in, lp.b_out, lp.h_top, lp.h_bot) for lp in plan.layers}
+    assert got == {
+        "conv1": (64, 16, 0, 7),
+        "pool1": (16, 8, 0, 1),
+        "conv2": (8, 8, 2, 2),
+        "pool2": (8, 4, 0, 1),
+        "lrn2": (4, 4, 0, 0),
+    }
+    assert all(lp.s0_coef == 0 for lp in plan.layers)
+
+
+@pytest.mark.parametrize(
+    "n,want",
+    [
+        # ceil(L/n) already aligned: the plan of PR 40 and before, field for field
+        (2, [(114, 28, 2, 5, -2, 2, 119, 0), (28, 14, 0, 1, 0, 0, 29, 0),
+             (14, 14, 2, 2, 0, 0, 18, 0), (14, 7, 0, 1, 0, 0, 15, 0), (7, 7, 0, 0, 0, 0, 7, 0)]),
+        # 4*8 = 32 rows cannot cover 227 over 7: conv1's ceil wins and drifts
+        (7, [(33, 8, 6, 6, -1, 6, 39, 0), (8, 4, 0, 1, 0, 0, 9, 0),
+             (4, 4, 2, 2, 0, 0, 8, 0), (4, 2, 0, 1, 0, 0, 5, 0), (2, 2, 0, 0, 0, 0, 2, 0)]),
+    ],
+)
+def test_plan_unchanged_where_ceil_already_aligned(n, want):
+    assert [_geometry(lp) for lp in make_shard_plan(BLOCKS12, n).layers] == want
+
+
+@pytest.mark.parametrize("n", range(1, 17))
+def test_plan_windows_cover_what_each_shard_owns(n):
+    """Heights 63..227: the blocks chain (a layer's output block is the
+    next one's input block) and cover the rows, and every owning shard's
+    window lies in its padded buffer and spans exactly the input rows its
+    owned output rows read."""
+    for h in range(63, 228):
+        cfg = dataclasses.replace(BLOCKS12, in_height=h, in_width=h)
+        plan = make_shard_plan(cfg, n)
+        for lp, nxt in zip(plan.layers, plan.layers[1:]):
+            assert lp.b_out == nxt.b_in and lp.l_out == nxt.l_in, (h, lp.name)
+        for lp in plan.layers:
+            assert n * lp.b_in >= lp.l_in and n * lp.b_out >= lp.l_out, (h, lp.name)
+            for i in range(n):
+                s, e = owned_range(lp.b_out, lp.l_out, i)
+                if s >= e:
+                    continue
+                s0 = i * lp.s0_coef + lp.s0_const
+                assert 0 <= s0 and s0 + lp.win_rows <= lp.padded_rows, (h, n, lp.name, i)
+                first = i * lp.b_in - lp.h_top + s0  # the window's first row, global
+                assert first == s * lp.stride - lp.padding, (h, n, lp.name, i)
+                last = (e - 1) * lp.stride - lp.padding + lp.filter_size
+                assert last <= first + lp.win_rows, (h, n, lp.name, i)
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 4, 5, 7, 8])
 def test_sharded_matches_single_deterministic(n, single_out):
+    """Float32, bit for bit: plans drawn from the consumer (3, 4, 5, 8) and
+    plans that drift (2, 7) alike."""
     params = init_params_deterministic()
     x = deterministic_input(batch=1)
     fwd = build_sharded_forward(BLOCKS12, n_shards=n)
     out = np.asarray(fwd(params, x))
     assert out.shape == single_out.shape
-    np.testing.assert_allclose(out, single_out, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(out, single_out)
 
 
 @pytest.mark.parametrize("n", [2, 8])
@@ -61,7 +125,7 @@ def test_sharded_matches_single_random(n):
     x = random_input(kx, batch=2)
     want = np.asarray(jax.jit(forward_blocks12)(params, x))
     got = np.asarray(build_sharded_forward(BLOCKS12, n_shards=n)(params, x))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("n", [2, 4])
@@ -246,7 +310,10 @@ def test_step_program_moves_only_row_blocks(abstract):
     assert [(op, pairs) for op, pairs, _t in of_the_input[:3]] == [
         ("collective_permute", f"[[0, {j}]]") for j in (1, 2, 3)
     ], sent
-    assert [t for _op, _pairs, t in of_the_input[:3]] == ["2x57x227x3xbf16"] * 3, sent
+    # 64-row blocks of the 227 real rows: the last carries 35, and no zero row
+    assert [t for _op, _pairs, t in of_the_input[:3]] == [
+        "2x64x227x3xbf16", "2x64x227x3xbf16", "2x35x227x3xbf16"
+    ], sent
     # the rest are conv1's halo rows: nothing else of the input moves
     assert all(int(t.split("x")[1]) < 10 for _op, _pairs, t in of_the_input[3:]), sent
     assert not [t for _op, _pairs, t in sent if t.endswith("x227x3xf32")], sent
@@ -280,13 +347,17 @@ def test_scatter_counters():
 
     params, x = _small_case()
     fwd = build_sharded_forward(SMALL, n_shards=4, compute_dtype=jnp.bfloat16)
-    block = 2 * 16 * 63 * 3  # (N, b0, W, C) elements: 64 rows over 4 shards
+    row = 2 * 63 * 3  # (N, 1, W, C) elements; blocks of 16, 16, 16 and 15 real rows
+    block = 16 * row
     before = read()
     fwd(params, x)  # held by device 0, which owns block 0: three blocks leave, in bf16
     fwd(params, x)
-    assert [a - b for a, b in zip(read(), before)] == [2, 0, 0, 2 * 3 * block * 2]
+    assert [a - b for a, b in zip(read(), before)] == [2, 0, 0, 2 * (16 + 16 + 15) * row * 2]
     before = read()
     fwd(params, jax.device_put(x, jax.devices()[2]))  # device 2 keeps block 2
+    assert [a - b for a, b in zip(read(), before)] == [1, 0, 0, (16 + 16 + 15) * row * 2]
+    before = read()
+    fwd(params, jax.device_put(x, jax.devices()[3]))  # device 3 keeps the short block
     assert [a - b for a, b in zip(read(), before)] == [1, 0, 0, 3 * block * 2]
     before = read()
     fwd(params, jax.device_put(x, jax.devices()[-1]))  # held outside the mesh: x leaves whole
@@ -327,3 +398,38 @@ def test_parameters_are_placed_once_for_a_tree_that_comes_again():
     on_host = jax.tree.map(np.asarray, params)
     assert fwd._on_every_device(on_host) is on_host
     np.testing.assert_array_equal(np.asarray(fwd(on_host, x)), first)
+
+
+def test_halo_bytes_gauge_at_four_shards():
+    """``sharding.halo_bytes``: what one interior chip receives by halo in a
+    step, set as the step program is traced — conv1's 7 input rows, pool1's
+    1, conv2's 4 and pool2's 1, at batch 128 in bf16: 7.0 MB (12.6 MB with
+    the plan of PR 40)."""
+    import jax.numpy as jnp
+
+    from cuda_mpi_gpu_cluster_programming_tpu.observability.metrics import registry
+    from cuda_mpi_gpu_cluster_programming_tpu.parallel import sharded
+
+    params = jax.eval_shape(init_params_deterministic)
+    x = jax.ShapeDtypeStruct((128, 227, 227, 3), jnp.float32)
+    build_sharded_forward(BLOCKS12, n_shards=4, compute_dtype=jnp.bfloat16).lower(params, x)
+    rows = 7 * 227 * 3 + 1 * 55 * 96 + 4 * 27 * 96 + 1 * 27 * 256
+    assert registry().summary()[sharded.HALO_BYTES] == 128 * rows * 2 == 6_995_712
+
+
+def test_scatter_sends_only_real_rows_at_four_shards():
+    """227 rows in blocks of 64: chip 0 sends 64, 64 and 35 real rows (163,
+    not 3 x 57 = 171) and the short block is padded where it lands; the
+    output is the in-graph program's, bit for bit."""
+    import jax.numpy as jnp
+
+    from cuda_mpi_gpu_cluster_programming_tpu.observability.metrics import registry
+    from cuda_mpi_gpu_cluster_programming_tpu.parallel import sharded
+
+    kp, kx = jax.random.split(jax.random.PRNGKey(3))
+    params, x = init_params_random(kp), random_input(kx, batch=1)
+    fwd = build_sharded_forward(BLOCKS12, n_shards=4, compute_dtype=jnp.bfloat16)
+    before = registry().summary().get(sharded.SCATTERED_BYTES, 0)
+    got = fwd(params, x)
+    assert registry().summary()[sharded.SCATTERED_BYTES] - before == 1 * 163 * 227 * 3 * 2
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(fwd.whole(params, x)))
