@@ -199,21 +199,6 @@ def language_model(exec_cfg: ExecConfig):
     return importlib.import_module(f".models.{exec_cfg.model}", __package__)
 
 
-def _resolve_variants(plan):
-    """Env-resolved variants, overlaid with a TunePlan's per-layer winners
-    when one is supplied. Precedence per knob: explicit env var beats the
-    tuned plan beats the code default (tuning.plan.effective_layer_variants
-    is the one implementation)."""
-    from .ops.pallas_kernels import KernelVariants
-
-    kv = KernelVariants.resolve()
-    if plan is None:
-        return kv
-    from .tuning.plan import effective_layer_variants
-
-    return effective_layer_variants(plan, base=kv)
-
-
 def build_forward(
     exec_cfg: ExecConfig,
     model_cfg=None,
@@ -242,7 +227,8 @@ def build_forward(
     before persisting a winner).
     ``plan``: a ``tuning.plan.TunePlan`` whose per-layer kernel variants the
     Pallas tiers run with (single-device AND sharded builders; reference
-    tiers ignore it); explicit env knobs still win — see docs/TUNING.md.
+    tiers ignore it); without one they run the defaults, ``KernelVariants()``
+    — see docs/TUNING.md.
     ``donate``: donate the input-activation buffer to the computation
     (single-device tiers; halves peak HBM for the activation at the cost of
     consuming ``x`` — callers that re-invoke with the same array, e.g. the
@@ -311,8 +297,9 @@ def build_forward(
                 )
             return _observed(fwd, exec_cfg, pol.name, n_shards)
         from .precision.quantize import forward_blocks12_int8w
+        from .tuning.plan import effective_layer_variants
 
-        kv = _resolve_variants(plan) if exec_cfg.tier == "pallas" else None
+        kv = effective_layer_variants(plan) if exec_cfg.tier == "pallas" else None
         tier = exec_cfg.tier
         return _observed(
             _jit(
@@ -417,12 +404,9 @@ def _build_forward_fp32(
         if exec_cfg.strategy == "single":
             if exec_cfg.tier == "pallas":
                 from .ops.pallas_model import forward_alexnet_pallas
+                from .tuning.plan import effective_layer_variants
 
-                # Resolve lowering variants NOW: each build_forward call
-                # re-reads the env, so the A/B workflow is build-per-variant
-                # instead of the round-3 process-per-variant footgun. A
-                # TunePlan overlays per-layer winners (env still wins).
-                kv = _resolve_variants(plan)
+                kv = effective_layer_variants(plan)
                 return _jit(
                     lambda p, x: forward_alexnet_pallas(p, x, model_cfg, variants=kv),
                     donate,
@@ -448,14 +432,12 @@ def _build_forward_fp32(
     model_cfg = model_cfg or BLOCKS12
     if exec_cfg.strategy == "single":
         if exec_cfg.tier == "pallas":
-            from .ops.pallas_model import _chain_variant, forward_blocks12_pallas
+            from .ops.pallas_model import forward_blocks12_pallas
+            from .tuning.plan import effective_layer_variants
 
-            kv = _resolve_variants(plan)  # eager: see alexnet_full branch
-            ch = _chain_variant()
+            kv = effective_layer_variants(plan)
             return _jit(
-                lambda p, x: forward_blocks12_pallas(
-                    p, x, model_cfg, variants=kv, chain=ch
-                ),
+                lambda p, x: forward_blocks12_pallas(p, x, model_cfg, variants=kv),
                 donate,
             )
         return _jit(lambda p, x: forward_blocks12(p, x, model_cfg), donate)

@@ -57,8 +57,6 @@ def block_fusible_reason(
     *,
     variant: str,
     row_block: int,
-    k_block: int,
-    pool: str,
     out_h: int,
     pool_window: int,
 ) -> str:
@@ -71,18 +69,11 @@ def block_fusible_reason(
         return "block fusion needs an adjacent pool"
     if variant not in ("taps", "vcol"):
         return f"block fusion supports taps/vcol only (conv={variant})"
-    if pool != "sep2":
-        return (
-            "block fusion pools in-kernel via the sep2 phase split "
-            "(pool=phases excluded)"
-        )
     if row_block < out_h:
         return (
             f"block fusion needs the whole image per program "
             f"(row_block {row_block} < ho {out_h})"
         )
-    if k_block:
-        return "block fusion does not compose with k_block (no K grid dim)"
     return ""
 
 
@@ -206,8 +197,8 @@ def conv_block_pallas(
     pool_window: int,
     pool_stride: int,
     lrn=None,
-    variant: str | None = None,
-    row_block: int | None = None,
+    variant: str = pk.KernelVariants.conv,
+    row_block: int = pk.KernelVariants.row_block,
     scale: jax.Array | None = None,
     vma=None,
 ) -> jax.Array:
@@ -221,7 +212,7 @@ def conv_block_pallas(
     x.dtype for fp32/bf16; for int8w, bf16 (block 1) or fp32 after the
     in-kernel LRN (block 2) — matching the staged quantized chain's
     stage-boundary dtypes. Geometry the gate refuses is a raise, never a
-    silent fallback (same policy as hpool/k_block)."""
+    silent fallback (same policy as hpool)."""
     lrn_t = None
     if lrn is not None:
         lrn_t = (
@@ -235,8 +226,8 @@ def conv_block_pallas(
         pool_window=pool_window,
         pool_stride=pool_stride,
         lrn=lrn_t,
-        variant=variant if variant is not None else "vcol",
-        row_block=row_block if row_block is not None else pk._ROW_BLOCK,
+        variant=variant,
+        row_block=row_block,
         vma=tuple(vma) if vma is not None else None,
     )
 
@@ -269,8 +260,7 @@ def _conv_block(
     ho = (h - f + 2 * padding) // s + 1
     wo = (wdt - f + 2 * padding) // s + 1
     why = block_fusible_reason(
-        variant=variant, row_block=row_block, k_block=0, pool="sep2",
-        out_h=ho, pool_window=pool_window,
+        variant=variant, row_block=row_block, out_h=ho, pool_window=pool_window,
     )
     if why:
         raise ValueError(why)
@@ -333,8 +323,8 @@ def int8w_conv_block_pallas(
     pool_window: int,
     pool_stride: int,
     lrn=None,
-    variant: str | None = None,
-    row_block: int | None = None,
+    variant: str = pk.KernelVariants.conv,
+    row_block: int = pk.KernelVariants.row_block,
     vma=None,
 ) -> jax.Array:
     """The dequant-free int8w megakernel variant: int8 weights cast to bf16

@@ -13,10 +13,9 @@ scalar code that would waste the MXU entirely. Here:
 - maxpool: separable two-stage max (rows then cols). The stride-s phase
   split is a PURE VIEW reshape (H -> (H/s, s) preserves contiguity), so
   no strided gather is ever materialized; the W stage reuses the same
-  kernel after an XLA transpose. Measured on v5e (scripts/pool_ab.py,
-  b=128 fp32): 3.7x faster than the phase-stack kernel on lane-aligned
-  channels (pool2: 0.39 vs 1.44 ms), within noise on pool1's C=96.
-  TPU_FRAMEWORK_POOL=phases restores the old single-kernel lowering.
+  kernel after an XLA transpose. Measured on v5e (b=128 fp32): 3.7x
+  faster than a phase-stack kernel on lane-aligned channels (pool2: 0.39
+  vs 1.44 ms), within noise on pool1's C=96 (docs/PALLAS_PERF.md).
 - LRN: channel-window sum of squares via shifted adds, one pow + divide —
   both LRN alpha conventions supported (see ops.reference.lrn).
 
@@ -37,6 +36,7 @@ code path is unit-testable on the CPU mesh.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple
 
@@ -65,113 +65,34 @@ def _vmem_spec(block_shape=None, index_map=None):
     return pl.BlockSpec(block_shape, index_map, memory_space=pltpu.VMEM)
 
 
-def env_variant(env_name: str, default: str, allowed: tuple) -> str:
-    """Resolve a lowering-variant switch from the environment (shared by
-    TPU_FRAMEWORK_CONV / _POOL here and _CHAIN in pallas_model).
+# The conv lowerings a KernelVariants may name: those the chip has not
+# ruled out (docs/PALLAS_PERF.md keeps the measurements of those it did):
+#   "taps"  — fq^2 tap matmuls per row block, static unroll.
+#   "vcol"  (default) — in-kernel (VMEM) im2col over the qw taps: taps' 1x
+#             HBM traffic with one fq*cs-contraction matmul per qh row (see
+#             _conv_vcol_kernel). ADOPTED as the default from the 2026-07-31
+#             on-chip A/B: with rowblock 64 it is the grid winner at b=128 —
+#             bf16 2.997 ms (42.7k img/s, 0.42x v1_jit, from taps' 0.38x)
+#             and fp32 11.003 ms (11.6k img/s, 0.53x v1_jit).
+#   "g8"    — phase-packed conv for strided convs (s>=2; s=1 runs vcol):
+#             space-to-depth at g=2s puts g*g*C channels on the lanes (conv1:
+#             192 vs 48) and computes the 2x2 output phases on separate grid
+#             programs (see _conv_g8_kernel). Lowers through Mosaic and agrees
+#             with the fp32 XLA forward inside the precision gate at b=128,
+#             fp32 and bf16 (v5e, 2026-09-26, PR 21); never timed (ROADMAP S5).
+CONV_VARIANTS = ("taps", "vcol", "g8")
 
-    Resolved at TRACE time — outside the per-op jit, so the variant
-    participates in the jit cache key. Build-time callers
-    (configs.build_forward, the sharded tier) resolve variants EAGERLY via
-    KernelVariants.resolve() and close over the result, so re-calling
-    build_forward after an env flip returns a function with the new
-    variant — the supported A/B workflow is build-per-variant (the round-3
-    process-per-variant footgun is gone; tests/test_configs.py holds
-    this)."""
-    import os
-
-    v = os.environ.get(env_name, "").strip().lower()
-    if not v:
-        return default  # unset or set-but-empty: the default
-    if v not in allowed:
-        raise ValueError(f"{env_name} must be {'|'.join(allowed)}, got {v!r}")
-    return v
-
-
-# Conv lowering variants:
-#   "taps"  (default) — fq^2 tap matmuls per row block, static unroll.
-#   "pairs" — adjacent-qw taps fused two-at-a-time: a host-side shifted
-#             concat doubles the contraction dim (conv1: 48 -> 96 of the
-#             MXU's 128 rows, the round-3 verdict's named underfill lever)
-#             at 2x input HBM — the midpoint between "taps" (1x HBM, 48
-#             contraction) and "fused" (fq^2 x HBM, measured 2x slower).
-#   "fused" — host-side im2col + ONE big matmul per row block. Measured
-#             ~2x SLOWER on v5e (docs/PALLAS_PERF.md round-3 results);
-#             kept as the recorded negative result.
-#   "vcol"  — in-kernel (VMEM) im2col over the qw taps: taps' 1x HBM
-#             traffic with one fq*cs-contraction matmul per qh row
-#             (round-5 lever; see _conv_vcol_kernel). ADOPTED as the
-#             default from the 2026-07-31 on-chip A/B: with rowblock 64
-#             it is the grid winner at b=128 — bf16 2.997 ms (42.7k
-#             img/s, 0.42x v1_jit, from taps' 0.38x) and fp32 11.003 ms
-#             (11.6k img/s, 0.53x v1_jit — the first tier/compute cell
-#             to clear the 0.5x adoption bar).
-#   "g8"    — phase-packed conv for strided convs (s>=2; s=1 falls back
-#             to vcol): space-to-depth at g=2s puts g*g*C channels on the
-#             lanes (conv1: 192 vs 48) and computes the 2x2 output phases
-#             on separate grid programs (see _conv_g8_kernel). Round-5
-#             named lever targeting conv1's measured data-movement bound.
-#             Lowers through Mosaic and agrees with the fp32 XLA forward
-#             inside the precision gate at b=128, fp32 and bf16 (v5e,
-#             2026-09-26, PR 21 — after its output store stopped going
-#             through a memref slice Mosaic refused); never timed.
-def _conv_variant() -> str:
-    return env_variant("TPU_FRAMEWORK_CONV", "vcol", ("taps", "pairs", "fused", "vcol", "g8"))
-
-
-# Default output rows per conv program (TPU_FRAMEWORK_ROWBLOCK overrides).
-# BH * Wo_pad is the matmul M dim. 64 (i.e. the whole 55/27-row image for
-# the AlexNet convs, grid over batch only) won the 2026-07-31 on-chip
-# rowblock sweep at every measured cell — 8/16/32/64 bf16 full pass:
+# Output rows per conv program: BH * Wo_pad is the matmul M dim. A wider
+# block amortizes per-program overhead and weight re-reads across more MXU
+# work at more VMEM per program. 64 (i.e. the whole 55/27-row image for the
+# AlexNet convs, grid over batch only) won the 2026-07-31 on-chip rowblock
+# sweep at every measured cell — 8/16/32/64 bf16 full pass:
 # 3.588/3.642/3.219/2.997 ms with vcol — the per-program VMEM footprint
 # (conv1 rb=64: ~360 KB window + ~1.4 MB acc) stays well under budget.
-_ROW_BLOCK = 64
+ROW_BLOCKS = (8, 16, 32, 64)
 # W padded up to this multiple so the (BH, Wo, C) -> (BH*Wo, C) collapse is
 # sublane-aligned for fp32 (8) and bf16 (16) alike.
 _W_ALIGN = 16
-
-
-# Output-row block height (the matmul M dim is rowblock * Wo_pad): a wider
-# block amortizes per-program overhead and weight re-reads across more MXU
-# work at more VMEM per program — the round-3 verdict's lever (b), made
-# measurable now that the sep2 pool freed VMEM headroom.
-def _row_block() -> int:
-    return int(env_variant("TPU_FRAMEWORK_ROWBLOCK", str(_ROW_BLOCK), ("8", "16", "32", "64")))
-
-
-# Output-channel (K) grid blocking for the taps variant — the third lever,
-# named in the round-4 verdict as the follow-up if pairs/rowblock miss the
-# bar. 0 = off (whole K per program, the historical layout). A K block
-# splits the filter bank across grid programs: each program's weight slice
-# and accumulator shrink K/nk-fold (conv2: 256 -> 128 halves the VMEM-
-# resident weights and the fp32 acc), buying Mosaic pipelining headroom at
-# the cost of re-reading the input window once per K block. Output blocks
-# are disjoint, accumulation order per output element is unchanged —
-# bitwise identical to unblocked, like the rowblock lever.
-def _k_block() -> int:
-    return int(env_variant("TPU_FRAMEWORK_KBLOCK", "0", ("0", "64", "128")))
-
-
-# One warning per (k_block, K) per process: trace-time, so an unbounded
-# per-call stream would drown the A/B log it is trying to protect.
-_K_BLOCK_WARNED: set = set()
-
-
-def _warn_k_block_dropped(k_block: int, kk: int) -> None:
-    key = (k_block, kk)
-    if key in _K_BLOCK_WARNED:
-        return
-    _K_BLOCK_WARNED.add(key)
-    import warnings
-
-    warnings.warn(
-        f"requested k_block={k_block} does not apply to K={kk} (needs "
-        f"K % k_block == 0 and K > k_block) — this conv runs UNBLOCKED; "
-        "label its A/B rows kb=0 (KernelVariants.bind(K) makes the repr "
-        f"state this: kb={k_block}->0(K={kk}))",
-        RuntimeWarning,
-        stacklevel=3,
-    )
-
 
 # Epilogue fusion (round-5 lever): "hpool" fuses the separable pool's H
 # stage into the conv epilogue where the model's conv feeds a pool (the
@@ -181,74 +102,36 @@ def _warn_k_block_dropped(k_block: int, kk: int) -> None:
 # model builder falls back to the separate pool otherwise.
 # "block" goes all the way: the whole block (conv+ReLU+pool, +LRN when one
 # trails the pool) runs as ONE VMEM-resident pass (ops/megakernel.py) —
-# interior activations never touch HBM. Same geometry regime as hpool
-# (taps/vcol, sep2, whole image per program, no k_block).
-def _fuse_variant() -> str:
-    return env_variant("TPU_FRAMEWORK_FUSE", "none", ("none", "hpool", "block"))
+# interior activations never touch HBM. Same geometry regime as hpool.
+# Neither has been timed on the chip (ROADMAP S5, S11).
+FUSES = ("none", "hpool", "block")
 
 
-class KernelVariants(NamedTuple):
-    """Resolved lowering-variant set — hashable, so it can ride jit static
-    args. ``resolve()`` reads the environment ONCE; build-time callers
-    (configs.build_forward, the sharded tier) resolve eagerly and close
-    over the result, which kills the round-3 footgun where flipping an env
-    var after the first forward silently kept the old variant inside the
-    outer jit's trace: every ``build_forward`` call now re-reads the env
-    and returns a fresh function carrying its variants explicitly."""
+def _refuse_unknown(field: str, value, allowed: tuple) -> None:
+    if value not in allowed:
+        raise ValueError(f"{field} must be one of {allowed}, got {value!r}")
+
+
+@dataclasses.dataclass(frozen=True)
+class KernelVariants:
+    """One conv layer's Pallas lowering (and the pool it feeds) — hashable,
+    so it can ride closures and jit static args. Which one runs is the
+    code's default or a tune plan's per-layer winner
+    (``tuning.plan.effective_layer_variants``); a value outside the kept
+    sets raises here, where it is built."""
 
     conv: str = "vcol"
-    pool: str = "sep2"
-    row_block: int = _ROW_BLOCK
-    k_block: int = 0
+    row_block: int = 64
     fuse: str = "none"
-    # Layer-binding metadata, NOT a lowering knob: the conv's output-channel
-    # count when the variants are bound to one layer (``bind``; the tuner's
-    # per-layer plans always bind). 0 = unbound/process-global. Lets the repr
-    # state the EFFECTIVE k_block next to the requested one, so tuner logs
-    # and A/B rows are self-labeling even though _warn_k_block_dropped fires
-    # only once per process.
-    k_channels: int = 0
 
-    @classmethod
-    def resolve(cls) -> "KernelVariants":
-        return cls(
-            conv=_conv_variant(), pool=_pool_variant(), row_block=_row_block(),
-            k_block=_k_block(), fuse=_fuse_variant(),
-        )
-
-    def bind(self, k_channels: int) -> "KernelVariants":
-        """The same knobs bound to a conv with K output channels."""
-        return self._replace(k_channels=k_channels)
-
-    def knobs(self) -> "KernelVariants":
-        """The lowering knobs alone (binding stripped) — the equality the
-        tuner's candidate dedup and tests should compare on."""
-        return self._replace(k_channels=0)
-
-    @property
-    def effective_k_block(self) -> int:
-        """The k_block that actually applies at K=k_channels (the geometry
-        gate in _conv2d_pallas: K % k_block == 0 and K > k_block, else the
-        conv runs unblocked). Unbound variants report the requested value —
-        only a bound layer has a geometry to judge against. The hardware
-        lane rule (k_block % 128) is NOT folded in: on chip that case
-        raises rather than silently degrading."""
-        if not self.k_block or not self.k_channels:
-            return self.k_block
-        if self.k_channels % self.k_block == 0 and self.k_channels > self.k_block:
-            return self.k_block
-        return 0
+    def __post_init__(self):
+        _refuse_unknown("KernelVariants.conv", self.conv, CONV_VARIANTS)
+        _refuse_unknown("KernelVariants.row_block", self.row_block, ROW_BLOCKS)
+        _refuse_unknown("KernelVariants.fuse", self.fuse, FUSES)
 
     def label(self) -> str:
-        """Compact A/B-row/tuner-log label; requested->effective k_block is
-        spelled out when a bound geometry drops the request."""
-        kb = str(self.k_block)
-        if self.k_channels and self.effective_k_block != self.k_block:
-            kb = f"{self.k_block}->{self.effective_k_block}(K={self.k_channels})"
-        return (
-            f"conv={self.conv} pool={self.pool} rb={self.row_block} "
-            f"kb={kb} fuse={self.fuse}"
-        )
+        """Compact A/B-row/tuner-log label."""
+        return f"conv={self.conv} rb={self.row_block} fuse={self.fuse}"
 
     def __repr__(self) -> str:
         return f"KernelVariants({self.label()})"
@@ -309,77 +192,6 @@ def _conv_epilogue(acc, b_ref, o_ref, *, bh: int, wo_p: int, k: int, relu: bool,
     o_ref[out_index] = out
 
 
-def _conv_fused_kernel(x_ref, w_ref, b_ref, o_ref, *, bh: int, wo_p: int, relu: bool):
-    """im2col variant: x_ref (1, bh, wo_p, fq^2*cs), w_ref (fq^2*cs, K)."""
-    kdim = x_ref.shape[-1]
-    k = w_ref.shape[-1]
-    acc = jnp.dot(
-        x_ref[0].reshape(bh * wo_p, kdim),
-        w_ref[:],
-        preferred_element_type=jnp.float32,
-        precision=_mxu_precision(x_ref.dtype),
-    )
-    _conv_epilogue(acc, b_ref, o_ref, bh=bh, wo_p=wo_p, k=k, relu=relu)
-
-
-def _pairs_acc(xp_ref, wp_ref, leftover, *, fq: int, bh: int, wo_p: int):
-    """Shared pair-matmul accumulation of both pairs kernels: xp_ref
-    (1, Hs, Ws-1, 2*S*S*C) holds column j's and j+1's channels concatenated
-    (host-side shifted concat), so tap pair (qw=2p, 2p+1) is ONE matmul with
-    a doubled contraction dim. ``leftover`` is ``(x_ref, wl_ref)`` for the
-    odd trailing tap (fq odd; reads the plain s2d buffer), or None when fq
-    is even. Accumulation order is fixed (qh outer; pairs left-to-right,
-    then the leftover), so results stay deterministic — but differ from
-    "taps" in the last ulps (one 2cs-wide reduction vs two cs-wide adds);
-    tests hold bitwise equality within a variant, allclose across variants.
-    """
-    cs2 = xp_ref.shape[-1]
-    k = wp_ref.shape[-1]
-    row0 = pl.program_id(1) * bh
-    prec = _mxu_precision(xp_ref.dtype)
-    n_pairs = fq // 2
-    acc = jnp.zeros((bh * wo_p, k), jnp.float32)
-    for qh in range(fq):
-        for p in range(n_pairs):
-            win = xp_ref[0, pl.ds(row0 + qh, bh), 2 * p : 2 * p + wo_p, :]
-            acc = acc + jnp.dot(
-                win.reshape(bh * wo_p, cs2),
-                wp_ref[qh, p, :, :],
-                preferred_element_type=jnp.float32,
-                precision=prec,
-            )
-        if leftover is not None:
-            x_ref, wl_ref = leftover
-            cs = x_ref.shape[-1]
-            win = x_ref[0, pl.ds(row0 + qh, bh), fq - 1 : fq - 1 + wo_p, :]
-            acc = acc + jnp.dot(
-                win.reshape(bh * wo_p, cs),
-                wl_ref[qh, :, :],
-                preferred_element_type=jnp.float32,
-                precision=prec,
-            )
-    return acc
-
-
-def _conv_pairs_kernel(
-    xp_ref, x_ref, wp_ref, wl_ref, b_ref, o_ref, *, fq: int, bh: int, wo_p: int, relu: bool
-):
-    """Odd-fq pairs variant: pair matmuls plus the leftover tap from x_ref."""
-    acc = _pairs_acc(xp_ref, wp_ref, (x_ref, wl_ref), fq=fq, bh=bh, wo_p=wo_p)
-    _conv_epilogue(acc, b_ref, o_ref, bh=bh, wo_p=wo_p, k=wp_ref.shape[-1], relu=relu)
-
-
-def _conv_pairs_even_kernel(
-    xp_ref, wp_ref, b_ref, o_ref, *, fq: int, bh: int, wo_p: int, relu: bool
-):
-    """Even-fq pairs variant: pairs cover every tap, so the plain s2d buffer
-    and the leftover weight tap are not operands at all — the round-4
-    advisor flagged their dead VMEM residency/HBM traffic in the variant
-    whose whole point is better HBM/MXU balance."""
-    acc = _pairs_acc(xp_ref, wp_ref, None, fq=fq, bh=bh, wo_p=wo_p)
-    _conv_epilogue(acc, b_ref, o_ref, bh=bh, wo_p=wo_p, k=wp_ref.shape[-1], relu=relu)
-
-
 def _conv_kernel(x_ref, w_ref, b_ref, o_ref, *, fq: int, bh: int, wo_p: int, relu: bool, hpool=None):
     """Space-to-depth conv: x_ref (1, Hs, Ws, S*S*C), w_ref (fq, fq, S*S*C, K).
 
@@ -421,12 +233,11 @@ def _conv_vcol_kernel(x_ref, w_ref, b_ref, o_ref, *, fq: int, bh: int, wo_p: int
     traffic as "taps" (1x input), but the fq qw-windows are concatenated
     on the lane axis INSIDE the kernel, so each qh row is ONE matmul with
     an fq*cs contraction (conv1: 144 vs 48 of the MXU's 128 rows; conv2:
-    480 vs 96) instead of fq skinny ones. This is "pairs"/"fused"'s fill
-    win without their host-side HBM blowup — the concat is a VMEM lane
-    relayout, whose cost is what the A/B measures. Accumulation: one
-    reduction per qh over fq*cs (deterministic; differs from taps in the
-    last ulps like the other variants — allclose across variants, bitwise
-    within)."""
+    480 vs 96) instead of fq skinny ones — the fill win of a host-side
+    im2col without its HBM blowup; the concat is a VMEM lane relayout,
+    whose cost is what the A/B measures. Accumulation: one reduction per
+    qh over fq*cs (deterministic; differs from taps in the last ulps —
+    allclose across variants, bitwise within)."""
     cs = x_ref.shape[-1]
     k = w_ref.shape[-1]
     row0 = pl.program_id(1) * bh
@@ -561,24 +372,21 @@ def conv2d_pallas(
     padding_w: int | None = None,
     relu: bool = False,
     vma=None,
-    variant: str | None = None,
-    row_block: int | None = None,
-    k_block: int | None = None,
+    variant: str = KernelVariants.conv,
+    row_block: int = KernelVariants.row_block,
     hpool: tuple | None = None,
 ) -> jax.Array:
-    """Direct conv (+bias, optional fused ReLU) — thin wrapper resolving the
-    lowering variant (explicit arg wins; env var otherwise) before entering
-    jit. ``vma``: mesh axes the call varies over inside a check_vma=True
-    shard_map (ops.vma). ``hpool=(window, stride)``: fuse the separable
-    pool's H stage into the conv epilogue (requires variant taps/vcol and
-    row_block >= the conv's output height; see _conv_epilogue) — the
-    result has pooled H, full W; run :func:`maxpool_pallas_w` after."""
+    """Direct conv (+bias, optional fused ReLU) — thin wrapper normalising
+    ``vma`` before entering jit. ``variant`` is one of ``CONV_VARIANTS``;
+    any other name raises. ``vma``: mesh axes the call varies over inside a
+    check_vma=True shard_map (ops.vma). ``hpool=(window, stride)``: fuse the
+    separable pool's H stage into the conv epilogue (requires variant
+    taps/vcol and row_block >= the conv's output height; see
+    _conv_epilogue) — the result has pooled H, full W; run
+    :func:`maxpool_pallas_w` after."""
     return _conv2d_pallas(
         x, w, b, stride=stride, padding=padding, padding_w=padding_w,
-        relu=relu,
-        variant=variant if variant is not None else _conv_variant(),
-        row_block=row_block if row_block is not None else _row_block(),
-        k_block=k_block if k_block is not None else _k_block(),
+        relu=relu, variant=variant, row_block=row_block,
         vma=tuple(vma) if vma is not None else None,
         hpool=hpool,
     )
@@ -588,7 +396,7 @@ def conv2d_pallas(
     jax.jit,
     static_argnames=(
         "stride", "padding", "padding_w", "relu", "variant", "row_block",
-        "k_block", "vma", "hpool",
+        "vma", "hpool",
     ),
 )
 def _conv2d_pallas(
@@ -600,9 +408,8 @@ def _conv2d_pallas(
     padding: int = 0,
     padding_w: int | None = None,
     relu: bool = False,
-    variant: str = "taps",
-    row_block: int = _ROW_BLOCK,
-    k_block: int = 0,
+    variant: str,
+    row_block: int,
     vma=None,
     hpool: tuple | None = None,
 ) -> jax.Array:
@@ -619,6 +426,7 @@ def _conv2d_pallas(
     all unit-stride and each matmul contracts over S*S*C. For S=1 this
     degenerates to the identity packing.
     """
+    _refuse_unknown("conv2d_pallas variant", variant, CONV_VARIANTS)
     if hpool is not None and variant not in ("taps", "vcol"):
         raise ValueError(
             f"hpool fusion supports the taps/vcol lowering only, got {variant!r}"
@@ -690,172 +498,51 @@ def _conv2d_pallas(
     ws2d = _weights_to_depth(w, s, fq)
     cs = s * s * c
 
-    if variant == "fused":
-        # im2col variant: XLA materializes the tap-concatenated input
-        # host-side (HBM cost ~fq^2 x input, still << compute at these
-        # sizes) and the kernel is ONE (bh*wo_p, fq^2*cs) x (fq^2*cs, K)
-        # MXU matmul per row block — 3-9x better array fill than the
-        # tap-loop on conv1. Accumulation: one reduction over the whole
-        # contraction (deterministic, but a DIFFERENT fixed order than the
-        # tap-loop variant — pick one variant per process; tests hold
-        # within-variant bitwise equality).
-        xcol = jnp.concatenate(
-            [
-                xs[:, qh : qh + ho_p, qw : qw + wo_p, :]
-                for qh in range(fq)
-                for qw in range(fq)
-            ],
-            axis=-1,
-        )  # (N, ho_p, wo_p, fq^2*cs)
-        operands = (xcol, ws2d.reshape(fq * fq * cs, w.shape[-1]), b)
-        kernel = functools.partial(_conv_fused_kernel, bh=bh, wo_p=wo_p, relu=relu)
-        in_specs = [
-            _vmem_spec((1, bh, wo_p, fq * fq * cs), lambda i, j: (i, j, 0, 0)),
-            _vmem_spec(),
-            _vmem_spec(),
-        ]
-    elif variant == "pairs" and fq >= 2:
-        # Host-side shifted concat: xpair[..., j, :] carries column j's AND
-        # j+1's channel blocks, so each kernel matmul contracts over 2*cs
-        # (conv1: 96/128 MXU rows vs taps' 48/128) at 2x input HBM traffic.
-        xpair = jnp.concatenate([xs[:, :, :-1, :], xs[:, :, 1:, :]], axis=-1)
-        m = fq // 2
-        wpair = jnp.concatenate(
-            [ws2d[:, 0 : 2 * m : 2], ws2d[:, 1 : 2 * m : 2]], axis=2
-        )  # (fq, m, 2*cs, K)
-        if fq % 2:
-            wlast = ws2d[:, fq - 1]  # (fq, cs, K): the odd leftover tap
-            operands = (xpair, xs, wpair, wlast, b)
-            kernel = functools.partial(
-                _conv_pairs_kernel, fq=fq, bh=bh, wo_p=wo_p, relu=relu
+    operands = (xs, ws2d, b)
+    # taps, or the vcol kernel (which g8 runs at stride 1, with no phases
+    # to pack).
+    kern_fn = _conv_kernel if variant == "taps" else _conv_vcol_kernel
+    kernel = functools.partial(kern_fn, fq=fq, bh=bh, wo_p=wo_p, relu=relu)
+    kk = w.shape[-1]
+    if hpool is not None:
+        # Fused H-stage pool (see _conv_epilogue): caller contract, not a
+        # silent fallback — the model builder gates on this.
+        if bh != ho:
+            raise ValueError(
+                f"hpool fusion needs the whole image per program "
+                f"(row_block {row_block} < ho {ho})"
             )
-            in_specs = [
-                _vmem_spec((1, hs, ws - 1, 2 * cs), lambda i, j: (i, 0, 0, 0)),
+        pwin, pstr = hpool
+        hp_o = (ho - pwin) // pstr + 1
+        kernel = functools.partial(
+            kern_fn, fq=fq, bh=bh, wo_p=wo_p, relu=relu,
+            hpool=(pwin, pstr, hp_o),
+        )
+        out = pl.pallas_call(
+            kernel,
+            grid=(n, 1),
+            in_specs=[
                 _vmem_spec((1, hs, ws, cs), lambda i, j: (i, 0, 0, 0)),
                 _vmem_spec(),
                 _vmem_spec(),
-                _vmem_spec(),
-            ]
-        else:
-            # Even fq: pairs cover all taps — xs/wlast are not operands
-            # (dead VMEM residency + HBM traffic otherwise; round-4 advisor).
-            operands = (xpair, wpair, b)
-            kernel = functools.partial(
-                _conv_pairs_even_kernel, fq=fq, bh=bh, wo_p=wo_p, relu=relu
-            )
-            in_specs = [
-                _vmem_spec((1, hs, ws - 1, 2 * cs), lambda i, j: (i, 0, 0, 0)),
-                _vmem_spec(),
-                _vmem_spec(),
-            ]
-    else:  # "taps"/"vcol" (and "pairs" at fq == 1, where there is nothing to pair)
-        operands = (xs, ws2d, b)
-        kern_fn = _conv_vcol_kernel if variant in ("vcol", "g8") else _conv_kernel
-        kernel = functools.partial(kern_fn, fq=fq, bh=bh, wo_p=wo_p, relu=relu)
-        kk = w.shape[-1]
-        if hpool is not None:
-            # Fused H-stage pool (see _conv_epilogue): caller contract, not
-            # a silent fallback — the model builder gates on these.
-            if bh != ho:
-                raise ValueError(
-                    f"hpool fusion needs the whole image per program "
-                    f"(row_block {row_block} < ho {ho})"
-                )
-            if k_block:
-                raise ValueError(
-                    "hpool fusion does not compose with k_block (the fused "
-                    "path has no K grid dim); unset one of them"
-                )
-            pwin, pstr = hpool
-            hp_o = (ho - pwin) // pstr + 1
-            kernel = functools.partial(
-                kern_fn, fq=fq, bh=bh, wo_p=wo_p, relu=relu,
-                hpool=(pwin, pstr, hp_o),
-            )
-            out = pl.pallas_call(
-                kernel,
-                grid=(n, 1),
-                in_specs=[
-                    _vmem_spec((1, hs, ws, cs), lambda i, j: (i, 0, 0, 0)),
-                    _vmem_spec(),
-                    _vmem_spec(),
-                ],
-                out_specs=_vmem_spec((1, hp_o, wo_p, kk), lambda i, j: (i, j, 0, 0)),
-                out_shape=vma_struct((n, hp_o, wo_p, kk), x.dtype, vma),
-                compiler_params=_tc_params("parallel", "parallel"),
-                interpret=_interpret(),
-                name=f"conv2d_{variant}_hpool",
-            )(*operands)
-            return out[:, :, :wo, :]
-        # Mosaic constraint (measured on the real v5e, 2026-07-31): every
-        # blocked operand's minor dim is k_block, and the lane tiling is 128
-        # — a non-multiple (the env's 64 setting) cannot lower on chip
-        # ("block shape is a multiple of the tiling size"). Interpret mode
-        # has no tiling, so CI keeps exercising 64; on hardware the request
-        # is REFUSED rather than silently dropped (same raise-not-fallback
-        # policy as hpool): an A/B row labeled kb=64 measuring kb=0 is
-        # mislabeled perf evidence (ADVICE round-5 item 1).
-        k_block_ok = k_block % 128 == 0 or _interpret()
-        if k_block and not k_block_ok:
-            raise ValueError(
-                f"k_block={k_block} cannot lower on {jax.default_backend()}: "
-                "the lane tiling is 128, so k_block must be a multiple of 128 "
-                "on hardware (interpret mode has no tiling); unset "
-                "TPU_FRAMEWORK_KBLOCK or use 128"
-            )
-        if k_block and not (kk % k_block == 0 and kk > k_block):
-            # Geometry fallback (e.g. conv1's K=96 under kb=128): legitimate
-            # per-layer degradation, but it must be VISIBLE — a one-time
-            # warning per (k_block, K) so A/B logs can label this layer kb=0.
-            _warn_k_block_dropped(k_block, kk)
-        if k_block and kk % k_block == 0 and kk > k_block:
-            # Third grid dim over K blocks (the round-4 verdict's named
-            # next lever): each program owns k_block output channels, so
-            # the VMEM-resident weight slice and fp32 accumulator shrink
-            # kk/k_block-fold at the cost of re-reading the input window
-            # per K block (the x spec ignores the k index, so Mosaic can
-            # keep the window resident across the inner dim). Outputs are
-            # disjoint and per-element accumulation order is untouched —
-            # bitwise identical to unblocked, like the rowblock lever.
-            nk = kk // k_block
-            # Bias rides as (1, K) with a (1, k_block) block: a rank-1
-            # (k_block,) spec is illegal on chip — rank-1 tiling is
-            # 256 for bf16 (128 lanes x 2 packing), so a 128 block was
-            # rejected by the lowering. Rank-2 puts k_block on the lane
-            # dim where 128 is exactly the tile. The epilogue's
-            # broadcast add is rank-agnostic.
-            operands = (xs, ws2d, b.reshape(1, kk))
-            in_specs = [
-                _vmem_spec((1, hs, ws, cs), lambda i, j, k: (i, 0, 0, 0)),
-                _vmem_spec((fq, fq, cs, k_block), lambda i, j, k: (0, 0, 0, k)),
-                _vmem_spec((1, k_block), lambda i, j, k: (0, k)),
-            ]
-            out = pl.pallas_call(
-                kernel,
-                grid=(n, nbh, nk),
-                in_specs=in_specs,
-                out_specs=_vmem_spec(
-                    (1, bh, wo_p, k_block), lambda i, j, k: (i, j, 0, k)
-                ),
-                out_shape=vma_struct((n, ho_p, wo_p, kk), x.dtype, vma),
-                compiler_params=_tc_params("parallel", "parallel", "parallel"),
-                interpret=_interpret(),
-                name=f"conv2d_{variant}_kblock",
-            )(*operands)
-            if ho_p != ho or wo_p != wo:
-                out = out[:, :ho, :wo, :]
-            return out
-        in_specs = [
-            _vmem_spec((1, hs, ws, cs), lambda i, j: (i, 0, 0, 0)),
-            _vmem_spec(),
-            _vmem_spec(),
-        ]
+            ],
+            out_specs=_vmem_spec((1, hp_o, wo_p, kk), lambda i, j: (i, j, 0, 0)),
+            out_shape=vma_struct((n, hp_o, wo_p, kk), x.dtype, vma),
+            compiler_params=_tc_params("parallel", "parallel"),
+            interpret=_interpret(),
+            name=f"conv2d_{variant}_hpool",
+        )(*operands)
+        return out[:, :, :wo, :]
     out = pl.pallas_call(
         kernel,
         grid=(n, nbh),
-        in_specs=in_specs,
-        out_specs=_vmem_spec((1, bh, wo_p, w.shape[-1]), lambda i, j: (i, j, 0, 0)),
-        out_shape=vma_struct((n, ho_p, wo_p, w.shape[-1]), x.dtype, vma),
+        in_specs=[
+            _vmem_spec((1, hs, ws, cs), lambda i, j: (i, 0, 0, 0)),
+            _vmem_spec(),
+            _vmem_spec(),
+        ],
+        out_specs=_vmem_spec((1, bh, wo_p, kk), lambda i, j: (i, j, 0, 0)),
+        out_shape=vma_struct((n, ho_p, wo_p, kk), x.dtype, vma),
         compiler_params=_tc_params("parallel", "parallel"),
         interpret=_interpret(),
         name=f"conv2d_{variant}",
@@ -867,90 +554,24 @@ def _conv2d_pallas(
 
 def conv2d_pallas_hvalid(
     x, w, b, *, stride: int, padding_w: int, vma=None,
-    variant: str | None = None, row_block: int | None = None,
-    k_block: int | None = None,
+    variant: str = KernelVariants.conv, row_block: int = KernelVariants.row_block,
 ):
     """Sharded-tier entry: VALID on H (halo-provided), padded on W, fused ReLU
     is NOT applied here (the sharded pipeline masks then relus)."""
     return conv2d_pallas(
         x, w, b, stride=stride, padding=0, padding_w=padding_w, vma=vma,
-        variant=variant, row_block=row_block, k_block=k_block,
+        variant=variant, row_block=row_block,
     )
 
 
-def _pool_kernel(x_ref, o_ref, *, window: int, stride: int, ho: int, wo: int):
-    """x_ref: (s*s, 1, hp, wp, C) stacked stride-phases; max over window taps.
-
-    Tap (fy, fx) lives in phase (fy % s)*s + (fx % s) at spatial offset
-    (fy//s, fx//s) — every in-kernel slice is unit-stride (Mosaic forbids
-    strided vector slices; the phase split is done host-side by XLA).
-    """
-    s = stride
-    c = x_ref.shape[-1]
-    out = None
-    for fy in range(window):
-        for fx in range(window):
-            ph = (fy % s) * s + (fx % s)
-            qh, qw = fy // s, fx // s
-            win = lax.slice(
-                x_ref[ph, 0], (qh, qw, 0), (qh + ho, qw + wo, c)
-            )
-            out = win if out is None else jnp.maximum(out, win)
-    o_ref[0] = out
-
-
-def _pool_phases(x: jax.Array, s: int, hp: int, wp: int) -> jax.Array:
-    """(N,H,W,C) -> (s*s, N, hp, wp, C): stride-phase views, zero-padded.
-
-    Padded rows/cols are never read: kernel taps stop at fy,fx < window.
-    """
-    n, h, w, c = x.shape
-    phases = []
-    for r in range(s):
-        for p in range(s):
-            v = x[:, r::s, p::s, :][:, :hp, :wp, :]  # crop phases longer than hp/wp
-            phases.append(
-                jnp.pad(v, ((0, 0), (0, hp - v.shape[1]), (0, wp - v.shape[2]), (0, 0)))
-            )
-    return jnp.stack(phases)
-
-
-def _pool_variant() -> str:
-    # sep2 is the measured default (scripts/pool_ab.py).
-    return env_variant("TPU_FRAMEWORK_POOL", "sep2", ("sep2", "phases"))
-
-
-def maxpool_pallas(
-    x: jax.Array, *, window: int, stride: int, vma=None, variant: str | None = None
-) -> jax.Array:
-    """Window max — thin wrapper resolving the lowering variant (explicit
-    arg wins; env var otherwise) before entering jit. ``vma``: see ops.vma."""
-    vma = tuple(vma) if vma is not None else None
-    if (variant if variant is not None else _pool_variant()) == "phases":
-        return _maxpool_phases(x, window=window, stride=stride, vma=vma)
-    return _maxpool_sep2(x, window=window, stride=stride, vma=vma)
-
-
 @functools.partial(jax.jit, static_argnames=("window", "stride", "vma"))
-def _maxpool_phases(x: jax.Array, *, window: int, stride: int, vma=None) -> jax.Array:
-    n, h, wdt, c = x.shape
-    s = stride
-    ho = (h - window) // s + 1
-    wo = (wdt - window) // s + 1
-    qmax = (window - 1) // s
-    hp, wp = ho + qmax, wo + qmax
-    xph = _pool_phases(x, s, hp, wp)
-    kernel = functools.partial(_pool_kernel, window=window, stride=s, ho=ho, wo=wo)
-    return pl.pallas_call(
-        kernel,
-        grid=(n,),
-        in_specs=[_vmem_spec((s * s, 1, hp, wp, c), lambda i: (0, i, 0, 0, 0))],
-        out_specs=_vmem_spec((1, ho, wo, c), lambda i: (i, 0, 0, 0)),
-        out_shape=vma_struct((n, ho, wo, c), x.dtype, vma),
-        compiler_params=_tc_params("parallel"),
-        interpret=_interpret(),
-        name="maxpool_phases",
-    )(xph)
+def maxpool_pallas(x: jax.Array, *, window: int, stride: int, vma=None) -> jax.Array:
+    """Window max as a separable two-stage pool: rows, transpose, rows
+    again, transpose. ``vma``: a tuple of mesh axes, see ops.vma."""
+    y = _pool_rows(x, window=window, stride=stride, vma=vma)  # (N, ho, W, C)
+    yt = jnp.swapaxes(y, 1, 2)                           # (N, W, ho, C)
+    z = _pool_rows(yt, window=window, stride=stride, vma=vma)  # (N, wo, ho, C)
+    return jnp.swapaxes(z, 1, 2)                         # (N, ho, wo, C)
 
 
 def _axis_pool_kernel(x_ref, o_ref, *, window: int, stride: int, ho: int):
@@ -972,7 +593,7 @@ def _pool_rows(x: jax.Array, *, window: int, stride: int, vma=None) -> jax.Array
     """Max-pool the H axis via the view-reshape phase split. x: (N,H,W,C).
 
     The reshape H -> (hq, s) is contiguity-preserving — XLA emits no data
-    movement — which is the whole advantage over the phase-stack path
+    movement — which is the whole advantage over a phase-stack pool
     (whose s*s strided gathers cost more than the pool itself)."""
     n, h, w, c = x.shape
     s = stride
@@ -993,15 +614,6 @@ def _pool_rows(x: jax.Array, *, window: int, stride: int, vma=None) -> jax.Array
         interpret=_interpret(),
         name="maxpool_rows",
     )(xv)
-
-
-@functools.partial(jax.jit, static_argnames=("window", "stride", "vma"))
-def _maxpool_sep2(x: jax.Array, *, window: int, stride: int, vma=None) -> jax.Array:
-    """Separable two-stage pool: rows, transpose, rows again, transpose."""
-    y = _pool_rows(x, window=window, stride=stride, vma=vma)  # (N, ho, W, C)
-    yt = jnp.swapaxes(y, 1, 2)                           # (N, W, ho, C)
-    z = _pool_rows(yt, window=window, stride=stride, vma=vma)  # (N, wo, ho, C)
-    return jnp.swapaxes(z, 1, 2)                         # (N, ho, wo, C)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "stride", "vma"))

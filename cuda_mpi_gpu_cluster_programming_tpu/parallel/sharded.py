@@ -180,9 +180,9 @@ def build_sharded_forward(
     off the timed path, so the hot loop stays free of host round trips.
 
     ``plan``: a ``tuning.plan.TunePlan`` — the pallas tier runs each conv
-    layer (and the pool it feeds) under the plan's per-layer winners, with
-    the same env > plan > default knob precedence as the single-device
-    builders (``tuning.plan.effective_layer_variants``). The ``fuse`` knob
+    layer (and the pool it feeds) under the plan's per-layer winners, the
+    defaults where it has none, as the single-device builders do
+    (``tuning.plan.effective_layer_variants``). The ``fuse`` knob
     does not apply on this path (the hvalid lowering has no fused epilogue
     to hang an hpool stage off) and is ignored; reference tier ignores the
     whole plan, as everywhere else.
@@ -205,41 +205,27 @@ def build_sharded_forward(
 
     if tier == "pallas":
         from ..ops.pallas_kernels import (
-            KernelVariants,
             conv2d_pallas_hvalid,
             lrn_pallas,
             maxpool_pallas,
         )
+        from ..tuning.plan import effective_layer_variants
 
         # vma-tagged out_shapes (ops.vma) let this shard_map keep
         # check_vma=True — previously the pallas tier forced the checker
-        # off for the whole body, halo ppermutes included. Variants resolve
-        # eagerly at build time (same footgun fix as configs.build_forward);
-        # a TunePlan overlays per-layer winners (env knobs still win).
-        kv = KernelVariants.resolve()
-        lv = None
-        if plan is not None:
-            from ..tuning.plan import effective_layer_variants
-
-            lv = effective_layer_variants(plan, base=kv)
-
-        def _fns(v):
-            return (
-                functools.partial(
-                    conv2d_pallas_hvalid, vma=(AXIS,), variant=v.conv,
-                    row_block=v.row_block, k_block=v.k_block,
-                ),
-                functools.partial(maxpool_pallas, vma=(AXIS,), variant=v.pool),
-            )
-
-        # Per-layer kernel fns: a conv's tuned variants also govern the
-        # pool it feeds (same adjacency contract as _conv_then_pool).
+        # off for the whole body, halo ppermutes included.
+        lv = effective_layer_variants(plan)
+        pool_fn = functools.partial(maxpool_pallas, vma=(AXIS,))
+        # Per-layer kernel fns: each conv under its own tuned variants; every
+        # pool runs the one sep2 lowering.
         layer_fns = {}
-        governing = kv
         for lp in splan.layers:
-            if lp.kind == "conv":
-                governing = lv.for_layer(lp.name) if lv is not None else kv
-            layer_fns[lp.name] = _fns(governing)
+            v = lv.for_layer(lp.name)
+            conv_fn = functools.partial(
+                conv2d_pallas_hvalid, vma=(AXIS,), variant=v.conv,
+                row_block=v.row_block,
+            )
+            layer_fns[lp.name] = (conv_fn, pool_fn)
         # The Pallas tier runs the Pallas LRN per shard too, as the
         # single-device Pallas forward does: on a TPU the XLA LRN is
         # last-ulps off it (different window sum and pow), which broke the

@@ -103,7 +103,7 @@ def int8w_conv(
         y = pk.conv2d_pallas(
             xq, wq, jnp.zeros((q.shape[-1],), jnp.bfloat16),
             stride=stride, padding=padding, relu=False,
-            variant=v.conv, row_block=v.row_block, k_block=v.k_block,
+            variant=v.conv, row_block=v.row_block,
         ).astype(jnp.float32)
     else:
         y = lax.conv_general_dilated(
@@ -139,8 +139,7 @@ def int8w_conv_then_pool(x, q, scale, b, cspec, pspec, names, v=None, *, tier="p
         from ..ops import megakernel as mk
 
         if not mk.block_fusible_reason(
-            variant=v.conv, row_block=v.row_block, k_block=v.k_block,
-            pool=v.pool, out_h=ho, pool_window=pspec.window,
+            variant=v.conv, row_block=v.row_block, out_h=ho, pool_window=pspec.window,
         ):
             with scopes.layer(*names):
                 return mk.int8w_conv_block_pallas(
@@ -157,10 +156,7 @@ def int8w_conv_then_pool(x, q, scale, b, cspec, pspec, names, v=None, *, tier="p
         if tier == "pallas":
             from ..ops import pallas_kernels as pk
 
-            pool_variant = v.pool if v is not None else None
-            out = pk.maxpool_pallas(
-                y, window=pspec.window, stride=pspec.stride, variant=pool_variant
-            )
+            out = pk.maxpool_pallas(y, window=pspec.window, stride=pspec.stride)
         else:
             from ..ops import reference as ops
 
@@ -248,9 +244,7 @@ def forward_blocks12_int8w(
         tap(cname, cur)
         with scopes.layer(pname):
             if tier == "pallas":
-                cur = pk.maxpool_pallas(
-                    cur, window=pspec.window, stride=pspec.stride, variant=lv.pool
-                )
+                cur = pk.maxpool_pallas(cur, window=pspec.window, stride=pspec.stride)
             else:
                 cur = ops.maxpool(cur, window=pspec.window, stride=pspec.stride)
         tap(pname, cur)

@@ -157,8 +157,8 @@ def make_parser() -> argparse.ArgumentParser:
         default="",
         help="TunePlan JSON path: with --tune the cache target (default "
         "perf/tune_plan.json), otherwise load per-layer kernel variants "
-        "from it; explicit TPU_FRAMEWORK_* env knobs still win "
-        "(docs/TUNING.md)",
+        "from it; layers it does not cover, or a stale plan, run the "
+        "defaults (docs/TUNING.md)",
     )
     p.add_argument(
         "--supervise",

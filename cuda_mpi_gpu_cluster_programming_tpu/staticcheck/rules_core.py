@@ -4,7 +4,7 @@ Codes (unchanged from scripts/lint.py so existing ``# noqa: <code>``
 annotations keep working):
 
   unused-import, bare-except, mutable-default, deprecated, raw-subprocess,
-  atomic-write, variant-env, tabs, trailing-ws, long-line
+  atomic-write, tabs, trailing-ws, long-line
 
 (`syntax` findings are emitted by the engine itself — a file that does not
 parse runs no rules.)
@@ -251,84 +251,6 @@ class AtomicWriteRule(Rule):
             "# noqa: atomic-write)",
             span=_node_span(node),
         )
-
-
-# Kernel-variant env knobs whose direct reads are confined to tuning/ and
-# ops/pallas_kernels.py (env_variant / KernelVariants.resolve) — keep in
-# sync with tuning.plan.VARIANT_ENV plus the chain knob.
-_VARIANT_KNOBS = {
-    "TPU_FRAMEWORK_CONV",
-    "TPU_FRAMEWORK_POOL",
-    "TPU_FRAMEWORK_ROWBLOCK",
-    "TPU_FRAMEWORK_KBLOCK",
-    "TPU_FRAMEWORK_FUSE",
-    "TPU_FRAMEWORK_CHAIN",
-}
-_VARIANT_KNOB_PREFIXES = ("PALLAS_",)
-
-
-def _is_variant_knob(name: str) -> bool:
-    return name in _VARIANT_KNOBS or name.startswith(_VARIANT_KNOB_PREFIXES)
-
-
-def _is_os_environ(node: ast.expr) -> bool:
-    return (
-        isinstance(node, ast.Attribute)
-        and node.attr == "environ"
-        and isinstance(node.value, ast.Name)
-        and node.value.id == "os"
-    )
-
-
-@register
-class VariantEnvRule(Rule):
-    code = "variant-env"
-
-    def applies(self, path: Path) -> bool:
-        """True = direct variant-knob env reads are forbidden here."""
-        return "tuning" not in path.parts and path.name != "pallas_kernels.py"
-
-    def check(self, ctx: FileContext) -> List[Finding]:
-        out = []
-        for node in ast.walk(ctx.tree):
-            knob = None
-            if isinstance(node, ast.Call):
-                f = node.func
-                if (
-                    isinstance(f, ast.Attribute)
-                    and f.attr == "get"
-                    and _is_os_environ(f.value)
-                ) or (
-                    isinstance(f, ast.Attribute)
-                    and f.attr == "getenv"
-                    and isinstance(f.value, ast.Name)
-                    and f.value.id == "os"
-                ):
-                    if node.args and isinstance(node.args[0], ast.Constant):
-                        knob = node.args[0].value
-            elif isinstance(node, ast.Subscript):
-                # os.environ["TPU_FRAMEWORK_..."] reads (stores are fine —
-                # tests and harnesses legitimately SET knobs; only reads
-                # fork the precedence).
-                if (
-                    isinstance(node.ctx, ast.Load)
-                    and _is_os_environ(node.value)
-                    and isinstance(node.slice, ast.Constant)
-                ):
-                    knob = node.slice.value
-            if isinstance(knob, str) and _is_variant_knob(knob):
-                out.append(
-                    self.finding(
-                        ctx, node.lineno,
-                        f"direct read of variant knob {knob!r} outside "
-                        "tuning// pallas_kernels.py forks the env > TunePlan "
-                        "> default precedence (route through "
-                        "KernelVariants.resolve or tuning.plan; deliberate "
-                        "reads: # noqa: variant-env)",
-                        span=_node_span(node),
-                    )
-                )
-        return out
 
 
 @register

@@ -1,17 +1,14 @@
 """Kernel autotuning: candidate space, sweep, and persistent TunePlans.
 
-The per-(geometry, dtype, batch, device, code-rev) replacement for hand-set
-TPU_FRAMEWORK_* variant knobs — see docs/TUNING.md. Env precedence: an
-explicit env var beats a tuned plan beats the code default.
+A Pallas lowering is the code's default (``KernelVariants()``) or a tuned
+plan's per-layer winner (``effective_layer_variants``) — see docs/TUNING.md.
 """
 
 from .autotune import autotune, autotune_model, tune_layer
 from .plan import (
     TunePlan,
-    VARIANT_ENV,
     code_rev,
     effective_layer_variants,
-    explicit_env_knobs,
     load_plan,
     plan_key,
     save_plan,
@@ -22,14 +19,12 @@ from .space import ConvGeometry, candidate_space, conv_geometries, prune_reason
 __all__ = [
     "ConvGeometry",
     "TunePlan",
-    "VARIANT_ENV",
     "autotune",
     "autotune_model",
     "candidate_space",
     "code_rev",
     "conv_geometries",
     "effective_layer_variants",
-    "explicit_env_knobs",
     "load_plan",
     "plan_key",
     "prune_reason",

@@ -24,7 +24,6 @@ from typing import Callable, Dict, Optional, Tuple
 
 from ..observability.trace import span as obs_span
 from ..ops.pallas_kernels import KernelVariants
-from ..ops.vma import interpret_mode
 from ..resilience import chaos
 from ..resilience.policy import Deadline
 from .plan import (
@@ -119,7 +118,7 @@ def _default_timer(
         fn = jax.jit(
             functools.partial(
                 pk.conv2d_pallas, stride=g.stride, padding=g.padding, relu=True,
-                variant=v.conv, row_block=v.row_block, k_block=v.k_block,
+                variant=v.conv, row_block=v.row_block,
             )
         )
     st = amortized_stats(
@@ -140,7 +139,6 @@ def tune_layer(
     warmup: int,
     timer: Timer,
     log: Callable[[str], None],
-    interpret: Optional[bool] = None,
     block_screen: str = "",
 ) -> Tuple[KernelVariants, dict, str]:
     """Sweep one layer; returns (winner, stats, degraded_reason).
@@ -151,12 +149,10 @@ def tune_layer(
     its fp32-oracle screen never spends timing budget and its fate is
     attributable in the plan record (``pruned_reasons``), exactly like a
     geometry prune."""
-    interpret = interpret_mode() if interpret is None else interpret
-    default = KernelVariants().bind(g.out_channels)
+    default = KernelVariants()
     pruned: list = []
     cands = candidate_space(
-        g, interpret=interpret, dtype=dtype,
-        on_prune=lambda v, why: pruned.append(why),
+        g, dtype=dtype, on_prune=lambda v, why: pruned.append(why)
     )
     if block_screen:
         kept = []
@@ -266,7 +262,7 @@ def autotune_model(
         if deadline.expired:
             # Degrade, don't wedge: remaining layers get the defaults and the
             # plan says which and why.
-            layers.append((name, KernelVariants().bind(g.out_channels)))
+            layers.append((name, KernelVariants()))
             stats[name] = {
                 "geometry": g.describe(),
                 "degraded": "deadline expired before sweep",

@@ -2,9 +2,8 @@
 
 A ``TunePlan`` records, for one (device kind, model geometry, batch, dtype,
 code revision) point, the per-layer ``KernelVariants`` the autotuner
-measured fastest — the durable analogue of the hand-set TPU_FRAMEWORK_*
-env knobs (the compilation-cache + sweep pattern of SNIPPETS.md [1] and
-the AutoTVM/Triton-style searchers in PAPERS.md). Plan files hold many
+measured fastest (the compilation-cache + sweep pattern of SNIPPETS.md [1]
+and the AutoTVM/Triton-style searchers in PAPERS.md). Plan files hold many
 plans keyed by the full point, so one ``perf/tune_plan.json`` serves CPU
 CI and the v5e alike.
 
@@ -16,7 +15,7 @@ File format (docs/TUNING.md):
         "<device_kind>|<shape_key>|b<batch>|<dtype>|rev=<code_rev>": {
           "device_kind": ..., "shape_key": ..., "batch": ..., "dtype": ...,
           "code_rev": ..., "degraded": "", "created": "...",
-          "layers":  {"conv1": {"conv": "vcol", "pool": "sep2", ...}},
+          "layers":  {"conv1": {"conv": "vcol", "row_block": 64, "fuse": "none"}},
           "stats":   {"conv1": {"best_ms": ..., "default_ms": ..., ...}}
         }
       }
@@ -24,12 +23,12 @@ File format (docs/TUNING.md):
 
 Staleness: ``code_rev`` hashes the kernel/lowering sources; a plan tuned
 against different kernel code is a MISS (re-sweep), never silently reused.
+So is a plan that names a lowering field or value the code no longer has
+(``k_block``, ``pool``, ``conv="pairs"``: plans written before PR 42).
+Either miss is reported as a ``RuntimeWarning`` that names why.
 
-Precedence (one implementation, here): an EXPLICIT env knob beats the
-tuned plan beats the code default — so a hand A/B (TPU_FRAMEWORK_CONV=taps)
-still pins every layer even when a plan is loaded, and an untuned knob
-falls back exactly as before. scripts/lint.py's ``variant-env`` rule keeps
-stray ``os.environ`` reads of these knobs from forking this chain.
+Which lowering runs (one implementation, ``effective_layer_variants``): the
+loaded plan's per-layer winner, else the code default ``KernelVariants()``.
 """
 
 from __future__ import annotations
@@ -38,23 +37,13 @@ import dataclasses
 import datetime
 import hashlib
 import json
-import os
+import warnings
 from pathlib import Path
 from typing import Dict, Optional, Tuple
 
 from ..ops.pallas_kernels import KernelVariants, LayerVariants
 
 PLAN_VERSION = 1
-
-# Variant-knob field -> env var. The single source the precedence merge and
-# the lint rule's knob census both read.
-VARIANT_ENV = {
-    "conv": "TPU_FRAMEWORK_CONV",
-    "pool": "TPU_FRAMEWORK_POOL",
-    "row_block": "TPU_FRAMEWORK_ROWBLOCK",
-    "k_block": "TPU_FRAMEWORK_KBLOCK",
-    "fuse": "TPU_FRAMEWORK_FUSE",
-}
 
 # Sources whose drift invalidates tuned winners: the kernels themselves,
 # the model chain that decides fusion adjacency, the candidate space, and
@@ -76,14 +65,6 @@ def code_rev() -> str:
     for rel in _REV_FILES:
         h.update((here / rel).read_bytes())
     return h.hexdigest()[:12]
-
-
-def explicit_env_knobs() -> frozenset:
-    """Variant-knob FIELDS the environment explicitly sets right now
-    (non-empty value) — these outrank any tuned plan."""
-    return frozenset(
-        f for f, env in VARIANT_ENV.items() if os.environ.get(env, "").strip()
-    )
 
 
 def _input_dims(model_cfg) -> Tuple[int, int, int]:
@@ -123,17 +104,11 @@ class TunePlan:
             self.device_kind, self.shape_key, self.batch, self.dtype, self.code_rev
         )
 
-    def variants_for(self, name: str, default: Optional[KernelVariants] = None):
-        for n, v in self.layers:
-            if n == name:
-                return v
-        return default if default is not None else KernelVariants()
-
     def plan_hash(self) -> str:
         """10-hex identity of (key, winners) — the CSV row label that
         makes tuned measurements attributable to one exact plan."""
         payload = json.dumps(
-            {"key": self.key, "layers": {n: v._asdict() for n, v in self.layers}},
+            {"key": self.key, "layers": {n: dataclasses.asdict(v) for n, v in self.layers}},
             sort_keys=True,
         )
         return hashlib.sha256(payload.encode()).hexdigest()[:10]
@@ -146,7 +121,7 @@ class TunePlan:
             "dtype": self.dtype,
             "code_rev": self.code_rev,
             "degraded": self.degraded,
-            "layers": {n: v._asdict() for n, v in self.layers},
+            "layers": {n: dataclasses.asdict(v) for n, v in self.layers},
             "stats": self.stats,
         }
 
@@ -212,6 +187,19 @@ def save_plan(plan: TunePlan, path) -> str:
     return plan.key
 
 
+def _plan_or_stale(key: str, obj: dict) -> Optional[TunePlan]:
+    """The plan stored under ``key``, or None with a warning when it names a
+    lowering field or value this code no longer has."""
+    try:
+        return TunePlan.from_obj(obj)
+    except (TypeError, ValueError) as e:
+        warnings.warn(
+            f"tune plan {key} is stale ({e}); not applied",
+            RuntimeWarning, stacklevel=3,
+        )
+        return None
+
+
 def load_plan(
     path,
     *,
@@ -225,7 +213,8 @@ def load_plan(
     """The plan for this exact point, or None (= tune or run untuned).
 
     A different ``code_rev`` is a MISS even when everything else matches —
-    stale winners must never be applied to changed kernels. With
+    stale winners must never be applied to changed kernels — and so is a
+    plan whose winners this code cannot build; both warn, naming why. With
     ``match_any_batch`` a same-device/geometry/dtype plan tuned at another
     batch is accepted as the nearest usable point (the variants space is
     geometry-dominated; the returned plan keeps its own batch so consumers
@@ -236,16 +225,28 @@ def load_plan(
         return None
     rev = rev or code_rev()
     sk = shape_key(model_cfg)
-    exact = plans.get(plan_key(device_kind, sk, batch, dtype, rev))
-    if exact is not None:
-        return TunePlan.from_obj(exact)
-    if not match_any_batch:
-        return None
+    key = plan_key(device_kind, sk, batch, dtype, rev)
+    if key in plans:
+        return _plan_or_stale(key, plans[key])
     prefix = f"{device_kind}|{sk}|b"
     suffix = f"|{dtype}|rev={rev}"
-    for key in sorted(plans):
-        if key.startswith(prefix) and key.endswith(suffix):
-            return TunePlan.from_obj(plans[key])
+    if match_any_batch:
+        for k in sorted(plans):
+            if k.startswith(prefix) and k.endswith(suffix):
+                plan = _plan_or_stale(k, plans[k])
+                if plan is not None:
+                    return plan
+    other_revs = sorted({
+        k.rsplit("|rev=", 1)[1] for k in plans
+        if k.startswith(prefix) and f"|{dtype}|rev=" in k and not k.endswith(suffix)
+    })
+    if other_revs:
+        warnings.warn(
+            f"tune plan for {device_kind}|{sk}|{dtype} is stale: tuned at "
+            f"code_rev {', '.join(other_revs)}, the kernels are at {rev}; "
+            "not applied",
+            RuntimeWarning, stacklevel=2,
+        )
     return None
 
 
@@ -364,21 +365,8 @@ def load_policy(
     return None
 
 
-def effective_layer_variants(
-    plan: TunePlan, base: Optional[KernelVariants] = None
-) -> LayerVariants:
-    """Merge a tuned plan with the environment into the per-layer variants a
-    forward builder closes over. Precedence per knob: explicit env var >
-    tuned plan > code default. ``base`` is the env-resolved variants
-    (``KernelVariants.resolve()``), whose values are authoritative exactly
-    for the knobs the env explicitly sets; unset knobs take the plan's
-    winners. Layers the plan does not cover fall back to ``base`` whole."""
-    base = base if base is not None else KernelVariants.resolve()
-    explicit = explicit_env_knobs()
-    layers = []
-    for name, pv in plan.layers:
-        merged = {
-            f: getattr(base if f in explicit else pv, f) for f in VARIANT_ENV
-        }
-        layers.append((name, KernelVariants(**merged, k_channels=pv.k_channels)))
-    return LayerVariants(layers=tuple(layers), default=base)
+def effective_layer_variants(plan: Optional[TunePlan]) -> LayerVariants:
+    """The lowering a forward builder closes over: each layer's tuned
+    winner, the default ``KernelVariants()`` for every layer the plan does
+    not cover — and for all of them when there is no plan."""
+    return LayerVariants(layers=plan.layers if plan is not None else ())

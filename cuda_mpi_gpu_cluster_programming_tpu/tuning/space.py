@@ -2,13 +2,12 @@
 
 One conv layer (plus the pool it feeds, when adjacent) is the tuning unit —
 exactly the granularity ``ops.pallas_model._conv_then_pool`` lowers at. The
-space is the cartesian product of every ``KernelVariants`` knob, PRUNED to
-combinations that can actually lower and DEDUPED to distinct effective
-lowerings, so the sweep never spends timing budget on a candidate that
-``conv2d_pallas`` would reject (hardware k_block lane rule), silently
-degrade (geometry-dropped k_block — the mislabeled-A/B-row hazard
-``_warn_k_block_dropped`` guards), or alias (row blocks beyond the output
-height all clamp to whole-image programs).
+space is the cartesian product of every ``KernelVariants`` knob (3 conv
+lowerings x 4 row blocks x 3 fusions = 36), PRUNED to combinations that
+can actually lower and DEDUPED to distinct effective lowerings, so the
+sweep never spends timing budget on a candidate that silently falls back
+(g8 at stride 1 runs vcol) or aliases another (row blocks beyond the
+output height all clamp to whole-image programs).
 
 Every prune is attributable: ``prune_reason`` returns WHY a combo is out,
 and ``candidate_space`` can report each drop to a logger — no silent caps.
@@ -21,15 +20,7 @@ import itertools
 from typing import Callable, List, Optional, Tuple
 
 from ..ops.megakernel import block_fusible_reason
-from ..ops.pallas_kernels import KernelVariants
-
-# Knob domains — mirror the env_variant allowed-sets in ops.pallas_kernels
-# (the tuner must not invent values the env interface refuses).
-CONV_VARIANTS = ("taps", "pairs", "fused", "vcol", "g8")
-POOL_VARIANTS = ("sep2", "phases")
-ROW_BLOCKS = (8, 16, 32, 64)
-K_BLOCKS = (0, 64, 128)
-FUSES = ("none", "hpool", "block")
+from ..ops.pallas_kernels import CONV_VARIANTS, FUSES, ROW_BLOCKS, KernelVariants
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,10 +47,6 @@ class ConvGeometry:
     @property
     def out_h(self) -> int:
         return (self.in_h - self.filter_size + 2 * self.padding) // self.stride + 1
-
-    @property
-    def fq(self) -> int:
-        return -(-self.filter_size // self.stride)  # ceil(F/S): taps per axis
 
     @property
     def has_pool(self) -> bool:
@@ -113,9 +100,7 @@ def conv_geometries(model_cfg) -> List[ConvGeometry]:
     return out
 
 
-def prune_reason(
-    v: KernelVariants, g: ConvGeometry, *, interpret: bool, dtype: str = "fp32"
-) -> str:
+def prune_reason(v: KernelVariants, g: ConvGeometry, *, dtype: str = "fp32") -> str:
     """Why this combo is out of the sweep ('' = legal). Mirrors the gates in
     _conv2d_pallas / _conv_then_pool — a candidate this accepts must lower
     and run the variant it claims. ``dtype`` is the sweep's precision
@@ -131,41 +116,25 @@ def prune_reason(
             "hpool fusion needs the in-kernel bias/ReLU epilogue; int8w "
             "applies bias after the per-channel rescale (precision.quantize)"
         )
-    if v.conv == "pairs" and g.fq < 2:
-        return f"pairs degenerates to taps at fq={g.fq} (nothing to pair)"
     if v.conv == "g8" and g.stride < 2:
         return "g8 falls back to vcol at stride 1 (phase packing needs s>=2)"
-    if v.k_block:
-        if v.conv not in ("taps", "vcol"):
-            return f"k_block applies to the taps/vcol path only (conv={v.conv})"
-        if v.k_block % 128 != 0 and not interpret:
-            return f"k_block={v.k_block} cannot lower on hardware (lane tiling 128)"
-        if not (g.out_channels % v.k_block == 0 and g.out_channels > v.k_block):
-            return (
-                f"k_block={v.k_block} dropped at K={g.out_channels} "
-                "(runs unblocked — duplicate of kb=0)"
-            )
     if v.fuse == "hpool":
         if not g.has_pool:
             return "hpool fusion needs an adjacent pool"
         if v.conv not in ("taps", "vcol"):
             return f"hpool fusion supports taps/vcol only (conv={v.conv})"
-        if v.pool != "sep2":
-            return "hpool fusion is the sep2 pool's H stage (pool=phases excluded)"
         if v.row_block < g.out_h:
             return (
                 f"hpool fusion needs the whole image per program "
                 f"(row_block {v.row_block} < ho {g.out_h})"
             )
-        if v.k_block:
-            return "hpool fusion does not compose with k_block"
     if v.fuse == "block":
         # One gate for builder, wrapper, and sweep: ops.megakernel owns
         # the block-fusion geometry rules, so a candidate this accepts is
         # exactly one ops.pallas_model._conv_then_pool would fuse.
         why = block_fusible_reason(
-            variant=v.conv, row_block=v.row_block, k_block=v.k_block,
-            pool=v.pool, out_h=g.out_h, pool_window=g.pool_window,
+            variant=v.conv, row_block=v.row_block, out_h=g.out_h,
+            pool_window=g.pool_window,
         )
         if why:
             return why
@@ -173,38 +142,26 @@ def prune_reason(
 
 
 def _effective_signature(v: KernelVariants, g: ConvGeometry) -> tuple:
-    """What actually lowers: row blocks clamp to the output height, and the
-    pool knob is moot without an adjacent pool."""
-    return (
-        v.conv,
-        v.pool if g.has_pool else "-",
-        min(v.row_block, g.out_h),
-        v.k_block,
-        v.fuse,
-    )
+    """What actually lowers: row blocks clamp to the output height, and g8
+    runs each output phase whole whatever the row block."""
+    rows = g.out_h if v.conv == "g8" else min(v.row_block, g.out_h)
+    return (v.conv, rows, v.fuse)
 
 
 def candidate_space(
     g: ConvGeometry,
     *,
-    interpret: bool,
     dtype: str = "fp32",
     on_prune: Optional[Callable[[KernelVariants, str], None]] = None,
 ) -> List[KernelVariants]:
-    """Every legal, effectively-distinct candidate for this layer, each
-    bound to the layer's K so logs/plans are self-labeling. ``dtype``: the
-    sweep's precision policy (int8w excludes epilogue fusion — see
-    prune_reason)."""
+    """Every legal, effectively-distinct candidate for this layer.
+    ``dtype``: the sweep's precision policy (int8w excludes epilogue
+    fusion — see prune_reason)."""
     seen: set = set()
     out: List[KernelVariants] = []
-    for conv, pool, rb, kb, fuse in itertools.product(
-        CONV_VARIANTS, POOL_VARIANTS, ROW_BLOCKS, K_BLOCKS, FUSES
-    ):
-        v = KernelVariants(
-            conv=conv, pool=pool, row_block=rb, k_block=kb, fuse=fuse,
-            k_channels=g.out_channels,
-        )
-        why = prune_reason(v, g, interpret=interpret, dtype=dtype)
+    for conv, rb, fuse in itertools.product(CONV_VARIANTS, ROW_BLOCKS, FUSES):
+        v = KernelVariants(conv=conv, row_block=rb, fuse=fuse)
+        why = prune_reason(v, g, dtype=dtype)
         if not why:
             sig = _effective_signature(v, g)
             if sig in seen:

@@ -1,11 +1,10 @@
 """Per-layer Pallas-vs-XLA A/B for the v3 tier's five ops (chip evidence).
 
-The v3_pallas full-pass bar (bf16 >= 0.5x v1_jit at b=128) has now missed
-on all three named levers (pairs, rowblock, kblock). This script attributes
-the remaining gap per layer: each of the five ops in forward_blocks12_pallas
-is timed in isolation against the XLA lowering of the same math, same
-shapes, same dtype — so the next lever (or the documented negative) is
-named from measurement, not guesswork.
+This script attributes the v3_pallas tier's gap to v1_jit per layer: each
+of the five ops in forward_blocks12_pallas, under the default lowering
+(``KernelVariants()``), is timed in isolation against the XLA lowering of
+the same math, same shapes, same dtype — so the next lever (or the
+documented negative) is named from measurement, not guesswork.
 
 Usage (real chip):
     python scripts/v3_layer_ab.py [--compute bf16] [--batch 128] [--repeats 100]
@@ -55,7 +54,7 @@ def main() -> int:
     args = ap.parse_args()
     dtype = jnp.bfloat16 if args.compute == "bf16" else jnp.float32
 
-    v = pk.KernelVariants.resolve()
+    v = pk.KernelVariants()
     cfg = BLOCKS12
     params = init_params_deterministic()
     x0 = deterministic_input(batch=args.batch).astype(dtype)
@@ -69,7 +68,7 @@ def main() -> int:
     def conv_pallas(x, w, b, spec):
         return pk.conv2d_pallas(
             x, w, b, stride=spec.stride, padding=spec.padding, relu=True,
-            variant=v.conv, row_block=v.row_block, k_block=v.k_block,
+            variant=v.conv, row_block=v.row_block,
         )
 
     def conv_xla(x, w, b, spec):
@@ -91,7 +90,7 @@ def main() -> int:
         return jnp.maximum(out + b, 0.0).astype(x.dtype)
 
     def pool_pallas(x, spec):
-        return pk.maxpool_pallas(x, window=spec.window, stride=spec.stride, variant=v.pool)
+        return pk.maxpool_pallas(x, window=spec.window, stride=spec.stride)
 
     def pool_xla(x, spec):
         return lax.reduce_window(
@@ -128,8 +127,7 @@ def main() -> int:
 
     plat = jax.devices()[0].platform
     print(f"# v3 per-layer A/B  platform={plat} compute={args.compute} "
-          f"batch={args.batch} conv={v.conv} rb={v.row_block} kb={v.k_block} "
-          f"pool={v.pool}")
+          f"batch={args.batch} {v.label()}")
     print(f"{'layer':<12} {'pallas_ms':>10} {'xla_ms':>8} {'pallas/xla':>10}")
     tot_p = tot_x = 0.0
     for name, fp, fx, xin in stages:

@@ -103,7 +103,7 @@ def test_cross_tier_gap_is_real_and_bounded(workload):
 
 
 @pytest.mark.parametrize("n", [1, 2, 3, 4])
-def test_pallas_tier_sharding_under_g8(workload, monkeypatch, n):
+def test_pallas_tier_sharding_under_g8(workload, n):
     """Pre-adoption guard for the queued g8 chip A/B: shard-vs-single
     under the phase-packed conv.
 
@@ -116,20 +116,20 @@ def test_pallas_tier_sharding_under_g8(workload, monkeypatch, n):
     (``2 * b_pool1``: 20 rows at 3 shards), so every start is even and
     every case here is bitwise (docs/PALLAS_PERF.md).
 
-    The single-device side passes ``variants`` EXPLICITLY: a bare
-    ``jax.jit(forward_blocks12_pallas)`` after the fixture already traced
-    the default variant would hit the jit cache and silently compare g8
-    against vcol — the documented round-3 footgun the build-per-variant
-    workflow exists to avoid (first version of this test did exactly
-    that and produced a last-ulps false alarm)."""
+    Both sides are handed g8 in code: the single-device forward as its
+    ``variants``, the sharded build as a tune plan whose every conv layer
+    won with it."""
     from cuda_mpi_gpu_cluster_programming_tpu.ops import pallas_kernels as pk
+    from cuda_mpi_gpu_cluster_programming_tpu.tuning.plan import TunePlan
 
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "g8")
-    params, x, _, _ = workload
-    single = np.asarray(
-        forward_blocks12_pallas(params, x, variants=pk.KernelVariants(conv="g8"))
+    g8 = pk.KernelVariants(conv="g8")
+    plan = TunePlan(
+        device_kind="cpu", shape_key="blocks12_227x227x3", batch=1, dtype="fp32",
+        code_rev="", layers=(("conv1", g8), ("conv2", g8)),
     )
+    params, x, _, _ = workload
+    single = np.asarray(forward_blocks12_pallas(params, x, variants=g8))
     got = np.asarray(
-        build_forward(REGISTRY["v5_collective"], BLOCKS12, n_shards=n)(params, x)
+        build_forward(REGISTRY["v5_collective"], BLOCKS12, n_shards=n, plan=plan)(params, x)
     )
     np.testing.assert_array_equal(got, single)

@@ -41,28 +41,43 @@ def test_replicated_matches_single(n):
     np.testing.assert_allclose(np.asarray(repl), np.asarray(single), rtol=1e-6)
 
 
-def test_build_forward_rebinds_variant_per_build(monkeypatch):
-    """The round-3 footgun fix: flipping TPU_FRAMEWORK_CONV and re-calling
-    build_forward must yield the new variant (previously the outer jit
-    silently kept the old trace; the supported A/B is build-per-variant)."""
-    import numpy as np
+# The six environment names that chose a Pallas lowering before PR 42, each
+# set to a value other than its old default.
+OLD_LOWERING_ENV = {
+    "TPU_FRAMEWORK_CONV": "taps", "TPU_FRAMEWORK_POOL": "phases",
+    "TPU_FRAMEWORK_ROWBLOCK": "8", "TPU_FRAMEWORK_KBLOCK": "128",
+    "TPU_FRAMEWORK_FUSE": "hpool", "TPU_FRAMEWORK_CHAIN": "pad128",
+}
 
-    from cuda_mpi_gpu_cluster_programming_tpu.configs import REGISTRY, build_forward
-    from cuda_mpi_gpu_cluster_programming_tpu.models.init import (
-        deterministic_input,
-        init_params_deterministic,
+
+@pytest.mark.parametrize("key, n_shards", [
+    ("v3_pallas", 1), ("v6_full_pallas", 1), ("v5_collective", 2),
+])
+def test_build_forward_reads_no_environment(monkeypatch, key, n_shards):
+    """A Pallas lowering is chosen in code (the default or a tune plan), so
+    the program build_forward lowers is the same with the old environment
+    names set as without them."""
+    from cuda_mpi_gpu_cluster_programming_tpu.models.alexnet import Blocks12Config
+    from cuda_mpi_gpu_cluster_programming_tpu.models.alexnet_full import (
+        AlexNetConfig, init_full_deterministic,
     )
 
-    params = init_params_deterministic()
-    x = deterministic_input(batch=1)
-    monkeypatch.delenv("TPU_FRAMEWORK_CONV", raising=False)
-    f_taps = build_forward(REGISTRY["v3_pallas"])
-    out_taps = np.asarray(f_taps(params, x))
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "pairs")
-    f_pairs = build_forward(REGISTRY["v3_pallas"])
-    out_pairs = np.asarray(f_pairs(params, x))
-    # Different lowering, same math (reduction-reorder tolerance).
-    np.testing.assert_allclose(out_pairs, out_taps, rtol=1e-5, atol=1e-5)
-    # The two builds really did trace different variants: their jitted
-    # callables are distinct functions with distinct closed-over variants.
-    assert f_taps is not f_pairs
+    if REGISTRY[key].model == "alexnet_full":
+        cfg = AlexNetConfig(blocks12=Blocks12Config(in_height=99, in_width=99),
+                            fc6=32, fc7=32, num_classes=10)
+        params = init_full_deterministic(cfg)
+    else:
+        cfg = Blocks12Config(in_height=63, in_width=63)
+        params = init_params_deterministic(cfg)
+    x = jax.ShapeDtypeStruct((2, cfg.in_height, cfg.in_width, 3), jax.numpy.float32)
+
+    def lowered():
+        fwd = build_forward(REGISTRY[key], cfg, n_shards=n_shards)
+        return fwd.lower(params, x).as_text()
+
+    for name in OLD_LOWERING_ENV:
+        monkeypatch.delenv(name, raising=False)
+    clean = lowered()
+    for name, value in OLD_LOWERING_ENV.items():
+        monkeypatch.setenv(name, value)
+    assert lowered() == clean

@@ -5,7 +5,9 @@ file of the repo, every ``python -m <module>`` a module that can be found,
 and every ``--option`` handed to the four command-line entry points of the
 package is one their parsers know. And no document names the retired second
 benchmark's environment variables or its round files (PR 29): speed is
-measured by ``benchmark/run.py`` alone.
+measured by ``benchmark/run.py`` alone; nor the environment names that chose
+a Pallas lowering until PR 42: a lowering is the code's default or a tune
+plan's winner.
 """
 
 import argparse
@@ -32,7 +34,9 @@ FENCE = re.compile(r"^```.*?$(.*?)^```\s*$", re.S | re.M)
 SCRIPT = re.compile(r"\bpython3? +(?!-)(\S+\.py)\b")
 MODULE = re.compile(r"\bpython3? +-m +([A-Za-z_][\w.]*)(.*)")
 OPTION = re.compile(r"(?<![\w-])(--[a-z][\w-]*)")
-RETIRED = re.compile("BENCH" + r"_(?:[A-Z][A-Z0-9_]*|r[\d*][\w.*]*)")
+RETIRED = re.compile(
+    "BENCH" + r"_(?:[A-Z][A-Z0-9_]*|r[\d*][\w.*]*)" + "|TPU_FRAMEWORK" + r"_\w*"
+)
 
 
 def _commands(text: str):

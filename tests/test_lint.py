@@ -86,42 +86,6 @@ def test_lint_raw_subprocess_scoped_to_transport_dirs(tmp_path):
     assert proc.returncode == 0, proc.stdout
 
 
-def test_lint_variant_env_reads_scoped_to_tuning(tmp_path):
-    """Direct reads of the Pallas variant knobs fork the env > TunePlan >
-    default precedence (docs/TUNING.md): flagged everywhere except tuning/
-    and ops/pallas_kernels.py; writes and noqa'd reads are fine."""
-    src = (
-        "import os\n"
-        "a = os.environ.get('TPU_FRAMEWORK_CONV')\n"        # read: flagged
-        "b = os.environ['TPU_FRAMEWORK_KBLOCK']\n"          # read: flagged
-        "c = os.getenv('PALLAS_WHATEVER_KNOB')\n"           # read: flagged
-        "os.environ['TPU_FRAMEWORK_CONV'] = 'taps'\n"       # write: fine
-        "d = os.environ.get('OTHER_CONFIG')\n"              # other var: fine
-        "e = os.environ.get('TPU_FRAMEWORK_FUSE')  # noqa: variant-env\n"
-    )
-    bad = tmp_path / "stray.py"
-    bad.write_text(src)
-    proc = subprocess.run(
-        [sys.executable, str(ROOT / "scripts" / "lint.py"), str(bad)],
-        capture_output=True, text=True, timeout=60,
-    )
-    assert proc.returncode == 1
-    assert proc.stdout.count("[variant-env]") == 3, proc.stdout
-    for lineno in (":2:", ":3:", ":4:"):
-        assert lineno in proc.stdout
-
-    # The sanctioned readers are exempt wholesale.
-    for rel in ("tuning", ):
-        scoped = tmp_path / rel / "reader.py"
-        scoped.parent.mkdir(exist_ok=True)
-        scoped.write_text(src.replace("  # noqa: variant-env", ""))
-        proc = subprocess.run(
-            [sys.executable, str(ROOT / "scripts" / "lint.py"), str(scoped)],
-            capture_output=True, text=True, timeout=60,
-        )
-        assert "[variant-env]" not in proc.stdout, proc.stdout
-
-
 def test_lint_atomic_write_rule(tmp_path):
     """Truncating writes of run artifacts are flagged everywhere except the
     sanctioned journal/checkpoint helpers; appends, non-artifacts and noqa'd

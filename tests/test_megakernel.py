@@ -47,14 +47,11 @@ def seeded():
 
 def test_block_fusible_reason_is_the_single_gate():
     """Every illegal combo names its reason; the legal regime is ''."""
-    ok = dict(variant="vcol", row_block=64, k_block=0, pool="sep2",
-              out_h=9, pool_window=3)
+    ok = dict(variant="vcol", row_block=64, out_h=9, pool_window=3)
     assert mk.block_fusible_reason(**ok) == ""
     for patch, needle in (
         (dict(variant="g8"), "taps/vcol"),
-        (dict(pool="phases"), "sep2"),
         (dict(row_block=8), "whole image"),
-        (dict(k_block=128), "k_block"),
         (dict(pool_window=0), "adjacent pool"),
     ):
         why = mk.block_fusible_reason(**{**ok, **patch})
@@ -136,15 +133,15 @@ def test_candidate_space_offers_and_prunes_block_attributably():
     for g in ts.conv_geometries(SMALL):
         dropped = []
         cands = ts.candidate_space(
-            g, interpret=True, on_prune=lambda v, why: dropped.append((v, why))
+            g, on_prune=lambda v, why: dropped.append((v, why))
         )
         blocks = [v for v in cands if v.fuse == "block"]
         assert blocks, f"no block candidate at {g.name}"
         assert all(v.row_block >= g.out_h for v in blocks)
         assert all(
             not mk.block_fusible_reason(
-                variant=v.conv, row_block=v.row_block, k_block=v.k_block,
-                pool=v.pool, out_h=g.out_h, pool_window=g.pool_window,
+                variant=v.conv, row_block=v.row_block, out_h=g.out_h,
+                pool_window=g.pool_window,
             )
             for v in blocks
         )
@@ -155,9 +152,9 @@ def test_candidate_space_offers_and_prunes_block_attributably():
         assert block_drops and all(block_drops), f"unattributed prune at {g.name}"
         all_block_drops.extend(block_drops)
     # The fusibility gate's own words reach the prune log: conv1's small
-    # row_blocks fail the whole-image requirement, k_block never composes.
+    # row_blocks fail the whole-image requirement, g8 never fuses.
     assert any("whole image" in w for w in all_block_drops)
-    assert any("k_block" in w for w in all_block_drops)
+    assert any("taps/vcol" in w for w in all_block_drops)
 
 
 def test_tune_layer_block_screen_prunes_before_timing():
@@ -178,7 +175,7 @@ def test_tune_layer_block_screen_prunes_before_timing():
     reason = "fuse=block gate-pruned for int8w: block1 rel 0.2 > 0.06"
     winner, stats, degraded = tune_layer(
         g, dtype="fp32", batch=2, deadline=Deadline.after(60), repeats=1,
-        warmup=0, timer=timer, log=lambda s: None, interpret=True,
+        warmup=0, timer=timer, log=lambda s: None,
         block_screen=reason,
     )
     assert not degraded
@@ -189,7 +186,7 @@ def test_tune_layer_block_screen_prunes_before_timing():
     timed.clear()
     tune_layer(
         g, dtype="fp32", batch=2, deadline=Deadline.after(60), repeats=1,
-        warmup=0, timer=timer, log=lambda s: None, interpret=True,
+        warmup=0, timer=timer, log=lambda s: None,
     )
     assert any(v.fuse == "block" for v in timed)
 

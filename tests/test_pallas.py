@@ -140,131 +140,15 @@ def test_maxpool_pallas_even_window_leftover():
     assert jnp.array_equal(got, want)
 
 
-def test_conv_fused_variant_matches_taps(monkeypatch):
-    """TPU_FRAMEWORK_CONV=fused (im2col single-matmul) agrees with the
-    default tap-loop variant to fp32 reduction-reorder tolerance. For
-    DIRECT conv2d_pallas calls the variant is a static jit arg resolved
-    per call, so flipping the env re-traces; callers with their own outer
-    jit bake the variant at their trace time (the supported A/B is one
-    process per variant — see pallas_kernels/_conv_variant)."""
-    import numpy as np
-
-    from cuda_mpi_gpu_cluster_programming_tpu.ops.pallas_kernels import conv2d_pallas
-
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 31, 31, 3))
-    w = jax.random.normal(jax.random.PRNGKey(1), (11, 11, 3, 16)) * 0.1
-    b = jnp.ones((16,)) * 0.1
-
-    monkeypatch.delenv("TPU_FRAMEWORK_CONV", raising=False)
-    taps = np.asarray(conv2d_pallas(x, w, b, stride=4, relu=True))
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "fused")
-    fused = np.asarray(conv2d_pallas(x, w, b, stride=4, relu=True))
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "")  # set-but-empty = default
-    empty = np.asarray(conv2d_pallas(x, w, b, stride=4, relu=True))
-
-    assert taps.shape == fused.shape == (2, 6, 6, 16)
-    np.testing.assert_allclose(fused, taps, rtol=1e-5, atol=1e-6)
-    np.testing.assert_array_equal(empty, taps)  # same variant, same bits
+def _conv(x, w, b, v=pk.KernelVariants(), **kw):
+    """conv2d_pallas under the lowering ``v`` names."""
+    return pk.conv2d_pallas(x, w, b, variant=v.conv, row_block=v.row_block, **kw)
 
 
-def test_pool_variant_sep2_matches_phases(monkeypatch):
-    """The sep2 (separable two-stage) default and the phase-stack variant
-    are BITWISE equal: max is associative and exact in floating point, so
-    the stage split cannot change results. Covers odd H/W, both pool
-    geometries of the model, and an uneven window=2 case."""
-    for shape, window, stride in (
-        ((2, 55, 55, 96), 3, 2),
-        ((2, 27, 27, 256), 3, 2),
-        ((1, 11, 13, 4), 2, 2),
-        ((1, 9, 9, 8), 3, 3),
-    ):
-        x = jax.random.normal(jax.random.PRNGKey(7), shape, jnp.float32)
-        monkeypatch.delenv("TPU_FRAMEWORK_POOL", raising=False)
-        sep2 = np.asarray(pk.maxpool_pallas(x, window=window, stride=stride))
-        monkeypatch.setenv("TPU_FRAMEWORK_POOL", "phases")
-        phases = np.asarray(pk.maxpool_pallas(x, window=window, stride=stride))
-        np.testing.assert_array_equal(sep2, phases)
-
-
-def test_pool_variant_rejects_unknown(monkeypatch):
-    monkeypatch.setenv("TPU_FRAMEWORK_POOL", "quadtree")
-    x = jnp.ones((1, 8, 8, 4))
-    with pytest.raises(ValueError, match="TPU_FRAMEWORK_POOL"):
-        pk.maxpool_pallas(x, window=3, stride=2)
-
-
-def test_chain_variant_pad128_bitwise(monkeypatch):
-    """TPU_FRAMEWORK_CHAIN=pad128 (channel axis padded 96->128 through
-    block 1) vs the plain chain. Padded lanes carry exact zeros through
-    conv1 and contribute exact +0.0 terms to conv2's accumulation, so on
-    TPU — where Mosaic's matmul accumulation order is fixed — the two
-    chains are BITWISE equal (verified on a real v5e). XLA's CPU matmul
-    retiles the larger contraction across its threadpool (the 8-device
-    test mesh splits it further), reassociating the sum by ~1 ulp, so the
-    interpreter-mode assertion is tight-allclose instead. Measured on
-    v5e: no wall-clock delta (docs/PALLAS_PERF.md); kept as a layout
-    experiment."""
-    from cuda_mpi_gpu_cluster_programming_tpu.ops import pallas_model as pm
-
-    p = init_params_deterministic()
-    x = deterministic_input(batch=2)
-    monkeypatch.delenv("TPU_FRAMEWORK_CHAIN", raising=False)
-    plain = np.asarray(pm.forward_blocks12_pallas(p, x))
-    monkeypatch.setenv("TPU_FRAMEWORK_CHAIN", "pad128")
-    padded = np.asarray(pm.forward_blocks12_pallas(p, x))
-    if jax.default_backend() == "tpu":
-        np.testing.assert_array_equal(plain, padded)
-    else:
-        np.testing.assert_allclose(padded, plain, rtol=1e-6, atol=2e-5)
-
-
-def test_chain_variant_rejects_unknown(monkeypatch):
-    from cuda_mpi_gpu_cluster_programming_tpu.ops import pallas_model as pm
-
-    monkeypatch.setenv("TPU_FRAMEWORK_CHAIN", "pad256")
-    with pytest.raises(ValueError, match="TPU_FRAMEWORK_CHAIN"):
-        pm.forward_blocks12_pallas(init_params_deterministic(), deterministic_input(batch=1))
-
-
-def test_conv_pairs_variant_matches_taps(monkeypatch):
-    """TPU_FRAMEWORK_CONV=pairs (adjacent-tap fusion, doubled contraction)
-    agrees with the tap-loop default to reduction-reorder tolerance, at
-    both an odd fq (stride 4, fq=3: pairs + leftover tap) and an even fq
-    (stride 1 f=4, fq=4: pairs only), and is deterministic within-variant."""
-    import numpy as np
-
-    from cuda_mpi_gpu_cluster_programming_tpu.ops.pallas_kernels import conv2d_pallas
-
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 31, 31, 3))
-    w = jax.random.normal(jax.random.PRNGKey(1), (11, 11, 3, 16)) * 0.1
-    b = jnp.ones((16,)) * 0.1
-
-    monkeypatch.delenv("TPU_FRAMEWORK_CONV", raising=False)
-    taps = np.asarray(conv2d_pallas(x, w, b, stride=4, relu=True))
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "pairs")
-    pairs = np.asarray(conv2d_pallas(x, w, b, stride=4, relu=True))
-    pairs2 = np.asarray(conv2d_pallas(x, w, b, stride=4, relu=True))
-    np.testing.assert_allclose(pairs, taps, rtol=1e-5, atol=1e-6)
-    np.testing.assert_array_equal(pairs, pairs2)  # deterministic
-
-    # even fq: stride 1, F=4 -> fq=4, two pairs per row, no leftover tap
-    w4 = jax.random.normal(jax.random.PRNGKey(2), (4, 4, 3, 8)) * 0.1
-    b4 = jnp.zeros((8,))
-    monkeypatch.delenv("TPU_FRAMEWORK_CONV", raising=False)
-    taps4 = np.asarray(conv2d_pallas(x, w4, b4, stride=1, padding=1))
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "pairs")
-    pairs4 = np.asarray(conv2d_pallas(x, w4, b4, stride=1, padding=1))
-    np.testing.assert_allclose(pairs4, taps4, rtol=1e-5, atol=1e-6)
-
-
-def test_conv_row_block_variant_bitwise(monkeypatch):
-    """TPU_FRAMEWORK_ROWBLOCK changes only the grid tiling, not the
-    per-output accumulation order -> every setting is bitwise identical
-    (default is 64 since the 2026-07-31 on-chip sweep adopted it)."""
-    import numpy as np
-
-    from cuda_mpi_gpu_cluster_programming_tpu.ops.pallas_kernels import conv2d_pallas
-
+def test_conv_row_block_variant_bitwise():
+    """The row block changes only the grid tiling, not the per-output
+    accumulation order -> every setting is bitwise identical (default is
+    64 since the 2026-07-31 on-chip sweep adopted it)."""
     # Tall-narrow input: ho = (267-11)/4+1 = 65, so 8/16/32/64 produce
     # genuinely different grids (nbh 9/5/3/2) — a square 67x67 input
     # (ho=15) silently clamped 16/32/64 to the same single-block lowering
@@ -272,62 +156,49 @@ def test_conv_row_block_variant_bitwise(monkeypatch):
     x = jax.random.normal(jax.random.PRNGKey(5), (1, 267, 31, 3))
     w = jax.random.normal(jax.random.PRNGKey(6), (11, 11, 3, 16)) * 0.1
     b = jnp.zeros((16,))
-    monkeypatch.delenv("TPU_FRAMEWORK_ROWBLOCK", raising=False)
-    rdef = np.asarray(conv2d_pallas(x, w, b, stride=4))
-    for rb in ("8", "16", "32"):
-        monkeypatch.setenv("TPU_FRAMEWORK_ROWBLOCK", rb)
-        np.testing.assert_array_equal(np.asarray(conv2d_pallas(x, w, b, stride=4)), rdef)
+    rdef = np.asarray(_conv(x, w, b, stride=4))
+    for rb in (8, 16, 32):
+        got = _conv(x, w, b, pk.KernelVariants(row_block=rb), stride=4)
+        np.testing.assert_array_equal(np.asarray(got), rdef)
 
 
-def test_conv_vcol_variant_matches_taps(monkeypatch):
-    """TPU_FRAMEWORK_CONV=vcol (in-kernel im2col over the qw taps — the
-    adopted round-5 default) agrees with the tap-loop lowering to
-    reduction-reorder tolerance at conv1-like (stride 4, fq=3) and
-    conv2-like (stride 1, fq=5) geometry, and is deterministic
-    within-variant."""
-    import numpy as np
-
-    from cuda_mpi_gpu_cluster_programming_tpu.ops.pallas_kernels import conv2d_pallas
-
+def test_conv_vcol_variant_matches_taps():
+    """vcol (in-kernel im2col over the qw taps — the adopted round-5
+    default) agrees with the tap-loop lowering to reduction-reorder
+    tolerance at conv1-like (stride 4, fq=3) and conv2-like (stride 1,
+    fq=5) geometry, and is deterministic within-variant."""
+    taps_v, vcol_v = pk.KernelVariants(conv="taps"), pk.KernelVariants(conv="vcol")
     x = jax.random.normal(jax.random.PRNGKey(11), (2, 31, 31, 3))
     w = jax.random.normal(jax.random.PRNGKey(12), (11, 11, 3, 16)) * 0.1
     b = jnp.ones((16,)) * 0.1
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "taps")
-    taps = np.asarray(conv2d_pallas(x, w, b, stride=4, relu=True))
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "vcol")
-    vcol = np.asarray(conv2d_pallas(x, w, b, stride=4, relu=True))
-    vcol2 = np.asarray(conv2d_pallas(x, w, b, stride=4, relu=True))
+    taps = np.asarray(_conv(x, w, b, taps_v, stride=4, relu=True))
+    vcol = np.asarray(_conv(x, w, b, vcol_v, stride=4, relu=True))
+    vcol2 = np.asarray(_conv(x, w, b, vcol_v, stride=4, relu=True))
     np.testing.assert_allclose(vcol, taps, rtol=1e-5, atol=1e-6)
     np.testing.assert_array_equal(vcol, vcol2)  # deterministic
 
     w5 = jax.random.normal(jax.random.PRNGKey(13), (5, 5, 3, 8)) * 0.1
     b5 = jnp.zeros((8,))
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "taps")
-    taps5 = np.asarray(conv2d_pallas(x, w5, b5, stride=1, padding=2))
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "vcol")
-    vcol5 = np.asarray(conv2d_pallas(x, w5, b5, stride=1, padding=2))
+    taps5 = np.asarray(_conv(x, w5, b5, taps_v, stride=1, padding=2))
+    vcol5 = np.asarray(_conv(x, w5, b5, vcol_v, stride=1, padding=2))
     np.testing.assert_allclose(vcol5, taps5, rtol=1e-5, atol=1e-6)
 
 
-def test_conv_g8_variant_matches_taps(monkeypatch):
-    """TPU_FRAMEWORK_CONV=g8 (phase-packed conv: space-to-depth at g=2s,
-    2x2 output phases on separate grid programs, host-side de-interleave)
-    agrees with the tap-loop lowering at strided geometries — conv1-like
-    (s=4, odd output), s=2 with padding, s=3 — and falls back to vcol at
-    s=1, where there are no phases to pack."""
-    import numpy as np
-
-    from cuda_mpi_gpu_cluster_programming_tpu.ops.pallas_kernels import conv2d_pallas
-
+def test_conv_g8_variant_matches_taps():
+    """g8 (phase-packed conv: space-to-depth at g=2s, 2x2 output phases on
+    separate grid programs, host-side de-interleave) agrees with the
+    tap-loop lowering at strided geometries — conv1-like (s=4, odd
+    output), s=2 with padding, s=3 — and falls back to vcol at s=1, where
+    there are no phases to pack."""
+    taps_v, g8_v = pk.KernelVariants(conv="taps"), pk.KernelVariants(conv="g8")
     x = jax.random.normal(jax.random.PRNGKey(14), (2, 37, 37, 3))
     for f, s, pad, relu in [(11, 4, 0, True), (5, 2, 1, True), (7, 3, 2, False)]:
         w = jax.random.normal(jax.random.PRNGKey(15), (f, f, 3, 16)) * 0.1
         b = jnp.ones((16,)) * 0.1
-        monkeypatch.setenv("TPU_FRAMEWORK_CONV", "taps")
-        taps = np.asarray(conv2d_pallas(x, w, b, stride=s, padding=pad, relu=relu))
-        monkeypatch.setenv("TPU_FRAMEWORK_CONV", "g8")
-        g8 = np.asarray(conv2d_pallas(x, w, b, stride=s, padding=pad, relu=relu))
-        g8b = np.asarray(conv2d_pallas(x, w, b, stride=s, padding=pad, relu=relu))
+        kw = dict(stride=s, padding=pad, relu=relu)
+        taps = np.asarray(_conv(x, w, b, taps_v, **kw))
+        g8 = np.asarray(_conv(x, w, b, g8_v, **kw))
+        g8b = np.asarray(_conv(x, w, b, g8_v, **kw))
         assert g8.shape == taps.shape
         np.testing.assert_allclose(g8, taps, rtol=1e-5, atol=1e-6)
         np.testing.assert_array_equal(g8, g8b)  # deterministic
@@ -335,16 +206,14 @@ def test_conv_g8_variant_matches_taps(monkeypatch):
     # s=1: g8 degrades to the vcol lowering (bitwise same as explicit vcol)
     w1 = jax.random.normal(jax.random.PRNGKey(16), (3, 3, 3, 8)) * 0.1
     b1 = jnp.zeros((8,))
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "vcol")
-    vc = np.asarray(conv2d_pallas(x, w1, b1, stride=1, padding=1))
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "g8")
-    g1 = np.asarray(conv2d_pallas(x, w1, b1, stride=1, padding=1))
+    vc = np.asarray(_conv(x, w1, b1, pk.KernelVariants(conv="vcol"), stride=1, padding=1))
+    g1 = np.asarray(_conv(x, w1, b1, g8_v, stride=1, padding=1))
     np.testing.assert_array_equal(g1, vc)
 
 
-def test_conv_hpool_fusion_bitwise(monkeypatch):
+def test_conv_hpool_fusion_bitwise():
     """conv2d_pallas(hpool=...) + maxpool_pallas_w (the fused separable
-    pool, round-5 TPU_FRAMEWORK_FUSE=hpool lever) is bitwise identical to
+    pool, the round-5 fuse="hpool" lever) is bitwise identical to
     conv then maxpool_pallas: the in-kernel H stage pools the CASTED
     value — exactly the tensor the unfused sep2 H stage reads back — and
     max is exact. Covers fp32 + bf16, relu, odd pooled heights, and both
@@ -389,7 +258,7 @@ def test_conv_hpool_fusion_bitwise(monkeypatch):
     w = jnp.ones((11, 11, 3, 16))
     b = jnp.zeros((16,))
     with pytest.raises(ValueError, match="taps/vcol"):
-        pk.conv2d_pallas(x, w, b, stride=4, variant="pairs", hpool=(3, 2))
+        pk.conv2d_pallas(x, w, b, stride=4, variant="g8", hpool=(3, 2))
     with pytest.raises(ValueError, match="whole image"):
         pk.conv2d_pallas(
             x, w, b, stride=4, variant="vcol", row_block=8, hpool=(3, 2)
@@ -405,44 +274,21 @@ def test_conv_hpool_fusion_bitwise(monkeypatch):
     np.testing.assert_array_equal(base, fz)
 
 
-def test_conv_k_block_variant_bitwise(monkeypatch):
-    """TPU_FRAMEWORK_KBLOCK splits the filter bank across grid programs
-    (the round-4 verdict's named third lever): outputs are disjoint and the
-    per-element accumulation order is unchanged -> bitwise identical to the
-    unblocked default, including shapes where K % k_block != 0 (lever
-    silently off) and K == k_block (single block)."""
-    import numpy as np
-
-    from cuda_mpi_gpu_cluster_programming_tpu.ops.pallas_kernels import conv2d_pallas
-
-    x = jax.random.normal(jax.random.PRNGKey(7), (2, 31, 31, 8))
-    w = jax.random.normal(jax.random.PRNGKey(8), (5, 5, 8, 128)) * 0.1
-    b = jax.random.normal(jax.random.PRNGKey(9), (128,)) * 0.1
-    monkeypatch.delenv("TPU_FRAMEWORK_KBLOCK", raising=False)
-    ref = np.asarray(conv2d_pallas(x, w, b, stride=1, padding=2, relu=True))
-    for kb in ("64", "128"):
-        monkeypatch.setenv("TPU_FRAMEWORK_KBLOCK", kb)
-        got = np.asarray(conv2d_pallas(x, w, b, stride=1, padding=2, relu=True))
-        np.testing.assert_array_equal(got, ref)
-    # K=96 (conv1-like) not divisible by 64: the lever degrades to off.
-    w96 = jax.random.normal(jax.random.PRNGKey(10), (5, 5, 8, 96)) * 0.1
-    b96 = jnp.zeros((96,))
-    monkeypatch.delenv("TPU_FRAMEWORK_KBLOCK", raising=False)
-    ref96 = np.asarray(conv2d_pallas(x, w96, b96, stride=1))
-    monkeypatch.setenv("TPU_FRAMEWORK_KBLOCK", "64")
-    np.testing.assert_array_equal(
-        np.asarray(conv2d_pallas(x, w96, b96, stride=1)), ref96
-    )
-
-
-def test_conv_variant_rejects_unknown(monkeypatch):
-    import pytest
-
-    from cuda_mpi_gpu_cluster_programming_tpu.ops.pallas_kernels import conv2d_pallas
-
-    x = jnp.ones((1, 15, 15, 3))
-    w = jnp.ones((3, 3, 3, 4))
-    b = jnp.zeros((4,))
-    monkeypatch.setenv("TPU_FRAMEWORK_ROWBLOCK", "12")
-    with pytest.raises(ValueError, match="TPU_FRAMEWORK_ROWBLOCK"):
-        conv2d_pallas(x, w, b, stride=1)
+@pytest.mark.parametrize(
+    "field, value",
+    [("conv", "pairs"), ("conv", "fused"), ("conv", "bogus"), ("row_block", 12),
+     ("fuse", "bogus")],
+)
+def test_a_lowering_outside_the_kept_set_is_refused(field, value):
+    """A KernelVariants names only the kept lowerings (taps/vcol/g8, row
+    blocks 8-64, fuse none/hpool/block): anything else — the chip's deleted
+    losers and plain typos alike — raises where it is built, naming the
+    field. A conv name handed straight to the kernel raises too, where it
+    used to run taps."""
+    with pytest.raises(ValueError, match=f"KernelVariants.{field}"):
+        pk.KernelVariants(**{field: value})
+    if field == "conv":
+        x = jnp.ones((1, 15, 15, 3))
+        w = jnp.ones((3, 3, 3, 4))
+        with pytest.raises(ValueError, match=repr(value)):
+            pk.conv2d_pallas(x, w, jnp.zeros((4,)), stride=1, variant=value)

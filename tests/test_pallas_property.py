@@ -10,7 +10,6 @@ hand-picked. Deadlines are disabled: interpreter-mode pallas_call tracing
 is slow and measured in seconds, not milliseconds.
 """
 
-import os
 
 import jax
 import jax.numpy as jnp
@@ -43,30 +42,20 @@ def _rand(key, shape):
     stride=st.integers(1, 4),
     padding=st.integers(0, 3),
     relu=st.booleans(),
-    variant=st.sampled_from(["taps", "fused", "vcol", "pairs", "g8"]),
+    variant=st.sampled_from(pk.CONV_VARIANTS),
 )
 def test_conv_matches_reference(h, w_dim, c, k, f, stride, padding, relu, variant):
     # Reject (regenerate) degenerate geometries instead of silently
     # passing; unreachable with today's ranges, load-bearing if widened.
     assume(h + 2 * padding >= f and w_dim + 2 * padding >= f)
-    # Plain env set/restore per example (hypothesis rejects function-scoped
-    # fixtures; the variant env is read at trace time of the direct call).
-    saved = os.environ.get("TPU_FRAMEWORK_CONV")  # noqa: variant-env
-    os.environ["TPU_FRAMEWORK_CONV"] = variant
-    try:
-        _check_conv(h, w_dim, c, k, f, stride, padding, relu)
-    finally:
-        if saved is None:
-            os.environ.pop("TPU_FRAMEWORK_CONV", None)
-        else:
-            os.environ["TPU_FRAMEWORK_CONV"] = saved
-
-
-def _check_conv(h, w_dim, c, k, f, stride, padding, relu):
+    v = pk.KernelVariants(conv=variant)
     x = _rand(h * 31 + w_dim, (1, h, w_dim, c))
     w = _rand(f, (f, f, c, k)) * 0.2
     b = _rand(k, (k,)) * 0.1
-    got = np.asarray(pk.conv2d_pallas(x, w, b, stride=stride, padding=padding, relu=relu))
+    got = np.asarray(pk.conv2d_pallas(
+        x, w, b, stride=stride, padding=padding, relu=relu, variant=v.conv,
+        row_block=v.row_block,
+    ))
     want = np.asarray(ops.conv2d(x, w, b, stride=stride, padding=padding))
     if relu:
         want = np.maximum(want, 0.0)

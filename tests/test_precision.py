@@ -329,10 +329,10 @@ def test_int8w_candidate_space_excludes_epilogue_fusion():
     from cuda_mpi_gpu_cluster_programming_tpu.tuning import space as ts
 
     for g in ts.conv_geometries(SMALL):
-        fp32_fuses = {v.fuse for v in ts.candidate_space(g, interpret=True)}
+        fp32_fuses = {v.fuse for v in ts.candidate_space(g)}
         int8_fuses = {
             v.fuse
-            for v in ts.candidate_space(g, interpret=True, dtype="int8w")
+            for v in ts.candidate_space(g, dtype="int8w")
         }
         assert "hpool" in fp32_fuses
         assert "block" in fp32_fuses

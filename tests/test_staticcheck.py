@@ -762,7 +762,7 @@ def test_cli_list_rules_has_all_new_codes():
         "key-reuse", "jit-in-loop", "check-vma-disabled", "implicit-upcast",
         "stale-device-set", "span-write-in-timed-region",
         "blocking-socket-call-in-timed-region",
-        "raw-subprocess", "atomic-write", "variant-env", "deprecated",
+        "raw-subprocess", "atomic-write", "deprecated",
     ):
         assert code in proc.stdout, code
 

@@ -1,7 +1,8 @@
 """Autotuner tier-1 tests (CPU interpret mode): candidate-space pruning,
 plan cache round-trip, stale-key invalidation, resilience degradation
-(Deadline abort / chaos compile faults -> default plan), env precedence,
-and per-layer variant threading through the Pallas forward.
+(Deadline abort / chaos compile faults -> default plan), plans written
+before PR 42 loading as stale, and per-layer variant threading through the
+Pallas forward.
 
 The sweep itself is exercised with an injected deterministic timer (the
 real amortized-timing path is covered by the run CLI --tune test and the
@@ -9,6 +10,10 @@ production timing suite) so these stay fast and order-stable.
 """
 
 import json
+import os
+import subprocess
+import sys
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -61,33 +66,43 @@ def test_conv_geometries_carry_trailing_pools():
     assert g2.out_h == 27
 
 
-def test_space_prunes_geometry_dropped_k_block():
-    """conv1's K=96 divides by neither 64 nor 128 -> every k_block candidate
-    would run unblocked (the mislabeled-A/B hazard); none may survive."""
-    g1 = geometries()["conv1"]
-    cands = ts.candidate_space(g1, interpret=True)
-    assert cands and all(v.k_block == 0 for v in cands)
-    # conv2's K=256 admits both on interpret mode; hardware refuses 64
-    # (lane tiling 128) rather than silently dropping it.
-    g2 = geometries()["conv2"]
-    kbs_interp = {v.k_block for v in ts.candidate_space(g2, interpret=True)}
-    assert kbs_interp == {0, 64, 128}
-    kbs_hw = {v.k_block for v in ts.candidate_space(g2, interpret=False)}
-    assert kbs_hw == {0, 128}
+# conv1 (11x11 s4, 55 output rows, a pool after it): taps and vcol each run
+# rb 8/16/32/64 unfused plus hpool and block at rb 64 (the whole image) = 6
+# each; g8 takes no fusion and runs each output phase whole, so its four
+# row blocks are one lowering = 1. conv2 (5x5 s1, 27 rows): g8 falls back
+# to vcol at stride 1 = 0; taps and vcol each run rb 8/16/32 (64 clamps to
+# 32) unfused plus hpool and block at rb 32 = 5 each.
+KEPT_BY_HAND = {"conv1": 6 + 6 + 1, "conv2": 5 + 5 + 0}
+
+
+@pytest.mark.parametrize("layer", ["conv1", "conv2"])
+def test_candidate_space_is_the_kept_lowerings(layer):
+    """3 conv lowerings x 4 row blocks x 3 fusions = 36 candidates before
+    pruning, every one inside the kept domains; the pruned list is the
+    count worked out by hand above."""
+    g = geometries()[layer]
+    dropped = []
+    cands = ts.candidate_space(g, on_prune=lambda v, why: dropped.append(v))
+    assert len(cands) + len(dropped) == 36
+    for v in cands + dropped:
+        assert v.conv in ("taps", "vcol", "g8")
+        assert v.row_block in (8, 16, 32, 64)
+        assert v.fuse in ("none", "hpool", "block")
+    assert len(cands) == KEPT_BY_HAND[layer]
+    assert len(set(cands)) == len(cands)
 
 
 def test_space_prunes_variant_geometry_mismatches():
     gs = geometries()
-    c2 = ts.candidate_space(gs["conv2"], interpret=True)
+    c2 = ts.candidate_space(gs["conv2"])
     assert all(v.conv != "g8" for v in c2)  # stride 1: g8 falls back to vcol
-    c1 = ts.candidate_space(gs["conv1"], interpret=True)
+    c1 = ts.candidate_space(gs["conv1"])
     assert any(v.conv == "g8" for v in c1)  # stride 4: g8 is a real candidate
     # hpool candidates obey the production gate exactly.
     for cands, g in ((c1, gs["conv1"]), (c2, gs["conv2"])):
         for v in cands:
             if v.fuse == "hpool":
-                assert v.conv in ("taps", "vcol") and v.pool == "sep2"
-                assert v.row_block >= g.out_h and v.k_block == 0
+                assert v.conv in ("taps", "vcol") and v.row_block >= g.out_h
 
 
 def test_space_dedupes_clamped_row_blocks_and_reports_prunes():
@@ -95,27 +110,12 @@ def test_space_dedupes_clamped_row_blocks_and_reports_prunes():
     only one such candidate may survive — and every drop is reported."""
     g2 = geometries()["conv2"]  # out_h = 27: rb 32 and 64 alias
     dropped = []
-    cands = ts.candidate_space(
-        g2, interpret=True, on_prune=lambda v, why: dropped.append(why)
-    )
+    cands = ts.candidate_space(g2, on_prune=lambda v, why: dropped.append(why))
     taps_plain = [
-        v.row_block for v in cands
-        if (v.conv, v.pool, v.k_block, v.fuse) == ("taps", "sep2", 0, "none")
+        v.row_block for v in cands if (v.conv, v.fuse) == ("taps", "none")
     ]
     assert sorted(taps_plain) in ([8, 16, 32], [8, 16, 64])
     assert dropped and any("duplicate effective lowering" in w for w in dropped)
-
-
-def test_variants_repr_states_requested_vs_effective_k_block():
-    v = pk.KernelVariants(conv="taps", k_block=128).bind(96)
-    assert v.effective_k_block == 0
-    assert "kb=128->0(K=96)" in repr(v)
-    ok = pk.KernelVariants(conv="taps", k_block=128).bind(256)
-    assert ok.effective_k_block == 128
-    assert "kb=128 " in ok.label() + " "
-    # Unbound variants can't judge geometry: requested value stands.
-    assert pk.KernelVariants(k_block=64).effective_k_block == 64
-    assert v.knobs() == pk.KernelVariants(conv="taps", k_block=128)
 
 
 # ----------------------------------------------------------------- plan ---
@@ -132,7 +132,6 @@ def fake_timer(table=None):
         # Stable, distinct: favor vcol/sep2/none deterministically.
         ms = 10.0
         ms -= 3.0 * (v.conv == "vcol")
-        ms -= 1.0 * (v.pool == "sep2")
         ms -= 0.5 * (v.fuse == "none")
         ms -= 0.1 * v.row_block / 64.0
         return ms, 0.01, 3
@@ -151,7 +150,7 @@ def test_autotune_cache_round_trip(tmp_path):
     assert not cached and timer.calls and not plan.degraded
     assert [n for n, _ in plan.layers] == ["conv1", "conv2"]
     for _n, v in plan.layers:
-        assert v.conv == "vcol" and v.pool == "sep2"  # the scripted winner
+        assert v.conv == "vcol" and v.fuse == "none"  # the scripted winner
     obj = json.loads(path.read_text())
     assert plan.key in obj["plans"]
     # Second call: loaded from disk, NO sweep (the acceptance criterion).
@@ -225,7 +224,7 @@ def test_deadline_abort_falls_back_to_default_plan(tmp_path):
     assert plan.degraded and "deadline" in plan.degraded
     default = pk.KernelVariants()
     for name, v in plan.layers:
-        assert v.knobs() == default, (name, v)
+        assert v == default, (name, v)
         assert "degraded" in plan.stats[name]
 
 
@@ -253,33 +252,71 @@ def test_chaos_compile_faults_degrade_not_wedge(tmp_path, monkeypatch):
         )
         assert "all" in plan2.degraded and "failed" in plan2.degraded
         for _n, v in plan2.layers:
-            assert v.knobs() == pk.KernelVariants()
+            assert v == pk.KernelVariants()
     finally:
         chaos.reset()
 
 
-def test_env_precedence_explicit_env_beats_plan(tmp_path, monkeypatch):
-    """Explicit env knob > tuned plan > default — per knob, not whole-set."""
-    plan = autotune_model(
-        SMALL, dtype="fp32", batch=2,
-        timer=fake_timer(lambda g, v: 1.0 if (v.conv, v.row_block) == ("taps", 16) else 5.0),
-        log=lambda s: None, device_kind="cpu",
-    )
-    assert dict(plan.layers)["conv1"].conv == "taps"
-    for var in ("TPU_FRAMEWORK_CONV", "TPU_FRAMEWORK_POOL", "TPU_FRAMEWORK_ROWBLOCK",
-                "TPU_FRAMEWORK_KBLOCK", "TPU_FRAMEWORK_FUSE"):
-        monkeypatch.delenv(var, raising=False)
-    # No env: plan wins every knob.
-    lv = tp.effective_layer_variants(plan)
-    assert lv.for_layer("conv1").conv == "taps"
-    assert lv.for_layer("conv1").row_block == 16
-    # Explicit env pins ITS knob on every layer; the plan keeps the rest.
-    monkeypatch.setenv("TPU_FRAMEWORK_CONV", "fused")
-    lv2 = tp.effective_layer_variants(plan)
-    assert lv2.for_layer("conv1").conv == "fused"
-    assert lv2.for_layer("conv1").row_block == 16  # still the tuned value
-    # Unknown layers fall back to the env-resolved base whole.
-    assert lv2.for_layer("conv9").conv == "fused"
+def _write_plan_file(path, layers, rev=None):
+    """A plan file holding one plan for (cpu, SMALL, b2, fp32) whose layers
+    are stored exactly as given — the shape an older writer left them."""
+    rev = rev or tp.code_rev()
+    key = tp.plan_key("cpu", tp.shape_key(SMALL), 2, "fp32", rev)
+    path.write_text(json.dumps({"version": 1, "plans": {key: {
+        "device_kind": "cpu", "shape_key": tp.shape_key(SMALL), "batch": 2,
+        "dtype": "fp32", "code_rev": rev, "layers": layers,
+    }}}))
+
+
+COMMITTED_PLAN = Path(__file__).resolve().parent.parent / "perf" / "tune_plan.json"
+
+
+@pytest.mark.parametrize("case", ["committed", "conv_pairs", "k_block_128"])
+def test_a_plan_written_before_loads_as_stale(tmp_path, case):
+    """A plan file from before PR 42 — the committed one as it stands
+    (every layer names k_block and pool, and its code_rev is old), a plan
+    whose winner is conv="pairs", a plan with k_block 128 — loads as a
+    miss that names why, with no exception, and the defaults run. The
+    committed case also goes through run.py --plan."""
+    if case == "committed":
+        (stored,) = json.loads(COMMITTED_PLAN.read_text())["plans"].values()
+        blocks, batch = BLOCKS12, stored["batch"]
+        # at this code's rev: a plan tuned at another one
+        with pytest.warns(RuntimeWarning, match="stale: tuned at code_rev"):
+            assert tp.load_plan(COMMITTED_PLAN, device_kind="cpu",
+                                model_cfg=blocks, dtype="fp32", batch=batch) is None
+        # at its own rev: winners this code cannot build
+        with pytest.warns(RuntimeWarning, match="is stale .*(pool|k_block)"):
+            assert tp.load_plan(COMMITTED_PLAN, device_kind="cpu", model_cfg=blocks,
+                                dtype="fp32", batch=batch,
+                                rev=stored["code_rev"]) is None
+        proc = subprocess.run(
+            [sys.executable, "-m", "cuda_mpi_gpu_cluster_programming_tpu.run",
+             "--config", "v3_pallas", "--batch", "1", "--repeats", "1",
+             "--warmup", "0", "--plan", str(COMMITTED_PLAN)],
+            capture_output=True, text=True, timeout=600, cwd=COMMITTED_PLAN.parents[1],
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        )
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert "stale: tuned at code_rev" in proc.stderr
+        assert "Tune plan: none matching" in proc.stdout
+        # the default lowering's golden first values (deterministic init)
+        (line,) = [ln for ln in proc.stdout.splitlines() if ln.startswith("Final Output (")]
+        first = [float(t) for t in line.split(":", 1)[1].split()[:3]]
+        np.testing.assert_allclose(first, [29.2932, 25.9153, 23.3255], rtol=2e-5)
+        return
+    path = tmp_path / "plan.json"
+    if case == "conv_pairs":
+        layers, why = {"conv1": {"conv": "pairs", "row_block": 64, "fuse": "none"}}, "pairs"
+    else:
+        layers, why = {"conv2": {"conv": "vcol", "row_block": 32, "k_block": 128,
+                                 "fuse": "none"}}, "k_block"
+    _write_plan_file(path, layers)
+    with pytest.warns(RuntimeWarning, match=f"is stale .*{why}"):
+        assert tp.load_plan(path, device_kind="cpu", model_cfg=SMALL,
+                            dtype="fp32", batch=2) is None
+    # the builder, handed no plan, runs the defaults on every layer
+    assert tp.effective_layer_variants(None).for_layer("conv1") == pk.KernelVariants()
 
 
 # ------------------------------------------------------------ threading ---
@@ -304,8 +341,8 @@ def test_layer_variants_thread_through_forward():
     )
     lv = pk.LayerVariants(
         layers=(
-            ("conv1", pk.KernelVariants(conv="taps", row_block=16).bind(96)),
-            ("conv2", pk.KernelVariants(conv="vcol", fuse="hpool").bind(256)),
+            ("conv1", pk.KernelVariants(conv="taps", row_block=16)),
+            ("conv2", pk.KernelVariants(conv="vcol", fuse="hpool")),
         ),
         default=pk.KernelVariants(),
     )
