@@ -26,12 +26,15 @@ What happens to ``x`` depends on where it lives, which the code can see:
 - a concrete array on ONE device (committed or not): the step program
   itself scatters it. The program's argument is a batch-sharded global
   array whose shard on the holder is ``x`` as it stands (no copy) and whose
-  other shards are stand-ins that are never read; inside, the holder does
-  ``cast_in``, cuts the ``H`` real rows into the ``n`` row blocks in the
-  compute type and sends block ``j`` to device ``j`` by a ``ppermute``
-  with itself as the one source; a block short of ``b0`` rows (the last,
-  where ``n*b0 > H``) is zero-padded where it arrives, so no zero row
-  travels. One
+  other shards are stand-ins that are never read; inside, the holder cuts
+  the ``H`` real rows into the ``n`` row blocks one by one, its own last,
+  casts each to the compute type in the same pass (``cast_in``) and sends
+  block ``j`` to device ``j`` by a ``ppermute`` with itself as the one
+  source; the block of a device two hops off that two of the holder's
+  neighbours reach in one (a v5e 2x2's diagonal) goes first, in parts that
+  those neighbours forward (:func:`scatter_routes`); a block short of
+  ``b0`` rows (the last, where ``n*b0 > H``) is zero-padded where it
+  arrives, so no zero row travels. One
   dispatch a step, and nothing is replicated (a jitted multi-device program
   treats a single-device argument as replicated: the whole float32 batch
   went from the holder to every device before every step);
@@ -141,6 +144,57 @@ def _pool_hvalid(x, *, window: int, stride: int):
     return ops.maxpool(x, window=window, stride=stride)
 
 
+def _coords(device):
+    """Where ``device`` sits in the chips' interconnect, or None (the CPU's
+    devices have no coordinates)."""
+    return getattr(device, "coords", None)
+
+
+def _hops(a, b) -> Optional[int]:
+    """Links between two devices by their coordinates, or None."""
+    ca, cb = _coords(a), _coords(b)
+    if ca is None or cb is None:
+        return None
+    return sum(abs(p - q) for p, q in zip(ca, cb))
+
+
+def scatter_routes(devices, src: int) -> list:
+    """``[(j, vias)]``: each device ``j`` that the holder ``src`` sends a row
+    block to, in the order the holder sends them, and the neighbours its
+    block goes through. A device two hops off that two neighbours of the
+    holder each reach in one (on a v5e 2x2, the diagonal) gets its block in
+    parts, one through each neighbour, and first: a link serves its
+    transfers in the order it is handed them, so the parts reach the
+    neighbours before their own blocks take the links, and each of the
+    holder's two links carries 81.5 of the 163 rows that leave it where one
+    carried 99. Every other block goes straight (``vias == ()``), in mesh
+    order, as every block does where the devices have no coordinates."""
+    devices = list(devices)
+    routes = []
+    for j in range(len(devices)):
+        if j == src:
+            continue
+        vias = ()
+        if _hops(devices[src], devices[j]) == 2:
+            vias = tuple(
+                k for k in range(len(devices))
+                if _hops(devices[src], devices[k]) == 1 and _hops(devices[k], devices[j]) == 1
+            )
+        routes.append((j, vias if len(vias) > 1 else ()))
+    return sorted(routes, key=lambda route: not route[1])
+
+
+def _from_wire(wire: jax.Array, shape) -> jax.Array:
+    """The (N, r, W, C) block of ``shape`` back from the form it travels in
+    (``cut`` in :func:`build_sharded_forward`)."""
+    nb, r, w, c = shape
+    return wire.reshape(r, c, -1, nb)[:, :, :w].transpose(3, 0, 2, 1)
+
+
+# Rows of a TPU memory tile: W is padded to a multiple of it on the wire.
+WIRE_TILE_ROWS = 8
+
+
 def build_sharded_forward(
     model_cfg: Blocks12Config = BLOCKS12,
     n_shards: int = 1,
@@ -167,8 +221,8 @@ def build_sharded_forward(
 
     ``compute_dtype``: the compute type of ``configs.build_forward``'s bf16
     mode. Parameters and ``x`` are cast to it inside the program under the
-    ``cast_in`` scope (``x`` on its holder, before it is cut, so that the
-    blocks travel in the compute type), and the output comes back float32.
+    ``cast_in`` scope (``x`` on its holder, each block as it is cut, so that
+    the blocks travel in the compute type), and the output comes back float32.
     ``None`` computes in the dtype of what arrives.
 
     ``with_digests``: additionally return a per-stage activation digest
@@ -331,6 +385,24 @@ def build_sharded_forward(
         with scopes.cast_in():
             return x.astype(x_dtype)
 
+    def cut(x, lo: int, hi: int, wire: bool):
+        """Rows ``lo:hi`` of ``x`` (N, H, W, C) in the compute type, under
+        ``cast_in`` (the compiler folds the cast into the cut). ``wire``: as
+        the block leaves its holder, the batch last and W padded to whole
+        tiles, flat to (r*C*W', N). That is the order a TPU holds the float32
+        batch in (the batch in lanes; H, C, then W above it), so one fusion
+        reads and casts the rows, a pad fills W, and the collective sends the
+        result as it stands; a 4-D block went out in a layout the compiler
+        picked to spare its padding, one more pass over it on the holder."""
+        with scopes.cast_in():
+            rows = x[:, lo:hi].astype(x_dtype or x.dtype)
+            if not wire:
+                return rows
+            nb, r, w, c = rows.shape
+            wp = -(-w // WIRE_TILE_ROWS) * WIRE_TILE_ROWS
+            t = jnp.pad(jnp.transpose(rows, (1, 3, 2, 0)), ((0, 0), (0, 0), (0, wp - w), (0, 0)))
+            return t.reshape(r * c * wp, nb)
+
     def pad_rows(x):
         pad = h_pad - x.shape[1]
         if pad:
@@ -365,19 +437,53 @@ def build_sharded_forward(
         return jax.jit(fwd)
 
     def scatter_step_from(src: int):
+        routes = [*scatter_routes(mesh.devices.flat, src), (src, ())]
+
         def scatter_body(xl):
             # xl (N, H, W, C): the batch on device src, its stand-in elsewhere
-            xl = cast_x(xl)
-            h = xl.shape[1]
+            nb, h, w, c = xl.shape
+            arrived = [None] * n
+            first_hops = []  # the relayed parts as the neighbours received them
+            relayed = 0
+
+            def take(lo, hi, wire):
+                # rows lo:hi cut and cast, each block in a pass of its own:
+                # the next is cut from an x that waits on this one, so the
+                # casts keep the routes' order and the compiler cannot merge
+                # them back into one cast of the whole x
+                nonlocal xl
+                xl, block = lax.optimization_barrier((xl, cut(xl, lo, hi, wire)))
+                return block
+
             with scopes.scatter():
-                arrived = []
-                for j in range(n):
+                for j, vias in routes:
                     # the real rows of block j alone; zeros only where it lands
-                    block = xl[:, min(j * b0, h) : min((j + 1) * b0, h)]
-                    if j != src and block.shape[1]:
-                        block = lax.ppermute(block, AXIS, perm=[(src, j)])
+                    lo, hi = min(j * b0, h), min((j + 1) * b0, h)
+                    if j == src or hi == lo:
+                        block = take(lo, hi, wire=False)
+                    elif vias:
+                        cuts = [lo + (hi - lo) * i // len(vias) for i in range(len(vias) + 1)]
+                        parts = []
+                        for via, a, b in zip(vias, cuts, cuts[1:]):
+                            part = lax.ppermute(take(a, b, wire=True), AXIS, perm=[(src, via)])
+                            first_hops.append(part)
+                            part = lax.ppermute(part, AXIS, perm=[(via, j)])
+                            parts.append(_from_wire(part, (nb, b - a, w, c)))
+                        block = jnp.concatenate(parts, axis=1)
+                        relayed += nb * (hi - lo) * w * c
+                    else:
+                        # the holder's links take this block once the relayed
+                        # parts are through them: a link sends in the order
+                        # it is handed its transfers
+                        wire, *_ = lax.optimization_barrier((take(lo, hi, wire=True), *first_hops))
+                        wire = lax.ppermute(wire, AXIS, perm=[(src, j)])
+                        block = _from_wire(wire, (nb, hi - lo, w, c))
                     short = b0 - block.shape[1]
-                    arrived.append(jnp.pad(block, ((0, 0), (0, short), (0, 0), (0, 0))))
+                    arrived[j] = jnp.pad(block, ((0, 0), (0, short), (0, 0), (0, 0)))
+                # set as the program is traced, as sharding.halo_bytes is
+                metrics_registry().gauge(RELAYED_BYTES).set(
+                    relayed * np.dtype(x_dtype or xl.dtype).itemsize
+                )
                 return lax.select_n(lax.axis_index(AXIS), *arrived)
 
         scatter = shard_map(
@@ -406,6 +512,7 @@ PLACED_CALLS = "sharding.scatter.placed_calls"
 IN_GRAPH_CALLS = "sharding.scatter.in_graph_calls"
 SCATTERED_BYTES = "sharding.scatter.bytes_off_holder"
 HALO_BYTES = "sharding.halo_bytes"
+RELAYED_BYTES = "sharding.scatter.relayed_bytes"
 
 
 class RowScatteredForward:
@@ -427,7 +534,10 @@ class RowScatteredForward:
     (x a tracer, counted once per trace, or laid out some other way). The
     gauge ``sharding.halo_bytes`` is set as a step program is traced: the
     bytes one interior chip receives by halo in one step
-    (``breakdown.comm_compute_breakdown``'s total for that batch and type).
+    (``breakdown.comm_compute_breakdown``'s total for that batch and type);
+    ``sharding.scatter.relayed_bytes`` as a scatter program is traced: the
+    bytes of the real rows that reach their owner through a neighbour in one
+    call (:func:`scatter_routes`; 0 where the devices have no coordinates).
     """
 
     def __init__(self, *, whole, step, scatter_step, rows: NamedSharding, b0: int, x_dtype):

@@ -8,6 +8,7 @@ transports, and batch > 1.
 """
 
 import dataclasses
+import re
 
 import jax
 import numpy as np
@@ -22,6 +23,7 @@ from cuda_mpi_gpu_cluster_programming_tpu.models import (
     random_input,
 )
 from cuda_mpi_gpu_cluster_programming_tpu.parallel.plan import make_shard_plan, owned_range
+from cuda_mpi_gpu_cluster_programming_tpu.parallel import sharded
 from cuda_mpi_gpu_cluster_programming_tpu.parallel.sharded import build_sharded_forward
 
 
@@ -282,9 +284,11 @@ def test_step_program_moves_only_row_blocks(abstract):
     """The point of PR 26, held in the compiled step program at four shards
     and the real geometry: what crosses between devices on the input side is
     one row block in the compute type to each other device, each by a
-    collective-permute with the holder as its one source; the whole input is
-    never gathered or replicated (each device's parameter is the float32
-    batch, real on the holder alone). And ``fwd.lower`` of the public
+    collective-permute with the holder as its one source, in mesh order (the
+    CPU's devices have no coordinates, so no block is relayed); the whole
+    input is never gathered, replicated or cast (each device's parameter is
+    the float32 batch, real on the holder alone, and each block is cut from
+    it before it is cast). And ``fwd.lower`` of the public
     signature (concrete arguments, or bare ``ShapeDtypeStruct``s as the
     benchmark's readers pass) gives that program, with the halos, the
     convolutions and the scopes."""
@@ -299,24 +303,30 @@ def test_step_program_moves_only_row_blocks(abstract):
         x = jax.ShapeDtypeStruct(x.shape, jnp.float32)
     fwd = build_sharded_forward(BLOCKS12, n_shards=4, compute_dtype=jnp.bfloat16)
     lowered = fwd.lower(params, x)
+    program = lowered.as_text()
     # what is sent, as the program is written (the CPU's compiler widens a
     # bf16 collective to float32; the TPU's sends it as it is)
     sent = re.findall(
         r"stablehlo\.(collective_permute|all_gather|all_to_all|all_reduce)\S*\(.*?"
         r"(?:source_target_pairs = dense<(\[\[.*?\]\])>.*?)?: \(tensor<(\w+)>\)",
-        lowered.as_text(),
+        program,
     )
-    of_the_input = [(op, pairs, t) for op, pairs, t in sent if t.endswith("x227x3xbf16")]
-    assert [(op, pairs) for op, pairs, _t in of_the_input[:3]] == [
+    of_the_input = [(op, pairs, t) for op, pairs, t in sent if re.fullmatch(r"\[\[0, \d\]\]", pairs)]
+    assert [(op, pairs) for op, pairs, _t in of_the_input] == [
         ("collective_permute", f"[[0, {j}]]") for j in (1, 2, 3)
     ], sent
-    # 64-row blocks of the 227 real rows: the last carries 35, and no zero row
-    assert [t for _op, _pairs, t in of_the_input[:3]] == [
-        "2x64x227x3xbf16", "2x64x227x3xbf16", "2x35x227x3xbf16"
+    # 64-row blocks of the 227 real rows, the last of 35 and no zero row, in
+    # the compute type as they travel: the batch last, W padded to 232
+    assert [t for _op, _pairs, t in of_the_input] == [
+        f"{rows * 3 * 232}x2xbf16" for rows in (64, 64, 35)
     ], sent
-    # the rest are conv1's halo rows: nothing else of the input moves
-    assert all(int(t.split("x")[1]) < 10 for _op, _pairs, t in of_the_input[3:]), sent
+    # the rest of the input that moves is conv1's halo rows
+    halos = [t for _op, _pairs, t in sent if t.endswith("x227x3xbf16")]
+    assert halos and all(int(t.split("x")[1]) < 10 for t in halos), sent
     assert not [t for _op, _pairs, t in sent if t.endswith("x227x3xf32")], sent
+    # each block is cut from the float32 batch, then cast: no convert of the whole
+    assert "x227x227x3xbf16" not in program
+    assert not re.search(r"stablehlo\.convert .*tensor<2x227x227x3xf32>", program)
     text = lowered.compile().as_text()
     entry = text[text.index("ENTRY") :]
     inputs = re.findall(r"= (\w+\[[\d,]*\])\S* parameter\(\d+\), sharding=\{devices=", entry)
@@ -324,7 +334,60 @@ def test_step_program_moves_only_row_blocks(abstract):
     assert "collective-permute" in text and "convolution" in text
     for layer in ("conv1", "pool1", "conv2", "pool2"):
         assert f"halo.{layer}" in text
-    assert "/cast_in/" in text and "/scatter/" in text
+    assert "/scatter/cast_in/" in text
+
+
+V5E_2X2 = {0: (0, 0, 0), 1: (1, 0, 0), 2: (0, 1, 0), 3: (1, 1, 0)}
+
+
+def test_scatter_routes():
+    """On a v5e 2x2 (mesh devices 0-3 at (0,0) (1,0) (0,1) (1,1)) the
+    holder's diagonal is two hops off and both its neighbours reach it in
+    one: its block goes first, in parts through both; the others go
+    straight, in mesh order. Without coordinates every block goes straight."""
+
+    class Chip:
+        def __init__(self, i):
+            self.coords = V5E_2X2[i]
+
+    v5e = [Chip(i) for i in range(4)]
+    assert sharded.scatter_routes(v5e, 0) == [(3, (1, 2)), (1, ()), (2, ())]
+    assert sharded.scatter_routes(v5e, 1) == [(2, (0, 3)), (0, ()), (3, ())]
+    assert sharded.scatter_routes(v5e, 3) == [(0, (1, 2)), (1, ()), (2, ())]
+    assert sharded.scatter_routes(jax.devices()[:4], 0) == [(1, ()), (2, ()), (3, ())]
+    assert sharded.scatter_routes(jax.devices()[:1], 0) == []
+
+
+@pytest.mark.parametrize("compute", ["fp32", "bf16"])
+def test_relayed_scatter_equals_in_graph_bitwise(monkeypatch, compute):
+    """With the CPU's devices placed as a v5e 2x2's, the diagonal's block
+    reaches it in two parts through the holder's neighbours, and the output
+    is the in-graph program's, bit for bit. ``sharding.scatter.relayed_bytes``
+    reads the real rows relayed, set as the program is traced; 0 where the
+    devices have no coordinates."""
+    import jax.numpy as jnp
+
+    from cuda_mpi_gpu_cluster_programming_tpu.observability.metrics import registry
+
+    params, x = _small_case()
+    dtype = jnp.bfloat16 if compute == "bf16" else None
+    fwd = build_sharded_forward(SMALL, n_shards=4, compute_dtype=dtype)
+    want = np.asarray(fwd.whole(params, x))
+    got = fwd(params, x)
+    np.testing.assert_array_equal(np.asarray(got), want)
+    assert registry().summary()[sharded.RELAYED_BYTES] == 0
+
+    monkeypatch.setattr(sharded, "_coords", lambda d: V5E_2X2.get(d.id))
+    fwd = build_sharded_forward(SMALL, n_shards=4, compute_dtype=dtype)
+    text = fwd.lower(params, x).as_text()
+    pairs = re.findall(r"source_target_pairs = dense<(\[\[\d, \d\]\])>", text)
+    assert pairs[:4] == ["[[0, 1]]", "[[1, 3]]", "[[0, 2]]", "[[2, 3]]"], pairs
+    got = fwd(params, x)
+    assert got.dtype == np.float32
+    np.testing.assert_array_equal(np.asarray(got), want)
+    # 63 rows in blocks of 16: the diagonal's 15 real rows, 2 images of 63 x 3
+    itemsize = 2 if compute == "bf16" else 4
+    assert registry().summary()[sharded.RELAYED_BYTES] == 15 * 2 * 63 * 3 * itemsize
 
 
 def test_scatter_counters():
@@ -333,7 +396,6 @@ def test_scatter_counters():
     import jax.numpy as jnp
 
     from cuda_mpi_gpu_cluster_programming_tpu.observability.metrics import registry
-    from cuda_mpi_gpu_cluster_programming_tpu.parallel import sharded
 
     def read():
         got = registry().summary()
@@ -408,7 +470,6 @@ def test_halo_bytes_gauge_at_four_shards():
     import jax.numpy as jnp
 
     from cuda_mpi_gpu_cluster_programming_tpu.observability.metrics import registry
-    from cuda_mpi_gpu_cluster_programming_tpu.parallel import sharded
 
     params = jax.eval_shape(init_params_deterministic)
     x = jax.ShapeDtypeStruct((128, 227, 227, 3), jnp.float32)
@@ -424,7 +485,6 @@ def test_scatter_sends_only_real_rows_at_four_shards():
     import jax.numpy as jnp
 
     from cuda_mpi_gpu_cluster_programming_tpu.observability.metrics import registry
-    from cuda_mpi_gpu_cluster_programming_tpu.parallel import sharded
 
     kp, kx = jax.random.split(jax.random.PRNGKey(3))
     params, x = init_params_random(kp), random_input(kx, batch=1)
